@@ -57,17 +57,21 @@ def _gather_block_major(kv: jax.Array, block_ids: jax.Array) -> jax.Array:
     return jnp.moveaxis(jnp.take(kv, block_ids, axis=1), 1, 0)
 
 
-def supports_pinned_host(device: Optional[jax.Device] = None) -> bool:
-    """Whether the backend exposes a pinned_host memory space (TPU yes,
-    CPU tests typically yes on recent jaxlib, but never assumed)."""
-    try:
-        device = device or jax.devices()[0]
-        return any(
-            memory.kind == "pinned_host"
-            for memory in device.addressable_memories()
-        )
-    except Exception:
-        return False
+def supports_pinned_host(device: jax.Device) -> bool:
+    """Whether ``device`` exposes a pinned_host memory space (the TPU
+    and CPU backends both do)."""
+    return any(
+        memory.kind == "pinned_host"
+        for memory in device.addressable_memories()
+    )
+
+
+def _to_pinned_host(array: jax.Array) -> jax.Array:
+    """Async transfer into the pinned_host space of the array's own
+    device(s): same sharding, host memory kind."""
+    return jax.device_put(
+        array, array.sharding.with_memory_kind("pinned_host")
+    )
 
 
 class KVCachePool:
@@ -90,16 +94,12 @@ class KVCachePool:
             self.kv = jax.device_put(jnp.zeros(shape, dtype), sharding)
         else:
             self.kv = jnp.zeros(shape, dtype)
-        self._pinned_host = supports_pinned_host(
+        # Whether this pool's device exposes a pinned_host memory space
+        # (the staging engine's fast-path gate).  Fixed at construction:
+        # a pinned transfer that fails raises, it does not degrade.
+        self.pinned_host = supports_pinned_host(
             next(iter(self.kv.devices()))
         )
-
-    @property
-    def pinned_host(self) -> bool:
-        """Whether this pool's device exposes a pinned_host memory
-        space (the staging engine's fast-path gate; flips off after a
-        failed transfer so the probe is never retried per job)."""
-        return self._pinned_host
 
     @property
     def block_nbytes(self) -> int:
@@ -124,13 +124,8 @@ class KVCachePool:
         """
         ids = jnp.asarray(np.asarray(block_ids, dtype=np.int32))
         gathered = _gather(self.kv, ids)
-        if self._pinned_host:
-            try:
-                gathered = jax.device_put(
-                    gathered, jax.memory.TransferToMemoryKind("pinned_host")
-                )
-            except Exception:
-                self._pinned_host = False
+        if self.pinned_host:
+            gathered = _to_pinned_host(gathered)
         return np.asarray(jax.device_get(gathered))
 
     def stage_gather_pinned(self, block_ids: Sequence[int]) -> jax.Array:
@@ -141,15 +136,12 @@ class KVCachePool:
         previous slot's file I/O (the staging engine's double-buffered
         pipeline) and force only at submit time.  Raises when the
         backend has no pinned_host space — callers gate on
-        :attr:`pinned_host` and fall back to :meth:`gather_block_major`.
+        :attr:`pinned_host` and use :meth:`gather_block_major` there.
         """
-        if not self._pinned_host:
+        if not self.pinned_host:
             raise RuntimeError("device exposes no pinned_host memory space")
         ids = jnp.asarray(np.asarray(block_ids, dtype=np.int32))
-        gathered = _gather_block_major(self.kv, ids)
-        return jax.device_put(
-            gathered, jax.memory.TransferToMemoryKind("pinned_host")
-        )
+        return _to_pinned_host(_gather_block_major(self.kv, ids))
 
     def gather_block_major(self, block_ids: Sequence[int]) -> np.ndarray:
         """Block-major host gather ``[n, L, 2, bs, h, d]`` — the file
@@ -158,13 +150,8 @@ class KVCachePool:
         backend supports it, plain transfer otherwise."""
         ids = jnp.asarray(np.asarray(block_ids, dtype=np.int32))
         gathered = _gather_block_major(self.kv, ids)
-        if self._pinned_host:
-            try:
-                gathered = jax.device_put(
-                    gathered, jax.memory.TransferToMemoryKind("pinned_host")
-                )
-            except Exception:
-                self._pinned_host = False
+        if self.pinned_host:
+            gathered = _to_pinned_host(gathered)
         return np.asarray(jax.device_get(gathered))
 
     def scatter_block_major(

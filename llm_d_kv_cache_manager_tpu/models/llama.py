@@ -193,32 +193,38 @@ def _logits(x: jnp.ndarray, params: Params) -> jnp.ndarray:
     )
 
 
-def _prefill_attention(q, k, v, cfg: LlamaConfig, q_offset=0, use_flash=True):
+def _prefill_attention(
+    q, k, v, cfg: LlamaConfig, q_offset=0, use_flash=True, interpret=False
+):
     """Dense for short sequences, blockwise flash for long (static
     shapes make the switch a trace-time decision).
 
     Flash routing on TPU: wide q tiles (full/paged prefill) go to the
-    Pallas kernel (ops/flash_pallas.py, ~2x the scan op's throughput on
-    8k prefill); short continuation suffixes keep the scan op, whose
-    cost is dominated by the K/V read either way.  ``use_flash=False``
-    forces dense: neither flash op has a custom VJP, so under ``grad``
-    they keep the same O(Tq*Tk) residuals as dense while serializing
-    the backward chunk-by-chunk — training paths should differentiate
-    through the fused dense einsum instead.
+    Pallas kernel (ops/flash_pallas.py); short continuation suffixes
+    keep the scan op, whose cost is dominated by the K/V read either
+    way.  The choice is made from what the trace can see — backend,
+    shapes, the kernel's VMEM bound — and chip_smoke.py asserts which
+    one the lowered program holds.  ``interpret=True`` runs the Pallas
+    kernel in interpret mode wherever it would run compiled on TPU (CPU
+    tests of the TPU routing).  ``use_flash=False`` forces dense:
+    neither flash op has a custom VJP, so under ``grad`` they keep the
+    same O(Tq*Tk) residuals as dense while serializing the backward
+    chunk-by-chunk — training paths should differentiate through the
+    fused dense einsum instead.
     """
     if use_flash and k.shape[1] >= cfg.flash_attention_min_len:
         if (
             q.shape[1] >= cfg.flash_attention_min_len
             and isinstance(q_offset, int)
-            and jax.default_backend() == "tpu"
+            and (interpret or jax.default_backend() == "tpu")
             and flash_pallas.fits_vmem(
                 k.shape[1], k.shape[-1], jnp.dtype(k.dtype).itemsize
             )
         ):
-            # Beyond the VMEM budget the scan op streams K/V from HBM
+            # Beyond the VMEM bound the scan op streams K/V from HBM
             # at any length (e.g. 32k+ prompts).
             return flash_pallas.flash_gqa_attention_pallas(
-                q, k, v, q_offset=q_offset
+                q, k, v, q_offset=q_offset, interpret=interpret
             )
         return flash_gqa_attention(q, k, v, q_offset=q_offset)
     return causal_gqa_attention(q, k, v, q_offset=q_offset)
@@ -323,6 +329,7 @@ def prefill_paged(
     kv_pool: jnp.ndarray,
     block_table: jnp.ndarray,
     cfg: LlamaConfig,
+    interpret: bool = False,
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Prefill writing per-layer K/V into the paged pool.
 
@@ -330,6 +337,7 @@ def prefill_paged(
     overwritten — give padded sequences scratch block ids).
     kv_pool: [L, num_blocks, 2, block_size, Hkv, Dh] (KVCachePool.kv).
     block_table: [B, T/block_size] pool block ids for each sequence.
+    ``interpret``: Pallas kernels in interpret mode (CPU tests).
     Returns (logits [B, T, V], new kv_pool).
     """
     B, T = tokens.shape
@@ -340,7 +348,7 @@ def prefill_paged(
         lp, kv_layer = inputs
         h = _rms_norm(x, lp["ln1"])
         q, k, v = _qkv(h, lp, positions, cfg.rope_theta)
-        attn = _prefill_attention(q, k, v, cfg)
+        attn = _prefill_attention(q, k, v, cfg, interpret=interpret)
         x = x + jnp.einsum("bthk,hkd->btd", attn, lp["wo"])
         x = x + _mlp(_rms_norm(x, lp["ln2"]), lp)
         kv_layer = _scatter_kv_blocks(
@@ -359,6 +367,7 @@ def prefill_continue(
     block_table: jnp.ndarray,
     prefix_len: int,
     cfg: LlamaConfig,
+    interpret: bool = False,
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Prefill only the uncached suffix of a prompt (prefix-cache hit).
 
@@ -374,6 +383,7 @@ def prefill_continue(
     block_table: [B, (prefix_len + Ts) / block_size] — prefix blocks
     first, then the blocks to write.  ``prefix_len`` is static
     (% block_size == 0); one compile per distinct padded prefix length.
+    ``interpret``: Pallas kernels in interpret mode (CPU tests).
     Returns (suffix logits [B, Ts, V], new kv_pool).
     """
     B, Ts = tokens.shape
@@ -401,7 +411,9 @@ def prefill_continue(
             (pre[:, 0].astype(k.dtype), k), axis=1
         )  # [B, prefix+Ts, Hkv, Dh]
         v_full = jnp.concatenate((pre[:, 1].astype(v.dtype), v), axis=1)
-        attn = _prefill_attention(q, k_full, v_full, cfg, q_offset=prefix_len)
+        attn = _prefill_attention(
+            q, k_full, v_full, cfg, q_offset=prefix_len, interpret=interpret
+        )
         x = x + jnp.einsum("bthk,hkd->btd", attn, lp["wo"])
         x = x + _mlp(_rms_norm(x, lp["ln2"]), lp)
         kv_layer = _scatter_kv_blocks(
@@ -529,13 +541,15 @@ def decode_step(
     block_table: jnp.ndarray,
     context_len: jnp.ndarray,
     cfg: LlamaConfig,
+    interpret: bool = False,
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """One decode step over the paged pool.
 
     tokens: [B] current token ids; context_len: [B] length *including*
     the current token; block_table: [B, max_blocks].  Writes the new
     token's K/V into the pool slot, attends over the table, and returns
-    (logits [B, V], new kv_pool).
+    (logits [B, V], new kv_pool).  ``interpret``: the Pallas decode
+    kernel in interpret mode (CPU tests).
     """
     B = tokens.shape[0]
     pos = context_len - 1  # [B]
@@ -569,6 +583,7 @@ def decode_step(
                 context_len,
                 blocks_per_step=cfg.decode_blocks_per_step,
                 mxu_native=cfg.decode_mxu_native,
+                interpret=interpret,
             )
         else:
             attn = paged_attention(

@@ -1,6 +1,6 @@
 """Native runtime loader.
 
-``get_library()`` returns the ctypes handle to libkvtpu_native.so, building
+``get_library()`` returns the ctypes handle to the native library, building
 it on first use when a compiler is available; returns None otherwise so
 every caller can fall back to pure Python.  Set ``KVTPU_DISABLE_NATIVE=1``
 to force the fallback.

@@ -1,12 +1,19 @@
-"""Builds libkvtpu_native.so with g++ (no CUDA, no external deps).
+"""Builds the native library with g++ (no CUDA, no external deps).
 
 Usage: ``python -m llm_d_kv_cache_manager_tpu.native.build [--force]``.
 The library lands next to this file and is picked up by the ctypes loader;
 callers that find no compiler fall back to pure Python transparently.
+
+The library is a generated file (``*.so`` is in .gitignore), so which
+one gets loaded is decided by the committed sources alone: its name
+carries a digest of ``src/`` and the compile flags, and a library built
+from anything else has another name and is never loaded.
 """
 
 from __future__ import annotations
 
+import glob
+import hashlib
 import os
 import shutil
 import subprocess
@@ -14,34 +21,39 @@ import sys
 import tempfile
 
 _SRC_FILES = ["hashing.cpp", "numa.cpp", "thread_pool.cpp", "file_io.cpp", "engine.cpp"]
+_HEADERS = ["kvtpu_native.hpp", "debug_utils.hpp"]
+_FLAGS = [
+    "-O3", "-std=c++17", "-fPIC", "-shared", "-pthread", "-Wall", "-Wextra",
+]
 
-LIB_NAME = "libkvtpu_native.so"
+_LIB_STEM = "libkvtpu_native"
 
 
 def _paths():
     here = os.path.dirname(os.path.abspath(__file__))
-    src_dir = os.path.join(here, "src")
-    return here, src_dir, os.path.join(here, LIB_NAME)
+    return here, os.path.join(here, "src")
+
+
+def _source_digest(src_dir: str) -> str:
+    digest = hashlib.sha256(" ".join(_FLAGS).encode())
+    for name in (*_SRC_FILES, *_HEADERS):
+        with open(os.path.join(src_dir, name), "rb") as handle:
+            digest.update(name.encode() + b"\0" + handle.read())
+    return digest.hexdigest()[:16]
 
 
 def lib_path() -> str:
-    return _paths()[2]
-
-
-def needs_build() -> bool:
-    here, src_dir, lib = _paths()
-    if not os.path.exists(lib):
-        return True
-    lib_mtime = os.path.getmtime(lib)
-    sources = [os.path.join(src_dir, f) for f in _SRC_FILES]
-    sources.append(os.path.join(src_dir, "kvtpu_native.hpp"))
-    return any(os.path.getmtime(s) > lib_mtime for s in sources)
+    here, src_dir = _paths()
+    return os.path.join(
+        here, f"{_LIB_STEM}.{_source_digest(src_dir)}.so"
+    )
 
 
 def build(force: bool = False) -> str | None:
     """Compile the library; returns its path, or None if no compiler."""
-    here, src_dir, lib = _paths()
-    if not force and not needs_build():
+    here, src_dir = _paths()
+    lib = lib_path()
+    if not force and os.path.exists(lib):
         return lib
     compiler = shutil.which("g++") or shutil.which("c++")
     if compiler is None:
@@ -51,10 +63,7 @@ def build(force: bool = False) -> str | None:
     # parallel test workers) must never load a torn .so.
     fd, tmp = tempfile.mkstemp(suffix=".so", dir=here)
     os.close(fd)
-    cmd = [
-        compiler, "-O3", "-std=c++17", "-fPIC", "-shared", "-pthread",
-        "-Wall", "-Wextra", "-o", tmp, *sources,
-    ]
+    cmd = [compiler, *_FLAGS, "-o", tmp, *sources]
     try:
         subprocess.run(cmd, check=True, capture_output=True, text=True)
         os.replace(tmp, lib)
@@ -67,6 +76,11 @@ def build(force: bool = False) -> str | None:
         if os.path.exists(tmp):
             os.unlink(tmp)
         raise
+    # Libraries of other source states are dead weight (a process that
+    # already loaded one keeps its mapping).
+    for stale in glob.glob(os.path.join(here, f"{_LIB_STEM}*.so")):
+        if stale != lib:
+            os.unlink(stale)
     return lib
 
 
@@ -75,7 +89,7 @@ def build_stress(tsan: bool = False) -> str | None:
     the binary path, or None if no compiler.  With ``tsan=True`` the
     whole engine is instrumented with ThreadSanitizer — the race
     detection SURVEY.md §5 notes the reference never wired up."""
-    here, src_dir, _ = _paths()
+    here, src_dir = _paths()
     compiler = shutil.which("g++") or shutil.which("c++")
     if compiler is None:
         return None

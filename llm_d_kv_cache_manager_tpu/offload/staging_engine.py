@@ -16,11 +16,13 @@ them —
 — so the device DMA for slot N overlaps the file I/O for slot N-1, the
 way the reference overlaps ``cudaMemcpyAsync`` with its NUMA-pinned I/O
 threads (storage_offload.cpp:145-239).  On backends with a
-``pinned_host`` memory space (TPU) the DMA lands file-layout bytes
-straight in pinned pages (the transpose happens on device,
-models/kv_cache_pool.py); on backends without one the lane's slots are
-plain reusable numpy buffers and the pipeline still holds (CPU parity
-path, exercised by tests).
+``pinned_host`` memory space (TPU, and the CPU backend the tests run
+on) the DMA lands file-layout bytes straight in pinned pages (the
+transpose happens on device, models/kv_cache_pool.py); with
+``use_pinned=False`` the lane's slots are plain reusable numpy buffers
+and the pipeline still holds (the parity path, exercised by tests).
+A pinned transfer that fails raises — the engine never degrades to the
+plain path on its own.
 
 Contract with the shared :class:`~llm_d_kv_cache_manager_tpu.native.
 engine.OffloadEngine`: the staging engine submits one engine **sub-job
@@ -102,7 +104,7 @@ class StagingConfig:
     per in-flight transfer); ``slots_per_lane`` is the pipeline depth
     (2 = classic double buffering: one slot in device DMA while the
     other is in file I/O).  ``use_pinned=None`` probes the pool's
-    device; ``False`` forces the CPU parity path."""
+    device; ``False`` forces the plain-buffer parity path."""
 
     lanes_per_chip: int = DEFAULT_LANES_PER_CHIP
     slots_per_lane: int = DEFAULT_SLOTS_PER_LANE
@@ -214,7 +216,7 @@ class StagingEngine:
 
     @property
     def uses_pinned(self) -> bool:
-        """Whether the pinned_host DMA path is active (False = CPU
+        """Whether the pinned_host DMA path is active (False = the
         parity path with plain reusable numpy slots)."""
         return self._use_pinned
 
@@ -526,21 +528,12 @@ class StagingEngine:
         ``slots_per_lane``, without a redundant copy into a reusable
         buffer (the preallocated slot buffer serves the load side)."""
         if self._use_pinned:
-            try:
-                pinned = self.pool.stage_gather_pinned(ids)
-                host = np.asarray(pinned)
-                # Keep the pinned pages alive until the file write is
-                # harvested, in case the numpy view aliases them.
-                slot.pinned_ref = pinned
-                return host
-            except Exception:
-                logger.warning(
-                    "pinned_host staging failed; falling back to plain "
-                    "host transfers",
-                    exc_info=True,
-                )
-                # gil-atomic: one-way degrade flag; False is absorbing
-                self._use_pinned = False
+            pinned = self.pool.stage_gather_pinned(ids)
+            host = np.asarray(pinned)
+            # Keep the pinned pages alive until the file write is
+            # harvested, in case the numpy view aliases them.
+            slot.pinned_ref = pinned
+            return host
         host = self.pool.gather_block_major(ids)
         slot.pinned_ref = host
         return host

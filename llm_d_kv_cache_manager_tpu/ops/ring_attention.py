@@ -51,25 +51,6 @@ from jax.sharding import PartitionSpec as P
 NEG_INF = -1e30
 
 
-def _shard_map(fn, mesh, in_specs, out_specs, check_vma=None):
-    """``jax.shard_map`` across the supported JAX range: newer releases
-    export it at the top level (replication checker flag ``check_vma``),
-    older ones only under ``jax.experimental.shard_map`` where the same
-    flag is ``check_rep``."""
-    top = getattr(jax, "shard_map", None)
-    if top is not None:
-        extra = {} if check_vma is None else {"check_vma": check_vma}
-        return top(
-            fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **extra
-        )
-    from jax.experimental.shard_map import shard_map as legacy
-
-    extra = {} if check_vma is None else {"check_rep": check_vma}
-    return legacy(
-        fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **extra
-    )
-
-
 def resolve_auto_impl(
     mesh_platform: str,
     local_kv_tokens: int,
@@ -344,7 +325,7 @@ def ring_attention_sharded(
     spec = P(bspec, axis_name, head_axis, None)
 
     def build(resolved: str):
-        check_vma = None
+        check_vma = True
         if resolved == "flash":
             local = functools.partial(
                 _ring_attention_local_flash,
@@ -367,7 +348,7 @@ def ring_attention_sharded(
             )
         else:
             raise ValueError(f"unknown ring impl {resolved!r}")
-        return _shard_map(
+        return jax.shard_map(
             local,
             mesh=mesh,
             in_specs=(spec, spec, spec),

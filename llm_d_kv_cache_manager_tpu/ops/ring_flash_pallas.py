@@ -198,19 +198,16 @@ def flash_partial(
     # Under shard_map with check_vma, outputs must declare how they
     # vary over the mesh — same as the inputs (the ring body runs
     # per-shard).
-    try:
-        vma = {"vma": jax.typeof(q).vma}
-    except AttributeError:  # older jax: no vma tracking
-        vma = {}
+    vma = jax.typeof(q).vma
     acc, m, l = pl.pallas_call(
         kernel,
         out_shape=(
-            jax.ShapeDtypeStruct((B, H, Tq_pad, D), jnp.float32, **vma),
+            jax.ShapeDtypeStruct((B, H, Tq_pad, D), jnp.float32, vma=vma),
             jax.ShapeDtypeStruct(
-                (B, H, Tq_pad, ML_LANES), jnp.float32, **vma
+                (B, H, Tq_pad, ML_LANES), jnp.float32, vma=vma
             ),
             jax.ShapeDtypeStruct(
-                (B, H, Tq_pad, ML_LANES), jnp.float32, **vma
+                (B, H, Tq_pad, ML_LANES), jnp.float32, vma=vma
             ),
         ),
         grid=(B, H, nq),

@@ -89,19 +89,12 @@ def activate(mesh: Mesh) -> Iterator[Mesh]:
     """Enter a mesh context so bare PartitionSpecs resolve (e.g. in
     ``lax.with_sharding_constraint``).
 
-    Prefers ``jax.set_mesh`` (jax >= 0.6, the non-deprecated path: it
-    also sets the abstract mesh, which ``with mesh:`` no longer does),
-    falling back to the legacy ``with mesh:`` thread-resources context
-    on older jax.  All framework entry points route through here so the
+    ``jax.set_mesh`` also sets the abstract mesh, which ``with mesh:``
+    does not.  All framework entry points route through here so the
     choice lives in one place.
     """
-    set_mesh = getattr(jax, "set_mesh", None)
-    if set_mesh is not None:
-        with set_mesh(mesh):
-            yield mesh
-    else:
-        with mesh:
-            yield mesh
+    with jax.set_mesh(mesh):
+        yield mesh
 
 
 def mesh_is_active() -> bool:
@@ -114,29 +107,18 @@ def mesh_is_active() -> bool:
     try/except-ing ``with_sharding_constraint``, which would silently
     bake a constraint-free trace into the jit cache under a mesh.
     """
-    try:
-        abstract = jax.sharding.get_abstract_mesh()
-        if abstract is not None and not getattr(abstract, "empty", True):
-            return True
-    except Exception:  # noqa: BLE001 API drift; kvlint: disable=KV005
-        # Capability probe: absence of the new-style API is an expected
-        # state on older jax, not an error — fall through to the legacy
-        # probe (a log here would fire on every trace).
-        pass
-    try:
-        # ``with mesh:`` still routes through the legacy thread-resources
-        # env (jax 0.9: get_abstract_mesh()/get_mesh() only see
-        # jax.set_mesh).  The attribute works but warns; keep the probe
-        # quiet until the legacy context manager loses the env entirely.
-        import warnings
+    if not jax.sharding.get_abstract_mesh().empty:
+        return True
+    # ``with mesh:`` still routes through the thread-resources env
+    # (get_abstract_mesh() only sees jax.set_mesh), and callers enter
+    # it.  The attribute works but warns; keep the probe quiet.
+    import warnings
 
-        with warnings.catch_warnings():
-            warnings.simplefilter("ignore", DeprecationWarning)
-            from jax.interpreters import pxla
+    with warnings.catch_warnings():
+        warnings.simplefilter("ignore", DeprecationWarning)
+        from jax.interpreters import pxla
 
-            return not pxla.thread_resources.env.physical_mesh.empty
-    except Exception:  # noqa: BLE001
-        return False
+        return not pxla.thread_resources.env.physical_mesh.empty
 
 
 def make_mesh(

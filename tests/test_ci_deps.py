@@ -60,6 +60,7 @@ LOCAL_TOP_LEVELS = {
     "render_chart",  # hack/render_chart.py imported by test_chart.py
     "helpers",  # tests/helpers, sys.path'd by profiling scripts
     "bench",
+    "chip_smoke",
     "__graft_entry__",
 }
 

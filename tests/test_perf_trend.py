@@ -1,7 +1,7 @@
 """Perf-trend gate (hack/perf_trend.py; ISSUE 14 satellite).
 
-The acceptance contract directly: the tool passes on the repo's real
-BENCH_r01–r06 trajectory, fails on a synthetic regressed artifact,
+The acceptance contract directly: the tool passes on the artifacts the
+repo still keeps, fails on a synthetic regressed artifact,
 parses every artifact shape the trajectory contains (parsed /
 headline / compact), and skips errored runs as baselines.
 """
@@ -132,9 +132,33 @@ class TestGate:
     def test_passes_on_real_trajectory(self):
         assert main(["--dir", REPO_ROOT]) == 0
 
-    def test_real_trajectory_has_headlines(self):
-        runs = load_trajectory(REPO_ROOT)
-        assert len(runs) >= 6
+    def test_trajectory_of_mixed_shapes_has_headlines(self, tmp_path):
+        _write(
+            tmp_path,
+            "BENCH_r01.json",
+            {
+                "n": 1,
+                "rc": 0,
+                "parsed": {
+                    "metric": "p50_ttft_speedup_precise_vs_round_robin",
+                    "value": 4.0,
+                },
+            },
+        )
+        _write(
+            tmp_path,
+            "BENCH_r02.json",
+            {
+                "n": 2,
+                "rc": 0,
+                "headline": {
+                    "regime": "event_storm",
+                    "apply_msgs_per_sec": 500.0,
+                },
+            },
+        )
+        runs = load_trajectory(str(tmp_path))
+        assert [n for n, _, _ in runs] == [1, 2]
         measured = {
             key for _, _, headlines in runs for key in headlines
         }
@@ -363,11 +387,17 @@ class TestMultichipDisplay:
         # A failing MULTICHIP artifact never fails the gate.
         assert main(["--dir", str(tmp_path)]) == 0
 
-    def test_real_trajectory_parses(self):
+    def test_every_multichip_run_carries_a_status(self, tmp_path):
         from hack.perf_trend import load_multichip_trajectory
 
-        runs = load_multichip_trajectory(REPO_ROOT)
-        assert len(runs) >= 5
+        for n in range(1, 4):
+            _write(
+                tmp_path,
+                f"MULTICHIP_r{n:02d}.json",
+                {"n_devices": 8, "rc": 0, "ok": True, "tail": ""},
+            )
+        runs = load_multichip_trajectory(str(tmp_path))
+        assert len(runs) == 3
         assert all("status" in facts for _, _, facts in runs)
 
     def test_unreadable_multichip_skipped(self, tmp_path):

@@ -1,4 +1,4 @@
-"""Staging-engine tests: parity vs the one-shot oracle, CPU fallback,
+"""Staging-engine tests: parity vs the one-shot oracle, pinned vs plain slots,
 lane backpressure (watchdog-armed), write-side RTT stamping, atomic
 file layout, and the staged demotion target's real byte moves."""
 
@@ -231,8 +231,8 @@ class TestStagedParity:
         connector.close()
 
 
-class TestCpuFallback:
-    def test_fallback_when_pinned_unsupported(self, tmp_path):
+class TestPinnedAndPlainPaths:
+    def test_plain_slots_when_pinned_forced_off(self, tmp_path):
         """use_pinned=None probes the pool; forcing False must keep
         the pipeline byte-correct through plain reusable slots."""
         pool = KVCachePool(POOL_CONFIG)
@@ -259,10 +259,26 @@ class TestCpuFallback:
         np.testing.assert_array_equal(on_disk, expected)
         connector.close()
 
-    def test_auto_probe_matches_pool(self, tmp_path):
+    def test_pinned_path_survives_a_staged_store(self, tmp_path):
+        """The CPU backend lists a pinned_host memory, so the auto
+        probe takes the pinned path — and it must still be on after a
+        store has actually used it (a transfer call the installed JAX
+        lacks once flipped both flags off on the first gather, silently,
+        with every test passing on the plain path)."""
         pool = KVCachePool(POOL_CONFIG)
         connector, _ = make_connector(tmp_path, 1, pool=pool)
-        assert connector.staging.uses_pinned == pool.pinned_host
+        assert pool.pinned_host and connector.staging.uses_pinned
+        fill_pool_blocks(pool, [0, 1])
+        connector.store_handler.transfer_async(
+            1, group_blocks_per_file([0xA], [0, 1], 2)
+        )
+        assert connector.store_handler.wait(1) == JobStatus.SUCCEEDED
+        assert pool.pinned_host and connector.staging.uses_pinned
+        staged = pool.stage_gather_pinned([0, 1])
+        assert staged.sharding.memory_kind == "pinned_host"
+        np.testing.assert_array_equal(
+            np.asarray(staged), pool.gather_block_major([0, 1])
+        )
         connector.close()
 
 
