@@ -25,7 +25,7 @@ project rules (KV006-KV008) consume:
   first-argument names, with module-level string constants resolved
   through f-strings (the ``f"{_NAMESPACE}_..."`` pattern).
 * **stage names** — string literals handed to ``span``/``obs_span``,
-  ``add_completed`` and ``start_trace``: the
+  ``add_completed``, ``start_trace`` and ``root_trace``: the
   ``kvtpu_stage_latency_seconds{stage=...}`` label vocabulary.
 * **the documented surface** — knobs parsed from the env-var tables of
   ``docs/configuration.md`` and ``docs/observability.md``, metric
@@ -56,7 +56,9 @@ from hack.kvlint.guards import is_lock_call as _is_lock_call
 
 _METRIC_FACTORIES = {"Counter", "Gauge", "Histogram", "Summary"}
 
-_SPAN_CALLS = {"span", "obs_span", "add_completed", "start_trace"}
+_SPAN_CALLS = {
+    "span", "obs_span", "add_completed", "start_trace", "root_trace",
+}
 
 LOCK_ORDER_RE = re.compile(
     r"kvlint:\s*lock-order:\s*"

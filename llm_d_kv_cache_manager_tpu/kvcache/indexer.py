@@ -23,6 +23,14 @@ bit-identical to the straight-line path (pinned by property tests);
 ``READ_PATH_FAST_LANE=0`` or ``IndexerConfig.read_path_fast_lane=False``
 restores the straight-line path.
 
+Tracing (docs/observability.md): called with no trace active — linked
+into a scheduler as a library — the three scoring entry points start
+their own ``indexer.score`` trace at the tracer's sample rate; under an
+API layer's trace their spans join it.  A traced request runs exactly
+the code an untraced one runs (the score memo included); a *forced*
+trace (``explain=1``, a sampled ``traceparent``) additionally records
+per-pod provenance on the ``score`` span of a walk.
+
 Against a backend that fans lookups out over the wire (the cluster
 ``RemoteIndex``), the chunked drive additionally pipelines: chunk N+1
 is hashed and dispatched while chunk N's owner RPCs are in flight, and
@@ -57,6 +65,7 @@ from llm_d_kv_cache_manager_tpu.kvcache.scorer import (
 )
 from llm_d_kv_cache_manager_tpu.obs.trace import (
     current_trace,
+    root_trace,
     span as obs_span,
 )
 from llm_d_kv_cache_manager_tpu.preprocessing.chat_templating import (
@@ -229,10 +238,20 @@ class _ScoreMemoEntry:
 _PROVENANCE_MAX_PODS = 32
 
 
+def _wants_provenance() -> bool:
+    """Per-pod chain-break tracking is diagnosis, not the hot path:
+    only a trace that was asked for by name (``Trace.forced``) pays
+    for it; a trace drawn by the sample rate records the walk as an
+    untraced request runs it."""
+    active = current_trace()
+    return active is not None and active.forced
+
+
 def _provenance_attr(chain) -> Dict[str, dict]:
     """Per-pod ``{blocks_matched, break_index}`` span attribute for a
-    traced scoring request (cross-link: a slow trace in /debug/traces
-    is diagnosable without re-issuing ``?explain=1``), size-capped."""
+    forced trace's walk (cross-link: a traceparent-sampled request in
+    /debug/traces is diagnosable without re-issuing ``?explain=1``),
+    size-capped."""
     provenance = chain.provenance()
     if len(provenance) <= _PROVENANCE_MAX_PODS:
         return provenance
@@ -665,15 +684,16 @@ class Indexer:
         coefficient is set; omitted, scores are bit-identical to the
         load-blind path.
         """
-        if self._fast_lane:
-            scores = self._get_pod_scores_fast(
-                prompt, model_name, pod_identifiers, render_req
-            )
-        else:
-            scores = self._get_pod_scores_straight(
-                prompt, model_name, pod_identifiers, render_req
-            )
-        return self._blend_loads(scores, pod_loads)
+        with root_trace("indexer.score"):
+            if self._fast_lane:
+                scores = self._get_pod_scores_fast(
+                    prompt, model_name, pod_identifiers, render_req
+                )
+            else:
+                scores = self._get_pod_scores_straight(
+                    prompt, model_name, pod_identifiers, render_req
+                )
+            return self._blend_loads(scores, pod_loads)
 
     def _get_pod_scores_straight(
         self,
@@ -698,14 +718,14 @@ class Indexer:
         ledger = self.cache_stats
         sampled = ledger is not None and ledger.should_sample()
         track_tiers = sampled and ledger.tier_detail_due()
-        traced = current_trace() is not None
+        provenance = _wants_provenance()
         pod_set = set(pod_identifiers) if pod_identifiers else None
         with obs_span("index_lookup") as s:
             key_to_pods = self.kv_block_index.lookup(block_keys, pod_set)
             s.set_attr("keys_hit", len(key_to_pods))
         with obs_span("score") as s:
             chain = self.scorer.begin(
-                track_tiers=track_tiers, track_deaths=traced
+                track_tiers=track_tiers, track_deaths=provenance
             )
             # lookup() already applied the pod filter; feeding every
             # key keeps break indices aligned with explain's.
@@ -716,22 +736,25 @@ class Indexer:
                 chain.scores, pod_identifiers
             )
             s.set_attr("pods", len(scores))
-            if traced:
+            if provenance:
                 s.set_attr("provenance", _provenance_attr(chain))
-        if sampled:
-            family = ledger.family_key(block_keys, len(block_keys))
-            _ledger_record(
-                ledger,
-                family,
-                model_name,
-                len(block_keys),
-                chain.matched_blocks,
-                chain.tier_counts,
-            )
-            if self.policy_engine is not None:
-                self.policy_engine.observe_scored(block_keys, family)
-        if self.capture is not None:
-            self._capture_score(model_name, tokens, pod_identifiers, scores)
+        with obs_span("bookkeeping"):
+            if sampled:
+                family = ledger.family_key(block_keys, len(block_keys))
+                _ledger_record(
+                    ledger,
+                    family,
+                    model_name,
+                    len(block_keys),
+                    chain.matched_blocks,
+                    chain.tier_counts,
+                )
+                if self.policy_engine is not None:
+                    self.policy_engine.observe_scored(block_keys, family)
+            if self.capture is not None:
+                self._capture_score(
+                    model_name, tokens, pod_identifiers, scores
+                )
         logger.debug(
             "scored %d pods over %d block keys", len(scores), len(block_keys)
         )
@@ -747,7 +770,14 @@ class Indexer:
         """The fast lane: memoized prefix keys + chunked early-exit
         hashing/lookup/scoring, fronted by the request score memo.
         Identical scores to the straight path
-        (tests/test_read_path_fastlane.py pins it)."""
+        (tests/test_read_path_fastlane.py pins it).
+
+        ``tr`` below is read for span recording alone: a traced
+        request takes every branch an untraced one takes, and each
+        span is stamped where its work starts and ends — one
+        ``hash_blocks`` / ``index_lookup`` / ``score`` span per chunk,
+        ``memo_check`` before the walk and ``bookkeeping`` after it
+        for what is none of the three."""
         memo = self._score_memo
         memo_key = None
         if memo is not None and render_req is None:
@@ -756,7 +786,7 @@ class Indexer:
                 model_name,
                 tuple(pod_identifiers) if pod_identifiers else None,
             )
-        active_trace = current_trace()
+        tr = current_trace()
         ledger = self.cache_stats
         sampled = ledger is not None and ledger.should_sample()
         track_tiers = sampled and ledger.tier_detail_due()
@@ -765,11 +795,8 @@ class Indexer:
                 prompt, model_name, render_req, self._key_space
             )
             s.set_attr("tokens", len(result.tokens))
-        # Anchor for the traced stage layout below: everything from
-        # here to the emit point belongs to some walk stage, so the
-        # stage spans are laid out to cover this whole interval (the
-        # slo smoke pins stage-sum ≈ end-to-end ±5%).
-        walk_start = time.perf_counter()
+        perf = time.perf_counter
+        front_start = perf()
 
         tokens = result.tokens
         block_size = self.token_processor.block_size
@@ -790,7 +817,8 @@ class Indexer:
         # stayed deep predicts a likely-alive chain worth dispatching
         # ahead of the current chunk's replies.
         predicted_hit_blocks = 0
-        if memo_key is not None and active_trace is None:
+        memo_state = "off"
+        if memo_key is not None:
             # Exact-prompt score memo, validated optimistically: the
             # memoized result is served only when (1) tokenization
             # served the exact token stream the walk that computed it
@@ -799,9 +827,7 @@ class Indexer:
             # to different token values with the same count, and
             # different tokens mean different block keys) and (2) the
             # index's per-shard version vector is unchanged since that
-            # walk began (no score-relevant mutation landed).  Traced
-            # requests always walk, so sampled traces carry real stage
-            # spans.
+            # walk began (no score-relevant mutation landed).
             hit = memo.get(memo_key)
             if (
                 hit is not None
@@ -842,21 +868,25 @@ class Indexer:
                     len(hit.scores),
                     len(hit.touch_keys),
                 )
+                if tr is not None:
+                    span = tr.add_completed("memo_check", front_start)
+                    span.set_attr("memo", "hit")
+                    span.set_attr("pods", len(hit.scores))
                 return dict(hit.scores)
+            memo_state = "miss"
             if hit is not None:
+                memo_state = "stale"
                 predicted_hit_blocks = hit.matched_blocks
         processor = self.token_processor
         scorer = self.scorer
+        provenance = _wants_provenance()
         chain = scorer.begin(
-            track_tiers=track_tiers, track_deaths=active_trace is not None
+            track_tiers=track_tiers, track_deaths=provenance
         )
         chunk_size = self._lookup_chunk
-        perf = time.perf_counter
 
-        hash_s = 0.0
         lookup_s = 0.0
-        score_s = 0.0
-        keys_hit = 0
+        score_span = None
         record_lookup = self._record_chain_lookup
         hits_per_pod: Dict[str, int] = {}
         parent_key = (
@@ -879,9 +909,10 @@ class Indexer:
             un-dispatched chunk, advancing the dispatch cursor.  Both
             drives below share it, so chunk boundaries — hence scorer
             advance granularity and scores — are identical."""
-            nonlocal hash_s, next_pos, parent_key, chunk_size
+            nonlocal next_pos, parent_key, chunk_size
             t_0 = perf()
-            if next_pos < memo_blocks:
+            from_memo = next_pos < memo_blocks
+            if from_memo:
                 # The memoized prefix needs no hashing, so early exit
                 # saves nothing there: drive it as ONE chunk (one
                 # grouped lock pass over the whole prefix).
@@ -905,7 +936,10 @@ class Indexer:
                 # per-chunk overhead.
                 if chunk_size < 512:
                     chunk_size *= 2
-            hash_s += perf() - t_0
+            if tr is not None:
+                span = tr.add_completed("hash_blocks", t_0)
+                span.set_attr("block_keys", len(chunk))
+                span.set_attr("memo_blocks", len(chunk) if from_memo else 0)
             next_pos += len(chunk)
             return chunk
 
@@ -929,6 +963,10 @@ class Indexer:
         speculated = 0
         predicted_blocks = max(memo_blocks, predicted_hit_blocks)
         ledger_predicted = ledger is None
+        if tr is not None:
+            tr.add_completed("memo_check", front_start).set_attr(
+                "memo", memo_state
+            )
         while position < total_blocks and alive:
             if depth > 0:
                 while len(in_flight) < depth and next_pos < total_blocks:
@@ -942,11 +980,15 @@ class Indexer:
                     # Dispatch counts as lookup time: an unarmed (or
                     # closed) router resolves the chunk inline right
                     # here, and that wall time must land in the
-                    # index_lookup stage, not in an untracked gap
-                    # (the slo smoke pins stage-sum ≈ end-to-end).
+                    # index_lookup stage, not in an untracked gap.
                     t_d = perf()
                     handle = index.lookup_chain_async(chunk)
-                    lookup_s += perf() - t_d
+                    t_e = perf()
+                    lookup_s += t_e - t_d
+                    if tr is not None:
+                        tr.add_completed(
+                            "index_lookup", t_d, t_e
+                        ).set_attr("dispatched", len(chunk))
                     in_flight.append((chunk, handle))
                 key_chunk, handle = in_flight.popleft()
                 t_1 = perf()
@@ -957,8 +999,11 @@ class Indexer:
                 pods_per_key = index.lookup_chain(key_chunk)
             t_2 = perf()
             lookup_s += t_2 - t_1
+            if tr is not None:
+                tr.add_completed("index_lookup", t_1, t_2).set_attr(
+                    "keys_hit", len(pods_per_key)
+                )
             keys_done.extend(key_chunk)
-            keys_hit += len(pods_per_key)
             if memo_key is not None and pods_per_key:
                 touched_keys.extend(key_chunk[: len(pods_per_key)])
             if record_lookup is not None:
@@ -983,7 +1028,9 @@ class Indexer:
                 scorer.advance(chain, pods_per_key, pod_set)
                 and len(pods_per_key) == len(key_chunk)
             )
-            score_s += perf() - t_2
+            if tr is not None:
+                score_span = tr.add_completed("score", t_2)
+                score_span.set_attr("pods", len(chain.scores))
             position += len(key_chunk)
             if (
                 not ledger_predicted
@@ -1004,6 +1051,7 @@ class Indexer:
                     predicted_blocks = max(
                         predicted_blocks, int(prediction)
                     )
+        tail_start = perf() if tr is not None else 0.0
         if speculated or in_flight:
             # Wasted = dispatched but never consumed (early exit after
             # the chain died); the executor finishes them harmlessly in
@@ -1101,34 +1149,16 @@ class Indexer:
             if self.policy_engine is not None:
                 self.policy_engine.observe_scored(keys_done, family)
 
-        tracer = active_trace
-        if tracer is not None:
-            # One span per pipeline stage (the stage vocabulary the
-            # metrics histogram and the debug surface share), durations
-            # accumulated across chunks and emitted as contiguous
-            # intervals covering [walk_start, now].  lookup/score keep
-            # their measured durations; hash_blocks absorbs the walk's
-            # fixed bookkeeping (memo check + version capture up front,
-            # memo store / ledger / prefix attach at the tail) so the
-            # stage sum tracks the request's end-to-end latency.
-            end = perf()
-            span = tracer.add_completed(
-                "hash_blocks", walk_start,
-                end - lookup_s - score_s,
-            )
-            span.set_attr("block_keys", len(keys_done))
-            span.set_attr("memo_blocks", memo_blocks)
-            span = tracer.add_completed(
-                "index_lookup", end - lookup_s - score_s, end - score_s
-            )
-            span.set_attr("keys_hit", keys_hit)
-            span = tracer.add_completed("score", end - score_s, end)
-            span.set_attr("pods", len(chain.scores))
-            span.set_attr("provenance", _provenance_attr(chain))
         if self.capture is not None:
             self._capture_score(
                 model_name, tokens, pod_identifiers, chain.scores
             )
+        if tr is not None:
+            if provenance and score_span is not None:
+                # On the walk's last score span, after the death
+                # fix-up above (forced traces only: Trace.forced).
+                score_span.set_attr("provenance", _provenance_attr(chain))
+            tr.add_completed("bookkeeping", tail_start)
         logger.debug(
             "fast-lane scored %d pods over %d/%d block keys "
             "(%d memoized)",
@@ -1185,6 +1215,19 @@ class Indexer:
         walks the full chain: break indices need the straight-line
         path, never the early-exit fast lane); not for every request.
         """
+        with root_trace("indexer.score"):
+            return self._get_pod_scores_explained(
+                prompt, model_name, pod_identifiers, render_req, pod_loads
+            )
+
+    def _get_pod_scores_explained(
+        self,
+        prompt: str,
+        model_name: str,
+        pod_identifiers: Optional[Sequence[str]],
+        render_req: Optional[ApplyChatTemplateRequest],
+        pod_loads: Optional[Dict[str, float]],
+    ) -> Tuple[Dict[str, float], Dict]:
         tokens, block_keys = self._tokens_and_block_keys(
             prompt, model_name, render_req
         )

@@ -680,6 +680,7 @@ class _BatchApplier:
         if self._adds:
             adds, self._adds = self._adds, []
             traces, self._traces = self._traces, []
+            began = time.perf_counter() if traces else 0.0
             try:
                 self._index.add_entries_batch(adds)
             except Exception as exc:
@@ -695,6 +696,16 @@ class _BatchApplier:
                     tr.set_error(f"batched apply flush failed: {exc!r}")
                     tr.finish("error")
                 raise
+            if traces:
+                # One flush serves every message whose adds it carried:
+                # each owning trace gets the interval (once), because
+                # this — not kvevents.apply, which only digests — is
+                # where its blocks became visible to the read path.
+                done = time.perf_counter()
+                for tr in dict.fromkeys(traces):
+                    tr.add_completed(
+                        "kvevents.flush", began, done
+                    ).set_attr("adds", len(adds))
         if self._records:
             records, self._records = self._records, []
             for args in records:
@@ -1033,9 +1044,26 @@ class Pool:
             t0 = time.perf_counter()
             n_decoded = 0
             for message in messages:
-                if message.resync is None and message.decoded is None:
+                if message.resync is not None or message.decoded is not None:
+                    continue
+                n_decoded += 1
+                tr = message.trace
+                if tr is None:
                     self._predecode(message)
-                    n_decoded += 1
+                    continue
+                # The stage the in-worker fallback spans in
+                # _decode_message, stamped where it runs by default: on
+                # this thread, before the queue.  The message's queue
+                # wait starts where its decode ends, so the two spans
+                # never overlap.
+                began = time.perf_counter()
+                self._predecode(message)
+                message.enqueued_at = time.perf_counter()
+                span = tr.add_completed(
+                    "kvevents.decode", began, message.enqueued_at
+                )
+                if message.decoded is not _DECODE_FAILED:
+                    span.set_attr("events", len(message.decoded.events))
             if n_decoded:
                 self._stage_account(
                     "decode", time.perf_counter() - t0, n_decoded

@@ -66,6 +66,7 @@ from llm_d_kv_cache_manager_tpu.obs.trace import (
     current_trace,
     format_traceparent,
     parse_traceparent,
+    root_trace,
     span,
     use_trace,
 )
@@ -117,6 +118,7 @@ __all__ = [
     "current_trace",
     "format_traceparent",
     "parse_traceparent",
+    "root_trace",
     "span",
     "use_trace",
 ]

@@ -18,6 +18,12 @@ always-on in production:
 ``get`` resolves a trace id across all three tiers, so
 ``GET /debug/traces/<id>`` keeps working for a slow or errored trace
 whose ring slot is long gone.
+
+``export`` is the whole-window read: every retained trace's spans as
+flat rows on the ``time.perf_counter`` clock, plus the number of traces
+recorded but no longer retained — a reader that needs all of a window
+sizes the ring for it (``Tracer.configure(ring_size=...)``) and treats
+a non-zero drop count as "this export is not the window".
 """
 
 from __future__ import annotations
@@ -25,7 +31,7 @@ from __future__ import annotations
 import heapq
 import threading
 from collections import deque
-from typing import Deque, List, Optional, Tuple
+from typing import Deque, Dict, List, Optional, Tuple
 
 DEFAULT_RING_SIZE = 256
 DEFAULT_SLOW_KEEP = 32
@@ -108,6 +114,28 @@ class FlightRecorder:
         """Newest-first slice of the errored reservoir."""
         with self._lock:
             return list(self._errored)[::-1][:limit]
+
+    def export(self) -> Tuple[List[Dict], int]:
+        """``(rows, dropped)``: the spans of every retained trace
+        (ring, then slow and errored traces the ring has cycled past),
+        oldest first, and the count of traces recorded since the last
+        ``clear`` that no tier retains any more.  Each trace gives one
+        root row (``span`` None: the trace's own interval, status and
+        attributes) followed by a row per span — see
+        ``Trace.span_rows``.  Nothing is removed; spans stay in memory
+        until asked for, and asking twice returns them twice."""
+        with self._lock:
+            retained = list(self._ring)
+            seen = {id(trace) for trace in retained}
+            for trace in [t for _, _, t in self._slow] + list(self._errored):
+                if id(trace) not in seen:
+                    seen.add(id(trace))
+                    retained.append(trace)
+            dropped = self._recorded - len(retained)
+        rows: List[Dict] = []
+        for trace in retained:
+            rows.extend(trace.span_rows())
+        return rows, dropped
 
     def stats(self) -> dict:
         """Occupancy and throughput counters for /healthz."""

@@ -6,7 +6,23 @@ KV-event write path, and the offload pipelines.  Design constraints
 
 * **Always-on cheap.**  The untraced path allocates nothing: ``span()``
   returns a preallocated null context manager when no trace is active,
-  and an unsampled ``start_trace`` costs one counter increment.
+  and an unsampled ``start_trace`` costs one lock and one counter
+  increment.
+* **A traced request runs the code an untraced one runs.**  Tracing
+  records; it never chooses a path.  (The one documented exception:
+  a *forced* trace — ``?explain=1``, a sampled ``traceparent``,
+  ``force=True`` — additionally carries per-pod score provenance,
+  ``Trace.forced``.)
+* **Spans are intervals that happened.**  Every span's start and end
+  are ``time.perf_counter`` stamps taken where the work started and
+  ended, on the thread that did it; none is laid out after the fact.
+  A stage that runs in pieces (one chunk of the read path's walk at a
+  time) records one span per piece.
+* **Library calls trace themselves.**  An entry point that may be
+  called with no API layer above it (``Indexer.get_pod_scores`` linked
+  into a scheduler, ``kvevents.Pool.add_tasks``) starts its own trace
+  when none is active (``root_trace``); under a caller's trace its
+  spans join that trace and nothing else changes.
 * **Explicit propagation.**  A ``contextvars.ContextVar`` carries the
   active trace within a thread; crossing the thread-pool boundaries we
   own (tokenization pool, kvevents shards, offload workers) is done by
@@ -17,10 +33,17 @@ KV-event write path, and the offload pipelines.  Design constraints
   submitting thread keeps tracing, so span append is locked.
 * **Flat span model.**  Spans carry an optional ``parent`` stage *name*
   rather than a span-id tree: top-level spans (``parent is None``) are
-  the request's sequential stage breakdown — their durations sum to
-  ~the end-to-end latency — and dotted children (``tokenize.encode``)
-  attribute time inside a stage.  This is what /debug and ``explain=1``
-  render, and what feeds ``kvtpu_stage_latency_seconds{stage=...}``.
+  the request's sequential stage breakdown — they lie inside the
+  trace's interval and do not overlap on one thread, so their
+  durations sum to at most the end-to-end latency — and dotted
+  children (``tokenize.encode``) attribute time inside a stage.  The
+  ``stages`` view that /debug and ``explain=1`` render, and
+  ``kvtpu_stage_latency_seconds{stage=...}``, sum a trace's spans of
+  one name: one entry, one observation, per stage per trace.
+* **Exported whole.**  ``TRACER.recorder.export()`` returns every
+  retained trace's spans as flat rows on the ``perf_counter`` clock and
+  the number of traces no longer retained; ``configure(ring_size=...)``
+  sizes the ring for the window a reader wants whole.
 
 Env knobs (read at import; ``configure`` overrides for tests/embeds):
 ``TRACE_SAMPLE_RATE`` (0..1, default 0.01), ``TRACE_RING_SIZE``
@@ -191,8 +214,14 @@ class Trace:
         root_span_id: str,
         recorder: FlightRecorder,
         parent_span_id: Optional[str] = None,
+        forced: bool = False,
     ) -> None:
         self.name = name
+        # Asked for by name (explain, sampled traceparent, force=True)
+        # rather than drawn by the sample rate: the one thing a traced
+        # code path may branch on, and only to ADD diagnosis (score
+        # provenance) to what it records.
+        self.forced = forced
         self.trace_id = trace_id
         self.root_span_id = root_span_id
         self.parent_span_id = parent_span_id
@@ -258,11 +287,17 @@ class Trace:
             self.status = status
             spans = list(self._spans)
         # Outside the trace lock: the prometheus client and the
-        # recorder take their own locks.
-        for span in spans:
-            METRICS.stage_latency.labels(span.name).observe(
-                span.duration_s
-            )
+        # recorder take their own locks.  One observation per stage
+        # name: the time this request spent in that stage, however
+        # many pieces it ran in.
+        for name, duration_s in _sum_by_name(spans).items():
+            child = _STAGE_LATENCY.get(name)
+            if child is None:
+                # gil-atomic: racing writers store the same child
+                child = _STAGE_LATENCY[name] = METRICS.stage_latency.labels(
+                    name
+                )
+            child.observe(duration_s)
         self._recorder.record(self)
 
     def traceparent(self) -> str:
@@ -273,18 +308,38 @@ class Trace:
 
     @staticmethod
     def _stages_view(spans: List[Span]) -> List[Dict[str, Any]]:
-        """Top-level spans (parent None) in completion order: the
-        request's sequential stage latency breakdown."""
+        """Top-level spans (parent None) summed by name, in order of
+        first completion: the request's stage latency breakdown."""
+        top = _sum_by_name([s for s in spans if s.parent is None])
         return [
-            {"stage": s.name, "duration_ms": s.duration_s * 1e3}
-            for s in spans
-            if s.parent is None
+            {"stage": name, "duration_ms": duration_s * 1e3}
+            for name, duration_s in top.items()
         ]
 
     def stage_breakdown(self) -> List[Dict[str, Any]]:
         with self._lock:
             spans = list(self._spans)
         return self._stages_view(spans)
+
+    def span_rows(self) -> List[Dict[str, Any]]:
+        """Flat export rows, ``perf_counter`` seconds: one root row
+        (``span`` None) for the trace's own interval — still open
+        (``end`` None) until ``finish`` — then one per span."""
+        with self._lock:
+            spans = list(self._spans)
+            attrs = dict(self._attrs)
+            duration_s = self.duration_s
+            status = self.status
+        ident = {"trace_id": self.trace_id, "trace": self.name}
+        end = None if duration_s is None else self.start + duration_s
+        rows = [dict(ident, span=None, parent=None, start=self.start,
+                     end=end, status=status, attrs=attrs)]
+        rows.extend(
+            dict(ident, span=s.name, parent=s.parent, start=s.start,
+                 end=s.end, status=s.status, attrs=dict(s.attrs))
+            for s in spans
+        )
+        return rows
 
     def to_dict(self, include_spans: bool = True) -> Dict[str, Any]:
         with self._lock:
@@ -322,6 +377,20 @@ class Trace:
                 for s in spans
             ]
         return out
+
+
+# Labelled children of kvtpu_stage_latency_seconds by stage name: the
+# client's labels() lookup costs as much as the observation itself, and
+# a traced request pays it once per stage.
+_STAGE_LATENCY: Dict[str, Any] = {}
+
+
+def _sum_by_name(spans: List[Span]) -> Dict[str, float]:
+    """Summed duration per span name, in order of first appearance."""
+    totals: Dict[str, float] = {}
+    for s in spans:
+        totals[s.name] = totals.get(s.name, 0.0) + s.duration_s
+    return totals
 
 
 # ------------------------------ the tracer ------------------------------
@@ -377,15 +446,18 @@ class Tracer:
 
     def __init__(self, config: Optional[TracerConfig] = None) -> None:
         self.config = config or TracerConfig.from_env()
-        self.recorder = FlightRecorder(
+        self.recorder = self._new_recorder()
+        self._lock = threading.Lock()
+        self._sampled = 0  # guarded-by: _lock
+        self._dropped = 0  # guarded-by: _lock
+
+    def _new_recorder(self) -> FlightRecorder:
+        return FlightRecorder(
             ring_size=self.config.ring_size,
             slow_keep=self.config.slow_keep,
             error_keep=self.config.error_keep,
             slow_threshold_ms=self.config.slow_threshold_ms,
         )
-        self._lock = threading.Lock()
-        self._sampled = 0  # guarded-by: _lock
-        self._dropped = 0  # guarded-by: _lock
 
     def start_trace(
         self,
@@ -418,18 +490,25 @@ class Tracer:
             parent_span_id=(
                 parent.span_id if parent is not None else None
             ),
+            forced=force,
         )
 
     def configure(self, **overrides) -> None:
         """Mutate sampling knobs in place (tests, embedding apps).
 
-        Recorder geometry (ring/reservoir sizes) is fixed at
-        construction; only ``sample_rate`` and ``slow_threshold_ms``
-        are live-tunable.
+        ``sample_rate`` and ``slow_threshold_ms`` are live-tuned;
+        ``ring_size`` REBUILDS the recorder (what it retained is
+        gone; a trace still in flight finishes into the old one), so a
+        caller that wants a whole window exported sizes the ring for
+        it before the window opens.
         """
-        for key in ("sample_rate",):
-            if key in overrides:
-                self.config.sample_rate = float(overrides.pop(key))
+        if "sample_rate" in overrides:
+            self.config.sample_rate = float(overrides.pop("sample_rate"))
+        if "ring_size" in overrides:
+            self.config.ring_size = int(overrides.pop("ring_size"))
+            # Readers take the attribute once per use.
+            # gil-atomic: one reference store
+            self.recorder = self._new_recorder()
         if "slow_threshold_ms" in overrides:
             value = float(overrides.pop("slow_threshold_ms"))
             self.config.slow_threshold_ms = value
@@ -498,6 +577,41 @@ def span(name: str, parent: Optional[str] = None):
     if trace is None:
         return _NULL_SPAN_CTX
     return trace.span(name, parent)
+
+
+class root_trace:
+    """Trace a library entry point that may have no API layer above it.
+
+    With no trace active, ask the process tracer for one under
+    ``name`` (the sample rate decides), bind it for the scope, and
+    finish it at exit — errored when the scope raised.  Under an
+    active trace, or unsampled, it does nothing: the scope's spans
+    join the caller's trace, or vanish.
+    """
+
+    __slots__ = ("_trace", "_token")
+
+    def __init__(self, name: str) -> None:
+        self._trace = (
+            TRACER.start_trace(name) if _CURRENT.get() is None else None
+        )
+        self._token = None
+
+    def __enter__(self) -> Optional[Trace]:
+        if self._trace is not None:
+            self._token = _CURRENT.set(self._trace)
+        return self._trace
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        trace = self._trace
+        if trace is not None:
+            _CURRENT.reset(self._token)
+            if exc_type is not None:
+                trace.set_error(repr(exc))
+                trace.finish("error")
+            else:
+                trace.finish()
+        return False
 
 
 class shield_trace:

@@ -343,7 +343,8 @@ class TokenizationPool:
                 prompt, task.model_name, add_special_tokens
             )
             s.set_attr("tokens", len(encoding.tokens))
-        self._prefix_store.add_tokenization(
-            prompt, encoding.tokens, encoding.offsets, task.model_name
-        )
+        with obs_span("tokenize.store", parent="tokenize"):
+            self._prefix_store.add_tokenization(
+                prompt, encoding.tokens, encoding.offsets, task.model_name
+            )
         return TokenizedPrompt(encoding.tokens, prompt)

@@ -170,9 +170,12 @@ class TestTraceparentSurface:
         trace latency within 5%.  Best-of-3 requests: the pin is on
         the instrumentation, and a single scheduler hiccup between
         stages (full-suite runs share one core) must not flake it."""
-        prompt = SENTENCE * 200  # long enough that stages dominate
         best_gap = None
         for attempt in range(3):
+            # Long enough that stages dominate, and new each time: a
+            # repeat is a score-memo hit under a trace as without one,
+            # and has no walk stages to cover.
+            prompt = SENTENCE * (200 + attempt)
             trace_id = f"{0x51051 + attempt:032x}"
             fleet.post(
                 "/score_completions",

@@ -115,7 +115,17 @@ class TestSampling:
         tracer.configure(sample_rate=1.0)
         assert tracer.start_trace("t") is not None
         with pytest.raises(TypeError):
-            tracer.configure(ring_size=5)
+            tracer.configure(ring_capacity=5)
+
+    def test_configure_ring_size_rebuilds_the_recorder(self):
+        tracer = make_tracer()
+        tracer.start_trace("before").finish()
+        old = tracer.recorder
+        tracer.configure(ring_size=5)
+        assert tracer.recorder is not old
+        assert tracer.recorder.ring_size == 5
+        assert tracer.recorder.export() == ([], 0)
+        assert tracer.stats()["ring_size"] == 5
 
 
 class TestTraceSpans:
@@ -335,6 +345,395 @@ class TestConcurrency:
         thread.join(timeout=10)
         trace.finish()
         assert len(trace.to_dict()["spans"]) == 2
+
+
+# ---- the program traces itself: library calls, real intervals, export ----
+
+MODEL = "obs-model"
+BLOCK = 4
+TOP_LEVEL = {
+    "tokenize", "memo_check", "hash_blocks", "index_lookup", "score",
+    "bookkeeping",
+}
+
+
+class _WordTokenizer:
+    """'tN' -> N."""
+
+    def type(self) -> str:
+        return "word"
+
+    def encode(self, prompt, model_name, add_special_tokens=True):
+        from llm_d_kv_cache_manager_tpu.tokenization.tokenizers import (
+            Encoding,
+        )
+
+        tokens, offsets, pos = [], [], 0
+        for word in prompt.split(" "):
+            tokens.append(int(word[1:]))
+            offsets.append((pos, pos + len(word)))
+            pos += len(word) + 1
+        return Encoding(tokens, offsets)
+
+
+def _prompt(tokens) -> str:
+    return " ".join(f"t{t}" for t in tokens)
+
+
+@pytest.fixture
+def global_tracer():
+    """The process tracer at rate 1.0 with an empty ring; its sampling
+    state and ring geometry are put back afterwards."""
+    from llm_d_kv_cache_manager_tpu.obs.trace import TRACER
+
+    rate, ring = TRACER.config.sample_rate, TRACER.config.ring_size
+    TRACER.configure(sample_rate=1.0, ring_size=64)
+    TRACER.reset()
+    yield TRACER
+    TRACER.configure(sample_rate=rate, ring_size=ring)
+    TRACER.reset()
+
+
+def _make_indexer(prefix_chunk_bytes=None):
+    from llm_d_kv_cache_manager_tpu.kvcache.indexer import (
+        Indexer,
+        IndexerConfig,
+    )
+    from llm_d_kv_cache_manager_tpu.kvcache.kvblock.index import PodEntry
+    from llm_d_kv_cache_manager_tpu.kvcache.kvblock.token_processor import (
+        TokenProcessorConfig,
+    )
+    from llm_d_kv_cache_manager_tpu.tokenization.pool import (
+        TokenizationPoolConfig,
+    )
+    from llm_d_kv_cache_manager_tpu.tokenization.prefixstore.lru_store import (  # noqa: E501
+        LRUStoreConfig,
+    )
+
+    ix = Indexer(
+        IndexerConfig(
+            prefix_store_config=(
+                LRUStoreConfig(block_size=prefix_chunk_bytes)
+                if prefix_chunk_bytes else LRUStoreConfig()),
+            token_processor_config=TokenProcessorConfig(block_size=BLOCK),
+            tokenizers_pool_config=TokenizationPoolConfig(
+                workers=1, model_name=MODEL
+            ),
+            lookup_chunk_size=2,  # several chunks in a 12-block walk
+            cache_stats=False,
+        ),
+        tokenizer=_WordTokenizer(),
+    )
+    ix.run()
+    tokens = [7 + i for i in range(BLOCK * 12)]
+    keys = ix.token_processor.tokens_to_kv_block_keys(0, tokens, MODEL)
+    ix.kv_block_index.add(keys, keys, [PodEntry("pod-a", "hbm")])
+    ix.kv_block_index.add(keys[:5], keys[:5], [PodEntry("pod-b", "hbm")])
+    ix.prompt = _prompt(tokens)
+    return ix
+
+
+@pytest.fixture
+def indexer():
+    ix = _make_indexer()
+    yield ix
+    ix.shutdown()
+
+
+def _by_trace(rows):
+    traces = {}
+    for row in rows:
+        traces.setdefault(row["trace_id"], []).append(row)
+    return list(traces.values())
+
+
+class TestLibraryCallsTraceThemselves:
+    def test_one_trace_of_real_intervals_with_counts(
+        self, global_tracer, indexer
+    ):
+        scores = indexer.get_pod_scores(indexer.prompt, MODEL)
+        assert scores == {"pod-a": 12.0, "pod-b": 5.0}
+        rows, dropped = global_tracer.recorder.export()
+        assert dropped == 0
+        (trace,) = _by_trace(rows)
+        root, spans = trace[0], trace[1:]
+        assert (root["trace"], root["span"], root["status"]) == (
+            "indexer.score", None, "ok")
+        top = sorted((r for r in spans if r["parent"] is None),
+                     key=lambda r: r["start"])
+        assert {r["span"] for r in top} == TOP_LEVEL
+        # Inside the root, and no overlap between siblings.
+        assert root["start"] <= top[0]["start"]
+        assert top[-1]["end"] <= root["end"]
+        for before, after in zip(top, top[1:]):
+            assert before["start"] <= before["end"] <= after["start"]
+        for child in (r for r in spans if r["parent"] == "tokenize"):
+            (tokenize,) = [r for r in top if r["span"] == "tokenize"]
+            assert tokenize["start"] <= child["start"]
+            assert child["end"] <= tokenize["end"]
+        # One span per chunk of the walk, counts taken where the work is.
+        by = {}
+        for r in top:
+            by.setdefault(r["span"], []).append(r["attrs"])
+        assert by["tokenize"] == [{"tokens": BLOCK * 12}]
+        assert by["memo_check"] == [{"memo": "miss"}]
+        assert len(by["hash_blocks"]) == len(by["score"]) > 1
+        assert sum(a["block_keys"] for a in by["hash_blocks"]) == 12
+        assert sum(a["memo_blocks"] for a in by["hash_blocks"]) == 0
+        assert sum(a["keys_hit"] for a in by["index_lookup"]) == 12
+        assert by["score"][-1] == {"pods": 2}  # sampled: no provenance
+
+    def test_traced_and_untraced_agree_on_scores_and_memo(
+        self, global_tracer, indexer
+    ):
+        """A traced request takes the untraced path: the second
+        identical prompt is a memo hit under a trace, as without one."""
+        pods = ["pod-a", "pod-b", "pod-c"]
+        other = _prompt(t + 1000 for t in range(BLOCK * 12))
+        prompts = (indexer.prompt, indexer.prompt, other, indexer.prompt)
+
+        def drive(ix):
+            """(scores, index lookups made) per call."""
+            calls = []
+            real = ix.kv_block_index.lookup_chain
+            ix.kv_block_index.lookup_chain = (
+                lambda keys: calls[-1][1].append(len(keys)) or real(keys))
+            for prompt in prompts:
+                calls.append((None, []))
+                scores = ix.get_pod_scores(prompt, MODEL, pods)
+                calls[-1] = (scores, calls[-1][1])
+            return calls
+
+        traced = drive(indexer)
+        rows, _ = global_tracer.recorder.export()
+        memo = [[r["attrs"]["memo"] for r in t if r["span"] == "memo_check"]
+                for t in _by_trace(rows)]
+        assert memo == [["miss"], ["hit"], ["miss"], ["hit"]]
+        walked = [bool({r["span"] for r in t} & {"hash_blocks", "score"})
+                  for t in _by_trace(rows)]
+        assert walked == [True, False, True, False]
+
+        global_tracer.configure(sample_rate=0.0)
+        twin = _make_indexer()
+        try:
+            untraced = drive(twin)
+        finally:
+            twin.shutdown()
+        assert traced == untraced
+        assert traced[1] == (
+            {"pod-a": 12.0, "pod-b": 5.0, "pod-c": 0.0}, [])
+
+    def test_keys_served_by_the_prefix_store_are_counted_as_memo_blocks(
+        self, global_tracer
+    ):
+        """A prompt that extends a stored one is served its tokens and
+        block keys by the prefix store (16-byte text chunks here): the
+        walk hashes nothing, and `hash_blocks` says so."""
+        ix = _make_indexer(prefix_chunk_bytes=16)
+        try:
+            ix.get_pod_scores(ix.prompt, MODEL)
+            longer = ix.prompt + " " + _prompt(range(900, 900 + BLOCK))
+            ix.get_pod_scores(longer, MODEL)
+        finally:
+            ix.shutdown()
+        rows, _ = global_tracer.recorder.export()
+        first, second = ([r["attrs"] for r in t if r["span"] == "hash_blocks"]
+                         for t in _by_trace(rows))
+        assert sum(a["memo_blocks"] for a in first) == 0
+        assert sum(a["block_keys"] for a in first) == 12
+        assert second == [{"block_keys": 11, "memo_blocks": 11}]
+
+    def test_under_a_callers_trace_nothing_is_started(
+        self, global_tracer, indexer
+    ):
+        outer = global_tracer.start_trace("api.layer")
+        with use_trace(outer):
+            indexer.get_pod_scores(indexer.prompt, MODEL)
+        assert global_tracer.recorder.export() == ([], 0)
+        outer.finish()
+        rows, _ = global_tracer.recorder.export()
+        assert {r["trace"] for r in rows} == {"api.layer"}
+        assert TOP_LEVEL <= {r["span"] for r in rows}
+
+    def test_only_a_forced_trace_carries_provenance(
+        self, global_tracer, indexer
+    ):
+        forced = global_tracer.start_trace("asked.for", force=True)
+        assert forced.forced
+        with use_trace(forced):
+            indexer.get_pod_scores(indexer.prompt, MODEL)
+        forced.finish()
+        last_score = [s for s in forced.to_dict()["spans"]
+                      if s["name"] == "score"][-1]
+        assert last_score["attributes"]["provenance"]["pod-b"] == {
+            "blocks_matched": 5, "break_index": 5}
+        assert not global_tracer.start_trace("drawn").forced
+
+    def test_rate_zero_allocates_no_trace(
+        self, global_tracer, indexer, monkeypatch
+    ):
+        from llm_d_kv_cache_manager_tpu.obs import trace as trace_module
+
+        global_tracer.configure(sample_rate=0.0)
+        made = []
+        real_init = trace_module.Trace.__init__
+        monkeypatch.setattr(
+            trace_module.Trace, "__init__",
+            lambda self, *a, **kw: made.append(1) or real_init(
+                self, *a, **kw))
+        before = global_tracer.stats()["traces_unsampled"]
+        assert indexer.get_pod_scores(indexer.prompt, MODEL)
+        assert made == []
+        assert global_tracer.stats()["traces_unsampled"] == before + 1
+        assert global_tracer.recorder.export() == ([], 0)
+
+    def test_a_failing_call_finishes_its_trace_errored(
+        self, global_tracer, indexer
+    ):
+        def boom(*args, **kwargs):
+            raise RuntimeError("tokenizer down")
+
+        indexer.tokenization_pool.tokenize_with_keys = boom
+        with pytest.raises(RuntimeError):
+            indexer.get_pod_scores(indexer.prompt, MODEL)
+        (trace,) = global_tracer.recorder.errored()
+        assert trace.name == "indexer.score" and trace.status == "error"
+        assert current_trace() is None
+
+    def test_stages_view_and_histogram_sum_a_stage_per_trace(
+        self, global_tracer, indexer
+    ):
+        from llm_d_kv_cache_manager_tpu.metrics.collector import METRICS
+
+        def count(stage):
+            return sum(
+                sample.value
+                for metric in METRICS.stage_latency.collect()
+                for sample in metric.samples
+                if sample.name.endswith("_count")
+                and sample.labels.get("stage") == stage)
+
+        before = count("hash_blocks")
+        indexer.get_pod_scores(indexer.prompt, MODEL)
+        (trace,) = global_tracer.recorder.recent()
+        view = trace.to_dict()
+        names = [s["stage"] for s in view["stages"]]
+        assert sorted(names) == sorted(TOP_LEVEL)  # one entry per stage
+        chunks = [s["duration_ms"] for s in view["spans"]
+                  if s["name"] == "hash_blocks"]
+        assert len(chunks) > 1
+        (summed,) = [s["duration_ms"] for s in view["stages"]
+                     if s["stage"] == "hash_blocks"]
+        assert summed == pytest.approx(sum(chunks))
+        assert sum(s["duration_ms"] for s in view["stages"]) <= (
+            view["duration_ms"])
+        assert count("hash_blocks") == before + 1
+
+
+class TestEventPlaneSpans:
+    def test_lockfree_predecode_yields_a_decode_span(self, global_tracer):
+        from llm_d_kv_cache_manager_tpu.kvcache.kvblock.in_memory import (
+            InMemoryIndex,
+        )
+        from llm_d_kv_cache_manager_tpu.kvcache.kvblock.index import (
+            InMemoryIndexConfig,
+        )
+        from llm_d_kv_cache_manager_tpu.kvcache.kvblock.token_processor import (  # noqa: E501
+            ChunkedTokenDatabase,
+            TokenProcessorConfig,
+        )
+        from llm_d_kv_cache_manager_tpu.kvevents.events import (
+            BlockStored,
+            EventBatch,
+        )
+        from llm_d_kv_cache_manager_tpu.kvevents.pool import (
+            Message,
+            Pool,
+            PoolConfig,
+        )
+
+        index = InMemoryIndex(InMemoryIndexConfig())
+        db = ChunkedTokenDatabase(TokenProcessorConfig(block_size=BLOCK))
+        pool = Pool(index, db, PoolConfig(concurrency=1))
+        assert pool._lockfree_decode  # the default path
+        pool.start()
+        try:
+            payload = EventBatch(ts=1.0, events=[BlockStored(
+                block_hashes=[11, 12], parent_block_hash=None,
+                token_ids=list(range(BLOCK * 2)), block_size=BLOCK,
+                medium="hbm")]).encode()
+            pool.add_task(Message(
+                topic=f"kv@pod-a@{MODEL}", payload=payload,
+                pod_identifier="pod-a", model_name=MODEL))
+            pool.drain()
+        finally:
+            pool.shutdown()
+        rows, dropped = global_tracer.recorder.export()
+        assert dropped == 0
+        (trace,) = _by_trace(rows)
+        root, spans = trace[0], sorted(trace[1:], key=lambda r: r["start"])
+        assert root["trace"] == "kvevents.message" and root["status"] == "ok"
+        assert [r["span"] for r in spans] == [
+            "kvevents.decode", "kvevents.queue_wait", "kvevents.apply",
+            "kvevents.flush"]
+        assert root["start"] <= spans[0]["start"]
+        assert spans[-1]["end"] <= root["end"]
+        for before, after in zip(spans, spans[1:]):
+            assert before["start"] <= before["end"] <= after["start"]
+        assert spans[0]["attrs"] == {"events": 1}
+        assert spans[2]["attrs"] == {"applied": 1}
+        assert spans[3]["attrs"] == {"adds": 1}
+        assert pool.stage_stats()["decode_msgs"] == 1  # /healthz still fed
+
+
+class TestExport:
+    def test_export_returns_every_span_and_counts_what_the_ring_lost(self):
+        tracer = make_tracer(ring_size=3)
+        for i in range(3):
+            trace = tracer.start_trace(f"req-{i}")
+            trace.set_attr("i", i)
+            with use_trace(trace):
+                with obs_span("a") as s:
+                    s.set_attr("n", i)
+                with obs_span("a.child", parent="a"):
+                    pass
+            trace.finish()
+        rows, dropped = tracer.recorder.export()
+        assert dropped == 0 and len(rows) == 9
+        assert [r["span"] for r in rows[:3]] == [None, "a", "a.child"]
+        assert [r["trace"] for r in rows[::3]] == ["req-0", "req-1", "req-2"]
+        root, a, child = rows[3:6]
+        assert root["attrs"] == {"i": 1} and a["attrs"] == {"n": 1}
+        assert child["parent"] == "a" and a["parent"] is None
+        assert root["trace_id"] == a["trace_id"] == child["trace_id"]
+        assert root["start"] <= a["start"] <= a["end"] <= child["start"]
+        assert child["end"] <= root["end"]
+        assert set(a) == {"trace_id", "trace", "span", "parent", "start",
+                          "end", "status", "attrs"}
+        # Asking again returns the same; the ring wrapping is reported.
+        assert tracer.recorder.export() == (rows, 0)
+        for i in range(2):
+            tracer.start_trace(f"late-{i}").finish()
+        rows, dropped = tracer.recorder.export()
+        assert dropped == 2
+        assert [r["trace"] for r in rows if r["span"] is None] == [
+            "req-2", "late-0", "late-1"]
+
+    def test_errored_and_slow_traces_outlive_the_ring_in_the_export(self):
+        tracer = make_tracer(ring_size=1, slow_threshold_ms=0.0)
+        first = tracer.start_trace("first")
+        first.set_error("boom")
+        first.finish()
+        tracer.start_trace("second").finish()
+        rows, dropped = tracer.recorder.export()
+        assert dropped == 0
+        assert [(r["trace"], r["status"]) for r in rows] == [
+            ("second", "ok"), ("first", "error")]
+
+    def test_an_open_trace_is_not_exported(self):
+        tracer = make_tracer()
+        tracer.start_trace("open")
+        assert tracer.recorder.export() == ([], 0)
 
 
 class TestKvlintGate:

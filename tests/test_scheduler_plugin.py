@@ -235,12 +235,19 @@ class TestPurgeOnExpiry:
             scorer._subscriptions.set("ns/pod-a", "10.0.0.1")
             time.sleep(0.2)
             scorer._subscriptions.sweep()
-            found = indexer.kv_block_index.lookup([0x61, 0x62])
-            survivors = {
-                p.pod_identifier
-                for pods in found.values()
-                for p in pods
-            }
+            # The purge runs on its own thread (the expiry callback
+            # must not stall scoring): wait for it, bounded.
+            deadline = time.monotonic() + 5.0
+            while True:
+                found = indexer.kv_block_index.lookup([0x61, 0x62])
+                survivors = {
+                    p.pod_identifier
+                    for pods in found.values()
+                    for p in pods
+                }
+                if survivors == {"10.0.0.2"} or time.monotonic() > deadline:
+                    break
+                time.sleep(0.01)
             assert survivors == {"10.0.0.2"}
         finally:
             scorer.shutdown()
