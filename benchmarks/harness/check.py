@@ -1,19 +1,21 @@
 """What decides `correct`: the served tokens and logits against the plain
 reference, and the engine's accounting against a plain prefix-cache model.
 
-Every number compared is printed beside its limit; the limits are the cell's
+Every number compared is printed beside its limit, on standard error and
+under "checks" in the result's line; the limits are the cell's
 (`benchmarks/cells/<cell>.json`, "limits"), set from chip readings of sound
 runs and of the float8 control (PERF.md section 2).
 """
 
 from __future__ import annotations
 
+import sys
 from collections import OrderedDict
 
 import jax
 import numpy as np
 
-from . import reference
+from . import family
 
 
 def sample(requests: list[dict], k: int, seed: int) -> list[dict]:
@@ -57,6 +59,7 @@ def against_reference(cfg: dict, seed: int, picked: list[dict],
     `decode_logit_rel_err`: the same for the served tokens' own logits over the
     decode steps.  With `quant`, the reference in that precision stands in the
     program's place (the control): its first choice, its row, its logits."""
+    reference = family.reference(cfg)
     weights = reference.make_weights(cfg, seed)
     gap, row_d, row_n, dec_d, dec_n = 0.0, 0.0, 0.0, 0.0, 0.0
     for r in picked:
@@ -111,12 +114,13 @@ def against_cache_model(log: list[dict], pool_blocks: int) -> dict:
 
 
 def verdict(numbers: dict, limits: dict) -> bool:
-    """Prints each number beside its limit; true when all are within."""
+    """Prints each number beside its limit on standard error (a run's last
+    lines there); true when all are within."""
     ok = True
     for name, value in numbers.items():
         limit = limits[name]
         within = value <= limit
         ok &= within
         print(f"check {name} = {value:.6g} (limit {limit:g}) "
-              f"{'ok' if within else 'FAILED'}", flush=True)
+              f"{'ok' if within else 'FAILED'}", file=sys.stderr, flush=True)
     return ok

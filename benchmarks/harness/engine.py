@@ -1,13 +1,15 @@
-"""The pod engine the benchmark drives, and the spans around each layer.
+"""The engine the benchmark drives around a pod, and the spans around each layer.
 
-The repo's only pod engine lives in `bench.py` (ROADMAP D4), which later PRs
-may change; the yardstick may not change with it, so this is a copy of its
-sound parts (`SimPod`, `FleetRouter.route/account/commit`, `publish_events`,
-`block_hash_chain`, `WordTokenizer`, `jit_prefills`) with two differences: the
-allocator never hands out a block a live sequence references (free list, then
-least-recently-used cached blocks that nobody references), and a decode
-program over slots is added.  The system under test is what it calls:
-`Indexer`, `kvevents.Pool`, `models/llama.py` and the kernels below it.
+What is the benchmark's, here: the records (spans, counters, series), the word
+tokenizer and the engine's own block hashes, `Fleet` (route, account, commit:
+a copy of the sound parts of `bench.py`'s `FleetRouter` and `publish_events`)
+and the two load loops (`run_stream`, `run_chat`).  What is the program's:
+the pod's cache manager and its three compiled programs (`harness/pod.py`
+until ROADMAP D4 moves them into the package) over the family's model step,
+which `Fleet` is handed as a module found by the configuration's `family`
+(`harness/family.py`); no model module is imported here.  The system under
+test is what these call: `Indexer`, `kvevents.Pool`, the family's model step
+and the kernels below it.
 """
 
 from __future__ import annotations
@@ -15,10 +17,9 @@ from __future__ import annotations
 import contextlib
 import hashlib
 import time
-from collections import OrderedDict, defaultdict
+from collections import defaultdict
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
 from llm_d_kv_cache_manager_tpu.kvcache.indexer import Indexer, IndexerConfig
@@ -30,9 +31,10 @@ from llm_d_kv_cache_manager_tpu.kvevents.events import (
     BlockRemoved, BlockStored, EventBatch,
 )
 from llm_d_kv_cache_manager_tpu.kvevents.pool import Message, Pool, PoolConfig
-from llm_d_kv_cache_manager_tpu.models import llama
 from llm_d_kv_cache_manager_tpu.tokenization.pool import TokenizationPoolConfig
 from llm_d_kv_cache_manager_tpu.tokenization.tokenizers import Encoding
+
+from .pod import Pod, jit_programs
 
 MODEL_NAME = "bench/model"
 BLOCK = 16  # tokens per K/V block: the index's block size
@@ -98,98 +100,21 @@ def block_hash_chain(tokens) -> list[int]:
     return hashes
 
 
-class Pod:
-    """One serving pod on the chip: its paged K/V pool and prefix cache."""
-
-    def __init__(self, name: str, cfg: llama.LlamaConfig, pool_blocks: int):
-        self.name = name
-        self.pool_blocks = pool_blocks
-        self.kv = jnp.zeros((cfg.n_layers, pool_blocks, 2, cfg.block_size,
-                             cfg.n_kv_heads, cfg.head_dim), jnp.dtype(cfg.dtype))
-        self.free = list(range(pool_blocks - 1, -1, -1))
-        self.cached: OrderedDict[int, int] = OrderedDict()  # hash -> block, LRU first
-        self.refs: dict[int, int] = defaultdict(int)  # block -> live sequences
-
-    def cached_prefix(self, hashes) -> list[int]:
-        ids = []
-        for h in hashes:
-            if h not in self.cached:
-                break
-            ids.append(self.cached[h])
-        return ids
-
-    def touch(self, hashes) -> None:
-        for h in hashes:
-            self.cached.move_to_end(h)
-
-    def alloc(self, n: int) -> tuple[list[int], list[int]]:
-        """n blocks no live sequence references; returns (ids, hashes evicted)."""
-        ids, evicted = [], []
-        while len(ids) < n and self.free:
-            ids.append(self.free.pop())
-        if len(ids) < n:
-            for h, bid in list(self.cached.items()):
-                if self.refs[bid]:
-                    continue
-                del self.cached[h]
-                evicted.append(h)
-                ids.append(bid)
-                if len(ids) == n:
-                    break
-        if len(ids) < n:
-            raise RuntimeError(f"{self.name}: pool exhausted by live sequences")
-        return ids, evicted
-
-    def hold(self, ids, by: int) -> None:
-        for bid in ids:
-            self.refs[bid] += by
-
-
-def jit_programs(cfg: llama.LlamaConfig, shapes: dict, interpret: bool) -> dict:
-    """The cell's compiled steps, named so that the trace reduction finds
-    them.  Each returns the greedy tokens with their logits as one array (one
-    transfer to the host) and, for a prefill, the last position's row of logits
-    (kept on the device for the output check); the pool is donated."""
-
-    def served(logits):
-        return jnp.stack((jnp.argmax(logits, -1).astype(jnp.float32),
-                          jnp.max(logits, -1)))
-
-    def last(logits, kv):
-        return served(logits[:, -1]), logits[0, -1], kv
-
-    def miss(p, t, kv, bt):
-        return last(*llama.prefill_paged(p, t, kv, bt, cfg, interpret=interpret))
-
-    def hit(p, t, kv, bt):
-        return last(*llama.prefill_continue(
-            p, t, kv, bt, shapes["hit"][0], cfg, interpret=interpret))
-
-    def decode(p, t, kv, bt, n):
-        logits, kv = llama.decode_step(p, t, kv, bt, n, cfg, interpret=interpret)
-        return served(logits), kv
-
-    programs = {}
-    for fn, key, name in ((miss, "miss", "miss_prefill_T{}"),
-                          (hit, "hit", "hit_prefill_P{}_S{}"),
-                          (decode, "decode", "decode_B{}")):
-        if key in shapes:
-            fn.__name__ = fn.__qualname__ = name.format(*shapes[key])
-            programs[key] = jax.jit(fn, donate_argnums=(2,))
-    return programs
-
-
 class Fleet:
     """Pods on one chip behind precise routing: the real `Indexer` scores,
     the real `kvevents.Pool` feeds its index."""
 
-    def __init__(self, cfg, params, traffic: dict, shapes: dict, rec: Records,
-                 interpret: bool) -> None:
-        self.cfg, self.params, self.rec = cfg, params, rec
-        self.pods = [Pod(f"pod-{i}", cfg, traffic["pool_blocks"])
+    def __init__(self, program, model, params, traffic: dict, shapes: dict,
+                 rec: Records, interpret: bool) -> None:
+        """`program`: the family's program module (`family.program(cfg)`);
+        `model`: its configuration object (`program.from_published`)."""
+        self.params, self.rec = params, rec
+        new_pod = getattr(program, "Pod", Pod)
+        self.pods = [new_pod(f"pod-{i}", program, model, traffic["pool_blocks"])
                      for i in range(traffic["pods"])]
         self.by_name = {p.name: p for p in self.pods}
-        self.programs = jit_programs(cfg, shapes, interpret)
+        self.programs = getattr(program, "jit_programs", jit_programs)(
+            program, model, shapes, interpret)
         self.shapes = shapes
         self.log: list[dict] = []  # every request since the pools were empty
         self._rr = 0

@@ -1,4 +1,8 @@
-"""The plain reference: the decoder's forward pass in jax.numpy, float32,
+"""The benchmark's side of the family `llama` (dense decoders with grouped-query
+attention), found by the configuration's `family` (`harness/family.py`): the
+plain reference, the seeded weights, the control, and the least-work counts.
+
+The plain reference: the decoder's forward pass in jax.numpy, float32,
 matrix products at precision "highest", no kernels, no cache, no batching.
 
 It imports nothing of the program and takes nothing the program made: the
@@ -32,6 +36,29 @@ def sizes(cfg: dict):
     return (cfg["num_hidden_layers"], cfg["hidden_size"], H,
             cfg["num_key_value_heads"], cfg["hidden_size"] // H,
             cfg["intermediate_size"], cfg["vocab_size"])
+
+
+def param_count(cfg: dict) -> int:
+    L, D, H, Hkv, Dh, F, V = sizes(cfg)
+    return V * D + D + L * (2 * D + D * Dh * (2 * H + 2 * Hkv) + 3 * D * F)
+
+
+def param_bytes(cfg: dict) -> int:
+    """The weights once, in the type they are served in."""
+    return param_count(cfg) * jnp.dtype(cfg["torch_dtype"]).itemsize
+
+
+def kv_block_bytes(cfg: dict, block: int) -> int:
+    """One block of K and V over all layers, in the served type."""
+    L, _, _, Hkv, Dh, _, _ = sizes(cfg)
+    return L * 2 * block * Hkv * Dh * jnp.dtype(cfg["torch_dtype"]).itemsize
+
+
+def prefill_attention_flops(cfg: dict, T: int) -> int:
+    """Causal attention of one prefill of T tokens, all layers: QK^T and PV
+    over the lower triangle, 2 * T^2 * H * Dh FLOPs a layer."""
+    L, _, H, _, Dh, _, _ = sizes(cfg)
+    return L * 2 * T * T * H * Dh
 
 
 def key_of(seed: int) -> jax.Array:

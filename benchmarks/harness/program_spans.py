@@ -27,9 +27,9 @@ that places the program's spans on the device trace's timeline
 (`clock_offset`, `place`); `idle_gaps_inner` then splits the device's idle time
 by the innermost span, the benchmark's or the program's, that covers it.
 
-No file that was here reads this module yet: `run.py` hands `read_metric` no
-rows and `reduce.read_metric` does not know the two kinds (PERF.md, Open
-questions).  `tests/traced_run.py` drives it around `run.run_cell`.
+`run.py --trace 1` runs the program's tracer at rate 1.0, exports the rows
+after the window and hands them to `reduce.read_metric`, which sends the two
+kinds here; `Trace.breakdown` carries `idle_gaps_inner`.
 """
 
 from __future__ import annotations

@@ -11,8 +11,14 @@ where its number comes from:
   {"from": "device",  "program": "hit_prefill_P\\d+_S\\d+", "reduce": "p50"}
   {"from": "roofline", "program": "miss_prefill_T\\d+",
    "op": "flash_gqa_attention_pallas", "cost": "flash_prefill_min_s"}
+  {"from": "program_span", "trace": "indexer.score", "name": "tokenize",
+   "reduce": "p50"}                    (the program's own spans: program_spans.py)
+  {"from": "program_attr", "name": "hash_blocks", "num": "memo_blocks",
+   "den": "block_keys"}
 
 A reader that finds nothing to read returns None and the metric is left out.
+A roofline's `cost` is a function of `costs.py` or of the configuration's
+family module (`costs.cost`).
 
 The trace, as the TPU runtime writes it: one plane "/device:TPU:<n>" per chip,
 whose line "XLA Modules" holds one event per run of a jitted program (named
@@ -144,11 +150,13 @@ class Trace:
                     sums[i] += b - a
         return [s for s in sums if s > 0]
 
-    def breakdown(self, top: int = 10) -> dict:
+    def breakdown(self, top: int = 10, placed=None) -> dict:
         """The device operations with most time, as <program>:<op>, and the
         idle time by the span the host was in: inside a program with nothing
         running ("in_program"), in one of the benchmark's outermost spans, or
-        in none ("other")."""
+        in none ("other").  With `placed`, the program's own spans on this
+        trace's clock (`program_spans.place`), also `idle_gaps_inner`: the same
+        idle time by the innermost span, the benchmark's or the program's."""
         starts = np.array([a for _, a, _ in self.programs])
         ends = np.array([b for _, _, b in self.programs])
         by_op: dict[str, float] = defaultdict(float)
@@ -176,13 +184,23 @@ class Trace:
             return [[k, v] for k, v in
                     sorted(times.items(), key=lambda kv: -kv[1])[:top]]
 
-        return {"device_ops": rank(by_op), "idle_gaps": rank(gaps)}
+        out = {"device_ops": rank(by_op), "idle_gaps": rank(gaps)}
+        if placed is not None:
+            from .program_spans import idle_gaps_inner
+
+            out["idle_gaps_inner"] = idle_gaps_inner(self, placed)[:top]
+        return out
 
 
 def read_metric(read: dict, rec, trace, window_s: float, env: dict):
     """One metric's number from a run, or None where there is nothing to read.
-    `env`: configuration, shapes and peaks for a roofline's cost function."""
+    `env`: configuration, shapes and peaks for a roofline's cost function; the
+    program's exported span rows and the window's bounds on `perf_counter`."""
     kind = read["from"]
+    if kind in ("program_span", "program_attr"):
+        from .program_spans import read as read_rows
+
+        return read_rows(read, env["rows"], *env["window"])
     if kind == "series":
         return reduce_values(rec.series.get(read["name"], ()), read["reduce"])
     if kind == "span":
@@ -205,7 +223,7 @@ def read_metric(read: dict, rec, trace, window_s: float, env: dict):
                 if read.get("op") else trace.program_times(read["program"]))
         if not took:
             return None
-        least = costs.COSTS[read["cost"]](env["cfg"], env["shapes"],
-                                          rec.counters, env["peaks"])
+        least = costs.cost(read["cost"], env["cfg"])(
+            env["cfg"], env["shapes"], rec.counters, env["peaks"])
         return 100.0 * least / statistics.median(took)
     raise ValueError(f"unknown metric source {kind!r}")

@@ -5,8 +5,10 @@
 Loads, warms up (set-up), measures for `--seconds` on the real clock, checks
 what the window served against the plain reference, and prints as its last line
 the contract's JSON object.  `--trace 1` profiles a window of at most
-TRACE_SECONDS and reports the per-layer metrics instead.  Fails, with no
-result line, where JAX finds no TPU or fewer chips than the cell asks for.
+TRACE_SECONDS with the program's own tracer (`obs/trace.py`) at rate 1.0, and
+reports the per-layer metrics instead; `--trace 0` runs with that tracer off.
+Fails, with no result line, where JAX finds no TPU or fewer chips than the
+cell asks for.
 """
 
 from __future__ import annotations
@@ -28,6 +30,7 @@ BENCH = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(BENCH)
 sys.path[:0] = [p for p in (ROOT,) if p not in sys.path]
 TRACE_SECONDS = 12.0
+TRACE_RING = 1 << 16  # traces kept: set-up's and a window's are some hundreds
 COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 
 
@@ -57,17 +60,22 @@ def read_thread_entries() -> None:
 
 def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
              root: str = BENCH, on_cpu: bool = False, overrides: dict | None = None,
-             control: bool = False) -> dict:
+             control: bool = False, tracer_rate: float | None = None) -> dict:
     """The whole of one run; returns the result object.  `on_cpu` (the tests'
     tiny sizes only) skips the look for a chip; `overrides` replaces traffic
     parameters (the rate sweep); `control` adds the float8 control's numbers
-    (benchmarks/tests/control_run.py).  Beside the contract's keys the result
-    carries "extra": every metric's value, the numbers compared, the counters."""
+    (benchmarks/tests/control_run.py); `tracer_rate` is the program's tracer's
+    sample rate where it is not 1.0 with `trace` and 0.0 without
+    (benchmarks/tests/traced_run.py).  Beside the contract's keys the result
+    carries "extra": every metric's value, the numbers compared, the counters,
+    and what the traced run read them from."""
     import jax
     import jax.monitoring
 
-    from benchmarks.harness import check, costs, engine, reduce, reference, traffic
-    from llm_d_kv_cache_manager_tpu.models import llama
+    from benchmarks.harness import (
+        check, costs, engine, family, program_spans, reduce, traffic,
+    )
+    from llm_d_kv_cache_manager_tpu.obs.trace import TRACER
     from llm_d_kv_cache_manager_tpu.parallel.compile_cache import (
         configure_compile_cache,
     )
@@ -92,16 +100,15 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
     tracing = trace and chip
     if trace:
         seconds = min(seconds, TRACE_SECONDS)
+    if tracer_rate is None:
+        tracer_rate = float(trace)
+    TRACER.configure(sample_rate=tracer_rate, ring_size=TRACE_RING)
     rec = engine.Records(annotate=tracing)
-    model = llama.LlamaConfig(
-        vocab_size=cfg["vocab_size"], d_model=cfg["hidden_size"],
-        n_layers=cfg["num_hidden_layers"], n_heads=cfg["num_attention_heads"],
-        n_kv_heads=cfg["num_key_value_heads"], d_ff=cfg["intermediate_size"],
-        rope_theta=float(cfg["rope_theta"]), block_size=engine.BLOCK,
-        dtype=cfg["torch_dtype"])
+    program = family.program(cfg)
     shapes = traffic.shapes(tr)
-    fleet = engine.Fleet(model, reference.make_weights(cfg, seed), tr, shapes,
-                         rec, interpret=not chip)
+    fleet = engine.Fleet(program, program.from_published(cfg, engine.BLOCK),
+                         family.reference(cfg).make_weights(cfg, seed), tr,
+                         shapes, rec, interpret=not chip)
     trace_dir = os.path.join(ROOT, ".bench_trace", workload)
     state = {}
 
@@ -154,13 +161,25 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
     numbers.update(check.against_reference(cfg, seed, picked))
     correct = bool(picked) and check.verdict(numbers, cell["limits"])
 
-    pd_trace = None
+    pd_trace = placed = clock = None
+    rows, dropped = TRACER.recorder.export()
+    if dropped:
+        sys.exit(f"{dropped} traces of the program were dropped: the ring "
+                 f"of {TRACE_RING} is too small for this window")
     if tracing:
         found = glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
                           recursive=True)
+        with open(os.path.join(os.path.dirname(found[0]), "program_spans.jsonl"),
+                  "w") as f:
+            f.writelines(json.dumps(row) + "\n" for row in rows)
         pd_trace = reduce.Trace(found[0])
         device["busy_s"], device["window_s"] = pd_trace.busy_s, pd_trace.window_s
-    env = {"cfg": cfg, "shapes": shapes, "peaks": peak}
+        clock = program_spans.clock_offset(rec.spans, pd_trace.host)
+        print("clock offset, its spread, pairs:", clock, flush=True)
+        if clock[1] <= program_spans.CLOCK_SPREAD_LIMIT_S:
+            placed = program_spans.place(rows, clock[0])
+    env = {"cfg": cfg, "shapes": shapes, "peaks": peak, "rows": rows,
+           "window": (state["t0"], state["t0"] + window_s)}
     values = {name: reduce.read_metric(spec["read"], rec, pd_trace, window_s, env)
               for name, spec in specs.items()}
     values["setup_s"] = state["t0"] - T_START
@@ -173,18 +192,23 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
                           if v is not None and (n in per_layer) == bool(trace)},
               "device": device}
     if pd_trace is not None:
-        result["breakdown"] = pd_trace.breakdown()
+        result["breakdown"] = pd_trace.breakdown(placed=placed)
     result["extra"] = {"values": values, "numbers": numbers, "window_s": window_s,
-                       "counters": dict(rec.counters)}
+                       "counters": dict(rec.counters), "spans": rec.spans,
+                       "rows": rows, "window": env["window"], "trace": pd_trace,
+                       "clock": clock, "placed": placed}
     by_span: dict[str, list] = {}
     for name, start, stop in rec.spans:
         by_span.setdefault(name, []).append(stop - start)
-    print("spans p50/mean ms:", {n: (round(1e3 * reduce.percentile(v, 50), 3),
-                                     round(1e3 * sum(v) / len(v), 3))
-                                 for n, v in by_span.items()}, flush=True)
+    print("spans p50/mean/max ms:",
+          {n: tuple(round(1e3 * x, 3) for x in (
+              reduce.percentile(v, 50), sum(v) / len(v), max(v)))
+           for n, v in by_span.items()}, flush=True)
     if control:
         result["extra"]["control"] = check.against_reference(cfg, seed, picked,
                                                              "fp8")
+    result["checks"] = {name: {"value": value, "limit": cell["limits"][name]}
+                        for name, value in numbers.items()}
     return result
 
 
@@ -196,7 +220,7 @@ def main() -> None:
     ap.add_argument("--trace", type=int, choices=(0, 1), required=True)
     a = ap.parse_args()
     result = run_cell(a.workload, a.seed, a.seconds, bool(a.trace))
-    del result["extra"]  # the last line holds the contract's keys and no others
+    del result["extra"]  # the contract's keys, then the numbers compared
     print(json.dumps(result), flush=True)
 
 

@@ -14,12 +14,14 @@ import numpy as np
 import pytest
 
 from benchmarks import run
-from benchmarks.harness import check, costs, engine, reduce, reference, traffic
+from benchmarks.harness import (
+    check, costs, engine, family, family_llama, reduce, traffic,
+)
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 TINY = os.path.join(HERE, "data", "tiny")
 CELLS = ("tiny-docs-shared", "tiny-docs-unique", "tiny-chat-sysprompt")
-KEYS = {"correct", "attempted", "failed", "metrics", "device"}
+KEYS = {"correct", "attempted", "failed", "metrics", "device", "checks"}
 
 
 @pytest.fixture(scope="module")
@@ -47,8 +49,11 @@ def test_cell_runs_and_prints_the_contract(root, cell, trace):
     want = {n for n, s in specs.items() if ("layer" in s) == trace
             and s["read"]["from"] not in ("device", "roofline")}
     assert set(result["metrics"]) == want | (set() if trace else {"setup_s"})
-    assert all(m["value"] > 0 or n in ("queue_wait_p50_s",)
+    assert all(m["value"] > 0
+               or n in ("queue_wait_p50_s", "router_memo_block_share")
                for n, m in result["metrics"].items())
+    assert list(result)[-1] == "checks" and all(
+        c["value"] <= c["limit"] for c in result["checks"].values())
     json.dumps(result)
 
 
@@ -122,7 +127,7 @@ def test_float8_control_fails_and_the_program_passes(root, cell, capsys):
     limits = load(root, "cells", cell)["limits"]
     assert result["correct"]
     assert not check.verdict(result["extra"]["control"], limits)
-    assert "FAILED" in capsys.readouterr().out
+    assert "FAILED" in capsys.readouterr().err
 
 
 def test_an_altered_token_makes_the_run_incorrect(root, monkeypatch):
@@ -130,7 +135,7 @@ def test_an_altered_token_makes_the_run_incorrect(root, monkeypatch):
 
     def broken(self, *args):
         token, top, row = sound(self, *args)
-        return (token + 1) % self.cfg.vocab_size, top, row
+        return (token + 1) % 512, top, row
 
     monkeypatch.setattr(engine.Fleet, "prefill", broken)
     result = run.run_cell(CELLS[1], 4, 0.5, False, root=root, on_cpu=True)
@@ -140,10 +145,10 @@ def test_an_altered_token_makes_the_run_incorrect(root, monkeypatch):
 
 def test_reference_is_causal_and_padding_changes_nothing():
     cfg = load(TINY, "configs", "tiny")
-    w = reference.make_weights(cfg, 1)
+    w = family_llama.make_weights(cfg, 1)
     ids = np.arange(1, 301) % 500 + 1
-    full = np.asarray(reference.forward_logits(w, cfg, ids, 300))
-    part = np.asarray(reference.forward_logits(w, cfg, ids[:200], 1))
+    full = np.asarray(family_llama.forward_logits(w, cfg, ids, 300))
+    part = np.asarray(family_llama.forward_logits(w, cfg, ids[:200], 1))
     np.testing.assert_allclose(full[199], part[0], rtol=1e-4, atol=1e-5)
 
 
@@ -160,26 +165,67 @@ def test_percentile_is_nearest_rank():
     assert reduce.reduce_values([], "p50") is None
 
 
-def test_a_cell_is_added_with_files_only(root, tmp_path):
-    """A new configuration, a traffic mix of an existing kind and a per-layer
-    metric over an existing span: one file each, plus the cell's."""
+def test_a_cell_is_added_with_files_only(root, tmp_path, second_family):
+    """A new configuration of a second family, a traffic mix of an existing
+    kind, a per-layer metric over an existing span, one over a span of the
+    program's own and a roofline with a cost function of the family's: one new
+    file each, plus the cell's, and no edit to a file that is there.  The
+    family's two modules are `tests/data/two/*.py`, found beside the harness's
+    own (`second_family`, conftest.py)."""
     new = tmp_path / "bench"
     shutil.copytree(root, new)
-    cfg = {**load(root, "configs", "tiny"), "num_hidden_layers": 1}
-    tr = {**load(root, "traffic", "docs-unique"), "prompt_tokens": 96}
-    metric = {"unit": "s", "better": "lower", "source": "program_span",
-              "layer": "pod engine", "moves": "tok_s",
-              "read": {"from": "span", "name": "account", "reduce": "p95"}}
-    cell = {**load(root, "cells", "tiny-docs-unique"), "config": "one-layer",
-            "traffic": "short-unique", "metrics": ["tok_s", "account_p95_s"]}
+    cfg = {**load(root, "configs", "tiny"), "num_hidden_layers": 1,
+           "family": "two"}
+    tr = {**load(root, "traffic", "chat-sysprompt"), "turn_tokens": 48}
+    layer = {"unit": "s", "better": "lower", "source": "program_span",
+             "moves": "itl_p50_s"}
+    account = {**layer, "layer": "pod engine",
+               "read": {"from": "span", "name": "account", "reduce": "p95"}}
+    bookkeeping = {**layer, "layer": "router read path", "read": {
+        "from": "program_span", "trace": "indexer.score", "name": "bookkeeping",
+        "reduce": "p50"}}
+    roofline = {"unit": "%", "better": "higher", "source": "device_trace",
+                "layer": "model step", "moves": "itl_p50_s", "read": {
+                    "from": "roofline", "program": "miss_prefill_T\\d+",
+                    "cost": "miss_prefill_min_s"}}
+    cell = {**load(root, "cells", "tiny-chat-sysprompt"), "config": "one-layer",
+            "traffic": "short-chat",
+            "metrics": ["itl_p50_s", "account_p95_s", "router_bookkeeping_p50_s",
+                        "miss_prefill_roofline"]}
     for kind, name, body in (("configs", "one-layer", cfg),
-                             ("traffic", "short-unique", tr),
-                             ("metrics", "account_p95_s", metric),
+                             ("traffic", "short-chat", tr),
+                             ("metrics", "account_p95_s", account),
+                             ("metrics", "router_bookkeeping_p50_s", bookkeeping),
+                             ("metrics", "miss_prefill_roofline", roofline),
                              ("cells", "one-layer-short", cell)):
         (new / kind / f"{name}.json").write_text(json.dumps(body))
     result = run.run_cell("one-layer-short", 8, 0.5, True, root=str(new),
                           on_cpu=True)
-    assert result["correct"] and result["metrics"]["account_p95_s"]["value"] > 0
+    # hit and miss prefills and decode steps all ran on the two-leaf pool
+    assert result["correct"] and result["extra"]["counters"]["decode_steps"] > 0
+    assert set(result["metrics"]) == {"account_p95_s", "router_bookkeeping_p50_s"}
+    assert all(m["value"] > 0 for m in result["metrics"].values())
+    # the roofline needs a device trace: on the recorded one, its cost function
+    # is found in the family's module
+    env = {"cfg": cfg, "shapes": {"miss": (8448,)},
+           "peaks": costs.peaks("TPU v5 lite")}
+    share = reduce.read_metric(roofline["read"], engine.Records(),
+                               fixture("docs-shared"), 1.2, env)
+    assert share == pytest.approx(
+        100 * 2 * 8448 * (64 * 16 * 12 + 3 * 64 * 128) / 197e12
+        / ((0.27577677 + 0.262382062) / 2), rel=1e-6)
+
+
+def test_a_configuration_names_its_family():
+    cfg = load(TINY, "configs", "tiny")
+    assert family.reference(cfg) is family_llama
+    assert all(hasattr(family.program(cfg), n) for n in family.NAMES["program"])
+    for broken in ({k: v for k, v in cfg.items() if k != "family"},
+                   {**cfg, "family": "no-such"}):
+        with pytest.raises(KeyError):
+            family.reference(broken)
+        with pytest.raises(KeyError):
+            family.program(broken)
 
 
 def test_manifest_agrees_with_the_files():
@@ -195,7 +241,9 @@ def test_manifest_agrees_with_the_files():
         load(run.BENCH, "traffic", cell["traffic"])
     for c in manifest["configs"]:
         assert c["file"] == f"benchmarks/configs/{c['name']}.json"
-        assert set(c["reduced"]) == set(load(run.BENCH, "configs", c["name"])["reduced"])
+        cfg = load(run.BENCH, "configs", c["name"])
+        assert set(c["reduced"]) == set(cfg["reduced"])
+        assert family.reference(cfg) and family.program(cfg)  # both sides whole
     for name, m in metrics.items():
         if name == "setup_s":
             continue

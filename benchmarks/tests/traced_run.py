@@ -3,12 +3,12 @@ recording them costs.
 
     python3 benchmarks/tests/traced_run.py <cell> <seconds> <seed> <rate> [<rate> ...]
 
-Runs the cell's traced window (`run_cell(..., trace=True)`: profiler on, at most
-`run.TRACE_SECONDS`) once per tracer sample rate, in turn, in one process.  A
-run at rate 1.0 exports every span of the window (`TRACER.recorder.export()`;
-a dropped trace fails the run), writes them to
-`.bench_trace/<cell>/program_spans.jsonl` beside the `.xplane.pb`, and prints
-one JSON line with
+Runs the cell's traced window (`run_cell(..., trace=True, tracer_rate=<rate>)`:
+profiler on, at most `run.TRACE_SECONDS`) once per tracer sample rate, in turn,
+in one process.  `run_cell` exports every span of the window (a dropped trace
+fails the run) and writes them to `.bench_trace/<cell>/program_spans.jsonl`
+beside the `.xplane.pb`; a run at rate 1.0 is what `run.py --trace 1` does, and
+prints one JSON line with
 
 - every `program_span` / `program_attr` metric under `benchmarks/metrics/` that
   finds something to read in this cell, and the p50 of every other span name;
@@ -18,7 +18,8 @@ one JSON line with
   `route.score` span, and the event plane's over `publish_events`; how many
   program spans lie outside the benchmark span that called them;
 - on a chip: the clock offset between `perf_counter` and the profiler and its
-  spread (the run fails above 0.2 ms), and `idle_gaps_inner`.
+  spread (the run fails above 0.2 ms), and the whole of `idle_gaps_inner`
+  (`run.py`'s `breakdown` carries its ten largest).
 
 A run at rate 0.0 prints the outside spans alone.  Runs at a rate between the
 two (0.5) give the overhead, profiler on on both sides: after the last run one
@@ -30,10 +31,6 @@ drifts by more than the tracer costs (1.53 to 1.80 ms untraced, PERF.md section
 `tokenize.encode` read 1 ms above the later runs' (4.48 against 3.35 ms), so
 start with one run to spare, e.g. `0 0.5 0.5 0.5 0.5`.  The numbers of PERF.md
 section 6 (PR 25) are these lines.
-
-`run_cell` returns neither its `Records` nor its trace, so this script keeps
-the `Records` it builds by standing in for the class; `run.py` handing both to
-the readers is the edit PERF.md's Open questions name.
 """
 
 from __future__ import annotations
@@ -48,25 +45,12 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__)))))
 
 from benchmarks import run  # noqa: E402
-from benchmarks.harness import engine, program_spans, reduce  # noqa: E402
-from llm_d_kv_cache_manager_tpu.obs.trace import TRACER  # noqa: E402
+from benchmarks.harness import program_spans, reduce  # noqa: E402
 
 OUTSIDE = {"indexer.score": "route.score", "kvevents.message": "publish_events"}
 STAGES = {"indexer.score": ("tokenize", "hash_blocks", "index_lookup", "score"),
           "kvevents.message": ("kvevents.queue_wait", "kvevents.decode",
                                "kvevents.apply")}
-RING = 1 << 16
-Records = engine.Records
-
-
-class KeptRecords(Records):
-    """`engine.Records`, remembering the instance `run_cell` made."""
-
-    last: "KeptRecords | None" = None
-
-    def __init__(self, annotate: bool = False) -> None:
-        super().__init__(annotate)
-        KeptRecords.last = self
 
 
 def program_metrics(root: str, rows, t0: float, t1: float) -> dict:
@@ -184,48 +168,33 @@ def placed_inside(placed, host_spans) -> dict:
 def traced_run(cell: str, seed: int, seconds: float, rate: float, *,
                root: str = run.BENCH, on_cpu: bool = False) -> dict:
     """One traced window at one tracer rate; the printed line's object."""
-    engine.Records = KeptRecords
-    try:
-        TRACER.configure(sample_rate=rate, ring_size=RING)
-        result = run.run_cell(cell, seed, seconds, True, root=root, on_cpu=on_cpu)
-    finally:
-        engine.Records = Records
-    rec = KeptRecords.last
-    rows, dropped = TRACER.recorder.export()
-    if dropped:
-        sys.exit(f"{dropped} traces were dropped: the ring of {RING} is too "
-                 "small for this window")
+    result = run.run_cell(cell, seed, seconds, True, root=root, on_cpu=on_cpu,
+                          tracer_rate=rate)
+    extra = result["extra"]
+    spans, rows, (t0, t1) = extra["spans"], extra["rows"], extra["window"]
     line = {"cell": cell, "seed": seed, "rate": rate, "correct": result["correct"],
             "device": result["device"],
             "outside": {name: reduce.reduce_values(
-                [b - a for n, a, b in rec.spans if n == name], "p50")
+                [b - a for n, a, b in spans if n == name], "p50")
                 for name in OUTSIDE.values()}}
-    if not rate or not rec.spans:
+    if not rate or not spans:
         return line
-    t0 = min(a for _, a, _ in rec.spans)
-    t1 = max(b for _, _, b in rec.spans)
     traces = program_spans.by_trace(rows, t0, t1)
     line["traces"] = len(traces)
     line["metrics"] = program_metrics(root, rows, t0, t1)
     line["spans_p50"] = other_spans(traces, rows, t0, t1)
-    line["checks"] = consistency(traces, rec.spans)
+    line["checks"] = consistency(traces, spans)
     if rate < 1.0:
-        line["split"] = split(traces, rec.spans)
-    found = glob.glob(os.path.join(run.ROOT, ".bench_trace", cell, "**",
-                                   "*.xplane.pb"), recursive=True)
-    if "busy_s" in result["device"] and found and rate == 1.0:
-        with open(os.path.join(os.path.dirname(found[0]), "program_spans.jsonl"),
-                  "w") as f:
-            f.writelines(json.dumps(row) + "\n" for row in rows)
-        trace = reduce.Trace(found[0])
-        offset, spread, pairs = program_spans.clock_offset(rec.spans, trace.host)
+        line["split"] = split(traces, spans)
+    if extra["clock"] and rate == 1.0:
+        offset, spread, pairs = extra["clock"]
         line["clock"] = {"offset_s": offset, "spread_s": spread, "pairs": pairs}
-        if spread > program_spans.CLOCK_SPREAD_LIMIT_S:
+        if extra["placed"] is None:
             sys.exit(f"the two clocks' offset spreads by {spread * 1e3:.3f} ms")
-        placed = program_spans.place(rows, offset)
-        line["clock"].update(placed_inside(placed, trace.host))
+        line["clock"].update(placed_inside(extra["placed"], extra["trace"].host))
         line["idle_gaps"] = result["breakdown"]["idle_gaps"]
-        line["idle_gaps_inner"] = program_spans.idle_gaps_inner(trace, placed)
+        line["idle_gaps_inner"] = program_spans.idle_gaps_inner(
+            extra["trace"], extra["placed"])
     return line
 
 
