@@ -22,6 +22,83 @@ import jax.numpy as jnp
 import numpy as np
 
 
+@dataclass(frozen=True)
+class KVGroupSpec:
+    """One group of layers that share a block table and a retention
+    rule: what one slot of the group holds.  A dense model has one
+    group (every layer, whole context); a model that mixes window and
+    full attention layers has one group per kind, and ``window`` is the
+    number of positions a layer of the group still reads (None: all).
+    Block bytes, pool shapes and the scatter's geometry are read from
+    here by the pool below, by the pod's cache (models/pod.py) and by
+    each family's model step."""
+
+    num_layers: int
+    block_size: int
+    num_kv_heads: int
+    head_dim: int
+    dtype: str = "bfloat16"
+    window: Optional[int] = None
+    # A slot as [2, Hkv, block, Dh] instead of [2, block, Hkv, Dh]: the
+    # chip's tiles then lie over (block, Dh) whole, so a model with fewer
+    # than 8 KV heads neither pads its slots in VMEM nor has its pool
+    # re-laid-out around a prefill's scatter.  The uniform pool keeps the
+    # first form (the offload path's file layout is that one).
+    heads_first: bool = False
+
+    @property
+    def block_nbytes(self) -> int:
+        """Bytes of one slot: K and V of ``block_size`` positions over
+        the group's layers."""
+        return (
+            self.num_layers
+            * 2
+            * self.block_size
+            * self.num_kv_heads
+            * self.head_dim
+            * jnp.dtype(self.dtype).itemsize
+        )
+
+    def layer_shape(self, num_blocks: int) -> tuple:
+        """One layer's share of a pool of ``num_blocks`` slots."""
+        inner = (
+            (self.num_kv_heads, self.block_size)
+            if self.heads_first
+            else (self.block_size, self.num_kv_heads)
+        )
+        return (num_blocks, 2) + inner + (self.head_dim,)
+
+    @property
+    def window_blocks(self) -> int:
+        """Blocks before a block boundary that a query there reads:
+        ceil((window - 1) / block_size).  A prefix is servable only
+        where the group holds that many of its last blocks."""
+        if self.window is None:
+            raise ValueError("a group without a window reads every block")
+        return -(-(self.window - 1) // self.block_size)
+
+
+def scatter_kv_blocks(
+    kv_layer, k, v, block_ids, block_size, heads_first=False
+):
+    """Write per-token K/V ([B, T, Hkv, Dh] each, T a multiple of
+    ``block_size``) into the slots of one layer's pool
+    (``KVGroupSpec.layer_shape``) named by ``block_ids``
+    ([B, T/block_size]).  ONE layout for every prefill path of every
+    family: were it duplicated, a pool layout change could silently
+    diverge between them."""
+    B, T = k.shape[:2]
+    kv = jnp.stack((k, v), axis=2)  # [B, T, 2, Hkv, Dh]
+    kv = kv.reshape(
+        B, T // block_size, block_size, 2, kv.shape[-2], kv.shape[-1]
+    ).transpose(
+        (0, 1, 3, 4, 2, 5) if heads_first else (0, 1, 3, 2, 4, 5)
+    )  # [B, nb, 2, block, Hkv, Dh], or [B, nb, 2, Hkv, block, Dh]
+    return kv_layer.at[block_ids.reshape(-1)].set(
+        kv.reshape((-1,) + kv.shape[2:]).astype(kv_layer.dtype)
+    )
+
+
 @dataclass
 class KVCachePoolConfig:
     num_layers: int
@@ -30,6 +107,17 @@ class KVCachePoolConfig:
     num_kv_heads: int
     head_dim: int
     dtype: str = "bfloat16"
+
+    @property
+    def spec(self) -> KVGroupSpec:
+        """The pool's one group: every layer, whole context."""
+        return KVGroupSpec(
+            self.num_layers,
+            self.block_size,
+            self.num_kv_heads,
+            self.head_dim,
+            self.dtype,
+        )
 
 
 @jax.jit
@@ -81,13 +169,8 @@ class KVCachePool:
         sharding: Optional[jax.sharding.Sharding] = None,
     ) -> None:
         self.config = config
-        shape = (
-            config.num_layers,
-            config.num_blocks,
-            2,
-            config.block_size,
-            config.num_kv_heads,
-            config.head_dim,
+        shape = (config.num_layers,) + config.spec.layer_shape(
+            config.num_blocks
         )
         dtype = jnp.dtype(config.dtype)
         if sharding is not None:
@@ -104,15 +187,7 @@ class KVCachePool:
     @property
     def block_nbytes(self) -> int:
         """Bytes of one block across all layers (the offload unit)."""
-        c = self.config
-        return (
-            c.num_layers
-            * 2
-            * c.block_size
-            * c.num_kv_heads
-            * c.head_dim
-            * jnp.dtype(c.dtype).itemsize
-        )
+        return self.config.spec.block_nbytes
 
     def gather_to_host(self, block_ids: Sequence[int]) -> np.ndarray:
         """Pull blocks to host: one gather in HBM + one transfer.

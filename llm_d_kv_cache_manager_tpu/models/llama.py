@@ -30,6 +30,7 @@ import optax
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
+from llm_d_kv_cache_manager_tpu.models.kv_cache_pool import scatter_kv_blocks
 from llm_d_kv_cache_manager_tpu.ops.attention import causal_gqa_attention
 from llm_d_kv_cache_manager_tpu.ops.flash_attention import flash_gqa_attention
 from llm_d_kv_cache_manager_tpu.ops import flash_pallas
@@ -307,22 +308,6 @@ def forward(
     return _logits(x, params)
 
 
-def _scatter_kv_blocks(kv_layer, k, v, block_ids, block_size):
-    """Write per-token K/V ([B, T, Hkv, Dh] each, T a multiple of
-    ``block_size``) into the pool blocks named by ``block_ids``
-    ([B, T/block_size]).  ONE layout for every prefill path — were it
-    duplicated, a pool layout change could silently diverge between
-    them."""
-    B, T = k.shape[:2]
-    kv = jnp.stack((k, v), axis=2)  # [B, T, 2, Hkv, Dh]
-    kv = kv.reshape(
-        B, T // block_size, block_size, 2, kv.shape[-2], kv.shape[-1]
-    ).transpose(0, 1, 3, 2, 4, 5)  # [B, nb, 2, block, Hkv, Dh]
-    return kv_layer.at[block_ids.reshape(-1)].set(
-        kv.reshape((-1,) + kv.shape[2:]).astype(kv_layer.dtype)
-    )
-
-
 def prefill_paged(
     params: Params,
     tokens: jnp.ndarray,
@@ -351,7 +336,7 @@ def prefill_paged(
         attn = _prefill_attention(q, k, v, cfg, interpret=interpret)
         x = x + jnp.einsum("bthk,hkd->btd", attn, lp["wo"])
         x = x + _mlp(_rms_norm(x, lp["ln2"]), lp)
-        kv_layer = _scatter_kv_blocks(
+        kv_layer = scatter_kv_blocks(
             kv_layer, k, v, block_table, cfg.block_size
         )
         return x, kv_layer
@@ -416,7 +401,7 @@ def prefill_continue(
         )
         x = x + jnp.einsum("bthk,hkd->btd", attn, lp["wo"])
         x = x + _mlp(_rms_norm(x, lp["ln2"]), lp)
-        kv_layer = _scatter_kv_blocks(
+        kv_layer = scatter_kv_blocks(
             kv_layer, k, v, suffix_ids, cfg.block_size
         )
         return x, kv_layer
@@ -491,7 +476,7 @@ def prefill_chunked(
             # Scatter this chunk's K/V first: its keys then live in
             # the pool like every earlier chunk's, and ONE gathered
             # read serves the whole causal context.
-            kv_layer = _scatter_kv_blocks(
+            kv_layer = scatter_kv_blocks(
                 kv_layer, k, v, chunk_ids, cfg.block_size
             )
             full = jnp.take(kv_layer, block_table, axis=0)

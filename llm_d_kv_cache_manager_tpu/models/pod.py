@@ -1,0 +1,595 @@
+"""The pod's cache manager and its three compiled programs, generic over a
+family's model step (the module that gives ``new_pool``, ``prefill_paged``,
+``prefill_continue``, ``decode_step`` and, where the family's cache has more
+than one kind of state, ``cache_policy``).
+
+One group.  A logical block (one hash, ``block_size`` tokens) owns a slot of
+the pool while it is cached; the allocator hands out free blocks first, then
+least-recently-used cached blocks that no live sequence references, and gives
+their hashes back as evicted (the engine publishes them as ``BlockRemoved``).
+With no ``cache_policy`` this is all there is, rule for rule the benchmark's
+``harness/pod.py``.
+
+Two groups (a model that mixes window and full attention layers, after vLLM's
+hybrid KV-cache manager).  The *full group* is the above: one slot per logical
+block, K/V of the full-attention layers.  The *window group* has fewer slots,
+each the sliding layers' K/V of one logical block, kept by these rules:
+
+- every block handed out by ``alloc`` takes a window slot, except that of one
+  call only the last ``store_blocks`` do (a miss prefill stores the window K/V
+  of its trailing blocks only: the engine appends a call's blocks to the chain
+  in the order they were handed out);
+- a prefix of n blocks is a hit iff the full group holds all n and the window
+  group holds the last ``ceil((window-1)/block_size)`` of them (all n if
+  fewer): ``cached_prefix`` answers by that rule, and ``window_half_hits``
+  counts the prefixes it had to refuse;
+- a slot is *held* while its block is referenced by a live sequence and was in
+  that sequence's window at the last decode step (or was written since), and
+  while its block is referenced under a hash at all; every other slot is
+  *released*: findable until it is reused;
+- released slots are reused coldest first, those of blocks that no ask has
+  named before those of asked ones (a hit warms and marks the blocks it used;
+  a miss marks the blocks it stores under the hashes it asked for): a stream
+  of never-asked suffixes cannot push out a prefix that is asked for again.
+  ``protect_asked`` gives the full group the same order;
+- **the index stays truthful without a new event**: where the window group
+  reuses the slot of a block that is still cached, the pod evicts that block
+  and the tail of every chain through it from the full group too, and their
+  hashes ride in the list ``alloc`` returns, so ``BlockStored`` still means
+  "servable from here" and ``BlockRemoved`` "no longer".
+
+Positions are never told to a pod; they are known at each program call (a
+table is in chain order, decode brings ``context_len``), so ``jit_programs``
+returns plain functions that build the window layers' table on the host,
+record spans (``kvpool.window``, ``kv.read``, ``moe.expert_load``) and call the
+inner compiled programs, which keep the names the trace reduction looks for.
+"""
+
+from __future__ import annotations
+
+import weakref
+from collections import OrderedDict
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from llm_d_kv_cache_manager_tpu.obs.trace import root_trace, span
+
+RESERVED = np.iinfo(np.int64).max  # a slot taken and not yet in any window
+
+
+class PodKV:
+    """What a pod hands its programs as ``kv``: the arrays, and the pod whose
+    host-side tables say where each layer kind reads and writes."""
+
+    __slots__ = ("arrays", "_pod", "_table")
+
+    def __init__(self, arrays, pod) -> None:
+        # No cycle pod -> kv -> pod: a pod that is dropped frees its pools at
+        # once, without waiting for (or being frozen out of) the collector.
+        self.arrays, self._pod = arrays, weakref.ref(pod)
+        self._table = (None, None)  # the last decode table, host and device
+
+    def on_device(self, table) -> jax.Array:
+        """The decode steps' logical table on the device: sent again only
+        when a row changed (an admission or a finish, not every step)."""
+        table = np.asarray(table, np.int32)
+        host, device = self._table
+        if host is None or host.shape != table.shape or not np.array_equal(
+                host, table):
+            self._table = (table.copy(), jax.device_put(table))
+        return self._table[1]
+
+    @property
+    def pod(self) -> "Pod":
+        return self._pod()
+
+
+class Cached:
+    """hash -> block, least recently used first, as the engine writes it
+    (``pod.cached[h] = block``).  Two segments: blocks no ask has named, and
+    asked ones (only with ``protect_asked``; else all stay in the first).
+    Keeps the reverse map that coupled evictions need."""
+
+    def __init__(self, pod: "Pod") -> None:
+        self.pod = weakref.proxy(pod)
+        self.cold: OrderedDict[int, int] = OrderedDict()
+        self.hot: OrderedDict[int, int] = OrderedDict()
+        self.hash_of: dict[int, int] = {}
+
+    def __contains__(self, h) -> bool:
+        return h in self.cold or h in self.hot
+
+    def __getitem__(self, h) -> int:
+        return self.cold[h] if h in self.cold else self.hot[h]
+
+    def __iter__(self):
+        yield from self.cold
+        yield from self.hot
+
+    def items(self):
+        yield from self.cold.items()
+        yield from self.hot.items()
+
+    def __setitem__(self, h, bid: int) -> None:
+        segment = self.cold if h in self.cold else self.hot
+        if h in segment:  # stored again elsewhere: its place in the order stays
+            self._unhash(segment[h], h)
+        else:
+            asked = self.pod.protect_asked and h in self.pod.last_ask
+            segment = self.hot if asked else self.cold
+        segment[h] = bid
+        self.hash_of[bid] = h
+        self.pod.hashed[bid] = True
+        self.pod.asked[bid] = segment is self.hot
+
+    def __delitem__(self, h) -> None:
+        self._unhash((self.cold if h in self.cold else self.hot).pop(h), h)
+
+    def update(self, pairs) -> None:
+        for h, bid in pairs:
+            self[h] = bid
+
+    def _unhash(self, bid: int, h) -> None:
+        if self.hash_of.get(bid) == h:
+            del self.hash_of[bid]
+            self.pod.hashed[bid] = False
+
+    def touch(self, h) -> None:
+        """Most recently used; an asked block moves to the second segment."""
+        if self.pod.protect_asked:
+            bid = self.cold.pop(h, None)
+            if bid is not None:
+                self.hot[h] = bid
+                self.pod.asked[bid] = True
+                return
+        (self.cold if h in self.cold else self.hot).move_to_end(h)
+
+    def coldest_unreferenced(self, n: int) -> list:
+        """Up to n (hash, block) no live sequence references, in the order
+        they go: never-asked first, least recently used first."""
+        out = []
+        for segment in (self.cold, self.hot):
+            for h, bid in segment.items():
+                if len(out) == n:
+                    return out
+                if not self.pod.refs[bid]:
+                    out.append((h, bid))
+        return out
+
+
+class WindowGroup:
+    """The second group of slots: which logical block holds which slot, when
+    each was last in a live window, and the chains the blocks stand in."""
+
+    def __init__(self, pod: "Pod", slots: int, store_blocks: int, window: int,
+                 block_size: int) -> None:
+        self.pod, self.window, self.block = weakref.proxy(pod), window, block_size
+        self.need = -(-(window - 1) // block_size)  # blocks behind a boundary
+        self.width = self.need + 1  # blocks a decode step's window can span
+        self.store = store_blocks
+        self.slot_of = np.full(pod.pool_blocks, -1, np.int32)
+        self.block_of = np.full(slots, -1, np.int32)
+        self.stamp = np.zeros(slots, np.int64)  # tick it was last live
+        self.free = list(range(slots - 1, -1, -1))
+        self.tick = self.live_tick = 0
+        self.parent: dict[int, int] = {}
+        self.children: dict[int, set] = {}
+        # over the pod's life; a span reports what was added since the last
+        self.counts = dict(taken=0, released=0, reclaimed=0, half_hits=0)
+        self.reported = dict(self.counts)
+
+    # -- bookkeeping by block ------------------------------------------
+
+    def drop(self, bid: int) -> None:
+        slot = self.slot_of[bid]
+        if slot >= 0:
+            self.slot_of[bid], self.block_of[slot] = -1, -1
+            self.free.append(int(slot))
+
+    def forget(self, bid: int) -> None:
+        """A block that is handed out again starts with no slot, no place in
+        a chain and no ask to its name."""
+        self.drop(bid)
+        parent = self.parent.pop(bid, None)
+        if parent is not None:
+            self.children[parent].discard(bid)
+        for child in self.children.pop(bid, ()):
+            self.parent.pop(child, None)
+        self.pod.asked[bid] = False
+
+    def link(self, chain) -> None:
+        for a, b in zip(chain[:-1], chain[1:]):
+            a, b = int(a), int(b)
+            if self.parent.get(b) != a:
+                self.parent[b] = a
+                self.children.setdefault(a, set()).add(b)
+
+    def tail(self, bid: int) -> list:
+        """The block and every block of a chain through it, behind it."""
+        out, stack = [], [bid]
+        while stack:
+            out.append(stack.pop())
+            stack.extend(self.children.get(out[-1], ()))
+        return out
+
+    # -- the rules ------------------------------------------------------
+
+    def servable(self, ids: list, asked: int) -> int:
+        """The longest prefix of `ids` (cached in the full group, chain
+        order) whose last `need` blocks all hold a window slot."""
+        if not ids:
+            return 0
+        have = self.slot_of[np.asarray(ids)] >= 0
+        n = np.arange(1, len(ids) + 1)
+        run = n - np.maximum.accumulate(np.where(have, 0, n))
+        good = n[run >= np.minimum(n, self.need)]
+        m = int(good[-1]) if len(good) else 0
+        if len(ids) == asked and m < asked:
+            self.counts["half_hits"] += 1
+        return m
+
+    def warm(self, ids) -> None:
+        slots = self.slot_of[np.asarray(ids, np.int64)]
+        self.stamp[slots[slots >= 0]] = self.tick
+
+    def take(self, ids: list) -> list:
+        """Slots for the blocks one `alloc` hands out (the last `store` of
+        them); returns the hashes a reuse evicted from the full group."""
+        for bid in ids:
+            self.forget(bid)
+        want = ids[-self.store:]
+        evicted = self.reclaim(len(want)) if len(self.free) < len(want) else []
+        for bid in want:
+            self._assign(bid)
+        return evicted
+
+    def _assign(self, bid: int) -> None:
+        slot = self.free.pop()
+        self.slot_of[bid], self.block_of[slot] = slot, bid
+        self.stamp[slot] = RESERVED
+        self.counts["taken"] += 1
+
+    def reclaim(self, target: int) -> list:
+        """Free slots until `target` are free: released ones, never-asked
+        before asked, coldest first; a cached block goes with its tail."""
+        pod = self.pod
+        block = np.maximum(self.block_of, 0)
+        released = (pod.refs[block] == 0) | (
+            ~pod.hashed[block] & (self.stamp < self.live_tick))
+        slots = np.flatnonzero((self.block_of >= 0) & released)
+        order = slots[np.lexsort((self.stamp[slots],
+                                  pod.asked[self.block_of[slots]]))]
+        evicted = []
+        for slot in order:
+            if len(self.free) >= target:
+                break
+            bid = int(self.block_of[slot])
+            if bid < 0:
+                continue  # went with an earlier block's tail
+            before = len(self.free)
+            if pod.hashed[bid]:
+                evicted += pod.evict_tail(bid)
+            else:
+                self.drop(bid)
+            self.counts["reclaimed"] += len(self.free) - before
+        if len(self.free) < target:
+            raise RuntimeError(
+                f"{pod.name}: window group exhausted by live sequences")
+        return evicted
+
+    # -- one table a program call ----------------------------------------
+
+    def _slots(self, ids: np.ndarray, what: str) -> np.ndarray:
+        slots = self.slot_of[ids]
+        if (slots < 0).any():
+            raise RuntimeError(
+                f"{self.pod.name}: {what} reads a block that holds no window "
+                "slot (a prefix the window rule refuses, or blocks used out "
+                "of the order they were handed out)")
+        self.tick += 1
+        self.stamp[slots] = self.tick
+        return slots.astype(np.int32)
+
+    def miss_tables(self, table: np.ndarray) -> dict:
+        kept = min(table.shape[1], self.store)
+        for row in table:
+            self.link(row)
+        return {"full": table,
+                "window": self._slots(table[:, table.shape[1] - kept:],
+                                      "a miss prefill")}
+
+    def hit_tables(self, table: np.ndarray, prefix_blocks: int) -> dict:
+        seen = min(prefix_blocks, self.need)
+        for row in table:
+            self.link(row[max(prefix_blocks - 1, 0):])
+        return {"full": table,
+                "window": self._slots(table[:, prefix_blocks - seen:],
+                                      "a hit prefill")}
+
+    def decode_tables(self, table: np.ndarray, context_len: np.ndarray):
+        """The window table of one decode step and what the step reads:
+        (tables, {"full_blocks", "window_blocks", "uniform_blocks"})."""
+        first = np.maximum(context_len - self.window, 0) // self.block
+        current = (context_len - 1) // self.block
+        cols = first[:, None] + np.arange(self.width)[None, :]
+        ids = np.take_along_axis(
+            table, np.minimum(cols, table.shape[1] - 1), axis=1)
+        at = np.take_along_axis(table, current[:, None], axis=1)
+        ids = np.where(cols <= current[:, None], ids, at)  # padding: current
+        # A block that comes back into a window after its slot was reused
+        # (the engine's scratch block, when a decode slot falls idle again)
+        # takes one anew; what a reuse evicts waits for the next `alloc`.
+        missing = self.slot_of[ids] < 0
+        if missing.any():
+            for bid in np.unique(ids[missing]):
+                if not self.free:
+                    self.pod.unpublished += self.reclaim(1)
+                self._assign(int(bid))
+        before = self.live_tick
+        slots = self._slots(ids, "a decode step")
+        self.live_tick = self.tick
+        # live at the last decode step, in no window now
+        self.counts["released"] += int((self.stamp == before).sum()) if before else 0
+        window_blocks = int((current - first + 1).sum())
+        whole = int((current + 1).sum())
+        return ({"full": table, "window": slots,
+                 "first": (first * self.block).astype(np.int32)},
+                {"full_blocks": whole, "window_blocks": window_blocks,
+                 "uniform_blocks": whole})
+
+
+class Pod:
+    """One serving pod on the chip: its paged K/V pools and prefix cache.  The
+    allocator never hands out a block a live sequence references."""
+
+    def __init__(self, name: str, program, model, pool_blocks: int) -> None:
+        self.name = name
+        self.pool_blocks = pool_blocks
+        self.kv = PodKV(program.new_pool(model, pool_blocks), self)
+        self.free = list(range(pool_blocks - 1, -1, -1))
+        self.refs = np.zeros(pool_blocks, np.int32)  # block -> live sequences
+        self.asked = np.zeros(pool_blocks, bool)  # block -> named by an ask
+        self.hashed = np.zeros(pool_blocks, bool)  # block -> cached under a hash
+        policy = getattr(program, "cache_policy", lambda model: {})(model)
+        self.protect_asked = bool(policy.get("protect_asked"))
+        self.last_ask: frozenset = frozenset()  # the last missed ask's hashes
+        self.cached = Cached(self)
+        self.unpublished: list[int] = []  # evicted outside `alloc`
+        self.window = (WindowGroup(self, **policy["window"])
+                       if policy.get("window") else None)
+        self.pending_load = None  # (device counts, tokens) of a decode step
+
+    def cached_prefix(self, hashes) -> list[int]:
+        ids = []
+        for h in hashes:
+            if h not in self.cached:
+                break
+            ids.append(self.cached[h])
+        if self.window is not None:
+            ids = ids[:self.window.servable(ids, len(hashes))]
+        if len(ids) < len(hashes):
+            self.last_ask = frozenset(hashes)
+        return ids
+
+    def touch(self, hashes) -> None:
+        for h in hashes:
+            self.cached.touch(h)
+        if self.window is not None and len(hashes):
+            self.window.warm([self.cached[h]
+                              for h in hashes[-self.window.need:]])
+
+    def alloc(self, n: int) -> tuple[list[int], list[int]]:
+        """n blocks no live sequence references; returns (ids, hashes evicted)."""
+        ids, evicted = [], []
+        while len(ids) < n and self.free:
+            ids.append(self.free.pop())
+        for h, bid in self.cached.coldest_unreferenced(n - len(ids)):
+            del self.cached[h]
+            evicted.append(h)
+            ids.append(bid)
+        if len(ids) < n:
+            raise RuntimeError(f"{self.name}: pool exhausted by live sequences")
+        if self.window is not None:
+            evicted += self.window.take(ids) + self.unpublished
+            self.unpublished = []
+        return ids, evicted
+
+    def evict_tail(self, bid: int) -> list[int]:
+        """Out of the full group: a block and every cached block behind it in
+        a chain.  Returns their hashes; their ids go back to the free list."""
+        out = []
+        for x in self.window.tail(bid):
+            h = self.cached.hash_of.get(x)
+            self.window.forget(x)
+            if h is None:
+                continue
+            if self.refs[x]:  # a sequence holds its whole chain or none of it
+                raise RuntimeError(f"{self.name}: block {x} is referenced "
+                                   f"behind block {bid}, which is not")
+            del self.cached[h]
+            out.append(h)
+            self.free.append(x)
+        return out
+
+    def hold(self, ids, by: int) -> None:
+        ids = np.asarray(ids, np.int64)
+        np.add.at(self.refs, ids, by)
+        if by > 0 or not len(ids):
+            return
+        if self.window is not None:  # a sequence's own blocks: no hash to keep
+            idle = ids[self.refs[ids] == 0]
+            for bid in idle[~self.hashed[idle]]:
+                self.window.drop(bid)
+
+    def tables(self, kind: str, table, context_len=None, prefix_blocks=0):
+        """What a program call is handed as its table: the logical table
+        alone with one group, both groups' tables with two."""
+        table = np.asarray(table, np.int32)
+        if self.window is None:
+            return table
+        with span("kvpool.window") as s:
+            if kind == "decode":
+                tables, read = self.window.decode_tables(
+                    table, np.asarray(context_len, np.int64))
+            else:
+                tables, read = (self.window.miss_tables(table) if kind == "miss"
+                                else self.window.hit_tables(table, prefix_blocks)
+                                ), None
+            s.set_attr("calls", 1)
+            for key, value in self.window.counts.items():
+                s.set_attr(key, value - self.window.reported[key])
+            self.window.reported = dict(self.window.counts)
+        if read is not None:
+            with span("kv.read") as s:
+                for key, value in read.items():
+                    s.set_attr(key, value)
+        return tables
+
+    def report_load(self, model) -> None:
+        """The expert layers' counts of the last decode step as spans.  The
+        step that made them has long ended (its tokens were read back), so
+        this waits for nothing."""
+        if self.pending_load is None:
+            return
+        counts, tokens = self.pending_load
+        self.pending_load = None
+        for layer, (touched, most) in enumerate(np.asarray(counts)):
+            with span("moe.expert_load") as s:
+                s.set_attr("layer", layer)
+                s.set_attr("experts_held", model.n_experts)
+                s.set_attr("experts_touched", int(touched))
+                s.set_attr("max_tokens", int(most))
+                s.set_attr("mean_tokens",
+                           tokens * model.top_k / model.n_experts)
+
+
+def inner_programs(program, model, shapes: dict, interpret: bool) -> dict:
+    """The three steps as jitted functions over (params, tokens, pools,
+    tables[, context_len]), named so that the trace reduction finds them
+    (`miss_prefill_T..`, `hit_prefill_P.._S..`, `decode_B..`).  Each returns
+    the greedy tokens with their logits as one array (one transfer to the
+    host) and, for a prefill, the last position's row of logits; the pools
+    are donated whole and updated in place."""
+
+    def served(logits):
+        return jnp.stack((jnp.argmax(logits, -1).astype(jnp.float32),
+                          jnp.max(logits, -1)))
+
+    def last(logits, kv):
+        return served(logits[:, -1]), logits[0, -1], kv
+
+    def miss(p, t, kv, bt):
+        return last(*program.prefill_paged(p, t, kv, bt, model,
+                                           interpret=interpret))
+
+    def hit(p, t, kv, bt):
+        return last(*program.prefill_continue(
+            p, t, kv, bt, shapes["hit"][0], model, interpret=interpret))
+
+    def decode(p, ints, kv, table):
+        """`ints` [B, 2 or 3 + window width] int32: each sequence's token, its
+        context length and, with a window group, the position its window
+        table starts at and that table's slots.  One array, because every
+        argument that comes from the host costs a transfer of its own (0.12 ms
+        each on the chip's host; my chip run, PR 29); `table` stays on the
+        device between the steps that do not change it."""
+        tables = table if ints.shape[1] == 2 else {
+            "full": table, "first": ints[:, 2], "window": ints[:, 3:]}
+        logits, kv = program.decode_step(p, ints[:, 0], kv, tables, ints[:, 1],
+                                         model, interpret=interpret)
+        return served(logits), kv
+
+    inner = {}
+    for fn, key, name in ((miss, "miss", "miss_prefill_T{}"),
+                          (hit, "hit", "hit_prefill_P{}_S{}"),
+                          (decode, "decode", "decode_B{}")):
+        if key in shapes:
+            fn.__name__ = fn.__qualname__ = name.format(*shapes[key])
+            inner[key] = jax.jit(fn, donate_argnums=(2,))
+    return inner
+
+
+def example_args(key: str, shapes: dict, pod: "Pod", block: int) -> tuple:
+    """What follows the parameters and the pools in a call of the step `key`,
+    in its shapes: (tokens, tables) of a prefill, (ints, table) of a decode
+    step; the tables name block 0 only and touch no state."""
+    i32 = np.int32
+    if key == "decode":
+        B = shapes["decode"][0]
+        width = 2 if pod.window is None else 3 + pod.window.width
+        return (np.ones((B, width), i32),
+                np.zeros((B, shapes["max_blocks"]), i32))
+    tokens = sum(shapes[key])
+    pre = shapes[key][0] // block if key == "hit" else 0
+    return (np.zeros((1, tokens - pre * block), i32),
+            _dry_tables(pod, key, np.zeros((1, tokens // block), i32),
+                        prefix_blocks=pre))
+
+
+def jit_programs(program, model, shapes: dict, interpret: bool) -> dict:
+    """The cell's steps as plain functions over a pod's `kv` handle.  Each
+    builds its tables on the host (`Pod.tables`), then calls the inner
+    compiled program (`inner_programs`).  All three are compiled at the first
+    call of any (set-up), so that a shape first used inside a measured window
+    does not compile there."""
+    block = model.block_size
+    inner, compiled = inner_programs(program, model, shapes, interpret), {}
+
+    def spec(x):
+        return jax.ShapeDtypeStruct(np.shape(x), np.asarray(x).dtype)
+
+    def run(key, p, kv, first, second):
+        if not compiled:
+            for k, fn in inner.items():
+                a, b = ((first, second) if k == key
+                        else example_args(k, shapes, kv.pod, block))
+                compiled[k] = fn.lower(p, spec(a), kv.arrays,
+                                       jax.tree.map(spec, b)).compile()
+        *out, kv.arrays = compiled[key](p, first, kv.arrays, second)
+        # what a step counted on the device is no part of the pools: it is
+        # not handed back in, so reading it later finds it alive
+        load = (kv.arrays.pop("load", None)
+                if isinstance(kv.arrays, dict) else None)
+        return (*out, kv), load
+
+    def prefill(key):
+        prefix_blocks = shapes[key][0] // block if key == "hit" else 0
+
+        def run_prefill(p, t, kv, bt):
+            with root_trace("pod.step"):
+                tables = kv.pod.tables(key, bt, prefix_blocks=prefix_blocks)
+                return run(key, p, kv, np.asarray(t, np.int32), tables)[0]
+
+        return run_prefill
+
+    def run_decode(p, t, kv, bt, n):
+        pod = kv.pod
+        with root_trace("pod.step") as traced:
+            tables = pod.tables("decode", bt, context_len=n)
+            if traced is not None:
+                pod.report_load(model)
+            ints = [t, n] if pod.window is None else [
+                t, n, tables["first"], *tables["window"].T]
+            out, load = run("decode", p, kv,
+                            np.stack(ints, axis=1, dtype=np.int32),
+                            kv.on_device(bt))
+            if traced is not None and load is not None:
+                pod.pending_load = (load, len(t))
+            return out
+
+    return {key: run_decode if key == "decode" else prefill(key)
+            for key in inner}
+
+
+def _dry_tables(pod: Pod, kind: str, table, prefix_blocks=0):
+    """A prefill's tables in the shapes `Pod.tables` would give, for
+    compiling ahead."""
+    group = pod.window
+    if group is None:
+        return table
+    kept = (min(table.shape[1], group.store) if kind == "miss"
+            else table.shape[1] - prefix_blocks + min(prefix_blocks, group.need))
+    return {"full": table,
+            "window": np.zeros((table.shape[0], kept), np.int32)}

@@ -8,7 +8,8 @@ VMEM scratch, every tile contraction on the MXU
 (``preferred_element_type=f32``), and the causal upper triangle never
 read — the loop's trip count stops at the tile's last visible chunk
 (q_offset + (qi+1)*q_block), so continuation suffixes (short q over a
-long cached prefix) do only the work the mask allows.
+long cached prefix) do only the work the mask allows.  With a static
+``window`` the loop also starts at the band's first chunk.
 
 Layout: TPU block specs need the tiled axes last, so the wrapper runs
 in [B, H, T, D] (transposing at the boundary; XLA fuses these into the
@@ -60,6 +61,7 @@ def _flash_kernel(
     q_block: int,
     kv_chunk: int,
     scale: float,
+    window: int | None,
 ):
     qi = pl.program_id(2)
     q_start = q_offset + qi * q_block  # absolute position of q row 0
@@ -93,6 +95,8 @@ def _flash_kernel(
         q_pos = q_start + row
         k_pos = k_start + col
         mask = (k_pos <= q_pos) & (k_pos < kv_len)
+        if window is not None:
+            mask &= k_pos > q_pos - window
         s = jnp.where(mask, s, NEG_INF)
 
         m_prev = m_ref[:, :1]  # [q_block, 1]
@@ -112,7 +116,16 @@ def _flash_kernel(
         )
         return 0
 
-    jax.lax.fori_loop(0, n_chunks, chunk_body, 0)
+    if window is None:
+        jax.lax.fori_loop(0, n_chunks, chunk_body, 0)
+    else:
+        # The band's first chunk: the tile's first row sees nothing
+        # before q_start - window + 1.  A later row may find a whole
+        # chunk masked before its own band begins; what that adds
+        # (exp(0) per column) is wiped by ``correction`` = 0 at the
+        # row's first visible chunk, which every row has (its diagonal).
+        first = jnp.maximum(q_start - (window - 1), 0) // kv_chunk
+        jax.lax.fori_loop(first, n_chunks, chunk_body, 0)
 
     l = l_ref[:, :1]
     out = acc_ref[...] / jnp.where(l == 0.0, 1.0, l)  # pad rows: 0 not NaN
@@ -121,7 +134,7 @@ def _flash_kernel(
 
 @functools.partial(
     jax.jit,
-    static_argnames=("q_offset", "q_block", "kv_chunk", "interpret"),
+    static_argnames=("q_offset", "q_block", "kv_chunk", "interpret", "window"),
 )
 def flash_gqa_attention_pallas(
     q: jnp.ndarray,
@@ -132,10 +145,14 @@ def flash_gqa_attention_pallas(
     q_block: int = 256,
     kv_chunk: int = 512,
     interpret: bool = False,
+    window: int | None = None,
 ) -> jnp.ndarray:
     """Causal GQA flash attention.  q: [B, Tq, H, D]; k/v:
     [B, Tk, Hkv, D]; ``q_offset`` shifts q positions (continuation).
-    Returns [B, Tq, H, D] in q.dtype."""
+    ``window`` (static): row i also sees only keys j > i - window, and
+    the K/V chunks before the band are never read; None (the default)
+    is plain causal attention and lowers as it did before the argument
+    existed.  Returns [B, Tq, H, D] in q.dtype."""
     B, Tq, H, D = q.shape
     _, Tk, Hkv, _ = k.shape
     groups = H // Hkv
@@ -164,6 +181,7 @@ def flash_gqa_attention_pallas(
         q_block=q_block,
         kv_chunk=kv_chunk,
         scale=D**-0.5,
+        window=window,
     )
     out = pl.pallas_call(
         kernel,

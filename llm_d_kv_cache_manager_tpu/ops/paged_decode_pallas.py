@@ -40,14 +40,18 @@ BLOCKS_PER_STEP = 4
 def _decode_kernel(
     table_ref,  # SMEM [B, max_blocks] int32 (scalar prefetch)
     ctx_ref,  # SMEM [B] int32 (scalar prefetch)
-    q_ref,  # VMEM [1, H, D]
-    *rest,  # blocks_per_step kv refs, out ref, then scratch
+    *rest,  # [start ref (SMEM [B]) if windowed,] q ref (VMEM [1, H, D]),
+    # blocks_per_step kv refs, out ref, then scratch
     block_size: int,
     groups: int,
     scale: float,
     blocks_per_step: int,
     mxu_native: bool,
+    windowed: bool = False,
+    heads_first: bool = False,
 ):
+    start_ref = rest[0] if windowed else None
+    q_ref, *rest = rest[1:] if windowed else rest
     kv_refs = rest[:blocks_per_step]
     out_ref = rest[blocks_per_step]
     m_ref, l_ref, acc_ref = rest[blocks_per_step + 1 :]
@@ -65,7 +69,7 @@ def _decode_kernel(
 
     H = q_ref.shape[1]
     D = q_ref.shape[2]
-    Hkv = kv_refs[0].shape[3]
+    Hkv = kv_refs[0].shape[2 if heads_first else 3]
     # mxu_native: feed the dots bf16 operands with f32 accumulation (the
     # MXU's native mode) instead of upcasting K/V after the DMA — saves
     # the VPU cast and halves the operands' VMEM footprint.  Softmax
@@ -74,46 +78,69 @@ def _decode_kernel(
     q = q_ref[0].astype(jnp.float32) * scale  # [H, D]
     qb = q.reshape(Hkv, groups, D).astype(compute_dtype)
 
-    for i, kv_ref in enumerate(kv_refs):
-        # Valid positions in sub-block i: [(j*P+i)*bs, ctx).
-        valid = ctx - (j * blocks_per_step + i) * block_size
+    def attend(kb, vb, first, width):
+        """One online-softmax update over the keys at positions
+        first .. first+width-1; kb, vb: [Hkv, width, D]."""
+        s = jax.lax.dot_general(
+            qb,
+            kb,
+            (((2,), (2,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32,
+        )  # [Hkv, G, width]
+        s = s.reshape(H, width)
+        col = jax.lax.broadcasted_iota(jnp.int32, (H, width), 1)
+        seen = col < ctx - first
+        if windowed:
+            # Positions before the window's first (all in the table's
+            # first block) are masked like those past ctx.
+            seen &= col >= start_ref[b] - first
+        s = jnp.where(seen, s, NEG_INF)
 
-        @pl.when(valid > 0)
-        def _attend(kv_ref=kv_ref, valid=valid):
-            k = kv_ref[0, 0].astype(compute_dtype)  # [bs, Hkv, D]
-            v = kv_ref[0, 1].astype(compute_dtype)
-            kb = k.transpose(1, 0, 2)  # [Hkv, bs, D]
-            vb = v.transpose(1, 0, 2)
-            s = jax.lax.dot_general(
-                qb,
-                kb,
-                (((2,), (2,)), ((0,), (0,))),
-                preferred_element_type=jnp.float32,
-            )  # [Hkv, G, bs]
-            s = s.reshape(H, block_size)
-            col = jax.lax.broadcasted_iota(
-                jnp.int32, (H, block_size), 1
-            )
-            s = jnp.where(col < valid, s, NEG_INF)
+        m_prev = m_ref[:, :1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.exp(s - m_new)  # [H, width] f32
+        correction = jnp.exp(m_prev - m_new)
+        l_ref[...] = l_ref[...] * correction + jnp.sum(
+            p, axis=1, keepdims=True
+        )
+        m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
+        pb = p.reshape(Hkv, groups, width).astype(compute_dtype)
+        o = jax.lax.dot_general(
+            pb,
+            vb,
+            (((2,), (1,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32,
+        )  # [Hkv, G, D]
+        acc_ref[...] = acc_ref[...] * correction + o.reshape(H, D)
 
-            m_prev = m_ref[:, :1]
-            m_new = jnp.maximum(
-                m_prev, jnp.max(s, axis=1, keepdims=True)
+    if heads_first:
+        # Slots are [2, Hkv, bs, D]: the step's blocks side by side are one
+        # [Hkv, P*bs, D] operand, so a step is one update (P*bs = 128 keys
+        # at 8 blocks a step) and not P small ones.
+        first = j * blocks_per_step * block_size
+
+        @pl.when(first < ctx)
+        def _attend_step():
+            kb = jnp.concatenate([r[0, 0] for r in kv_refs], axis=1)
+            vb = jnp.concatenate([r[0, 1] for r in kv_refs], axis=1)
+            attend(
+                kb.astype(compute_dtype),
+                vb.astype(compute_dtype),
+                first,
+                blocks_per_step * block_size,
             )
-            p = jnp.exp(s - m_new)  # [H, bs] f32
-            correction = jnp.exp(m_prev - m_new)
-            l_ref[...] = l_ref[...] * correction + jnp.sum(
-                p, axis=1, keepdims=True
-            )
-            m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
-            pb = p.reshape(Hkv, groups, block_size).astype(compute_dtype)
-            o = jax.lax.dot_general(
-                pb,
-                vb,
-                (((2,), (1,)), ((0,), (0,))),
-                preferred_element_type=jnp.float32,
-            )  # [Hkv, G, D]
-            acc_ref[...] = acc_ref[...] * correction + o.reshape(H, D)
+
+    else:
+        for i, kv_ref in enumerate(kv_refs):
+            # Valid positions in sub-block i: [(j*P+i)*bs, ctx).
+            first = (j * blocks_per_step + i) * block_size
+
+            @pl.when(first < ctx)
+            def _attend(kv_ref=kv_ref, first=first):
+                # [bs, Hkv, D] -> [Hkv, bs, D]
+                kb = kv_ref[0, 0].astype(compute_dtype).transpose(1, 0, 2)
+                vb = kv_ref[0, 1].astype(compute_dtype).transpose(1, 0, 2)
+                attend(kb, vb, first, block_size)
 
     @pl.when(j == n_steps - 1)
     def _finalize():
@@ -124,7 +151,9 @@ def _decode_kernel(
 
 @functools.partial(
     jax.jit,
-    static_argnames=("interpret", "blocks_per_step", "mxu_native"),
+    static_argnames=(
+        "interpret", "blocks_per_step", "mxu_native", "heads_first"
+    ),
 )
 def paged_decode_attention_pallas(
     q: jnp.ndarray,
@@ -135,17 +164,26 @@ def paged_decode_attention_pallas(
     interpret: bool = False,
     blocks_per_step: int = BLOCKS_PER_STEP,
     mxu_native: bool = False,
+    start: jnp.ndarray | None = None,
+    heads_first: bool = False,
 ) -> jnp.ndarray:
-    """q: [B, H, D]; kv_layer: [num_blocks, 2, bs, Hkv, D];
+    """q: [B, H, D]; kv_layer: [num_blocks, 2, bs, Hkv, D]
+    (``heads_first``: [num_blocks, 2, Hkv, bs, D], read without the
+    transpose in VMEM);
     block_table: [B, max_blocks] int32; context_len: [B] int32.
-    Returns [B, H, D] in q.dtype.
+    ``start`` ([B] int32, window layers): the first position of the
+    table a sequence still sees, as in ``paged_attention``; without it
+    the kernel is the one it was.  Returns [B, H, D] in q.dtype.
 
     ``mxu_native=True`` keeps the attention dots in the input dtype
     (bf16 operands, f32 accumulation) instead of upcasting K/V to f32 in
     VMEM; bench.py's kernel sweep measures both and routes the winner.
     """
     B, H, D = q.shape
-    _, _, block_size, Hkv, _ = kv_layer.shape
+    if heads_first:
+        _, _, Hkv, block_size, _ = kv_layer.shape
+    else:
+        _, _, block_size, Hkv, _ = kv_layer.shape
     groups = H // Hkv
     max_blocks = block_table.shape[1]
     P_STEP = blocks_per_step
@@ -161,7 +199,7 @@ def paged_decode_attention_pallas(
     def kv_index(i):
         # Sub-block i of step j; past-context steps revisit the last
         # valid block (an unchanged index skips the DMA).
-        def index(b, j, table_ref, ctx_ref):
+        def index(b, j, table_ref, ctx_ref, *_):
             jc = jnp.minimum(
                 j * P_STEP + i,
                 jnp.maximum((ctx_ref[b] - 1) // block_size, 0),
@@ -170,8 +208,9 @@ def paged_decode_attention_pallas(
 
         return index
 
+    windowed = start is not None
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=2 + windowed,
         grid=(B, n_steps),
         in_specs=[
             pl.BlockSpec(
@@ -182,7 +221,7 @@ def paged_decode_attention_pallas(
         ]
         + [
             pl.BlockSpec(
-                (1, 2, block_size, Hkv, D),
+                (1,) + kv_layer.shape[1:],
                 kv_index(i),
                 memory_space=pltpu.VMEM,
             )
@@ -206,15 +245,15 @@ def paged_decode_attention_pallas(
         scale=D**-0.5,
         blocks_per_step=P_STEP,
         mxu_native=mxu_native,
+        windowed=windowed,
+        heads_first=heads_first,
     )
+    scalars = [block_table, context_len] + [
+        a for a in (start,) if a is not None
+    ]
     return pl.pallas_call(
         kernel,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         grid_spec=grid_spec,
         interpret=interpret,
-    )(
-        block_table.astype(jnp.int32),
-        context_len.astype(jnp.int32),
-        q,
-        *([kv_layer] * P_STEP),
-    )
+    )(*(a.astype(jnp.int32) for a in scalars), q, *([kv_layer] * P_STEP))
