@@ -86,7 +86,15 @@ def scatter_kv_blocks(
     (``KVGroupSpec.layer_shape``) named by ``block_ids``
     ([B, T/block_size]).  ONE layout for every prefill path of every
     family: were it duplicated, a pool layout change could silently
-    diverge between them."""
+    diverge between them.
+
+    Only the named slots are written, so a pool that is carried (a
+    scan's carry, or one array a layer as ``models/afmoe.py`` keeps
+    them) and donated by the caller's ``jit`` is updated where it lies;
+    handed in as a scan's xs and taken back as ys it is copied whole.
+    The first axis may as well hold several layers' slots: the `llama`
+    programs merge a pool's ``[L, N]`` into ``L * N`` and name layer
+    ``l``'s block ``b`` as ``l * N + b`` (``llama._scan_layers``)."""
     B, T = k.shape[:2]
     kv = jnp.stack((k, v), axis=2)  # [B, T, 2, Hkv, Dh]
     kv = kv.reshape(

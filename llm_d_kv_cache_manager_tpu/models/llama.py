@@ -308,6 +308,41 @@ def forward(
     return _logits(x, params)
 
 
+def _scan_layers(layer, x, params: Params, kv_pool: jnp.ndarray):
+    """The layer scan of every paged program: ONE rolled layer body,
+    with the KV pool as state that each layer updates in place.
+
+    The pool is carried, never the scan's xs -> ys: handed in as xs the
+    scan slices one layer out, the body's update makes a copy of it and
+    the ys write it back — three passes over the whole pool a call,
+    whatever the call touches.  Carried, the body writes only the slots
+    it names and reads only the blocks it gathers, and where the caller
+    donates the pool (``jax.jit(..., donate_argnums=(2,))``) the
+    returned pool IS the argument's buffer; a caller that does not
+    donate gets the same values through one copy the compiler makes.
+
+    No layer of the pool is sliced out either (one slice a layer is one
+    pool a call again): the pool is addressed as ``L * N`` slots, layer
+    ``l``'s block ``b`` at ``l * N + b`` (merging the two leading axes
+    moves nothing), and ``layer(x, slots, lp, base)`` adds
+    ``base = l * N`` to the block ids and tables it uses, so
+    ``scatter_kv_blocks``, ``paged_attention`` and the Pallas decode
+    kernel take the merged pool as they take one layer's.  Returns
+    (x, kv_pool in its own shape)."""
+    L, N = kv_pool.shape[:2]
+
+    def body(carry, inputs):
+        lp, l = inputs
+        return layer(*carry, lp, l * N), None
+
+    (x, slots), _ = lax.scan(
+        body,
+        (x, kv_pool.reshape((L * N,) + kv_pool.shape[2:])),
+        (params["layers"], jnp.arange(L, dtype=jnp.int32)),
+    )
+    return x, slots.reshape(kv_pool.shape)
+
+
 def prefill_paged(
     params: Params,
     tokens: jnp.ndarray,
@@ -323,25 +358,25 @@ def prefill_paged(
     kv_pool: [L, num_blocks, 2, block_size, Hkv, Dh] (KVCachePool.kv).
     block_table: [B, T/block_size] pool block ids for each sequence.
     ``interpret``: Pallas kernels in interpret mode (CPU tests).
-    Returns (logits [B, T, V], new kv_pool).
+    Returns (logits [B, T, V], new kv_pool).  The pool is carried
+    through the layers and only the table's blocks are written
+    (``_scan_layers``); donate it and the write is in place.
     """
     B, T = tokens.shape
     positions = jnp.broadcast_to(jnp.arange(T), (B, T))
     x = jnp.take(params["embed"], tokens, axis=0)
 
-    def layer(x, inputs):
-        lp, kv_layer = inputs
+    def layer(x, slots, lp, base):
         h = _rms_norm(x, lp["ln1"])
         q, k, v = _qkv(h, lp, positions, cfg.rope_theta)
         attn = _prefill_attention(q, k, v, cfg, interpret=interpret)
         x = x + jnp.einsum("bthk,hkd->btd", attn, lp["wo"])
         x = x + _mlp(_rms_norm(x, lp["ln2"]), lp)
-        kv_layer = scatter_kv_blocks(
-            kv_layer, k, v, block_table, cfg.block_size
+        return x, scatter_kv_blocks(
+            slots, k, v, base + block_table, cfg.block_size
         )
-        return x, kv_layer
 
-    x, kv_pool = lax.scan(layer, x, (params["layers"], kv_pool))
+    x, kv_pool = _scan_layers(layer, x, params, kv_pool)
     return _logits(x, params), kv_pool
 
 
@@ -369,7 +404,10 @@ def prefill_continue(
     first, then the blocks to write.  ``prefix_len`` is static
     (% block_size == 0); one compile per distinct padded prefix length.
     ``interpret``: Pallas kernels in interpret mode (CPU tests).
-    Returns (suffix logits [B, Ts, V], new kv_pool).
+    Returns (suffix logits [B, Ts, V], new kv_pool).  The pool is
+    carried through the layers: each gathers its prefix blocks from it
+    and writes only its suffix blocks (``_scan_layers``); donate it and
+    the write is in place.
     """
     B, Ts = tokens.shape
     if prefix_len % cfg.block_size or Ts % cfg.block_size:
@@ -383,12 +421,11 @@ def prefill_continue(
     prefix_ids = block_table[:, :npre]  # [B, npre]
     suffix_ids = block_table[:, npre : npre + nsuf]
 
-    def layer(x, inputs):
-        lp, kv_layer = inputs
+    def layer(x, slots, lp, base):
         h = _rms_norm(x, lp["ln1"])
         q, k, v = _qkv(h, lp, positions, cfg.rope_theta)
         # Gather the prefix K/V: [B, npre, 2, block, Hkv, Dh].
-        pre = jnp.take(kv_layer, prefix_ids, axis=0)
+        pre = jnp.take(slots, base + prefix_ids, axis=0)
         pre = pre.transpose(0, 2, 1, 3, 4, 5).reshape(
             B, 2, prefix_len, k.shape[-2], k.shape[-1]
         )
@@ -401,12 +438,11 @@ def prefill_continue(
         )
         x = x + jnp.einsum("bthk,hkd->btd", attn, lp["wo"])
         x = x + _mlp(_rms_norm(x, lp["ln2"]), lp)
-        kv_layer = scatter_kv_blocks(
-            kv_layer, k, v, suffix_ids, cfg.block_size
+        return x, scatter_kv_blocks(
+            slots, k, v, base + suffix_ids, cfg.block_size
         )
-        return x, kv_layer
 
-    x, kv_pool = lax.scan(layer, x, (params["layers"], kv_pool))
+    x, kv_pool = _scan_layers(layer, x, params, kv_pool)
     return _logits(x, params), kv_pool
 
 
@@ -439,7 +475,10 @@ def prefill_chunked(
     causality keeps them invisible to real positions).
     Returns (true-last-position logits [B, V], new kv_pool) — the
     serving contract (the next sampled token); intermediate
-    positions' logits are not materialized.
+    positions' logits are not materialized.  The pool is carried
+    through the chunks and, inside each, through the layers
+    (``_scan_layers``): a chunk writes its own blocks and gathers the
+    table's; donate the pool and the writes are in place.
     """
     B, T = tokens.shape
     C = chunk_tokens
@@ -469,17 +508,16 @@ def prefill_chunked(
             block_table, i * blocks_per_chunk, blocks_per_chunk, axis=1
         )
 
-        def layer(x, inputs):
-            lp, kv_layer = inputs
+        def layer(x, slots, lp, base):
             h = _rms_norm(x, lp["ln1"])
             q, k, v = _qkv(h, lp, positions, cfg.rope_theta)
             # Scatter this chunk's K/V first: its keys then live in
             # the pool like every earlier chunk's, and ONE gathered
             # read serves the whole causal context.
-            kv_layer = scatter_kv_blocks(
-                kv_layer, k, v, chunk_ids, cfg.block_size
+            slots = scatter_kv_blocks(
+                slots, k, v, base + chunk_ids, cfg.block_size
             )
-            full = jnp.take(kv_layer, block_table, axis=0)
+            full = jnp.take(slots, base + block_table, axis=0)
             # [B, nb, 2, bs, Hkv, Dh] -> [B, T, Hkv, Dh] per half.
             k_full = full[:, :, 0].reshape(B, T, Hkv, Dh).astype(
                 k.dtype
@@ -495,9 +533,9 @@ def prefill_chunked(
             )
             x = x + jnp.einsum("bthk,hkd->btd", attn, lp["wo"])
             x = x + _mlp(_rms_norm(x, lp["ln2"]), lp)
-            return x, kv_layer
+            return x, slots
 
-        x, kv_pool = lax.scan(layer, x, (params["layers"], kv_pool))
+        x, kv_pool = _scan_layers(layer, x, params, kv_pool)
         # Pick each sequence's TRUE last hidden state when it falls in
         # this chunk (ragged lengths: pad positions must never produce
         # the serving logits).  Hidden state only — projecting every
@@ -534,7 +572,10 @@ def decode_step(
     the current token; block_table: [B, max_blocks].  Writes the new
     token's K/V into the pool slot, attends over the table, and returns
     (logits [B, V], new kv_pool).  ``interpret``: the Pallas decode
-    kernel in interpret mode (CPU tests).
+    kernel in interpret mode (CPU tests).  The pool is carried through
+    the layers: each writes its one slot a sequence and attends through
+    the table over the merged pool (``_scan_layers``); donate it and
+    the write is in place.
     """
     B = tokens.shape[0]
     pos = context_len - 1  # [B]
@@ -545,15 +586,15 @@ def decode_step(
         block_table, block_idx[:, None], axis=1
     )[:, 0]
 
-    def layer(x, inputs):
-        lp, kv_layer = inputs
+    def layer(x, slots, lp, base):
         h = _rms_norm(x, lp["ln1"])
         h3 = h[:, None]  # [B, 1, D]
         q, k, v = _qkv(h3, lp, pos[:, None], cfg.rope_theta)
         kv_new = jnp.stack((k[:, 0], v[:, 0]), axis=1)  # [B, 2, Hkv, Dh]
-        kv_layer = kv_layer.at[block_ids, :, slot].set(
-            kv_new.astype(kv_layer.dtype)
+        slots = slots.at[base + block_ids, :, slot].set(
+            kv_new.astype(slots.dtype)
         )
+        table = base + block_table
         # "auto" = the recorded routing decision: the XLA gather (last
         # measured Pallas margin 1.09x — within noise — and the rule
         # requires >= 1.3x at two serving shapes; see LlamaConfig).
@@ -563,23 +604,21 @@ def decode_step(
         if use_pallas:
             attn = paged_decode_attention_pallas(
                 q[:, 0],
-                kv_layer,
-                block_table,
+                slots,
+                table,
                 context_len,
                 blocks_per_step=cfg.decode_blocks_per_step,
                 mxu_native=cfg.decode_mxu_native,
                 interpret=interpret,
             )
         else:
-            attn = paged_attention(
-                q[:, 0], kv_layer, block_table, context_len
-            )
+            attn = paged_attention(q[:, 0], slots, table, context_len)
         x = x + jnp.einsum("bhk,hkd->bd", attn, lp["wo"])
         h2 = _rms_norm(x, lp["ln2"])[:, None]
         x = x + _mlp(h2, lp)[:, 0]
-        return x, kv_layer
+        return x, slots
 
-    x, kv_pool = lax.scan(layer, x, (params["layers"], kv_pool))
+    x, kv_pool = _scan_layers(layer, x, params, kv_pool)
     return _logits(x, params), kv_pool
 
 

@@ -4,6 +4,8 @@ attention vs dense attention, and the sharded train step.
 Runs on the virtual 8-device CPU platform (conftest.py).
 """
 
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -199,6 +201,153 @@ def test_prefill_chunked_matches_full(params):
             rtol=2e-4,
             atol=2e-4,
             err_msg=f"sequence {b}",
+        )
+
+
+# ------------------------------------------------- the pool, carried in place
+#
+# The four paged programs carry the pool through their layer scan and
+# write only the slots they name (llama._scan_layers).  What they must
+# still compute is what the scan over (layers, pool) as xs -> ys
+# computed: the logits of a dense pass, the new K/V in the table's
+# slots of every layer, and every other slot of every layer untouched.
+
+SEQ_T = 16  # tokens a sequence of the cases below (and one to decode)
+
+
+@pytest.fixture(scope="module")
+def dense_pass(params):
+    """Two sequences of SEQ_T + 1 tokens with the logits [B, T, V] and
+    every layer's K and V [L, B, T, Hkv, Dh] of a dense pass over them,
+    by a plain loop over the layers: no pool, no scan."""
+    tokens = jax.random.randint(
+        jax.random.PRNGKey(21), (2, SEQ_T + 1), 0, CFG.vocab_size
+    )
+    B, T = tokens.shape
+    positions = jnp.broadcast_to(jnp.arange(T), (B, T))
+    x = jnp.take(params["embed"], tokens, axis=0)
+    ks, vs = [], []
+    for l in range(CFG.n_layers):
+        lp = jax.tree.map(lambda a: a[l], params["layers"])
+        h = llama._rms_norm(x, lp["ln1"])
+        q, k, v = llama._qkv(h, lp, positions, CFG.rope_theta)
+        attn = causal_gqa_attention(q, k, v)
+        x = x + jnp.einsum("bthk,hkd->btd", attn, lp["wo"])
+        x = x + llama._mlp(llama._rms_norm(x, lp["ln2"]), lp)
+        ks.append(k)
+        vs.append(v)
+    return (
+        tokens,
+        np.asarray(llama._logits(x, params)),
+        np.asarray(jnp.stack(ks)),
+        np.asarray(jnp.stack(vs)),
+    )
+
+
+def _write_positions(pool, ks, vs, table, first, last):
+    """``pool`` (numpy, in place) with the K/V of positions
+    [first[b], last[b]) of sequence b in the slots ``table`` names; the
+    written mask is returned beside it."""
+    written = np.zeros(pool.shape, bool)
+    for b in range(table.shape[0]):
+        for pos in range(first[b], last[b]):
+            block, at = table[b, pos // CFG.block_size], pos % CFG.block_size
+            pool[:, block, 0, at] = ks[:, b, pos]
+            pool[:, block, 1, at] = vs[:, b, pos]
+            written[:, block, :, at] = True
+    return written
+
+
+PAGED_CASES = [
+    (program, attention, donate)
+    for program, attention in (
+        ("prefill_paged", "auto"),
+        ("prefill_continue", "auto"),
+        ("prefill_chunked", "auto"),
+        ("decode_step", "auto"),
+        ("decode_step", "pallas"),
+    )
+    for donate in (False, True)
+]
+
+
+@pytest.mark.parametrize(
+    "program, attention, donate",
+    PAGED_CASES,
+    ids=["-".join((p, a, "donated" if d else "kept")) for p, a, d in PAGED_CASES],
+)
+def test_paged_programs_update_the_pool_in_place(
+    params, dense_pass, program, attention, donate
+):
+    cfg = dataclasses.replace(CFG, decode_attention=attention)
+    tokens, dense, ks, vs = dense_pass
+    B, T, P, bs = 2, SEQ_T, SEQ_T // 2, CFG.block_size
+    pool_blocks = 24
+    rng = np.random.default_rng(22)
+    # Noise everywhere: a slot that the program should not touch shows
+    # it if it does, in every layer.
+    before = rng.standard_normal(
+        (CFG.n_layers, pool_blocks, 2, bs, CFG.n_kv_heads, CFG.head_dim)
+    ).astype(np.float32)
+    # Block ids in no order, so that a layer offset applied to the
+    # wrong operand cannot go unseen.
+    table = rng.permutation(pool_blocks)[: B * (T // bs + 1)].reshape(
+        B, T // bs + 1
+    ).astype(np.int32)
+    zero, whole = np.zeros(B, int), np.full(B, T)
+
+    if program == "prefill_paged":
+        args = (tokens[:, :T], table[:, : T // bs])
+        call = lambda p, t, kv, bt: llama.prefill_paged(p, t, kv, bt, cfg)
+        first, last, want = zero, whole, dense[:, :T]
+    elif program == "prefill_chunked":
+        args = (tokens[:, :T], table[:, : T // bs])
+        call = lambda p, t, kv, bt: llama.prefill_chunked(
+            p, t, kv, bt, cfg, chunk_tokens=P
+        )
+        first, last, want = zero, whole, dense[:, T - 1]
+    elif program == "prefill_continue":
+        _write_positions(before, ks, vs, table, zero, np.full(B, P))
+        args = (tokens[:, P:T], table[:, : T // bs])
+        call = lambda p, t, kv, bt: llama.prefill_continue(
+            p, t, kv, bt, P, cfg
+        )
+        first, last, want = np.full(B, P), whole, dense[:, P:T]
+    else:  # decode_step, ragged: sequence 1 is three tokens behind
+        pos = np.array([T, T - 3])
+        _write_positions(before, ks, vs, table, zero, pos)
+        args = (
+            tokens[np.arange(B), pos],
+            table,
+            jnp.asarray(pos + 1, jnp.int32),
+        )
+        call = lambda p, t, kv, bt, n: llama.decode_step(
+            p, t, kv, bt, n, cfg, interpret=attention == "pallas"
+        )
+        first, last, want = pos, pos + 1, dense[np.arange(B), pos]
+
+    after = before.copy()
+    written = _write_positions(after, ks, vs, table, first, last)
+    kv_in = jnp.asarray(before)
+    logits, kv_out = jax.jit(call, donate_argnums=(2,) if donate else ())(
+        params, args[0], kv_in, *args[1:]
+    )
+    np.testing.assert_allclose(np.asarray(logits), want, rtol=2e-4, atol=2e-4)
+    kv_out = np.asarray(kv_out)
+    assert kv_out.shape == before.shape and kv_out.dtype == before.dtype
+    np.testing.assert_allclose(
+        kv_out[written], after[written], rtol=1e-5, atol=1e-5
+    )
+    # Every slot that was not named, of every layer, bit for bit.
+    np.testing.assert_array_equal(kv_out[~written], before[~written])
+    if not donate:  # the caller's pool is still the caller's
+        np.testing.assert_array_equal(np.asarray(kv_in), before)
+    if program == "prefill_chunked":  # ... and against prefill_paged
+        _, paged = llama.prefill_paged(
+            params, *args[:1], jnp.asarray(before), *args[1:], cfg
+        )
+        np.testing.assert_allclose(
+            kv_out, np.asarray(paged), rtol=1e-5, atol=1e-5
         )
 
 

@@ -1,20 +1,24 @@
-"""The Pallas kernels of the pod path compiled for a TPU v5e at the widths
-the benchmark serves them at, with no chip: the chip's compiler is installed
-here and compiles for a described device.  It proves "compiles" (tiling,
-VMEM), never "is right" or "is fast".  One file, and the topology only inside
-a fixture: a worker that cannot describe it skips these tests and no other
-(the `on-chip-measurement` guide, section 2).
+"""The Pallas kernels of the pod path, and the `llama` model-step programs,
+compiled for a TPU v5e at the widths the benchmark serves them at, with no
+chip: the chip's compiler is installed here and compiles for a described
+device.  It proves "compiles" (tiling, VMEM) and what the compiler's output
+says of copies and temporaries, never "is right" or "is fast".  One file, and
+the topology only inside a fixture: a worker that cannot describe it skips
+these tests and no other (the `on-chip-measurement` guide, section 2).
 """
 
 from __future__ import annotations
 
 import functools
+import math
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from llm_d_kv_cache_manager_tpu.models import llama
 from llm_d_kv_cache_manager_tpu.ops import flash_pallas
 from llm_d_kv_cache_manager_tpu.ops.paged_decode_pallas import (
     paged_decode_attention_pallas,
@@ -76,3 +80,97 @@ def test_paged_decode_kernel_compiles_heads_first(one_chip, slots, columns,
                     *shapes, ((B,), i32))
     else:
         compile_for(one_chip, fn, *shapes)
+
+
+# ------------------------------------- the llama programs and their KV pool
+
+# benchmarks/configs/mistral-7b-v0.3-l8.json and internlm2-1.8b.json; the
+# pools of benchmarks/traffic/docs-*.json and chat-sysprompt.json.
+MISTRAL = llama.LlamaConfig(vocab_size=32768, d_model=4096, n_layers=8,
+                            n_heads=32, n_kv_heads=8, d_ff=14336,
+                            rope_theta=1e6)
+INTERNLM2 = llama.LlamaConfig(vocab_size=92544, d_model=2048, n_layers=24,
+                              n_heads=16, n_kv_heads=8, d_ff=8192,
+                              rope_theta=1e6)
+
+INSTRUCTION = re.compile(r"%(\S+) = \w+\[([\d,]*)\]\S* ([\w-]+)\(([^)]*)\)")
+
+
+def pool_sized_moves(hlo: str, pool_shape: tuple) -> list:
+    """The instructions of a compiled program that move the whole pool or
+    one layer of it: a `copy` or a `dynamic-slice` with such a result, a
+    `dynamic-update-slice` with such an update.  (A scatter or a slice update
+    whose RESULT is the pool writes the carried buffer where it lies.)"""
+    L, N, *slot = pool_shape
+    moved = [[L, N] + slot, [L * N] + slot, [1, N] + slot, [N] + slot]
+    rows = INSTRUCTION.findall(hlo)
+    shapes = {name: [int(d) for d in dims.split(",") if d]
+              for name, dims, _, _ in rows}
+    found = []
+    for name, _, op, operands in rows:
+        if op == "dynamic-update-slice":
+            what = shapes.get(operands.split(",")[1].strip().lstrip("%"))
+        elif op in ("copy", "dynamic-slice"):
+            what = shapes[name]
+        else:
+            continue
+        if what in moved:
+            found.append(f"{op} {name} {what}")
+    return found
+
+
+SERVED = (  # name, widths, pool blocks, tokens, table, static prefix
+    ("miss_prefill_T8448", MISTRAL, 4096, (1, 8448), (1, 528), None),
+    ("hit_prefill_P8192_S256", MISTRAL, 4096, (1, 256), (1, 528), 8192),
+    ("hit_prefill_P2048_S512", INTERNLM2, 3072, (1, 512), (1, 160), 2048),
+    ("decode_B32", INTERNLM2, 3072, (32,), (32, 192), None),
+)
+
+
+@pytest.mark.parametrize("name, cfg, blocks, tokens, table, prefix", SERVED,
+                         ids=[case[0] for case in SERVED])
+def test_llama_programs_update_a_donated_pool_in_place(
+        one_chip, monkeypatch, name, cfg, blocks, tokens, table, prefix):
+    """The cells' programs, donated as `benchmarks/harness/pod.py` donates
+    them: the compiler leaves no copy, slice or temporary the size of the
+    pool, nor of one layer of it a layer (`llama._scan_layers`)."""
+    # The prefill's attention asks for the backend (llama._prefill_attention):
+    # as on the chip, so the miss prefill holds the flash kernel.
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    i32 = jnp.int32
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def last(logits, kv):
+        return logits[:, -1], kv
+
+    if name.startswith("miss"):
+        def program(p, t, kv, bt):
+            return last(*llama.prefill_paged(p, t, kv, bt, cfg))
+    elif name.startswith("hit"):
+        def program(p, t, kv, bt):
+            return last(*llama.prefill_continue(p, t, kv, bt, prefix, cfg))
+    else:
+        def program(p, t, kv, bt, n):
+            return llama.decode_step(p, t, kv, bt, n, cfg)
+    pool_shape = (cfg.n_layers, blocks, 2, cfg.block_size, cfg.n_kv_heads,
+                  cfg.head_dim)
+    params = jax.tree.map(
+        lambda a: spec(a.shape, a.dtype),
+        jax.eval_shape(functools.partial(llama.init_params, cfg=cfg),
+                       jax.random.key(0)))
+    args = [params, spec(tokens, i32), spec(pool_shape, jnp.bfloat16),
+            spec(table, i32)]
+    if name.startswith("decode"):
+        args.append(spec(tokens, i32))
+    compiled = jax.jit(program, donate_argnums=(2,)).trace(*args).lower(
+        lowering_platforms=("tpu",)).compile()
+
+    hlo = compiled.as_text()
+    assert ("tpu_custom_call" in hlo) == name.startswith("miss")
+    assert pool_sized_moves(hlo, pool_shape) == []
+    pool_bytes = 2 * math.prod(pool_shape)
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= pool_bytes  # the pool handed back
+    assert memory.temp_size_in_bytes < pool_bytes
