@@ -1,10 +1,10 @@
 # Developer entry points (reference: Makefile targets unit-test /
-# e2e-test / bench, .github/workflows/ci-pr-checks.yaml).
+# e2e-test, .github/workflows/ci-pr-checks.yaml).
 
 PYTHON ?= python
 CPU_ENV = JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8
 
-.PHONY: all lint kvlint racefuzz-smoke lockorder-smoke test unit-test e2e-test examples obs-smoke slo-smoke perf-smoke perf-trend profile-smoke events-smoke cachestats-smoke tiering-smoke transfer-smoke cluster-smoke offload-smoke replay-smoke whatif-smoke chip-smoke bench native native-race proto graft-check chart clean
+.PHONY: all lint kvlint racefuzz-smoke lockorder-smoke test unit-test e2e-test examples obs-smoke slo-smoke profile-smoke events-smoke cachestats-smoke tiering-smoke transfer-smoke cluster-smoke offload-smoke replay-smoke whatif-smoke chip-smoke native native-race proto graft-check chart clean
 
 all: native test
 
@@ -107,25 +107,11 @@ replay-smoke:
 # agrees (and a flow-control-starved arm measurably sheds with a
 # first SLO-divergence point), exercises GET /debug/whatif,
 # GET /debug/incidents/<id> and POST /admin/whatif against a live
-# bundle, and verifies the perf-trend capacity gate passes honestly
-# and fails a planted regression (docs/observability.md "What-if
-# engine").
+# bundle, and holds the live reference A/B to the recorded oracle
+# tests/testdata/WHATIF_r01.json exactly (docs/observability.md
+# "What-if engine").
 whatif-smoke:
 	$(CPU_ENV) $(PYTHON) hack/whatif_smoke.py
-
-# Read-path perf smoke (same invocation as CI's "Read-path perf
-# smoke" step): a few seconds of the bench's read_path regime on CPU,
-# asserting sane output + fast-lane score parity (docs/performance.md).
-perf-smoke:
-	$(CPU_ENV) $(PYTHON) hack/perf_smoke.py
-
-# Perf-trend gate (same invocation as CI's "Perf trend" step): parse
-# the BENCH_r*.json trajectory at the repo root, print the per-regime
-# headline trend table, and exit non-zero when the newest artifact
-# regresses a prior higher-is-better headline by >10%
-# (docs/benchmarks.md).
-perf-trend:
-	$(PYTHON) hack/perf_trend.py
 
 # Continuous-profiling smoke (same invocation as CI's "Profiling
 # smoke" step): booted service under named-thread traffic — collapsed
@@ -193,14 +179,11 @@ events-smoke:
 	$(CPU_ENV) $(PYTHON) hack/events_smoke.py
 
 # On a TPU host (one process per chip; from the sandbox, through the
-# chip tool: `chiprun -- python chip_smoke.py`).  Both fail when JAX
-# finds no TPU.  chip-smoke first: does the pod path start on the chip?
+# chip tool: `chiprun -- python chip_smoke.py`).  Fails when JAX finds
+# no TPU.  Does the pod path start on the chip?  The benchmark is
+# `python3 benchmarks/run.py --workload <cell> ...` (benchmarks/README.md).
 chip-smoke:
 	$(PYTHON) chip_smoke.py
-
-# Fleet-routing benchmark.
-bench:
-	$(PYTHON) bench.py
 
 # Render the serving-fleet chart: real helm when installed, the
 # subset renderer otherwise (same sources, same output).

@@ -1,15 +1,19 @@
 """The quickest proof that the pod path still starts on the chip.
 
 ``python chip_smoke.py`` drives the main path once, in ONE process, at
-the full width of the model the benchmark serves (``bench.CFG``, 8192 +
-256 tokens, block 16, 4 pods x 1536 blocks), with random weights made
-from a seed:
+a published configuration and the geometry of the benchmark's cell
+``mistral7b-docs-shared``, both read from that cell's files under
+``benchmarks/`` (``configs/mistral-7b-v0.3-l8.json``: Mistral-7B widths,
+8 of 32 layers; 8192 + 256 tokens, block 16, 4 pods x 4096 blocks =
+2.1 GB a pod), with random weights made from a seed.
 
-1. **fleet** — ``FleetRouter("precise")``: ``Indexer.get_pod_scores``
-   routes 2 prefix groups x 3 requests (2 misses, then 4 hits) to
-   ``SimPod`` paged pools; misses run ``llama.prefill_paged``, hits
-   ``llama.prefill_continue``; every request publishes its KVEvents
-   through the msgpack codec and ``kvevents.Pool`` into the index;
+1. **fleet** — the benchmark's ``harness.engine.Fleet`` (its ``route``
+   / ``account`` / ``prefill`` / ``commit``, used as they are):
+   ``Indexer.get_pod_scores`` routes 2 prefix groups x 3 requests (2
+   misses, then 4 hits) to the pods' paged pools; misses run
+   ``llama.prefill_paged``, hits ``llama.prefill_continue``; every
+   request publishes its KVEvents through the msgpack codec and
+   ``kvevents.Pool`` into the index;
 2. **reference** — paged prefill against the dense forward on a small
    input (the repo's own equivalence reference);
 3. **decode** — ``llama.decode_step`` x 8 on one sequence, the compiled
@@ -20,16 +24,17 @@ from a seed:
    zero them, load them back, bit-identical, through the pinned-host
    staging lanes and the native I/O engine built from ``native/src/``
    in the run;
-6. with four or more chips, **four_chips** — the fleet with pod *i*
-   committed to chip *i*, and ``__graft_entry__``'s sharded checks
+6. with four or more chips, **four_chips** — the fleet with pod *i*'s
+   pool and a replica of the parameters committed to chip *i* after
+   ``Fleet`` built them, and ``__graft_entry__``'s sharded checks
    (tp-sharded paged decode, ring prefill with the flash body compiled,
    per-chip staged offload) on the real devices.
 
 It checks what comes out, not only that it runs; any failed check or
 raised exception ends the process with a non-zero code and no result
 line.  No TPU is such a failure: there is no CPU mode in ``main()``.
-The phases are plain functions of a config and a geometry, and
-tests/test_chip_smoke.py calls them at a tiny size with
+The phases are plain functions of a model configuration and a
+geometry, and tests/test_chip_smoke.py calls them at a tiny size with
 ``interpret=True``.
 
 Last line of stdout on success:
@@ -42,7 +47,6 @@ import dataclasses
 import functools
 import json
 import os
-import random
 import shutil
 import sys
 import tempfile
@@ -54,7 +58,8 @@ import jax.numpy as jnp
 import numpy as np
 
 import __graft_entry__ as graft
-import bench
+from benchmarks import run as benchmark
+from benchmarks.harness import costs, engine, program_llama, traffic
 from llm_d_kv_cache_manager_tpu.models import llama
 from llm_d_kv_cache_manager_tpu.models.kv_cache_pool import (
     KVCachePool,
@@ -77,9 +82,12 @@ from llm_d_kv_cache_manager_tpu.parallel.compile_cache import (
 
 # Two paths that compute the same logits in bf16 with different
 # accumulation orders (Pallas vs XLA attention, cached vs recomputed
-# K/V) must agree to this share of the largest reference logit — the
-# bound bench.py's kernel equality gates use.
+# K/V) must agree to this share of the largest reference logit (the
+# benchmark's `prefill_logits_rel_err` limit is the same 0.05).
 BF16_REL_TOL = 0.05
+
+# The benchmark cell whose configuration and geometry this run takes.
+CELL = "mistral7b-docs-shared"
 
 # Files of the offload round trip hold this many device blocks.
 OFFLOAD_BLOCKS_PER_FILE = 4
@@ -96,11 +104,13 @@ def check(condition: bool, what: str) -> None:
 
 @dataclasses.dataclass(frozen=True)
 class Geometry:
-    """The traffic of one smoke run (``bench.py``'s workload shape)."""
+    """The traffic of one smoke run: documents of ``prefix_tokens``
+    asked with a fresh ``suffix_tokens`` question, as the cell's."""
 
     prefix_tokens: int
     suffix_tokens: int
     pool_blocks: int
+    pods: int = 4
     n_groups: int = 2
     reqs_per_group: int = 3
     decode_steps: int = 8
@@ -110,12 +120,36 @@ class Geometry:
     def total_tokens(self) -> int:
         return self.prefix_tokens + self.suffix_tokens
 
+    def traffic(self) -> dict:
+        """As a traffic file of the cell's kind, for ``Fleet`` and
+        ``harness.traffic.shapes``."""
+        return {
+            "kind": "paced_sessions",
+            "pods": self.pods,
+            "pool_blocks": self.pool_blocks,
+            "doc_tokens": self.prefix_tokens,
+            "question_tokens": self.suffix_tokens,
+        }
 
-FULL_GEOMETRY = Geometry(
-    prefix_tokens=bench.PREFIX_TOKENS,
-    suffix_tokens=bench.SUFFIX_TOKENS,
-    pool_blocks=bench.POOL_BLOCKS,
-)
+
+def full_setup() -> Tuple[llama.LlamaConfig, Geometry]:
+    """The model and the geometry of ``CELL``, from its files."""
+    cell = benchmark.load(benchmark.BENCH, "cells", CELL)
+    cfg = benchmark.load(benchmark.BENCH, "configs", cell["config"])
+    tr = benchmark.load(benchmark.BENCH, "traffic", cell["traffic"])
+    check(tr["kind"] == "paced_sessions", f"{CELL}: traffic kind {tr['kind']}")
+    return program_llama.from_published(cfg, engine.BLOCK), Geometry(
+        prefix_tokens=tr["doc_tokens"],
+        suffix_tokens=tr["question_tokens"],
+        pool_blocks=tr["pool_blocks"],
+        pods=tr["pods"],
+    )
+
+
+def max_rel_err(got, want) -> float:
+    """Largest difference over the largest reference value."""
+    got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+    return float(np.max(np.abs(got - want)) / (np.max(np.abs(want)) + 1e-6))
 
 
 def peak_hbm_gb() -> Optional[float]:
@@ -145,7 +179,7 @@ def logits_agree(got: np.ndarray, want: np.ndarray, what: str) -> float:
         bool(np.isfinite(got).all() and np.isfinite(want).all()),
         f"{what}: non-finite logits",
     )
-    err = bench.max_rel_err(got, want)
+    err = max_rel_err(got, want)
     check(err < BF16_REL_TOL, f"{what}: max rel err {err:.4f} >= {BF16_REL_TOL}")
     check(
         int(np.argmax(got)) == int(np.argmax(want)),
@@ -183,7 +217,7 @@ def compile_timed(jitted, *args, want_mosaic: Optional[bool] = None):
     return executable, time.perf_counter() - t0
 
 
-def alloc_spare(pod: bench.SimPod, n_blocks: int) -> List[int]:
+def alloc_spare(pod, n_blocks: int) -> List[int]:
     """``n_blocks`` of the pod's pool that no cached prefix lives in."""
     block_ids, evicted = pod.alloc(n_blocks)
     check(not evicted, f"{pod.name}: pool too small, evicted {len(evicted)}")
@@ -192,31 +226,36 @@ def alloc_spare(pod: bench.SimPod, n_blocks: int) -> List[int]:
 
 def make_requests(
     cfg: llama.LlamaConfig, geom: Geometry, seed: int
-) -> List[Tuple[int, str, List[int]]]:
+) -> List[Tuple[int, str, np.ndarray]]:
     """(group, prompt text, tokens): every group's first request, then
     every group's second, ... so precise routing sees ``n_groups``
     misses and then only hits."""
-    rng = random.Random(seed)
+    rng = np.random.default_rng(seed)
 
-    def draw(n: int) -> List[int]:
-        return [rng.randrange(1, cfg.vocab_size) for _ in range(n)]
+    def draw(n: int) -> np.ndarray:
+        return rng.integers(1, cfg.vocab_size, n, dtype=np.int64)
 
     prefixes = [draw(geom.prefix_tokens) for _ in range(geom.n_groups)]
     requests = []
     for _ in range(geom.reqs_per_group):
         for group in range(geom.n_groups):
-            tokens = prefixes[group] + draw(geom.suffix_tokens)
-            text = " ".join(f"t{t}" for t in tokens)
-            requests.append((group, text, tokens))
+            tokens = np.concatenate((prefixes[group], draw(geom.suffix_tokens)))
+            requests.append((group, engine.prompt_text(tokens.tolist()), tokens))
     return requests
 
 
 @dataclasses.dataclass
 class FleetResult:
-    fleet: bench.FleetRouter
+    fleet: engine.Fleet
+    params: Dict[str, object]  # pod -> the parameters on that pod's device
     holder: Dict[int, str]  # group -> pod that holds its prefix
     last_hit: dict  # the last hit request: pod, tokens, block ids, logits
-    prefill_full: object  # compiled miss prefill (default device only)
+
+    def prefill(self, pod, tokens, block_ids, hit: bool, first_new: int):
+        """``Fleet.prefill`` with the parameters that live where the
+        pod's pool does (``Fleet`` itself keeps one set for all pods)."""
+        self.fleet.params = self.params[pod.name]
+        return self.fleet.prefill(pod, tokens, block_ids, hit, first_new)
 
 
 def phase_fleet(
@@ -229,65 +268,55 @@ def phase_fleet(
     seed: int = 0,
 ) -> FleetResult:
     """Route, prefill and publish ``geom``'s requests through the
-    precise fleet.  ``devices``: pod *i*'s params and pool committed to
-    ``devices[i]`` (one chip per pod); None keeps every pod on the
-    default device, as ``bench.run_fleet`` does."""
+    benchmark's precise fleet.  ``devices``: pod *i*'s pool and a
+    replica of the parameters are committed to ``devices[i]`` after
+    ``Fleet`` built them (one chip per pod); None keeps every pod on the
+    default device, as the benchmark's cells do."""
     check(
-        cfg.block_size == bench.BLOCK_SIZE,
-        "the fleet's indexer hashes bench.BLOCK_SIZE-token blocks",
+        cfg.block_size == engine.BLOCK,
+        f"the fleet's indexer hashes {engine.BLOCK}-token blocks",
     )
     n_prefix_blocks = geom.prefix_tokens // cfg.block_size
     requests = make_requests(cfg, geom, seed)
-    prefill_full, prefill_suffix = bench.jit_prefills(
-        cfg, geom.prefix_tokens, interpret=interpret
+    records = engine.Records()
+    records.recording = True  # the counters of `route` are read below
+    tr = geom.traffic()
+    fleet = engine.Fleet(
+        program_llama, cfg, params, tr, traffic.shapes(tr), records, interpret
     )
-
-    def pod_factory(name: str) -> bench.SimPod:
-        device = None
-        pod_params = params
-        if devices is not None:
-            device = devices[int(name.rsplit("-", 1)[1])]
-            pod_params = jax.device_put(params, device)
-        return bench.SimPod(
-            name,
-            pod_params,
-            pool_blocks=geom.pool_blocks,
-            cfg=cfg,
-            device=device,
-        )
-
-    fleet = bench.FleetRouter("precise", with_kv=True, pod_factory=pod_factory)
     pod_names = [pod.name for pod in fleet.pods]
+    pod_params = dict.fromkeys(pod_names, params)
     compile_s = {"miss": 0.0, "hit": 0.0}
-    if devices is None:
+    if devices is not None:
+        for pod, device in zip(fleet.pods, devices):
+            pod.kv = jax.device_put(pod.kv, device)
+            pod_params[pod.name] = jax.device_put(params, device)
+    else:
         # One device: compile both programs ahead, apart from the run
         # time, and look at what the miss program lowered to.
         pod = fleet.pods[0]
-        table = jnp.zeros((1, geom.total_tokens // cfg.block_size), jnp.int32)
-        prefill_full, compile_s["miss"] = compile_timed(
-            prefill_full,
-            pod.params,
-            jnp.zeros((1, geom.total_tokens), jnp.int32),
-            pod.kv,
-            table,
-            want_mosaic=not interpret,
-        )
-        prefill_suffix, compile_s["hit"] = compile_timed(
-            prefill_suffix,
-            pod.params,
-            jnp.zeros((1, geom.suffix_tokens), jnp.int32),
-            pod.kv,
-            table,
-        )
+        table = np.zeros((1, geom.total_tokens // cfg.block_size), np.int32)
+        for key, n_new, want_mosaic in (
+            ("miss", geom.total_tokens, not interpret),
+            ("hit", geom.suffix_tokens, None),
+        ):
+            fleet.programs[key], compile_s[key] = compile_timed(
+                fleet.programs[key],
+                params,
+                np.zeros((1, n_new), np.int32),
+                pod.kv,
+                table,
+                want_mosaic=want_mosaic,
+            )
+    result = FleetResult(fleet, pod_params, holder={}, last_hit={})
 
-    holder: Dict[int, str] = {}
+    holder = result.holder
     run_s = {"miss": [], "hit": []}
-    last_hit: dict = {}
     worked_on = set()
     for group, text, tokens in requests:
-        hashes = bench.block_hash_chain(tokens)
-        scores = fleet.indexer.get_pod_scores(text, bench.MODEL_NAME, pod_names)
-        pod, _ = fleet.route(text, hashes)
+        hashes = engine.block_hash_chain(tokens)
+        scores = fleet.indexer.get_pod_scores(text, engine.MODEL_NAME, pod_names)
+        pod = fleet.route(text, hashes, n_prefix_blocks)
         if group in holder:
             # Requests 2..n of a group: the index learned the holder
             # from the events that pod published, and routes to it.
@@ -312,34 +341,18 @@ def phase_fleet(
             pod, hashes, n_prefix_blocks
         )
         check(hit == (first_new > 0), "hit without a cached prefix")
-        token_arr = jnp.asarray(np.asarray(tokens, np.int32)[None])
-        table = jnp.asarray([block_ids], jnp.int32)
-        if devices is not None:
-            token_arr, table = jax.device_put(
-                (token_arr, table), next(iter(pod.kv.devices()))
-            )
-        if hit:
-            (logits, pod.kv), seconds = timed(
-                prefill_suffix,
-                pod.params,
-                token_arr[:, geom.prefix_tokens :],
-                pod.kv,
-                table,
-            )
-        else:
-            (logits, pod.kv), seconds = timed(
-                prefill_full, pod.params, token_arr, pod.kv, table
-            )
-        run_s["hit" if hit else "miss"].append(seconds)
-        worked_on |= logits.devices()
-        last = np.asarray(logits[0, -1], np.float32)
+        t0 = time.perf_counter()
+        _, _, row = result.prefill(pod, tokens, block_ids, hit, first_new)
+        run_s["hit" if hit else "miss"].append(time.perf_counter() - t0)
+        worked_on |= row.devices()
+        last = np.asarray(row, np.float32)
         check(
-            logits.shape[-1] == cfg.vocab_size and bool(np.isfinite(last).all()),
-            f"group {group}: logits {logits.shape}, finite="
+            last.shape == (cfg.vocab_size,) and bool(np.isfinite(last).all()),
+            f"group {group}: logits {last.shape}, finite="
             f"{bool(np.isfinite(last).all())}",
         )
         if hit:
-            last_hit = {
+            result.last_hit = {
                 "pod": pod,
                 "tokens": tokens,
                 "block_ids": block_ids,
@@ -352,6 +365,12 @@ def phase_fleet(
         n_miss == geom.n_groups
         and n_hit == geom.n_groups * (geom.reqs_per_group - 1),
         f"{n_miss} misses and {n_hit} hits",
+    )
+    held = records.counters["held_somewhere"]
+    check(
+        held == n_hit and records.counters["routed_to_holder"] == held,
+        f"the fleet counted {held} held prefixes and "
+        f"{records.counters['routed_to_holder']} routed to a holder",
     )
     if devices is not None:
         # (Here each pod's first call of each program also compiled it
@@ -373,7 +392,7 @@ def phase_fleet(
         first_miss_s=run_s["miss"][0],
         first_hit_s=run_s["hit"][0],
     )
-    return FleetResult(fleet, holder, last_hit, prefill_full)
+    return result
 
 
 def phase_hit_vs_miss(
@@ -388,13 +407,8 @@ def phase_hit_vs_miss(
         if pod.name not in result.holder.values()
     )
     block_ids = alloc_spare(other, geom.total_tokens // cfg.block_size)
-    tokens = jnp.asarray(np.asarray(hit["tokens"], np.int32)[None])
-    logits, other.kv = result.prefill_full(
-        other.params, tokens, other.kv, jnp.asarray([block_ids], jnp.int32)
-    )
-    err = logits_agree(
-        hit["logits"], np.asarray(logits[0, -1]), "hit path vs miss path"
-    )
+    _, _, row = result.prefill(other, hit["tokens"], block_ids, False, 0)
+    err = logits_agree(hit["logits"], np.asarray(row), "hit path vs miss path")
     say("hit_vs_miss", max_rel_err=err, tol=BF16_REL_TOL)
 
 
@@ -406,18 +420,17 @@ def phase_block_until_ready(
     and what a host readback still costs after that.  Every time this
     script prints rests on the answer, so ``main()`` requires it."""
     pod = result.last_hit["pod"]
+    miss = result.fleet.programs["miss"]
     block_ids = alloc_spare(pod, geom.total_tokens // cfg.block_size)
-    tokens = jnp.zeros((1, geom.total_tokens), jnp.int32)
-    table = jnp.asarray([block_ids], jnp.int32)
+    tokens = np.zeros((1, geom.total_tokens), np.int32)
+    table = np.asarray([block_ids], np.int32)
     for _ in range(2):  # the first pass compiles the readback's own ops
         t0 = time.perf_counter()
-        logits, pod.kv = result.prefill_full(
-            pod.params, tokens, pod.kv, table
-        )
+        _, row, pod.kv = miss(result.params[pod.name], tokens, pod.kv, table)
         enqueue_s = time.perf_counter() - t0
-        jax.block_until_ready(logits)
+        jax.block_until_ready(row)
         ready_s = time.perf_counter() - t0
-        int(jnp.argmax(logits[0, -1]))
+        int(jnp.argmax(row))
         readback_s = time.perf_counter() - t0 - ready_s
     waits = bool(ready_s > 4 * enqueue_s and readback_s < 0.25 * ready_s)
     say(
@@ -426,7 +439,6 @@ def phase_block_until_ready(
         ready_s=ready_s,
         readback_after_ready_s=readback_s,
         waits=waits,
-        readback_floor_s=bench.measure_readback_rtt(),
     )
     return waits
 
@@ -491,7 +503,7 @@ def phase_decode(
     Pallas paged-decode kernel against the XLA gather, both from the
     same pool at every step."""
     hit = result.last_hit
-    pod = hit["pod"]
+    pod, params = hit["pod"], result.params[hit["pod"].name]
     spare = alloc_spare(pod, -(-geom.decode_steps // cfg.block_size))
     table = jnp.asarray([list(hit["block_ids"]) + spare], jnp.int32)
 
@@ -507,19 +519,19 @@ def phase_decode(
     ctx = jnp.asarray([geom.total_tokens + 1], jnp.int32)
     pallas, pallas_compile_s = compile_timed(
         jit_decode("pallas"),
-        pod.params, token, pod.kv, table, ctx,
+        params, token, pod.kv, table, ctx,
         want_mosaic=not interpret,
     )
     gather, gather_compile_s = compile_timed(
         jit_decode("gather"),
-        pod.params, token, pod.kv, table, ctx,
+        params, token, pod.kv, table, ctx,
         want_mosaic=False,
     )
     pallas_s, gather_s, worst = [], [], 0.0
     for step in range(geom.decode_steps):
-        (want, _), seconds = timed(gather, pod.params, token, pod.kv, table, ctx)
+        (want, _), seconds = timed(gather, params, token, pod.kv, table, ctx)
         gather_s.append(seconds)
-        (got, kv), seconds = timed(pallas, pod.params, token, pod.kv, table, ctx)
+        (got, kv), seconds = timed(pallas, params, token, pod.kv, table, ctx)
         pallas_s.append(seconds)
         worst = max(
             worst,
@@ -582,7 +594,7 @@ def phase_flash_bound(
     )
     got, run_s = timed(pallas, q, k, v)
     want = flash_gqa_attention(q, k, v)
-    err = bench.max_rel_err(got, want)
+    err = max_rel_err(got, want)
     check(
         bool(jnp.isfinite(got.astype(jnp.float32)).all()) and err < BF16_REL_TOL,
         f"flash kernel at {tokens} tokens: max rel err {err:.4f}",
@@ -742,7 +754,7 @@ def main() -> None:
         )
     # An unknown chip is an error here, before any number is printed
     # for it, not a None in a report.
-    peak = bench.peak_bf16_tflops(device.device_kind)
+    peak = costs.peaks(device.device_kind)["bf16_flops"] / 1e12
     cache_dir = configure_compile_cache()
     entries_before = cache_entries(cache_dir)
     print(
@@ -759,7 +771,10 @@ def main() -> None:
     say("native_build", library=os.path.basename(library),
         build_s=time.perf_counter() - t0)
 
-    cfg, geom = bench.CFG, FULL_GEOMETRY
+    cfg, geom = full_setup()
+    say("setup", cell=CELL, layers=cfg.n_layers, d_model=cfg.d_model,
+        vocab=cfg.vocab_size, tokens=geom.total_tokens, pods=geom.pods,
+        pool_blocks=geom.pool_blocks)
     t0 = time.perf_counter()
     params = jax.jit(functools.partial(llama.init_params, cfg=cfg))(
         jax.random.PRNGKey(0)
@@ -777,8 +792,8 @@ def main() -> None:
             "block_until_ready came back before the device was done: "
             "the times above are enqueue times",
         )
-        # Decode and offload keep one pool and one spare; the other
-        # pods' 1.6 GB each go back to the chip first.
+        # Decode and offload keep one pool; the other pods' 2.1 GB
+        # each go back to the chip first.
         keep = result.last_hit["pod"]
         for pod in result.fleet.pods:
             if pod is not keep:

@@ -653,7 +653,7 @@ def parse_docs(root: str) -> DocSurface:
 
 def _scan_external_env_reads(root: str) -> Set[str]:
     """Env names read outside the analyzed Python set: native C++
-    (``std::getenv``) and repo-root scripts (bench.py etc.)."""
+    (``std::getenv``) and repo-root scripts (chip_smoke.py etc.)."""
     names: Set[str] = set()
     patterns = [
         os.path.join(root, "*.py"),

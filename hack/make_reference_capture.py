@@ -1,10 +1,12 @@
 """Deterministic generator for the pinned what-if reference capture.
 
 ``tests/testdata/whatif_reference.cbor`` is the capture
-``hack/perf_trend.py`` replays (shards=1 vs shards=8 A/B) to gate
-capacity regressions, and the seed ``hack/whatif_smoke.py`` composes
-storms from.  It must be BYTE-STABLE across machines and package
-versions, so this generator:
+``obs.whatif.reference_ab`` replays (shards=1 vs shards=8 A/B); its
+deterministic headlines are held to the oracle beside it,
+``tests/testdata/WHATIF_r01.json``, by ``tests/test_whatif.py`` and
+``hack/whatif_smoke.py``, which also composes its storms from it.  It
+must be BYTE-STABLE across machines and package versions, so this
+generator:
 
 * drives a REAL stack (indexer + kvevents pool + flight recorder) with
   a seeded workload — recorded score maps and the canonical state

@@ -18,11 +18,11 @@ Closes the loop end to end:
   (``GET /debug/incidents/<id>``), replays the bundle through
   ``POST /admin/whatif`` by id, and checks ``GET /debug/whatif`` +
   the ``kvtpu_whatif_*`` metric families.
-* **Perf-trend gate**: ``hack/perf_trend.py`` must pass on the honest
-  checked-in trajectory (the live reference A/B equals
-  ``WHATIF_r01.json`` exactly — the headlines are deterministic) and
-  must FAIL when the baseline artifact is doctored to claim a higher
-  hit rate than the code can deliver.
+* **Recorded oracle**: the live reference A/B (shards=1 vs shards=8
+  over the pinned capture) equals
+  ``tests/testdata/WHATIF_r01.json`` exactly — the headlines are
+  deterministic, so an inequality means the engine's behavior changed
+  without regenerating the artifacts.
 
 Run: ``python hack/whatif_smoke.py`` (CI step "What-if smoke",
 ``make whatif-smoke``).  Prints "whatif smoke completed successfully"
@@ -32,7 +32,6 @@ on success; any assertion exits non-zero.
 import json
 import os
 import shutil
-import subprocess
 import sys
 import tempfile
 import urllib.request
@@ -79,6 +78,7 @@ REPO = os.path.join(os.path.dirname(os.path.abspath(__file__)), "..")
 REFERENCE = os.path.join(
     REPO, "tests", "testdata", "whatif_reference.cbor"
 )
+RECORDED = os.path.join(REPO, "tests", "testdata", "WHATIF_r01.json")
 MODEL = "whatif-ref"
 BLOCK_SIZE = 4
 
@@ -347,60 +347,16 @@ def check_service(workdir):
         indexer.shutdown()
 
 
-def check_perf_trend_gate(workdir):
-    env = dict(os.environ)
-    trend = os.path.join(REPO, "hack", "perf_trend.py")
-    honest = subprocess.run(
-        [sys.executable, trend],
-        cwd=REPO,
-        env=env,
-        capture_output=True,
-        text=True,
-        timeout=600,
-    )
-    assert honest.returncode == 0, (
-        f"perf-trend failed on the honest trajectory:\n{honest.stdout}"
-        f"\n{honest.stderr}"
-    )
-    assert "live reference A/B" in honest.stdout, honest.stdout
-
-    planted_dir = os.path.join(workdir, "planted")
-    os.makedirs(planted_dir)
-    with open(os.path.join(REPO, "WHATIF_r01.json")) as handle:
-        artifact = json.load(handle)
-    live_hit = artifact["headlines"]["whatif.hit_rate"]
-    artifact["headlines"]["whatif.hit_rate"] = min(1.0, live_hit * 1.5)
-    with open(
-        os.path.join(planted_dir, "WHATIF_r01.json"), "w"
-    ) as handle:
-        json.dump(artifact, handle)
-    planted = subprocess.run(
-        [sys.executable, trend, "--dir", planted_dir],
-        cwd=REPO,
-        env=env,
-        capture_output=True,
-        text=True,
-        timeout=600,
-    )
-    assert planted.returncode != 0, (
-        "perf-trend must fail on a planted capacity regression:\n"
-        f"{planted.stdout}"
-    )
-    assert "whatif.hit_rate (live)" in planted.stdout, planted.stdout
-    print(
-        "whatif-smoke: perf-trend gate ok (honest pass, planted "
-        "regression fail)"
-    )
-
+def check_recorded_baseline():
     # The recorded baseline IS the live measurement — the headlines
     # are deterministic, so an inequality here means the engine's
     # behavior changed without regenerating the artifacts.
     ab = whatif.reference_ab()
     live = whatif.gate_headlines(ab)
-    with open(os.path.join(REPO, "WHATIF_r01.json")) as handle:
+    with open(RECORDED) as handle:
         recorded = json.load(handle)["headlines"]
     assert live == recorded, (
-        "deterministic headlines drifted from WHATIF_r01.json: "
+        f"deterministic headlines drifted from {RECORDED}: "
         f"live {live} vs recorded {recorded} — regenerate the "
         "artifact (see hack/make_reference_capture.py docstring)"
     )
@@ -416,7 +372,7 @@ def main() -> None:
         storm = check_composition(workdir)
         check_ab(storm)
         check_service(workdir)
-        check_perf_trend_gate(workdir)
+        check_recorded_baseline()
     finally:
         shutil.rmtree(workdir, ignore_errors=True)
     print("whatif smoke completed successfully")

@@ -43,15 +43,16 @@ overflow, ``pod_budget`` — over-budget pod, ``shutdown``) and per pod
 in ``kvtpu_kvevents_pod_shed_total{pod=...}``; per-pod backlog rides
 the ``kvtpu_kvevents_pod_backlog{pod=...}`` gauge.
 ``PoolConfig.per_pod_flow_control=False`` restores the legacy global
-FIFO + drop-oldest (the bench A/B baseline).
+FIFO + drop-oldest (an escape hatch).
 
 **Write-path fast lane** (docs/event-plane.md): enqueue is batched
 (``add_tasks``: one shard-lock round trip per drained socket burst,
 metrics batched outside every lock) and the overflow victim — the
 longest lane — is picked O(1) from depth buckets instead of an
 O(lanes) ``max`` scan under the shard lock (the scan serialized
-enqueueing pollers against draining workers at saturation; BENCH_r06's
-pollers=4 < pollers=1 inversion).  With ``PoolConfig.lockfree_decode``
+enqueueing pollers against draining workers at saturation: four
+pollers applied fewer events than one, in a CPU run of round 6; not
+measured on a chip).  With ``PoolConfig.lockfree_decode``
 (``KVEVENTS_LOCKFREE_DECODE``, default on) payloads are msgpack-decoded
 on the enqueueing thread BEFORE the shard queue — a lock-free stage
 over (possibly zero-copy ``memoryview``) payloads — and workers apply
@@ -340,8 +341,8 @@ class _ShardQueue:
         # UNDER THE SHARD LOCK on every overflowing put: at saturation
         # with ~250 lanes/shard every enqueue paid it, pollers and
         # workers convoyed on the lock, and adding pollers made apply
-        # throughput WORSE (the pollers=4 < pollers=1 inversion in
-        # BENCH_r06).  Depths change by ±1 per operation, so bucket
+        # throughput WORSE (pollers=4 < pollers=1 in a CPU run of
+        # round 6).  Depths change by ±1 per operation, so bucket
         # moves (and the max's downward walk) are amortized O(1).
         self._by_depth: Dict[int, Dict[str, None]] = {}  # guarded-by: _lock
         self._max_lane = 0  # guarded-by: _lock

@@ -63,22 +63,22 @@ class LlamaConfig:
     # long-context prefill path).  Static shapes make this a trace-time
     # choice.
     flash_attention_min_len: int = 1024
-    # Decode attention over the paged pool.  "auto" resolves to the
-    # XLA gather EVERYWHERE — the recorded routing decision: the last
-    # committed chip measurement put the Pallas kernel at 1.09x over
-    # the gather (within noise; r4), and the routing rule requires
-    # >= 1.3x at two serving shapes before Pallas may be the default
-    # (bench.py DECODE_ROUTE_MIN_SPEEDUP).  bench.py re-measures every
-    # run and sets "pallas" explicitly when the kernel earns it;
-    # "pallas" / "gather" force one path.
+    # Decode attention over the paged pool.  "auto" is the XLA gather
+    # for every program of this module; "pallas" / "gather" force one
+    # path.  Nothing in the repo sets "pallas": the benchmark's llama
+    # cells all run the gather, chip_smoke.py and the tests compile the
+    # kernel beside it for equality only, and models/afmoe.py always
+    # takes the kernel.  Which path stays is ROADMAP S2/D6's to decide,
+    # on decode_step_roofline of the cell internlm2-chat-sysprompt
+    # (7.11 %; PERF_LEDGER.jsonl, PR 30).
     decode_attention: str = "auto"
-    # Pool blocks the Pallas decode kernel fetches per grid step;
-    # bench.py detail.kernels sweeps this at serving shapes and routes
-    # the measured winner here.
+    # Pool blocks the Pallas decode kernel fetches per grid step.
+    # Nothing in the repo sets it (tests/test_paged_decode_pallas.py
+    # holds other values to the same answers); S2/D6 decides with the path.
     decode_blocks_per_step: int = 4
     # Feed the decode-attention dots bf16 operands (f32 accumulation)
-    # instead of upcasting K/V in VMEM; swept by bench.py alongside the
-    # tile size.
+    # instead of upcasting K/V in VMEM.  Nothing in the repo sets it
+    # (tests only); not timed on a chip.
     decode_mxu_native: bool = False
 
     @property
@@ -595,11 +595,11 @@ def decode_step(
             kv_new.astype(slots.dtype)
         )
         table = base + block_table
-        # "auto" = the recorded routing decision: the XLA gather (last
-        # measured Pallas margin 1.09x — within noise — and the rule
-        # requires >= 1.3x at two serving shapes; see LlamaConfig).
-        # bench.py re-measures both compiled on the real chip every
-        # run (detail.kernels) and sets "pallas" when it earns it.
+        # "auto" is the XLA gather, as "gather" is; only an explicit
+        # "pallas" takes the kernel, and nothing in the repo sets it
+        # (see LlamaConfig).  ROADMAP S2/D6 decides between the two
+        # paths on the benchmark's decode_step_roofline (cell
+        # internlm2-chat-sysprompt), not on a sweep made in a run.
         use_pallas = cfg.decode_attention == "pallas"
         if use_pallas:
             attn = paged_decode_attention_pallas(

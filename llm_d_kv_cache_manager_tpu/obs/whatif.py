@@ -37,9 +37,9 @@ the same artifacts into DECISIONS (ROADMAP item 4):
 Surfaces: the CLI (``python -m llm_d_kv_cache_manager_tpu.obs.whatif
 run|ab|compose``), ``GET /debug/whatif`` (the bounded results
 registry), ``POST /admin/whatif`` (run against a retained incident
-bundle), ``kvtpu_whatif_*`` metrics, and the ``hack/perf_trend.py``
-gate over the pinned reference capture
-(``tests/testdata/whatif_reference.cbor``).  See
+bundle), ``kvtpu_whatif_*`` metrics, and :func:`reference_ab` over the
+pinned reference capture (``tests/testdata/whatif_reference.cbor``),
+whose headlines ``tests/testdata/WHATIF_r01.json`` records.  See
 docs/observability.md "What-if engine".
 """
 
@@ -91,7 +91,7 @@ DEFAULT_RESULTS_KEEP = 8
 MAX_CHECKPOINTS = 1024
 
 # The pinned reference capture (hack/make_reference_capture.py) —
-# what perf-trend's capacity gate and the smoke replay.
+# what reference_ab and hack/whatif_smoke.py replay.
 REFERENCE_CAPTURE_RELPATH = os.path.join(
     "tests", "testdata", "whatif_reference.cbor"
 )
@@ -881,8 +881,9 @@ def run_ab(
 
 
 def gate_headlines(ab: dict) -> Dict[str, float]:
-    """The deterministic higher-is-better headlines perf-trend gates
-    on the pinned reference capture (hack/perf_trend.py):
+    """The deterministic higher-is-better headlines of the pinned
+    reference capture, held exactly to tests/testdata/WHATIF_r01.json
+    (tests/test_whatif.py, hack/whatif_smoke.py):
 
     * ``whatif.hit_rate`` — arm A's measured hit rate (a hashing /
       chunking / index regression zeroes or dents it);

@@ -32,8 +32,8 @@ NEG_INF = -1e30
 # Default pool blocks fetched per grid step: amortizes per-step
 # pipeline overhead (528 one-block steps left the MXU mostly idle)
 # while each block still arrives through its own independently-
-# pipelined DMA.  bench.py's detail.kernels sweeps this on the real
-# chip and routes the winner via LlamaConfig.decode_blocks_per_step.
+# pipelined DMA.  LlamaConfig.decode_blocks_per_step overrides it and
+# nothing in the repo sets that; models/afmoe.py passes its own 32.
 BLOCKS_PER_STEP = 4
 
 
@@ -177,7 +177,7 @@ def paged_decode_attention_pallas(
 
     ``mxu_native=True`` keeps the attention dots in the input dtype
     (bf16 operands, f32 accumulation) instead of upcasting K/V to f32 in
-    VMEM; bench.py's kernel sweep measures both and routes the winner.
+    VMEM.  No caller outside the tests sets it; not timed on a chip.
     """
     B, H, D = q.shape
     if heads_first:

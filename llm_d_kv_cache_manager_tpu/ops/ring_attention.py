@@ -28,8 +28,8 @@ per step).  Two step bodies exist:
   diagonal, merged across steps by the flash-decoding combine.  With
   ``striped=True`` every step is a near-uniform causal band, so the
   layout's balance becomes ~half the per-step MXU work on every
-  device; measured per-step on the chip by bench.py (detail.
-  kernels.ring).
+  device.  Not measured on a chip: no cell of the benchmark enters
+  this module (ROADMAP D8).
 
 The model reaches both: ``forward(sp_mesh=..., ring_striped=True,
 ring_impl="flash")`` runs the whole network in stripe order and

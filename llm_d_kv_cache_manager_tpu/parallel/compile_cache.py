@@ -1,6 +1,6 @@
 """Where XLA's persistent compilation cache lives: one rule, one place.
 
-Every entry point that compiles for the chip (``bench.py``,
+Every entry point that compiles for the chip (``benchmarks/run.py``,
 ``chip_smoke.py``, ``__graft_entry__``'s ``__main__``) calls
 :func:`configure_compile_cache` before its first compile.  The
 directory is part of the cache key, so it must not move between runs:

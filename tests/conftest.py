@@ -10,7 +10,7 @@ import os
 # Tests always run on the CPU, whatever the environment preselects:
 # they validate multi-chip sharding on the virtual 8-device mesh, and a
 # chip belongs to one process at a time — it is reserved for
-# chip_smoke.py and bench.py, run through the chip tool.
+# chip_smoke.py and benchmarks/run.py, run through the chip tool.
 os.environ["JAX_PLATFORMS"] = "cpu"
 flags = os.environ.get("XLA_FLAGS", "")
 if "xla_force_host_platform_device_count" not in flags:

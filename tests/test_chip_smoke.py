@@ -11,6 +11,8 @@ import jax
 import pytest
 
 import chip_smoke
+from benchmarks import run
+from benchmarks.harness import engine, traffic
 from llm_d_kv_cache_manager_tpu.models import llama
 from llm_d_kv_cache_manager_tpu.parallel import compile_cache
 
@@ -36,6 +38,24 @@ TINY_GEOMETRY = chip_smoke.Geometry(
     decode_steps=3,
     reference_tokens=128,
 )
+
+
+def test_full_geometry_is_the_benchmark_cells():
+    """Widths, tokens, block, pods and pool come from the files of the
+    cell `mistral7b-docs-shared`."""
+    cell = run.load(run.BENCH, "cells", chip_smoke.CELL)
+    published = run.load(run.BENCH, "configs", cell["config"])
+    tr = run.load(run.BENCH, "traffic", cell["traffic"])
+    cfg, geom = chip_smoke.full_setup()
+    assert cell["config"] == "mistral-7b-v0.3-l8"
+    assert (cfg.d_model, cfg.n_layers, cfg.n_heads, cfg.n_kv_heads, cfg.d_ff,
+            cfg.vocab_size, cfg.head_dim) == tuple(published[k] for k in (
+                "hidden_size", "num_hidden_layers", "num_attention_heads",
+                "num_key_value_heads", "intermediate_size", "vocab_size",
+                "head_dim"))
+    assert cfg.block_size == engine.BLOCK == 16
+    assert geom.traffic() == {key: tr[key] for key in geom.traffic()}
+    assert traffic.shapes(geom.traffic()) == traffic.shapes(tr)
 
 
 def test_refuses_to_run_without_a_tpu():

@@ -59,7 +59,7 @@ LOCAL_TOP_LEVELS = {
     "hack",
     "render_chart",  # hack/render_chart.py imported by test_chart.py
     "helpers",  # tests/helpers, sys.path'd by profiling scripts
-    "bench",
+    "benchmarks",  # chip_smoke.py stands on the harness
     "chip_smoke",
     "__graft_entry__",
 }
@@ -102,7 +102,7 @@ def test_pip_list_covers_all_required_imports():
     imports = set()
     for sub in ("llm_d_kv_cache_manager_tpu", "tests", "examples", "hack"):
         imports |= _imports_under(REPO / sub)
-    # top-level scripts only (bench.py, __graft_entry__.py)
+    # top-level scripts only (chip_smoke.py, __graft_entry__.py)
     imports |= _imports_under(REPO, recursive=False)
 
     stdlib = set(sys.stdlib_module_names)

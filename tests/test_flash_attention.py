@@ -91,7 +91,8 @@ from llm_d_kv_cache_manager_tpu.ops.flash_pallas import (  # noqa: E402
 )
 def test_pallas_matches_dense(B, Tq, Tk, H, Hkv, D, q_offset):
     """The TPU kernel in interpreter mode vs the dense reference; the
-    same code compiles on-chip (exercised by bench.py)."""
+    same code compiles on-chip (tests/test_tpu_compile.py, and every
+    miss prefill of the benchmark runs it)."""
     q, k, v = _qkv(jax.random.PRNGKey(7), B, Tq, Tk, H, Hkv, D)
     q = q.astype(jnp.bfloat16)
     k = k.astype(jnp.bfloat16)

@@ -57,8 +57,9 @@ def test_matches_xla_gather(B, H, Hkv, D, max_blocks, ctx):
 
 @pytest.mark.parametrize("blocks_per_step", [1, 2, 8])
 def test_blocks_per_step_variants_match(blocks_per_step):
-    """The tile size bench.py sweeps on the chip must be correctness-
-    neutral at every value (ragged contexts + non-divisible tables)."""
+    """The tile size (LlamaConfig.decode_blocks_per_step) must be
+    correctness-neutral at every value (ragged contexts +
+    non-divisible tables)."""
     bs = 16
     q, kv, table, ctx_arr = make_case(
         jax.random.PRNGKey(2), 2, 8, 4, 64, 64, bs, 7, [97, 33]
@@ -79,7 +80,7 @@ def test_blocks_per_step_variants_match(blocks_per_step):
 
 def test_mxu_native_variant_matches():
     """The bf16-operand (mxu_native) dot path must agree with the f32
-    upcast path within bf16 tolerance; bench.py times both on the chip."""
+    upcast path within bf16 tolerance (neither is timed on a chip)."""
     bs = 16
     q, kv, table, ctx_arr = make_case(
         jax.random.PRNGKey(3), 2, 8, 4, 64, 64, bs, 7, [97, 33]

@@ -13,6 +13,7 @@ import random
 import pytest
 
 from llm_d_kv_cache_manager_tpu.kvcache.indexer import (
+    DEFAULT_LOOKUP_CHUNK,
     Indexer,
     IndexerConfig,
 )
@@ -716,7 +717,7 @@ class TestBatchedEventApply:
 # ----------------------------------------------------- end-to-end parity
 
 
-def make_indexer(fast, block_size=16, shards=8):
+def make_indexer(fast, block_size=16, shards=8, chunk=8):
     indexer = Indexer(
         IndexerConfig(
             token_processor_config=TokenProcessorConfig(
@@ -728,12 +729,69 @@ def make_indexer(fast, block_size=16, shards=8):
                 )
             ),
             read_path_fast_lane=fast,
-            lookup_chunk_size=8,
+            lookup_chunk_size=chunk,
         ),
         tokenizer=WordTokenizer(),
     )
     indexer.run()
     return indexer
+
+
+class TestFullGeometryWork:
+    """What one cold scoring call does at the benchmark's full geometry
+    (8192 + 256 tokens = 528 blocks of 16, the default lookup chunk),
+    counted, not timed: a lost early exit, a chain hashed twice or a
+    round per block shows here on any machine.  On the chip the same
+    call is timed by the cell mistral7b-docs-shared
+    (router_score_p50_s, router_index_lookup_p50_s, router_rank_p50_s)."""
+
+    TOKENS = 8192 + 256
+    BLOCKS = TOKENS // 16
+
+    @pytest.mark.parametrize(
+        "held_from, rounds, keys_walked, score",
+        [
+            # Held whole: chunks of 32, 64, 128, 256, then the last 48.
+            (0, 5, 528, 528.0),
+            # Dead at block 0 (every later block held): one chunk, out.
+            (1, 1, DEFAULT_LOOKUP_CHUNK, None),
+        ],
+    )
+    def test_rounds_and_keys_of_one_cold_call(
+        self, held_from, rounds, keys_walked, score
+    ):
+        indexer = make_indexer(True, chunk=DEFAULT_LOOKUP_CHUNK)
+        try:
+            rng = random.Random(31)
+            tokens = [rng.randrange(1, 60_000) for _ in range(self.TOKENS)]
+            keys = indexer.token_processor.tokens_to_kv_block_keys(
+                EMPTY_BLOCK_HASH, tokens, "m"
+            )
+            assert len(keys) == self.BLOCKS
+            indexer.kv_block_index.add(
+                keys[held_from:], keys[held_from:], [POD_A]
+            )
+            hashed, looked_up = [], []
+            processor, index = indexer.token_processor, indexer.kv_block_index
+            extend, lookup = processor.extend_block_keys, index.lookup_chain
+
+            def counting_extend(parent, suffix, model):
+                out = extend(parent, suffix, model)
+                hashed.append(len(out))
+                return out
+
+            def counting_lookup(chain):
+                looked_up.append(len(chain))
+                return lookup(chain)
+
+            processor.extend_block_keys = counting_extend
+            index.lookup_chain = counting_lookup
+            scores = indexer.get_pod_scores(words(tokens), "m")
+            assert scores == ({} if score is None else {"pod-a": score})
+            assert len(looked_up) == rounds
+            assert sum(looked_up) == sum(hashed) == keys_walked
+        finally:
+            indexer.shutdown()
 
 
 class TestFastLaneParity:

@@ -360,17 +360,18 @@ class TestReferenceArtifact:
         assert disk == build_reference_capture(), (
             "tests/testdata/whatif_reference.cbor is stale; "
             "regenerate with: python hack/make_reference_capture.py "
-            "(and refresh WHATIF_r01.json via the live headlines)"
+            "(and refresh tests/testdata/WHATIF_r01.json's headlines "
+            "from whatif.gate_headlines(whatif.reference_ab()))"
         )
 
     def test_reference_ab_matches_recorded_baseline(self):
-        """WHATIF_r01.json records deterministic measurements; the
-        live engine must reproduce them exactly."""
+        """tests/testdata/WHATIF_r01.json records deterministic
+        measurements; the live engine must reproduce them exactly."""
         ab = whatif.reference_ab()
         live = whatif.gate_headlines(ab)
         with open(
             os.path.join(
-                os.path.dirname(__file__), "..", "WHATIF_r01.json"
+                os.path.dirname(__file__), "testdata", "WHATIF_r01.json"
             )
         ) as handle:
             recorded = json.load(handle)["headlines"]
