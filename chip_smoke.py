@@ -17,7 +17,8 @@ a published configuration and the geometry of the benchmark's cell
 2. **reference** — paged prefill against the dense forward on a small
    input (the repo's own equivalence reference);
 3. **decode** — ``llama.decode_step`` x 8 on one sequence, the compiled
-   Pallas kernel against the XLA gather at the same context;
+   Pallas kernel (what serves on the chip) against the XLA gather at the
+   same context, both donating the pool;
 4. **flash_bound** — the Pallas flash kernel compiled and run at the
    longest context ``flash_pallas.fits_vmem`` admits;
 5. **offload** — ``TPUOffloadConnector``: store one group's blocks,
@@ -500,25 +501,33 @@ def phase_decode(
     interpret: bool = False,
 ) -> None:
     """``decode_steps`` greedy steps on the last hit's sequence: the
-    Pallas paged-decode kernel against the XLA gather, both from the
-    same pool at every step."""
+    Pallas paged-decode kernel (``llama.decode_step``'s rule: compiled
+    for the TPU, or interpreted) against the XLA gather
+    (``decode_attention="gather"``), both from the same pool at every
+    step.  Both programs donate the pool, as the benchmark's pod does, so
+    neither step's time holds a pool copy: the gather's step hands its
+    pool to the kernel's, which writes the same slot again before it
+    attends."""
     hit = result.last_hit
     pod, params = hit["pod"], result.params[hit["pod"].name]
     spare = alloc_spare(pod, -(-geom.decode_steps // cfg.block_size))
     table = jnp.asarray([list(hit["block_ids"]) + spare], jnp.int32)
 
-    def jit_decode(kind: str):
-        decode_cfg = dataclasses.replace(cfg, decode_attention=kind)
+    def jit_decode(decode_attention: str):
+        decode_cfg = dataclasses.replace(
+            cfg, decode_attention=decode_attention
+        )
         return jax.jit(
             lambda p, t, kv, bt, cl: llama.decode_step(
                 p, t, kv, bt, cl, decode_cfg, interpret=interpret
-            )
+            ),
+            donate_argnums=(2,),
         )
 
     token = jnp.asarray([int(np.argmax(hit["logits"]))], jnp.int32)
     ctx = jnp.asarray([geom.total_tokens + 1], jnp.int32)
     pallas, pallas_compile_s = compile_timed(
-        jit_decode("pallas"),
+        jit_decode("auto"),
         params, token, pod.kv, table, ctx,
         want_mosaic=not interpret,
     )
@@ -529,9 +538,9 @@ def phase_decode(
     )
     pallas_s, gather_s, worst = [], [], 0.0
     for step in range(geom.decode_steps):
-        (want, _), seconds = timed(gather, params, token, pod.kv, table, ctx)
+        (want, kv), seconds = timed(gather, params, token, pod.kv, table, ctx)
         gather_s.append(seconds)
-        (got, kv), seconds = timed(pallas, params, token, pod.kv, table, ctx)
+        (got, pod.kv), seconds = timed(pallas, params, token, kv, table, ctx)
         pallas_s.append(seconds)
         worst = max(
             worst,
@@ -541,7 +550,6 @@ def phase_decode(
                 f"decode step {step}: pallas vs gather",
             ),
         )
-        pod.kv = kv
         token = jnp.argmax(got, axis=-1).astype(jnp.int32)
         ctx = ctx + 1
     say(

@@ -54,6 +54,7 @@ from llm_d_kv_cache_manager_tpu.models.kv_cache_pool import (
 )
 from llm_d_kv_cache_manager_tpu.ops import flash_pallas
 from llm_d_kv_cache_manager_tpu.ops.paged_attention import paged_attention
+from llm_d_kv_cache_manager_tpu.ops import paged_decode_pallas
 from llm_d_kv_cache_manager_tpu.ops.paged_decode_pallas import (
     paged_decode_attention_pallas,
 )
@@ -618,12 +619,13 @@ def _write_token(pool, ids, at, new):
 
 
 def _decode_attention(q, pool, table, context_len, start, interpret):
-    """The paged kernel, which reads each table block once, where it runs
-    compiled (TPU) or is asked to be interpreted; elsewhere the XLA gather."""
-    if interpret or jax.default_backend() == "tpu":
+    """The paged kernel, which reads each table block once, where it serves
+    (compiled for the TPU, or interpreted); elsewhere the XLA gather."""
+    if paged_decode_pallas.serves(interpret):
         return paged_decode_attention_pallas(
             q, pool, table, context_len, start=start, heads_first=True,
-            blocks_per_step=DECODE_BLOCKS_PER_STEP, interpret=interpret,
+            blocks_per_step=DECODE_BLOCKS_PER_STEP, mxu_native=False,
+            interpret=interpret,
         )
     return paged_attention(
         q, pool, table, context_len, start=start, heads_first=True

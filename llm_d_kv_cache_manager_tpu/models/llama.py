@@ -10,8 +10,9 @@ axes (parallel/mesh.py):
 - params: layer axis over ``pp``, heads/ffn-hidden over ``tp``
 - activations: batch over ``dp``, sequence over ``sp``
 - serving KV state: the paged pool (models/kv_cache_pool.py), written
-  by prefill and read by ``paged_attention`` at decode — the compute
-  counterpart of the KV-block index the manager tracks fleet-wide.
+  by prefill and read at decode by the paged kernel
+  (ops/paged_decode_pallas.py; off the TPU by ``paged_attention``) —
+  the compute counterpart of the KV-block index the manager tracks fleet-wide.
 
 Capabilities: dense forward (training / scoring), paged prefill +
 decode (serving), ring-attention prefill for long context (ops/
@@ -34,6 +35,7 @@ from llm_d_kv_cache_manager_tpu.models.kv_cache_pool import scatter_kv_blocks
 from llm_d_kv_cache_manager_tpu.ops.attention import causal_gqa_attention
 from llm_d_kv_cache_manager_tpu.ops.flash_attention import flash_gqa_attention
 from llm_d_kv_cache_manager_tpu.ops import flash_pallas
+from llm_d_kv_cache_manager_tpu.ops import paged_decode_pallas
 from llm_d_kv_cache_manager_tpu.ops.paged_decode_pallas import (
     paged_decode_attention_pallas,
 )
@@ -63,23 +65,16 @@ class LlamaConfig:
     # long-context prefill path).  Static shapes make this a trace-time
     # choice.
     flash_attention_min_len: int = 1024
-    # Decode attention over the paged pool.  "auto" is the XLA gather
-    # for every program of this module; "pallas" / "gather" force one
-    # path.  Nothing in the repo sets "pallas": the benchmark's llama
-    # cells all run the gather, chip_smoke.py and the tests compile the
-    # kernel beside it for equality only, and models/afmoe.py always
-    # takes the kernel.  Which path stays is ROADMAP S2/D6's to decide,
-    # on decode_step_roofline of the cell internlm2-chat-sysprompt
-    # (7.11 %; PERF_LEDGER.jsonl, PR 30).
+    # Decode attention over the paged pool.  "auto": the paged kernel
+    # (ops/paged_decode_pallas.py) where the program is compiled for the
+    # TPU or asked to be interpreted, the XLA gather elsewhere; the rule
+    # models/afmoe.py has (ROADMAP D6, decided in PR 32 on the cell
+    # internlm2-chat-sysprompt).  "gather" asks for the XLA gather
+    # whatever the backend.  One caller needs it:
+    # __graft_entry__._dryrun_tp_decode hands decode_step a pool sharded
+    # over KV heads under plain jit, and a pallas_call is not partitioned
+    # for it.  chip_smoke.py's decode phase uses it for the comparison.
     decode_attention: str = "auto"
-    # Pool blocks the Pallas decode kernel fetches per grid step.
-    # Nothing in the repo sets it (tests/test_paged_decode_pallas.py
-    # holds other values to the same answers); S2/D6 decides with the path.
-    decode_blocks_per_step: int = 4
-    # Feed the decode-attention dots bf16 operands (f32 accumulation)
-    # instead of upcasting K/V in VMEM.  Nothing in the repo sets it
-    # (tests only); not timed on a chip.
-    decode_mxu_native: bool = False
 
     @property
     def head_dim(self) -> int:
@@ -577,6 +572,13 @@ def decode_step(
     the table over the merged pool (``_scan_layers``); donate it and
     the write is in place.
     """
+    if cfg.decode_attention not in ("auto", "gather"):
+        raise ValueError(f"decode_attention: {cfg.decode_attention!r}")
+    # The kernel reads each live block once where it lies; the gather
+    # copies every table column, widened to float32 (LlamaConfig).
+    use_kernel = cfg.decode_attention == "auto" and (
+        paged_decode_pallas.serves(interpret)
+    )
     B = tokens.shape[0]
     pos = context_len - 1  # [B]
     x = jnp.take(params["embed"], tokens, axis=0)  # [B, D]
@@ -595,21 +597,9 @@ def decode_step(
             kv_new.astype(slots.dtype)
         )
         table = base + block_table
-        # "auto" is the XLA gather, as "gather" is; only an explicit
-        # "pallas" takes the kernel, and nothing in the repo sets it
-        # (see LlamaConfig).  ROADMAP S2/D6 decides between the two
-        # paths on the benchmark's decode_step_roofline (cell
-        # internlm2-chat-sysprompt), not on a sweep made in a run.
-        use_pallas = cfg.decode_attention == "pallas"
-        if use_pallas:
+        if use_kernel:
             attn = paged_decode_attention_pallas(
-                q[:, 0],
-                slots,
-                table,
-                context_len,
-                blocks_per_step=cfg.decode_blocks_per_step,
-                mxu_native=cfg.decode_mxu_native,
-                interpret=interpret,
+                q[:, 0], slots, table, context_len, interpret=interpret
             )
         else:
             attn = paged_attention(q[:, 0], slots, table, context_len)

@@ -264,8 +264,8 @@ PAGED_CASES = [
         ("prefill_paged", "auto"),
         ("prefill_continue", "auto"),
         ("prefill_chunked", "auto"),
-        ("decode_step", "auto"),
-        ("decode_step", "pallas"),
+        ("decode_step", "auto"),  # on the CPU: the XLA gather
+        ("decode_step", "interpreted"),  # the paged kernel, by the rule
     )
     for donate in (False, True)
 ]
@@ -279,7 +279,6 @@ PAGED_CASES = [
 def test_paged_programs_update_the_pool_in_place(
     params, dense_pass, program, attention, donate
 ):
-    cfg = dataclasses.replace(CFG, decode_attention=attention)
     tokens, dense, ks, vs = dense_pass
     B, T, P, bs = 2, SEQ_T, SEQ_T // 2, CFG.block_size
     pool_blocks = 24
@@ -298,19 +297,19 @@ def test_paged_programs_update_the_pool_in_place(
 
     if program == "prefill_paged":
         args = (tokens[:, :T], table[:, : T // bs])
-        call = lambda p, t, kv, bt: llama.prefill_paged(p, t, kv, bt, cfg)
+        call = lambda p, t, kv, bt: llama.prefill_paged(p, t, kv, bt, CFG)
         first, last, want = zero, whole, dense[:, :T]
     elif program == "prefill_chunked":
         args = (tokens[:, :T], table[:, : T // bs])
         call = lambda p, t, kv, bt: llama.prefill_chunked(
-            p, t, kv, bt, cfg, chunk_tokens=P
+            p, t, kv, bt, CFG, chunk_tokens=P
         )
         first, last, want = zero, whole, dense[:, T - 1]
     elif program == "prefill_continue":
         _write_positions(before, ks, vs, table, zero, np.full(B, P))
         args = (tokens[:, P:T], table[:, : T // bs])
         call = lambda p, t, kv, bt: llama.prefill_continue(
-            p, t, kv, bt, P, cfg
+            p, t, kv, bt, P, CFG
         )
         first, last, want = np.full(B, P), whole, dense[:, P:T]
     else:  # decode_step, ragged: sequence 1 is three tokens behind
@@ -322,7 +321,7 @@ def test_paged_programs_update_the_pool_in_place(
             jnp.asarray(pos + 1, jnp.int32),
         )
         call = lambda p, t, kv, bt, n: llama.decode_step(
-            p, t, kv, bt, n, cfg, interpret=attention == "pallas"
+            p, t, kv, bt, n, CFG, interpret=attention == "interpreted"
         )
         first, last, want = pos, pos + 1, dense[np.arange(B), pos]
 
@@ -344,11 +343,47 @@ def test_paged_programs_update_the_pool_in_place(
         np.testing.assert_array_equal(np.asarray(kv_in), before)
     if program == "prefill_chunked":  # ... and against prefill_paged
         _, paged = llama.prefill_paged(
-            params, *args[:1], jnp.asarray(before), *args[1:], cfg
+            params, *args[:1], jnp.asarray(before), *args[1:], CFG
         )
         np.testing.assert_allclose(
             kv_out, np.asarray(paged), rtol=1e-5, atol=1e-5
         )
+
+
+@pytest.mark.parametrize(
+    "decode_attention, interpret, backend, kernel",
+    (
+        ("auto", False, "cpu", False),  # here: the XLA gather
+        ("auto", False, "tpu", True),  # compiled for the chip: the kernel
+        ("auto", True, "cpu", True),  # asked to be interpreted: the kernel
+        ("gather", False, "tpu", False),  # the one caller that asks
+        ("gather", True, "cpu", False),  # (__graft_entry__'s tp decode)
+    ),
+)
+def test_decode_attention_is_one_rule(
+    params, monkeypatch, decode_attention, interpret, backend, kernel
+):
+    """`decode_step` takes the paged kernel where it is compiled for the
+    TPU or interpreted, and the gather elsewhere or when asked."""
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    cfg = dataclasses.replace(CFG, decode_attention=decode_attention)
+    pool = jnp.zeros(
+        (CFG.n_layers, 8, 2, CFG.block_size, CFG.n_kv_heads, CFG.head_dim)
+    )
+    traced = jax.make_jaxpr(
+        lambda p, kv: llama.decode_step(
+            p, jnp.zeros((2,), jnp.int32), kv,
+            jnp.arange(8, dtype=jnp.int32).reshape(2, 4),
+            jnp.asarray([5, 9], jnp.int32), cfg, interpret=interpret,
+        )
+    )(params, pool)
+    assert ("pallas_call" in str(traced)) == kernel
+
+
+def test_decode_attention_refuses_an_unknown_path(params):
+    cfg = dataclasses.replace(CFG, decode_attention="pallas")
+    with pytest.raises(ValueError, match="decode_attention"):
+        llama.decode_step(params, None, None, None, None, cfg)
 
 
 def test_ring_attention_matches_dense():
