@@ -48,6 +48,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from llm_d_kv_cache_manager_tpu.models import moe_serve
 from llm_d_kv_cache_manager_tpu.models.kv_cache_pool import (
     KVGroupSpec,
     scatter_kv_blocks,
@@ -63,6 +64,8 @@ Params = Dict[str, Any]
 SLIDING, FULL = "sliding_attention", "full_attention"
 NEG_INF = -1e30
 HI = lax.Precision.HIGHEST
+# An expert layer's prefill runs over at most this many tokens at a time.
+MOE_CHUNK_TOKENS = moe_serve.MOE_CHUNK_TOKENS
 # Prefill attention below this key length is one dense masked product (XLA);
 # at and above it the Pallas flash kernel, whose VMEM bound
 # (flash_pallas.fits_vmem) is then the longest context a prefill takes.
@@ -71,8 +74,6 @@ FLASH_MIN_LEN = 1024
 # Read on the chip at the cell's shapes (64 sequences of 13 k): 8 / 16 / 32
 # blocks gave 6.6 / 5.3 / 4.6 ms for the full layer (my chip run, PR 29).
 DECODE_BLOCKS_PER_STEP = 32
-# An expert layer's prefill runs over at most this many tokens at a time.
-MOE_CHUNK_TOKENS = 4096
 
 
 @dataclass(frozen=True)
@@ -374,86 +375,34 @@ def _swiglu(x, w):
 
 def route(h, lp, cfg):
     """h: [N, D] float32 -> (experts picked [N, k], their weights [N, k]
-    float32).  Scores in float32; the bias enters the selection only."""
-    s = jax.nn.sigmoid(
-        jnp.dot(
-            h.astype(jnp.float32),
-            lp["router"].astype(jnp.float32),
-            precision=HI,
-        )
-    )
-    _, picked = lax.top_k(s + lp["route_bias"], cfg.top_k)
-    w = jnp.take_along_axis(s, picked, axis=1)
-    if cfg.route_norm:
-        w = w / jnp.sum(w, axis=1, keepdims=True)
-    return picked, w * cfg.route_scale
+    float32): `moe_serve.route` with this family's keys."""
+    return moe_serve.route(h, lp["router"], lp["route_bias"], cfg.top_k,
+                           cfg.route_norm, cfg.route_scale)
 
 
 def routed_experts(h, picked, w, experts, cfg):
-    """Sum over each token's picked experts of w_e Expert_e(h), without a
-    capacity; h [N, D] in the serving type; returns ([N, D] float32, picks
-    per expert).  Two ways, chosen by the number of tokens:
-
-    - many tokens (a prefill): the N*k picks are sorted by expert and each
-      expert multiplies its own rows (`lax.ragged_dot`);
-    - at most as many tokens as experts (a decode step): every expert
-      multiplies every token and the routing weights, zero for an expert not
-      picked, mask the sum.  A step of 64 sequences picks 512 times among 128
-      experts, so nearly every expert's weights are read either way, and the
-      grouped product then took 0.04 ms an expert touched, 5.1 ms for 127,
-      against 2.3 ms for all 128 in one batched product, 84 % of the chip's
-      bandwidth (my chip run, PR 29); its time no longer depends on which
-      experts a seed's router favours.
-    """
-    N, k = picked.shape
-    f32 = jnp.float32
-    flat = picked.reshape(-1)
-    sizes = jnp.bincount(flat, length=cfg.n_experts).astype(jnp.int32)
-    if N <= cfg.n_experts:
-        weight = jnp.zeros((N, cfg.n_experts), f32).at[
-            jnp.arange(N)[:, None], picked].add(w)
-        gate = jnp.einsum("nd,edf->enf", h, experts["w_gate"],
-                          preferred_element_type=f32)
-        up = jnp.einsum("nd,edf->enf", h, experts["w_up"],
-                        preferred_element_type=f32)
-        hidden = jax.nn.silu(gate) * up * weight.T[:, :, None]
-        out = jnp.einsum("enf,efd->nd", hidden.astype(h.dtype),
-                         experts["w_down"], preferred_element_type=f32)
-        return out, sizes
-    order = jnp.argsort(flat)
-    rows = jnp.take(h, order // k, axis=0)  # [N*k, D], grouped by expert
-    gate = lax.ragged_dot(rows, experts["w_gate"], sizes,
-                          preferred_element_type=f32)
-    up = lax.ragged_dot(rows, experts["w_up"], sizes,
-                        preferred_element_type=f32)
-    hidden = (jax.nn.silu(gate) * up).astype(h.dtype)
-    out = lax.ragged_dot(hidden, experts["w_down"], sizes,
-                         preferred_element_type=f32)
-    out = out * jnp.take(w.reshape(-1), order)[:, None]
-    back = jnp.zeros_like(order).at[order].set(jnp.arange(N * k))
-    return jnp.take(out, back, axis=0).reshape(N, k, -1).sum(axis=1), sizes
+    """`moe_serve.routed_experts`, batched under the routing's mask for at
+    most as many tokens as experts (a decode step), sorted by expert above (a
+    prefill).  A step of 64 sequences picks 512 times among 128 experts, so
+    nearly every expert's weights are read either way, and the grouped
+    product then took 0.04 ms an expert touched, 5.1 ms for 127, against
+    2.3 ms for all 128 in one batched product, 84 % of the chip's bandwidth
+    (my chip run, PR 29)."""
+    return moe_serve.routed_experts(h, picked, w, experts, cfg.n_experts,
+                                    batched=picked.shape[0] <= cfg.n_experts)
 
 
 def _moe(h, lp, cfg):
     """h: [B, T, D] float32 -> (Shared(h) + routed experts, float32; picks
     per expert [E])."""
-    B, T, D = h.shape
     act = lp["router"].dtype  # the serving type
-    flat = h.reshape(B * T, D)
-    n = -(-flat.shape[0] // MOE_CHUNK_TOKENS)
-    if flat.shape[0] % n:
-        n = 1
 
     def chunk(rows):
         picked, w = route(rows, lp, cfg)
         return routed_experts(rows.astype(act), picked, w, lp["experts"], cfg)
 
-    if n == 1:
-        out, sizes = chunk(flat)
-    else:
-        out, sizes = lax.map(chunk, flat.reshape(n, -1, D))
-        out, sizes = out.reshape(B * T, D), sizes.sum(axis=0)
-    return _swiglu(h.astype(act), lp["shared"]) + out.reshape(B, T, D), sizes
+    out, sizes = moe_serve.in_chunks(h, chunk, MOE_CHUNK_TOKENS)
+    return _swiglu(h.astype(act), lp["shared"]) + out.reshape(h.shape), sizes
 
 
 def _mlp_block(x, lp, cfg):

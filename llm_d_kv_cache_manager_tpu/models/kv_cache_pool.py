@@ -14,8 +14,9 @@ same sharding and XLA inserts the collectives.
 
 from __future__ import annotations
 
+import math
 from dataclasses import dataclass
-from typing import Optional, Sequence
+from typing import Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -29,9 +30,13 @@ class KVGroupSpec:
     group (every layer, whole context); a model that mixes window and
     full attention layers has one group per kind, and ``window`` is the
     number of positions a layer of the group still reads (None: all).
-    Block bytes, pool shapes and the scatter's geometry are read from
-    here by the pool below, by the pod's cache (models/pod.py) and by
-    each family's model step."""
+    A slot need not be K/V: with ``state_shape`` it is, a layer, one array
+    of that shape (a recurrent layer's state after the last position of a
+    logical block), its bytes do not grow with the block, and
+    ``stride_blocks`` says which block boundaries keep one
+    (``snapshot_blocks``).  Block bytes, pool shapes and the scatter's
+    geometry are read from here by the pool below, by the pod's cache
+    (models/pod.py) and by each family's model step."""
 
     num_layers: int
     block_size: int
@@ -45,11 +50,25 @@ class KVGroupSpec:
     # re-laid-out around a prefill's scatter.  The uniform pool keeps the
     # first form (the offload path's file layout is that one).
     heads_first: bool = False
+    # A slot as [block, Hkv, 2 * Dh]: a position's K in the lower half of the
+    # last axis and its V in the upper.  For a head size of 64, whose own last
+    # axis is half of what the chip lays arrays out by: the compiler would
+    # make the pool's slot axis the minor one, and every step that writes a
+    # slot would re-lay-out the whole pool around it.
+    packed: bool = False
+    state_shape: Optional[Tuple[int, ...]] = None
+    stride_blocks: Optional[int] = None
 
     @property
     def block_nbytes(self) -> int:
         """Bytes of one slot: K and V of ``block_size`` positions over
-        the group's layers."""
+        the group's layers, or the layers' states."""
+        if self.state_shape is not None:
+            return (
+                self.num_layers
+                * math.prod(self.state_shape)
+                * jnp.dtype(self.dtype).itemsize
+            )
         return (
             self.num_layers
             * 2
@@ -61,12 +80,30 @@ class KVGroupSpec:
 
     def layer_shape(self, num_blocks: int) -> tuple:
         """One layer's share of a pool of ``num_blocks`` slots."""
+        if self.state_shape is not None:
+            return (num_blocks,) + tuple(self.state_shape)
+        if self.packed:
+            return (num_blocks, self.block_size, self.num_kv_heads,
+                    2 * self.head_dim)
         inner = (
             (self.num_kv_heads, self.block_size)
             if self.heads_first
             else (self.block_size, self.num_kv_heads)
         )
         return (num_blocks, 2) + inner + (self.head_dim,)
+
+    def snapshot_blocks(self, first: int, count: int) -> list:
+        """Of the blocks ``first .. first + count - 1`` of a chain that one
+        prefill call writes, those whose end keeps a state slot: every
+        block whose index + 1 is a multiple of ``stride_blocks``, and the
+        call's last.  Static in a program's shapes, so the host that names
+        the slots and the step that fills them read one list."""
+        last = first + count - 1
+        return [
+            i
+            for i in range(first, last + 1)
+            if (i + 1) % self.stride_blocks == 0 or i == last
+        ]
 
     @property
     def window_blocks(self) -> int:

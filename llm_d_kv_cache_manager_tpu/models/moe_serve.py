@@ -1,0 +1,101 @@
+"""The serve path's expert layer, shared by the families that route by
+sigmoid scores with a selection bias (`models/afmoe.py`, `models/lfm2moe.py`):
+the router, and the sum over each token's picked experts without a capacity.
+(`models/moe.py` is the training path's: softmax scores, a static capacity,
+tokens over it dropped.)
+
+What differs between the families is an argument, and where an argument would
+add an operation to a family's trace the branch is taken in Python, so that
+each family's programs trace to what they were.
+"""
+
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+HI = lax.Precision.HIGHEST
+# An expert layer's prefill runs over at most this many tokens at a time.
+MOE_CHUNK_TOKENS = 4096
+
+
+def route(h, router, bias, top_k: int, norm: bool, scale: float,
+          norm_eps: float | None = None):
+    """h: [N, D] float32 -> (experts picked [N, k], their weights [N, k]
+    float32).  Scores ``sigmoid(h . router)`` in float32 at precision
+    highest; the bias enters the selection only; with ``norm`` the picked
+    scores are divided by their sum (plus ``norm_eps`` where a family's
+    published code adds one), then multiplied by ``scale``."""
+    s = jax.nn.sigmoid(
+        jnp.dot(
+            h.astype(jnp.float32),
+            router.astype(jnp.float32),
+            precision=HI,
+        )
+    )
+    _, picked = lax.top_k(s + bias, top_k)
+    w = jnp.take_along_axis(s, picked, axis=1)
+    if norm:
+        total = jnp.sum(w, axis=1, keepdims=True)
+        w = w / (total if norm_eps is None else total + norm_eps)
+    return picked, w * scale
+
+
+def routed_experts(h, picked, w, experts, n_experts: int, batched: bool):
+    """Sum over each token's picked experts of w_e Expert_e(h), without a
+    capacity; h [N, D] in the serving type; returns ([N, D] float32, picks
+    per expert).  Two ways, the caller's choice (each family's rule is read
+    on the chip at its own sizes):
+
+    - ``batched`` (a decode step's few tokens): every expert multiplies
+      every token and the routing weights, zero for an expert not picked,
+      mask the sum.  One batched product whose time does not depend on which
+      experts a seed's router favours; it reads every expert's weights;
+    - else (a prefill): the N*k picks are sorted by expert and each expert
+      multiplies its own rows (`lax.ragged_dot`).
+    """
+    N, k = picked.shape
+    f32 = jnp.float32
+    flat = picked.reshape(-1)
+    sizes = jnp.bincount(flat, length=n_experts).astype(jnp.int32)
+    if batched:
+        weight = jnp.zeros((N, n_experts), f32).at[
+            jnp.arange(N)[:, None], picked].add(w)
+        gate = jnp.einsum("nd,edf->enf", h, experts["w_gate"],
+                          preferred_element_type=f32)
+        up = jnp.einsum("nd,edf->enf", h, experts["w_up"],
+                        preferred_element_type=f32)
+        hidden = jax.nn.silu(gate) * up * weight.T[:, :, None]
+        out = jnp.einsum("enf,efd->nd", hidden.astype(h.dtype),
+                         experts["w_down"], preferred_element_type=f32)
+        return out, sizes
+    order = jnp.argsort(flat)
+    rows = jnp.take(h, order // k, axis=0)  # [N*k, D], grouped by expert
+    gate = lax.ragged_dot(rows, experts["w_gate"], sizes,
+                          preferred_element_type=f32)
+    up = lax.ragged_dot(rows, experts["w_up"], sizes,
+                        preferred_element_type=f32)
+    hidden = (jax.nn.silu(gate) * up).astype(h.dtype)
+    out = lax.ragged_dot(hidden, experts["w_down"], sizes,
+                         preferred_element_type=f32)
+    out = out * jnp.take(w.reshape(-1), order)[:, None]
+    back = jnp.zeros_like(order).at[order].set(jnp.arange(N * k))
+    return jnp.take(out, back, axis=0).reshape(N, k, -1).sum(axis=1), sizes
+
+
+def in_chunks(h, chunk, limit: int = MOE_CHUNK_TOKENS):
+    """``chunk`` ([n, D] float32 -> ([n, D] float32, picks per expert [E]))
+    over h [B, T, D], at most ``limit`` rows at a time where they divide
+    evenly: -> ([B * T, D], picks per expert summed)."""
+    B, T, D = h.shape
+    flat = h.reshape(B * T, D)
+    n = -(-flat.shape[0] // limit)
+    if flat.shape[0] % n:
+        n = 1
+    if n == 1:
+        out, sizes = chunk(flat)
+    else:
+        out, sizes = lax.map(chunk, flat.reshape(n, -1, D))
+        out, sizes = out.reshape(B * T, D), sizes.sum(axis=0)
+    return out, sizes

@@ -38,11 +38,42 @@ each the sliding layers' K/V of one logical block, kept by these rules:
   hashes ride in the list ``alloc`` returns, so ``BlockStored`` still means
   "servable from here" and ``BlockRemoved`` "no longer".
 
+A state group (a model whose recurrent layers keep a state that is not
+addressable by position: ``cache_policy`` names ``"state"`` in place of
+``"window"``).  A slot of it holds those layers' state after the last position
+of one logical block, and a prefix of n blocks can be continued only where the
+state after block n - 1 was kept:
+
+- **which boundaries keep one**: every block whose index + 1 in its chain is
+  a multiple of the group's ``stride_blocks`` and the last block of every
+  prefill call (``KVGroupSpec.snapshot_blocks``: a miss prefill writes them, a
+  hit prefill reads the one at its prefix's end and writes its own), and, for
+  each live sequence, its current block and the one before: a decode step for
+  the token at position p reads the slot of block ``(p - 1) // block`` and
+  writes the slot of block ``p // block``, so a finished block's slot is its
+  snapshot.  A sequence's own (unhashed) blocks behind those two give their
+  slots back at once;
+- **hit rule**: ``cached_prefix`` returns n blocks only if the full group
+  holds all n and the state group the snapshot of block n - 1; else the
+  longest such n, and ``resume_short_blocks`` counts what it gave up;
+- released slots are reused coldest first, never-asked before asked, as the
+  window group's, and **the index stays truthful at the boundaries that
+  matter, without a new event**: where the group reuses the slot of a
+  boundary whose block is still cached, the pod evicts the chain's tail from
+  there and the hashes ride in ``alloc``'s list.  Between two kept boundaries
+  the index over-counts a partial match by fewer than ``stride_blocks`` blocks
+  (that much recompute, never a wrong pod): the event that would tell it
+  ("state kept at block k") is ROADMAP R-M2's and not here;
+- a decode step whose sequence starts at a position no step wrote the state
+  of (the benchmark's set-up requests start ``done`` tokens into an answer) is
+  still handed a slot: what it holds is whatever the slot last held.
+
 Positions are never told to a pod; they are known at each program call (a
 table is in chain order, decode brings ``context_len``), so ``jit_programs``
-returns plain functions that build the window layers' table on the host,
-record spans (``kvpool.window``, ``kv.read``, ``moe.expert_load``) and call the
-inner compiled programs, which keep the names the trace reduction looks for.
+returns plain functions that build the second group's tables on the host,
+record spans (``kvpool.window`` and ``kv.read``, or ``kvpool.state`` and
+``state.read``; ``moe.expert_load``) and call the inner compiled programs,
+which keep the names the trace reduction looks for.
 """
 
 from __future__ import annotations
@@ -57,6 +88,11 @@ import numpy as np
 from llm_d_kv_cache_manager_tpu.obs.trace import root_trace, span
 
 RESERVED = np.iinfo(np.int64).max  # a slot taken and not yet in any window
+
+
+def cache_policy(program, model) -> dict:
+    """The family's `cache_policy(model)`; none means one group."""
+    return getattr(program, "cache_policy", lambda model: {})(model)
 
 
 class PodKV:
@@ -159,16 +195,15 @@ class Cached:
         return out
 
 
-class WindowGroup:
-    """The second group of slots: which logical block holds which slot, when
-    each was last in a live window, and the chains the blocks stand in."""
+class SlotGroup:
+    """A second group of slots beside the full group: which logical block
+    holds which slot, when each was last used, and the chains the blocks
+    stand in.  `WindowGroup` and `StateGroup` add their rules."""
 
-    def __init__(self, pod: "Pod", slots: int, store_blocks: int, window: int,
-                 block_size: int) -> None:
-        self.pod, self.window, self.block = weakref.proxy(pod), window, block_size
-        self.need = -(-(window - 1) // block_size)  # blocks behind a boundary
-        self.width = self.need + 1  # blocks a decode step's window can span
-        self.store = store_blocks
+    span, read_span = "", ""  # the spans `Pod.tables` records
+
+    def __init__(self, pod: "Pod", slots: int, counts: tuple) -> None:
+        self.pod = weakref.proxy(pod)
         self.slot_of = np.full(pod.pool_blocks, -1, np.int32)
         self.block_of = np.full(slots, -1, np.int32)
         self.stamp = np.zeros(slots, np.int64)  # tick it was last live
@@ -177,7 +212,7 @@ class WindowGroup:
         self.parent: dict[int, int] = {}
         self.children: dict[int, set] = {}
         # over the pod's life; a span reports what was added since the last
-        self.counts = dict(taken=0, released=0, reclaimed=0, half_hits=0)
+        self.counts = dict.fromkeys(counts, 0)
         self.reported = dict(self.counts)
 
     # -- bookkeeping by block ------------------------------------------
@@ -214,36 +249,9 @@ class WindowGroup:
             stack.extend(self.children.get(out[-1], ()))
         return out
 
-    # -- the rules ------------------------------------------------------
-
-    def servable(self, ids: list, asked: int) -> int:
-        """The longest prefix of `ids` (cached in the full group, chain
-        order) whose last `need` blocks all hold a window slot."""
-        if not ids:
-            return 0
-        have = self.slot_of[np.asarray(ids)] >= 0
-        n = np.arange(1, len(ids) + 1)
-        run = n - np.maximum.accumulate(np.where(have, 0, n))
-        good = n[run >= np.minimum(n, self.need)]
-        m = int(good[-1]) if len(good) else 0
-        if len(ids) == asked and m < asked:
-            self.counts["half_hits"] += 1
-        return m
-
     def warm(self, ids) -> None:
         slots = self.slot_of[np.asarray(ids, np.int64)]
         self.stamp[slots[slots >= 0]] = self.tick
-
-    def take(self, ids: list) -> list:
-        """Slots for the blocks one `alloc` hands out (the last `store` of
-        them); returns the hashes a reuse evicted from the full group."""
-        for bid in ids:
-            self.forget(bid)
-        want = ids[-self.store:]
-        evicted = self.reclaim(len(want)) if len(self.free) < len(want) else []
-        for bid in want:
-            self._assign(bid)
-        return evicted
 
     def _assign(self, bid: int) -> None:
         slot = self.free.pop()
@@ -251,13 +259,11 @@ class WindowGroup:
         self.stamp[slot] = RESERVED
         self.counts["taken"] += 1
 
-    def reclaim(self, target: int) -> list:
-        """Free slots until `target` are free: released ones, never-asked
-        before asked, coldest first; a cached block goes with its tail."""
+    def _reclaim(self, released: np.ndarray, target: int, what: str) -> list:
+        """Free slots until `target` are free, of those `released` marks:
+        never-asked before asked, coldest first; a cached block goes with
+        its tail, out of the full group too.  Returns the hashes evicted."""
         pod = self.pod
-        block = np.maximum(self.block_of, 0)
-        released = (pod.refs[block] == 0) | (
-            ~pod.hashed[block] & (self.stamp < self.live_tick))
         slots = np.flatnonzero((self.block_of >= 0) & released)
         order = slots[np.lexsort((self.stamp[slots],
                                   pod.asked[self.block_of[slots]]))]
@@ -276,8 +282,59 @@ class WindowGroup:
             self.counts["reclaimed"] += len(self.free) - before
         if len(self.free) < target:
             raise RuntimeError(
-                f"{pod.name}: window group exhausted by live sequences")
+                f"{pod.name}: {what} group exhausted by live sequences")
         return evicted
+
+
+class WindowGroup(SlotGroup):
+    """The sliding layers' K/V of one logical block a slot."""
+
+    span, read_span = "kvpool.window", "kv.read"
+
+    def __init__(self, pod: "Pod", slots: int, store_blocks: int, window: int,
+                 block_size: int) -> None:
+        super().__init__(pod, slots,
+                         ("taken", "released", "reclaimed", "half_hits"))
+        self.window, self.block = window, block_size
+        self.need = -(-(window - 1) // block_size)  # blocks behind a boundary
+        self.width = self.need + 1  # blocks a decode step's window can span
+        self.store = store_blocks
+
+    # -- the rules ------------------------------------------------------
+
+    def servable(self, ids: list, asked: int) -> int:
+        """The longest prefix of `ids` (cached in the full group, chain
+        order) whose last `need` blocks all hold a window slot."""
+        if not ids:
+            return 0
+        have = self.slot_of[np.asarray(ids)] >= 0
+        n = np.arange(1, len(ids) + 1)
+        run = n - np.maximum.accumulate(np.where(have, 0, n))
+        good = n[run >= np.minimum(n, self.need)]
+        m = int(good[-1]) if len(good) else 0
+        if len(ids) == asked and m < asked:
+            self.counts["half_hits"] += 1
+        return m
+
+    def take(self, ids: list) -> list:
+        """Slots for the blocks one `alloc` hands out (the last `store` of
+        them); returns the hashes a reuse evicted from the full group."""
+        for bid in ids:
+            self.forget(bid)
+        want = ids[-self.store:]
+        evicted = self.reclaim(len(want)) if len(self.free) < len(want) else []
+        for bid in want:
+            self._assign(bid)
+        return evicted
+
+    def reclaim(self, target: int) -> list:
+        """Free slots until `target` are free: released ones, never-asked
+        before asked, coldest first; a cached block goes with its tail."""
+        pod = self.pod
+        block = np.maximum(self.block_of, 0)
+        released = (pod.refs[block] == 0) | (
+            ~pod.hashed[block] & (self.stamp < self.live_tick))
+        return self._reclaim(released, target, "window")
 
     # -- one table a program call ----------------------------------------
 
@@ -340,6 +397,107 @@ class WindowGroup:
                  "uniform_blocks": whole})
 
 
+class StateGroup(SlotGroup):
+    """The recurrent layers' state after the last position of one logical
+    block a slot (the module's head has the rules)."""
+
+    span, read_span = "kvpool.state", "state.read"
+
+    def __init__(self, pod: "Pod", slots: int, spec, kv_block_nbytes: int
+                 ) -> None:
+        super().__init__(pod, slots, ("taken", "released", "reclaimed",
+                                      "resume_short_blocks", "asked_blocks"))
+        self.spec, self.block = spec, spec.block_size
+        self.kv_block_nbytes = kv_block_nbytes
+
+    def servable(self, ids: list, asked: int) -> int:
+        """The longest prefix of `ids` (cached in the full group, chain
+        order) whose last block holds a snapshot."""
+        self.counts["asked_blocks"] += asked
+        if not ids:
+            return 0
+        have = np.flatnonzero(self.slot_of[np.asarray(ids)] >= 0)
+        m = int(have[-1]) + 1 if len(have) else 0
+        self.counts["resume_short_blocks"] += len(ids) - m
+        return m
+
+    def take(self, ids: list) -> list:
+        """Blocks that `alloc` hands out start with no slot: which of them
+        keep one is known when a program call brings their place in the
+        chain."""
+        for bid in ids:
+            self.forget(bid)
+        return []
+
+    def reclaim(self, target: int, keep: np.ndarray) -> list:
+        """Free slots until `target` are free, of blocks no live sequence
+        references and the call at hand does not name (`keep`)."""
+        pod = self.pod
+        block = np.maximum(self.block_of, 0)
+        named = np.zeros(pod.pool_blocks, bool)
+        named[keep.ravel()] = True
+        return self._reclaim((pod.refs[block] == 0) & ~named[block], target,
+                             "state")
+
+    def _slots(self, ids: np.ndarray) -> np.ndarray:
+        """The slots of `ids`, stamped; a block without one takes one (a
+        block just handed out has none).  What a reuse evicts from the full
+        group waits for the next `alloc`."""
+        missing = np.unique(ids[self.slot_of[ids] < 0])
+        if len(missing) > len(self.free):
+            self.pod.unpublished += self.reclaim(len(missing), ids)
+        for bid in missing:
+            self._assign(int(bid))
+        slots = self.slot_of[ids]
+        self.tick += 1
+        self.stamp[slots] = self.tick
+        return slots.astype(np.int32)
+
+    def _prefill_tables(self, table: np.ndarray, first: int) -> dict:
+        kept = self.spec.snapshot_blocks(first, table.shape[1] - first)
+        for row in table:
+            self.link(row[max(first - 1, 0):])
+        return {"full": table, "state_write": self._slots(table[:, kept])}
+
+    def miss_tables(self, table: np.ndarray) -> dict:
+        return self._prefill_tables(table, 0)
+
+    def hit_tables(self, table: np.ndarray, prefix_blocks: int) -> dict:
+        last = table[:, prefix_blocks - 1]
+        if (self.slot_of[last] < 0).any():
+            raise RuntimeError(
+                f"{self.pod.name}: a hit prefill resumes from a block that "
+                "holds no snapshot (a prefix the state rule refuses)")
+        tables = self._prefill_tables(table, prefix_blocks)  # may reclaim
+        read = self.slot_of[last]  # ... but never a block this table names
+        self.stamp[read] = self.tick
+        return {**tables, "state_read": read.astype(np.int32)}
+
+    def decode_tables(self, table: np.ndarray, context_len: np.ndarray):
+        """The state slots of one decode step, [B, (read, written)], and
+        what the step reads."""
+        pod, rows = self.pod, np.arange(len(table))
+        pos = context_len - 1
+        cur, prev = pos // self.block, np.maximum(pos - 1, 0) // self.block
+        # A sequence that enters a new block: the block two back is no
+        # longer one of its two; its own blocks carry no hash to keep it for.
+        entered = np.flatnonzero((pos % self.block == 0) & (cur >= 2))
+        old = table[entered, cur[entered] - 2]
+        for bid in old[~pod.hashed[old] & (self.slot_of[old] >= 0)]:
+            self.drop(int(bid))
+            self.counts["released"] += 1
+        ids = np.stack((table[rows, prev], table[rows, cur]), axis=1)
+        slots = self._slots(ids)
+        self.live_tick = self.tick
+        live = context_len > 1
+        blocks = int((cur[live] + 1).sum())
+        return ({"full": table, "state": slots},
+                {"state_slots_live": len(self.block_of) - len(self.free),
+                 "blocks_live": pod.pool_blocks - len(pod.free),
+                 "state_bytes": int(live.sum()) * 2 * self.spec.block_nbytes,
+                 "kv_bytes": blocks * self.kv_block_nbytes})
+
+
 class Pod:
     """One serving pod on the chip: its paged K/V pools and prefix cache.  The
     allocator never hands out a block a live sequence references."""
@@ -352,13 +510,21 @@ class Pod:
         self.refs = np.zeros(pool_blocks, np.int32)  # block -> live sequences
         self.asked = np.zeros(pool_blocks, bool)  # block -> named by an ask
         self.hashed = np.zeros(pool_blocks, bool)  # block -> cached under a hash
-        policy = getattr(program, "cache_policy", lambda model: {})(model)
+        policy = cache_policy(program, model)
         self.protect_asked = bool(policy.get("protect_asked"))
         self.last_ask: frozenset = frozenset()  # the last missed ask's hashes
         self.cached = Cached(self)
         self.unpublished: list[int] = []  # evicted outside `alloc`
         self.window = (WindowGroup(self, **policy["window"])
                        if policy.get("window") else None)
+        self.state = (StateGroup(self, **policy["state"])
+                      if policy.get("state") else None)
+        if self.window is not None and self.state is not None:
+            raise NotImplementedError(
+                f"{name}: a window group and a state group in one pod (no "
+                "family has both; their hit rules would have to be met at "
+                "one length)")
+        self.second = self.window or self.state  # the group beside the full
         self.pending_load = None  # (device counts, tokens) of a decode step
 
     def cached_prefix(self, hashes) -> list[int]:
@@ -367,8 +533,8 @@ class Pod:
             if h not in self.cached:
                 break
             ids.append(self.cached[h])
-        if self.window is not None:
-            ids = ids[:self.window.servable(ids, len(hashes))]
+        if self.second is not None:
+            ids = ids[:self.second.servable(ids, len(hashes))]
         if len(ids) < len(hashes):
             self.last_ask = frozenset(hashes)
         return ids
@@ -379,6 +545,8 @@ class Pod:
         if self.window is not None and len(hashes):
             self.window.warm([self.cached[h]
                               for h in hashes[-self.window.need:]])
+        if self.state is not None and len(hashes):
+            self.state.warm([self.cached[hashes[-1]]])
 
     def alloc(self, n: int) -> tuple[list[int], list[int]]:
         """n blocks no live sequence references; returns (ids, hashes evicted)."""
@@ -391,8 +559,8 @@ class Pod:
             ids.append(bid)
         if len(ids) < n:
             raise RuntimeError(f"{self.name}: pool exhausted by live sequences")
-        if self.window is not None:
-            evicted += self.window.take(ids) + self.unpublished
+        if self.second is not None:
+            evicted += self.second.take(ids) + self.unpublished
             self.unpublished = []
         return ids, evicted
 
@@ -400,9 +568,9 @@ class Pod:
         """Out of the full group: a block and every cached block behind it in
         a chain.  Returns their hashes; their ids go back to the free list."""
         out = []
-        for x in self.window.tail(bid):
+        for x in self.second.tail(bid):
             h = self.cached.hash_of.get(x)
-            self.window.forget(x)
+            self.second.forget(x)
             if h is None:
                 continue
             if self.refs[x]:  # a sequence holds its whole chain or none of it
@@ -418,31 +586,32 @@ class Pod:
         np.add.at(self.refs, ids, by)
         if by > 0 or not len(ids):
             return
-        if self.window is not None:  # a sequence's own blocks: no hash to keep
+        if self.second is not None:  # a sequence's own blocks: no hash to keep
             idle = ids[self.refs[ids] == 0]
             for bid in idle[~self.hashed[idle]]:
-                self.window.drop(bid)
+                self.second.drop(bid)
 
     def tables(self, kind: str, table, context_len=None, prefix_blocks=0):
         """What a program call is handed as its table: the logical table
         alone with one group, both groups' tables with two."""
         table = np.asarray(table, np.int32)
-        if self.window is None:
+        group = self.second
+        if group is None:
             return table
-        with span("kvpool.window") as s:
+        with span(group.span) as s:
             if kind == "decode":
-                tables, read = self.window.decode_tables(
+                tables, read = group.decode_tables(
                     table, np.asarray(context_len, np.int64))
             else:
-                tables, read = (self.window.miss_tables(table) if kind == "miss"
-                                else self.window.hit_tables(table, prefix_blocks)
+                tables, read = (group.miss_tables(table) if kind == "miss"
+                                else group.hit_tables(table, prefix_blocks)
                                 ), None
             s.set_attr("calls", 1)
-            for key, value in self.window.counts.items():
-                s.set_attr(key, value - self.window.reported[key])
-            self.window.reported = dict(self.window.counts)
+            for key, value in group.counts.items():
+                s.set_attr(key, value - group.reported[key])
+            group.reported = dict(group.counts)
         if read is not None:
-            with span("kv.read") as s:
+            with span(group.read_span) as s:
                 for key, value in read.items():
                     s.set_attr(key, value)
         return tables
@@ -473,6 +642,8 @@ def inner_programs(program, model, shapes: dict, interpret: bool) -> dict:
     host) and, for a prefill, the last position's row of logits; the pools
     are donated whole and updated in place."""
 
+    stateful = bool(cache_policy(program, model).get("state"))
+
     def served(logits):
         return jnp.stack((jnp.argmax(logits, -1).astype(jnp.float32),
                           jnp.max(logits, -1)))
@@ -489,14 +660,20 @@ def inner_programs(program, model, shapes: dict, interpret: bool) -> dict:
             p, t, kv, bt, shapes["hit"][0], model, interpret=interpret))
 
     def decode(p, ints, kv, table):
-        """`ints` [B, 2 or 3 + window width] int32: each sequence's token, its
-        context length and, with a window group, the position its window
-        table starts at and that table's slots.  One array, because every
+        """`ints` [B, 2, 4 or 3 + window width] int32: each sequence's token,
+        its context length and, with a window group, the position its window
+        table starts at and that table's slots, or, with a state group, the
+        state slot it reads and the one it writes.  One array, because every
         argument that comes from the host costs a transfer of its own (0.12 ms
         each on the chip's host; my chip run, PR 29); `table` stays on the
         device between the steps that do not change it."""
-        tables = table if ints.shape[1] == 2 else {
-            "full": table, "first": ints[:, 2], "window": ints[:, 3:]}
+        if ints.shape[1] == 2:
+            tables = table
+        elif stateful:
+            tables = {"full": table, "state": ints[:, 2:]}
+        else:
+            tables = {"full": table, "first": ints[:, 2],
+                      "window": ints[:, 3:]}
         logits, kv = program.decode_step(p, ints[:, 0], kv, tables, ints[:, 1],
                                          model, interpret=interpret)
         return served(logits), kv
@@ -518,7 +695,8 @@ def example_args(key: str, shapes: dict, pod: "Pod", block: int) -> tuple:
     i32 = np.int32
     if key == "decode":
         B = shapes["decode"][0]
-        width = 2 if pod.window is None else 3 + pod.window.width
+        width = (4 if pod.state is not None else
+                 2 if pod.window is None else 3 + pod.window.width)
         return (np.ones((B, width), i32),
                 np.zeros((B, shapes["max_blocks"]), i32))
     tokens = sum(shapes[key])
@@ -570,8 +748,9 @@ def jit_programs(program, model, shapes: dict, interpret: bool) -> dict:
             tables = pod.tables("decode", bt, context_len=n)
             if traced is not None:
                 pod.report_load(model)
-            ints = [t, n] if pod.window is None else [
-                t, n, tables["first"], *tables["window"].T]
+            ints = ([t, n, *tables["state"].T] if pod.state is not None else
+                    [t, n] if pod.window is None else
+                    [t, n, tables["first"], *tables["window"].T])
             out, load = run("decode", p, kv,
                             np.stack(ints, axis=1, dtype=np.int32),
                             kv.on_device(bt))
@@ -586,6 +765,13 @@ def jit_programs(program, model, shapes: dict, interpret: bool) -> dict:
 def _dry_tables(pod: Pod, kind: str, table, prefix_blocks=0):
     """A prefill's tables in the shapes `Pod.tables` would give, for
     compiling ahead."""
+    if pod.state is not None:
+        kept = pod.state.spec.snapshot_blocks(
+            prefix_blocks, table.shape[1] - prefix_blocks)
+        rows = np.zeros(table.shape[0], np.int32)
+        return {"full": table,
+                "state_write": np.zeros((len(rows), len(kept)), np.int32),
+                **({"state_read": rows} if kind == "hit" else {})}
     group = pod.window
     if group is None:
         return table

@@ -20,7 +20,13 @@ is one: slots [2, Hkv, bs, D] (models/afmoe.py) side by side are one
 [Hkv, P*bs, D] operand; slots [2, bs, Hkv, D] (models/llama.py) are taken
 as they lie, [bs*Hkv, D] rows against every query head with the other KV
 heads' columns masked (K and V pass the MXU once either way, and nothing
-is re-laid-out in VMEM).
+is re-laid-out in VMEM).  ``packed`` slots [bs*Hkv, 2*D] (models/lfm2moe.py,
+head size 64) are the second form with a position's K in the lower half of a
+row's lanes and its V in the upper: the pool's minor axis is then 128 wide, as
+the chip lays arrays out (with 64 the compiler makes the slot axis the minor
+one and re-lays-out the whole pool around every step), one operand is both K
+and V, the query comes padded with zeros over V's lanes and the output is read
+from them.
 
 Contract matches ops/paged_attention.py::paged_attention; equivalence
 is pinned by tests/test_paged_decode_pallas.py (interpret mode on CPU);
@@ -68,6 +74,7 @@ def _decode_kernel(
     mxu_native: bool,
     windowed: bool = False,
     heads_first: bool = False,
+    packed: bool = False,
 ):
     # Scalar prefetch after the context: [start (SMEM [B]) if windowed,]
     # [the last block (SMEM [B]) unless heads_first: the index maps' own.]
@@ -176,6 +183,20 @@ def _decode_kernel(
         # PERF.md section 6, PR 32.)
         rows = block_size * Hkv
         width = blocks_per_step * rows
+        if packed:  # K and V side by side in the lanes: one operand is both
+
+            def key(r):
+                return r[0]
+
+            value = key
+        else:
+
+            def key(r):
+                return r[0, 0]
+
+            def value(r):
+                return r[0, 1]
+
         first = j * blocks_per_step * block_size
 
         @pl.when(first < ctx)
@@ -185,7 +206,7 @@ def _decode_kernel(
                 [
                     jax.lax.dot_general(
                         qc,
-                        r[0, 0].astype(compute_dtype),
+                        key(r).astype(compute_dtype),
                         (((1,), (1,)), ((), ())),
                         preferred_element_type=jnp.float32,
                     )
@@ -203,7 +224,7 @@ def _decode_kernel(
             o = sum(
                 jax.lax.dot_general(
                     p[:, i * rows : (i + 1) * rows],
-                    r[0, 1].astype(compute_dtype),
+                    value(r).astype(compute_dtype),
                     (((1,), (0,)), ((), ())),
                     preferred_element_type=jnp.float32,
                 )
@@ -221,7 +242,7 @@ def _decode_kernel(
 @functools.partial(
     jax.jit,
     static_argnames=(
-        "interpret", "blocks_per_step", "mxu_native", "heads_first"
+        "interpret", "blocks_per_step", "mxu_native", "heads_first", "packed"
     ),
 )
 def paged_decode_attention_pallas(
@@ -235,10 +256,12 @@ def paged_decode_attention_pallas(
     mxu_native: bool = MXU_NATIVE,
     start: jnp.ndarray | None = None,
     heads_first: bool = False,
+    packed: bool = False,
 ) -> jnp.ndarray:
     """q: [B, H, D]; kv_layer: [num_blocks, 2, bs, Hkv, D], or
-    ``heads_first``: [num_blocks, 2, Hkv, bs, D] (the module's head says
-    how a step's operand is made of each);
+    ``heads_first``: [num_blocks, 2, Hkv, bs, D], or ``packed``:
+    [num_blocks, bs, Hkv, 2*D] (the module's head says how a step's operand
+    is made of each);
     block_table: [B, max_blocks] int32; context_len: [B] int32.
     ``start`` ([B] int32, window layers): the first position of the
     table a sequence still sees, as in ``paged_attention``; without it
@@ -251,7 +274,14 @@ def paged_decode_attention_pallas(
     section 6, PR 32); models/afmoe.py passes False, as it was measured.
     """
     B, H, D = q.shape
-    if heads_first:
+    scale = D**-0.5
+    if packed:
+        if heads_first:
+            raise ValueError("packed slots are rows of positions, not heads")
+        _, block_size, Hkv, _ = kv_layer.shape
+        # zeros over V's lanes: the scores are q.k, the output's upper half p.v
+        q = jnp.concatenate((q, jnp.zeros_like(q)), axis=-1)
+    elif heads_first:
         _, _, Hkv, block_size, _ = kv_layer.shape
     else:
         _, _, block_size, Hkv, _ = kv_layer.shape
@@ -272,7 +302,8 @@ def paged_decode_attention_pallas(
     ]
     if not heads_first:
         kv_layer = kv_layer.reshape(
-            kv_layer.shape[:2] + (block_size * Hkv, D)
+            kv_layer.shape[: 1 if packed else 2]
+            + (block_size * Hkv, q.shape[2])
         )
         # The sequence's last valid block, once for all index maps: the
         # scalar core runs every operand's map twice a grid step, and a
@@ -302,7 +333,7 @@ def paged_decode_attention_pallas(
         grid=(B, n_steps),
         in_specs=[
             pl.BlockSpec(
-                (1, H, D),
+                (1, H, q.shape[2]),
                 lambda b, j, *_: (b, 0, 0),
                 memory_space=pltpu.VMEM,
             ),
@@ -316,29 +347,31 @@ def paged_decode_attention_pallas(
             for i in range(P_STEP)
         ],
         out_specs=pl.BlockSpec(
-            (1, H, D),
+            (1, H, q.shape[2]),
             lambda b, j, *_: (b, 0, 0),
             memory_space=pltpu.VMEM,
         ),
         scratch_shapes=[
             pltpu.VMEM((H, 128), jnp.float32),
             pltpu.VMEM((H, 128), jnp.float32),
-            pltpu.VMEM((H, D), jnp.float32),
+            pltpu.VMEM((H, q.shape[2]), jnp.float32),
         ],
     )
     kernel = functools.partial(
         _decode_kernel,
         block_size=block_size,
         groups=groups,
-        scale=D**-0.5,
+        scale=scale,
         blocks_per_step=P_STEP,
         mxu_native=mxu_native,
         windowed=windowed,
         heads_first=heads_first,
+        packed=packed,
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         grid_spec=grid_spec,
         interpret=interpret,
     )(*(a.astype(jnp.int32) for a in scalars), q, *([kv_layer] * P_STEP))
+    return out[..., D:] if packed else out

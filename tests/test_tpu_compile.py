@@ -18,7 +18,8 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from llm_d_kv_cache_manager_tpu.models import llama
+from llm_d_kv_cache_manager_tpu.models import lfm2moe, llama
+from llm_d_kv_cache_manager_tpu.models import pod as pod_programs
 from llm_d_kv_cache_manager_tpu.ops import flash_pallas
 from llm_d_kv_cache_manager_tpu.ops.paged_decode_pallas import (
     paged_decode_attention_pallas,
@@ -191,3 +192,62 @@ def test_llama_programs_update_a_donated_pool_in_place(
     memory = compiled.memory_analysis()
     assert memory.alias_size_in_bytes >= pool_bytes  # the pool handed back
     assert memory.temp_size_in_bytes < pool_bytes
+
+
+# --------------------------- the lfm2moe programs, K/V and state pools donated
+
+# benchmarks/configs/lfm2-8b-a1b-l13.json and benchmarks/traffic/chat-agents.json
+LFM2 = lfm2moe.Lfm2MoeConfig(
+    vocab_size=65536, d_model=2048, n_heads=32, n_kv_heads=8, d_ff=7168,
+    d_expert=1792, n_experts=32, top_k=4, n_dense_layers=1,
+    layer_types=("conv",) + ("full_attention", "conv", "conv", "conv") * 3,
+    state_slots=2048, state_stride_blocks=16)
+LFM2_SHAPES = {"miss": (8704,), "hit": (8192, 512), "decode": (64,),
+               "max_blocks": 576}
+# Temporaries a program may take beside 11.0 GB of weights and pools on a
+# 15.75-GB chip.  The miss prefill's are its 8704 tokens' activations (the
+# expert layer's 34 816 sorted rows) and the hit's the batched expert
+# product's [32, 512, 1792] float32 (0.28 GB); the hit's and the decode
+# step's held a copy of each 537-MB layer pool (2.3 and 1.1 GB) while slots
+# were [2, Hkv, 16, 64]: the compiler made the slot axis the minor one.
+LFM2_TEMP_LIMIT = {"miss": 2.6e9, "hit": 0.4e9, "decode": 0.1e9}
+
+
+@pytest.mark.parametrize("key", ("miss", "hit", "decode"))
+def test_lfm2moe_programs_compile_at_the_cells_shapes(one_chip, monkeypatch,
+                                                      key):
+    """The cell `lfm2moe-chat-agents`' three programs as `models/pod.py`
+    jits them, pools donated: they compile for the v5e (the flash kernel and
+    the paged kernel at head size 64 among them), hand the pools back where
+    they lie, and no instruction copies a layer's pool."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    def spec(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+
+    params = jax.tree.map(spec, jax.eval_shape(
+        lambda: lfm2moe.init_params(jax.random.key(0), LFM2)))
+    pools = jax.tree.map(spec, jax.eval_shape(
+        lambda: lfm2moe.new_pool(LFM2, 16384)))
+
+    class Shapes:  # what `example_args` reads of a pod
+        window = None
+
+        class state:
+            spec = lfm2moe.cache_groups(LFM2)["state"]
+
+    first, second = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+        pod_programs.example_args(key, LFM2_SHAPES, Shapes, BLOCK))
+    program = pod_programs.inner_programs(lfm2moe, LFM2, LFM2_SHAPES,
+                                          False)[key]
+    compiled = program.trace(params, first, pools, second).lower(
+        lowering_platforms=("tpu",)).compile()
+    hlo = compiled.as_text()
+    assert "tpu_custom_call" in hlo
+    slot = "16384,16,8,128"
+    assert not re.search(rf"= bf16\[{slot}\]\S* copy\(", hlo)
+    memory = compiled.memory_analysis()
+    pool_bytes = sum(2 * math.prod(a.shape) for a in jax.tree.leaves(pools))
+    assert memory.alias_size_in_bytes >= pool_bytes  # the pools handed back
+    assert memory.temp_size_in_bytes < LFM2_TEMP_LIMIT[key]
