@@ -344,13 +344,15 @@ def _write_token(pool, ids, at, k, v):
     return lax.fori_loop(0, ids.shape[0], one, pool)
 
 
-def _decode_attention(q, pool, table, context_len, interpret):
+def _decode_attention(q, pool, table, context_len, interpret, plan):
     """The paged kernel where it serves (compiled for the TPU, or
-    interpreted); elsewhere the XLA gather over the slots unpacked."""
-    if paged_decode_pallas.serves(interpret):
+    interpreted; `plan`: its `shared_prefix_plan` of this table); elsewhere
+    the XLA gather over the slots unpacked."""
+    if plan is not None:
         return paged_decode_attention_pallas(
             q, pool, table, context_len, packed=True,
             blocks_per_step=DECODE_BLOCKS_PER_STEP, interpret=interpret,
+            plan=plan,
         )
     Dh = q.shape[-1]
     unpacked = jnp.stack((pool[..., :Dh], pool[..., Dh:]), axis=1)
@@ -534,6 +536,13 @@ def decode_step(
     full_id = jnp.take_along_axis(
         tables["full"], (pos // bs)[:, None], axis=1)[:, 0]
     full, state, loads = list(pools["full"]), list(pools["state"]), []
+    # Which sequences' tables begin with the same blocks, once for the
+    # attention layers: all see this table.
+    plan = None
+    if paged_decode_pallas.serves(interpret):
+        plan = paged_decode_pallas.shared_prefix_plan(
+            tables["full"], context_len, block_size=bs,
+            blocks_per_step=DECODE_BLOCKS_PER_STEP)
     for l, lp in enumerate(params["layers"]):
         kind, i = cfg.slot_of_layer(l)
         h = _rms_norm(x, lp["ln_op"], cfg.rms_eps, lp["ln_op"].dtype)
@@ -549,12 +558,16 @@ def decode_step(
             q, k, v = _qkv(h, lp, pos[:, None], cfg)
             full[i] = _write_token(full[i], full_id, at, k[:, 0], v[:, 0])
             attn = _decode_attention(q[:, 0], full[i], tables["full"],
-                                     context_len, interpret)
+                                     context_len, interpret, plan)
             y = _attn_out(attn[:, None], lp)
         x, load = _ff_block(x + y, lp, cfg)
         if load is not None:
             loads.append(load)
-    return _finish(x[:, 0], params, cfg, full, state, loads)
+    logits, pools = _finish(x[:, 0], params, cfg, full, state, loads)
+    if plan is not None:
+        pools["attention_read"] = jnp.stack(
+            (plan["read_blocks"], plan["walked_blocks"]))
+    return logits, pools
 
 
 # ------------------------------------------------------ the plain reference

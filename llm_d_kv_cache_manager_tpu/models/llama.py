@@ -587,6 +587,13 @@ def decode_step(
     block_ids = jnp.take_along_axis(
         block_table, block_idx[:, None], axis=1
     )[:, 0]
+    # Which sequences' tables begin with the same blocks, once for all
+    # layers: each sees this table, shifted.
+    plan = None
+    if use_kernel:
+        plan = paged_decode_pallas.shared_prefix_plan(
+            block_table, context_len, block_size=cfg.block_size
+        )
 
     def layer(x, slots, lp, base):
         h = _rms_norm(x, lp["ln1"])
@@ -599,7 +606,8 @@ def decode_step(
         table = base + block_table
         if use_kernel:
             attn = paged_decode_attention_pallas(
-                q[:, 0], slots, table, context_len, interpret=interpret
+                q[:, 0], slots, table, context_len, interpret=interpret,
+                plan=plan,
             )
         else:
             attn = paged_attention(q[:, 0], slots, table, context_len)

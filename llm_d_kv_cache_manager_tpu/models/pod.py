@@ -72,8 +72,8 @@ Positions are never told to a pod; they are known at each program call (a
 table is in chain order, decode brings ``context_len``), so ``jit_programs``
 returns plain functions that build the second group's tables on the host,
 record spans (``kvpool.window`` and ``kv.read``, or ``kvpool.state`` and
-``state.read``; ``moe.expert_load``) and call the inner compiled programs,
-which keep the names the trace reduction looks for.
+``state.read``; ``moe.expert_load``, ``attention.read``) and call the inner
+compiled programs, which keep the names the trace reduction looks for.
 """
 
 from __future__ import annotations
@@ -88,6 +88,9 @@ import numpy as np
 from llm_d_kv_cache_manager_tpu.obs.trace import root_trace, span
 
 RESERVED = np.iinfo(np.int64).max  # a slot taken and not yet in any window
+# What a decode step may hand back beside its pools: counts made on the device
+# ("load": a row an expert layer; "attention_read": blocks read, blocks walked).
+COUNTED = ("load", "attention_read")
 
 
 def cache_policy(program, model) -> dict:
@@ -525,7 +528,7 @@ class Pod:
                 "family has both; their hit rules would have to be met at "
                 "one length)")
         self.second = self.window or self.state  # the group beside the full
-        self.pending_load = None  # (device counts, tokens) of a decode step
+        self.pending_load = None  # (a decode step's device counts, its tokens)
 
     def cached_prefix(self, hashes) -> list[int]:
         ids = []
@@ -617,14 +620,23 @@ class Pod:
         return tables
 
     def report_load(self, model) -> None:
-        """The expert layers' counts of the last decode step as spans.  The
-        step that made them has long ended (its tokens were read back), so
-        this waits for nothing."""
+        """What the last decode step counted on the device, as spans: the
+        blocks its attention read against a walk of every table, and the
+        expert layers' loads.  The step that made them has long ended (its
+        tokens were read back), so this waits for nothing."""
         if self.pending_load is None:
             return
-        counts, tokens = self.pending_load
+        counted, tokens = self.pending_load
         self.pending_load = None
-        for layer, (touched, most) in enumerate(np.asarray(counts)):
+        # to the host in one go: a transfer of its own costs each ≈0.5 ms
+        counted = jax.device_get(counted)
+        if "attention_read" in counted:
+            read, walked = np.asarray(counted["attention_read"])
+            with span("attention.read") as s:
+                s.set_attr("read_blocks", int(read))
+                s.set_attr("walked_blocks", int(walked))
+        for layer, (touched, most) in enumerate(
+                np.asarray(counted.get("load", ()))):
             with span("moe.expert_load") as s:
                 s.set_attr("layer", layer)
                 s.set_attr("experts_held", model.n_experts)
@@ -728,9 +740,9 @@ def jit_programs(program, model, shapes: dict, interpret: bool) -> dict:
         *out, kv.arrays = compiled[key](p, first, kv.arrays, second)
         # what a step counted on the device is no part of the pools: it is
         # not handed back in, so reading it later finds it alive
-        load = (kv.arrays.pop("load", None)
-                if isinstance(kv.arrays, dict) else None)
-        return (*out, kv), load
+        counted = ({k: kv.arrays.pop(k) for k in COUNTED if k in kv.arrays}
+                   if isinstance(kv.arrays, dict) else {})
+        return (*out, kv), counted
 
     def prefill(key):
         prefix_blocks = shapes[key][0] // block if key == "hit" else 0
@@ -751,11 +763,11 @@ def jit_programs(program, model, shapes: dict, interpret: bool) -> dict:
             ints = ([t, n, *tables["state"].T] if pod.state is not None else
                     [t, n] if pod.window is None else
                     [t, n, tables["first"], *tables["window"].T])
-            out, load = run("decode", p, kv,
-                            np.stack(ints, axis=1, dtype=np.int32),
-                            kv.on_device(bt))
-            if traced is not None and load is not None:
-                pod.pending_load = (load, len(t))
+            out, counted = run("decode", p, kv,
+                               np.stack(ints, axis=1, dtype=np.int32),
+                               kv.on_device(bt))
+            if traced is not None and counted:
+                pod.pending_load = (counted, len(t))
             return out
 
     return {key: run_decode if key == "decode" else prefill(key)

@@ -28,10 +28,28 @@ one and re-lays-out the whole pool around every step), one operand is both K
 and V, the query comes padded with zeros over V's lanes and the output is read
 from them.
 
+Over the second and third kind of slot a **shared-prefix pass** comes first.
+Live sequences whose tables begin with the same run of full blocks (a system
+prompt that `Pod.cached_prefix` gave them all) are found from the table
+itself, once a decode step (``shared_prefix_plan``: data, never shapes).  The
+pass brings each block of such a run from HBM once for up to
+``SHARED_SEQUENCES`` sequences and multiplies it against all their query rows
+together (``_shared_kernel``); then each sequence walks only the rest of its
+own table, its online softmax resumed from what the pass left (running
+maximum, sum, weighted values, float32): the same attention over the same
+keys in the same types, the float32 sums in another order.  The walk's grid is
+then the plan's list of the steps that have something to read, its length a
+value and not a shape, so a sequence with 40 blocks of its own takes two steps
+and not the table's six.  A sequence that shares nothing walks its whole table
+as before, and a table where nobody shares costs the set-finding and one empty
+step.  Heads-first slots and window layers (a window's start hides part of a
+prefix from each sequence on its own) keep the grid of tables by steps.
+
 Contract matches ops/paged_attention.py::paged_attention; equivalence
 is pinned by tests/test_paged_decode_pallas.py (interpret mode on CPU);
-tests/test_tpu_compile.py compiles both forms for the v5e at the served
-shapes, and llama.decode_step / afmoe.decode_step serve through it.
+tests/test_tpu_compile.py compiles every form for the v5e at the served
+shapes, and the decode steps of models/llama.py, afmoe.py and lfm2moe.py
+serve through it.
 """
 
 from __future__ import annotations
@@ -53,6 +71,15 @@ NEG_INF = -1e30
 # float32 accumulation.  models/afmoe.py passes its own for its slots.
 BLOCKS_PER_STEP = 32
 MXU_NATIVE = True
+# The shared pass's own, read on the chip at both cells' shapes (kernel alone,
+# a decode step's layers; PERF.md section 6, PR 34): sequences a group (their
+# query rows go through a block together: 8 x 16 heads = 128 rows in
+# internlm2-chat-sysprompt, 8 x 32 = 256 in lfm2moe-chat-agents, where the
+# sets are of about eight) and blocks a step (the scores of a step are
+# [sequences * H, blocks * bs * Hkv] float32: 1 and 2 MB; 8 / 16 / 32 blocks
+# read 6.96 / 6.55 / 6.53 ms and 3.73 / 3.51 / 3.57 ms).
+SHARED_SEQUENCES = 8
+SHARED_BLOCKS_PER_STEP = 16
 
 
 def serves(interpret: bool) -> bool:
@@ -62,11 +89,94 @@ def serves(interpret: bool) -> bool:
     return interpret or jax.default_backend() == "tpu"
 
 
+def _softmax_update(s, seen, m_ref, l_ref):
+    """The online-softmax statistics over one step's scores s [rows, width]
+    (f32) of which ``seen`` are visible (None: the hidden ones are at
+    NEG_INF already): returns the weights p (f32) and the factor the
+    accumulator shrinks by."""
+    if seen is not None:
+        s = jnp.where(seen, s, NEG_INF)
+    m_prev = m_ref[:, :1]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+    p = jnp.exp(s - m_new)  # [rows, width] f32
+    correction = jnp.exp(m_prev - m_new)
+    l_ref[...] = l_ref[...] * correction + jnp.sum(
+        p, axis=1, keepdims=True
+    )
+    m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
+    return p, correction
+
+
+def _own_head(shape, heads: int, groups: int):
+    """Where a block's scores [R, bs*Hkv] are a query row's (row r: query
+    head r % heads) against a row of its own KV head (column c: KV head
+    c % Hkv)."""
+    col = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    row = jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+    if shape[0] != heads:
+        row = jax.lax.rem(row, heads)
+    return jax.lax.rem(col, heads // groups) == jax.lax.div(row, groups)
+
+
+def _attend_rows(q, kv_refs, hide, m_ref, l_ref, acc_ref, *, packed: bool):
+    """One online-softmax update over the step's blocks for slots
+    [2, bs, Hkv, D], handed in as [2, bs*Hkv, D] (merging the two moves
+    nothing): a block's K is one [bs*Hkv, D] operand whose row r is position
+    r // Hkv of KV head r % Hkv.  Every query row (q: [R, D] in the products'
+    type) is multiplied against every row of K, and ``hide(i, scores)`` puts
+    block i's scores against other KV heads' rows, and against positions the
+    row does not see, at NEG_INF before the softmax: the step is one update
+    over P*bs*Hkv columns, with no re-layout of K or V in VMEM.  (Hkv times
+    the products' FLOPs on an MXU that few query rows leave idle; each K and
+    V row passes it once, as in any other form.  Measured against a transpose
+    a step and a transpose a block: PERF.md section 6, PR 32.)  The walk
+    hands in one sequence's heads, the shared pass those of a whole group."""
+    if packed:  # K and V side by side in the lanes: one operand is both
+
+        def key(r):
+            return r[0]
+
+        value = key
+    else:
+
+        def key(r):
+            return r[0, 0]
+
+        def value(r):
+            return r[0, 1]
+
+    rows = key(kv_refs[0]).shape[0]
+    s = jnp.concatenate(
+        [
+            hide(i, jax.lax.dot_general(
+                q,
+                key(r).astype(q.dtype),
+                (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            ))
+            for i, r in enumerate(kv_refs)
+        ],
+        axis=1,
+    )  # [R, width]
+    p, correction = _softmax_update(s, None, m_ref, l_ref)
+    p = p.astype(q.dtype)
+    o = sum(
+        jax.lax.dot_general(
+            p[:, i * rows : (i + 1) * rows],
+            value(r).astype(q.dtype),
+            (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+        for i, r in enumerate(kv_refs)
+    )  # [R, D]
+    acc_ref[...] = acc_ref[...] * correction + o
+
+
 def _decode_kernel(
     table_ref,  # SMEM [B, max_blocks] int32 (scalar prefetch)
     ctx_ref,  # SMEM [B] int32 (scalar prefetch)
     *rest,  # more scalar prefetch (below), q ref (VMEM [1, H, D]),
-    # blocks_per_step kv refs, out ref, then scratch
+    # blocks_per_step kv refs, [the shared pass's three,] out ref, scratch
     block_size: int,
     groups: int,
     scale: float,
@@ -75,21 +185,47 @@ def _decode_kernel(
     windowed: bool = False,
     heads_first: bool = False,
     packed: bool = False,
+    listed: bool = False,
 ):
     # Scalar prefetch after the context: [start (SMEM [B]) if windowed,]
-    # [the last block (SMEM [B]) unless heads_first: the index maps' own.]
+    # [the last block (SMEM [B]) unless heads_first: the index maps' own,]
+    # [if listed, ``shared_prefix_plan``'s walk: each sequence's place in the
+    # shared pass's results, then each grid step's sequence, first block and
+    # flags.]
     start_ref = rest[0] if windowed else None
-    q_ref, *rest = rest[windowed + (not heads_first) :]
+    if listed:  # never windowed nor heads_first: [last, place, then these]
+        seq_ref, first_ref, flag_ref = rest[2:5]
+    q_ref, *rest = rest[windowed + (not heads_first) + 4 * listed :]
     kv_refs = rest[:blocks_per_step]
-    out_ref = rest[blocks_per_step]
-    m_ref, l_ref, acc_ref = rest[blocks_per_step + 1 :]
+    rest = rest[blocks_per_step:]
+    if listed:  # what the shared pass left of this sequence's softmax
+        m0_ref, l0_ref, acc0_ref, *rest = rest
+    out_ref, m_ref, l_ref, acc_ref = rest
 
-    b = pl.program_id(0)
-    j = pl.program_id(1)
-    n_steps = pl.num_programs(1)
+    if listed:
+        # The grid is the list of steps that have something to read: this
+        # one's sequence and first block, and whether it opens the sequence
+        # (bit 0), closes it (bit 1), or opens it after a shared pass (bit 2).
+        w = pl.program_id(0)
+        b = seq_ref[w]
+        flags = flag_ref[w]
+        opens = (flags & 1) != 0
+
+        @pl.when((flags & 4) != 0)
+        def _resume():
+            m_ref[...] = m0_ref[...]
+            l_ref[...] = l0_ref[...]
+            acc_ref[...] = acc0_ref[...]
+
+    else:
+        b = pl.program_id(0)
+        j = pl.program_id(1)
+        n_steps = pl.num_programs(1)
     ctx = ctx_ref[b]
+    if not listed:  # here, so that the heads-first kernel traces as it did
+        opens = j == 0
 
-    @pl.when(j == 0)
+    @pl.when(opens)
     def _init():
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
@@ -106,21 +242,6 @@ def _decode_kernel(
     q = q_ref[0].astype(jnp.float32) * scale  # [H, D]
     if heads_first:
         qb = q.reshape(Hkv, groups, D).astype(compute_dtype)
-
-    def softmax_update(s, seen):
-        """The online-softmax statistics over one step's scores s [H, width]
-        (f32) of which ``seen`` are visible: returns the weights p (f32)
-        and the factor the accumulator shrinks by."""
-        s = jnp.where(seen, s, NEG_INF)
-        m_prev = m_ref[:, :1]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        p = jnp.exp(s - m_new)  # [H, width] f32
-        correction = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * correction + jnp.sum(
-            p, axis=1, keepdims=True
-        )
-        m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
-        return p, correction
 
     def in_context(position, first):
         """Which of the step's positions (an iota, counted from the step's
@@ -143,7 +264,9 @@ def _decode_kernel(
         )  # [Hkv, G, width]
         s = s.reshape(H, width)
         col = jax.lax.broadcasted_iota(jnp.int32, (H, width), 1)
-        p, correction = softmax_update(s, in_context(col, first))
+        p, correction = _softmax_update(
+            s, in_context(col, first), m_ref, l_ref
+        )
         pb = p.reshape(Hkv, groups, width).astype(compute_dtype)
         o = jax.lax.dot_general(
             pb,
@@ -171,78 +294,222 @@ def _decode_kernel(
             )
 
     else:
-        # Slots are [2, bs, Hkv, D], handed in as [2, bs*Hkv, D] (merging
-        # the two moves nothing): a block's K is one [bs*Hkv, D] operand
-        # whose row r is position r // Hkv of KV head r % Hkv.  Every
-        # query head is multiplied against every row and the rows of other
-        # KV heads are masked before the softmax: the step is one update
-        # over P*bs*Hkv columns, with no re-layout of K or V in VMEM.
-        # (Hkv times the products' FLOPs on an MXU that H query rows leave
-        # idle; each K and V row passes it once, as in any other form.
-        # Measured against a transpose a step and a transpose a block:
-        # PERF.md section 6, PR 32.)
-        rows = block_size * Hkv
-        width = blocks_per_step * rows
-        if packed:  # K and V side by side in the lanes: one operand is both
-
-            def key(r):
-                return r[0]
-
-            value = key
-        else:
-
-            def key(r):
-                return r[0, 0]
-
-            def value(r):
-                return r[0, 1]
-
-        first = j * blocks_per_step * block_size
+        first = (first_ref[w] if listed else j * blocks_per_step) * block_size
 
         @pl.when(first < ctx)
         def _attend_step():
-            qc = q.astype(compute_dtype)
-            s = jnp.concatenate(
-                [
-                    jax.lax.dot_general(
-                        qc,
-                        key(r).astype(compute_dtype),
-                        (((1,), (1,)), ((), ())),
-                        preferred_element_type=jnp.float32,
-                    )
-                    for r in kv_refs
-                ],
-                axis=1,
-            )  # [H, width]
-            col = jax.lax.broadcasted_iota(jnp.int32, (H, width), 1)
-            row = jax.lax.broadcasted_iota(jnp.int32, (H, width), 0)
-            own = jax.lax.rem(col, Hkv) == jax.lax.div(row, groups)
-            p, correction = softmax_update(
-                s, own & in_context(jax.lax.div(col, Hkv), first)
+            shape = (H, block_size * Hkv)
+            own = _own_head(shape, H, groups)
+            position = jax.lax.div(
+                jax.lax.broadcasted_iota(jnp.int32, shape, 1), Hkv
             )
-            p = p.astype(compute_dtype)
-            o = sum(
-                jax.lax.dot_general(
-                    p[:, i * rows : (i + 1) * rows],
-                    value(r).astype(compute_dtype),
-                    (((1,), (0,)), ((), ())),
-                    preferred_element_type=jnp.float32,
-                )
-                for i, r in enumerate(kv_refs)
-            )  # [H, D]
-            acc_ref[...] = acc_ref[...] * correction + o
 
-    @pl.when(j == n_steps - 1)
+            def hide(i, s):
+                seen = in_context(position, first + i * block_size)
+                return jnp.where(own & seen, s, NEG_INF)
+
+            _attend_rows(
+                q.astype(compute_dtype), kv_refs, hide, m_ref, l_ref,
+                acc_ref, packed=packed,
+            )
+
+    @pl.when((flags & 2) != 0 if listed else j == n_steps - 1)
     def _finalize():
         l = l_ref[:, :1]
         out = acc_ref[...] / jnp.where(l == 0.0, 1.0, l)
         out_ref[0] = out.astype(out_ref.dtype)
 
 
+def _shared_kernel(
+    table_ref,  # SMEM [B, max_blocks] int32 (scalar prefetch)
+    row_ref,  # SMEM [C]: each group's table row (its first member's),
+    run_ref,  # SMEM [C]: and its run, in blocks
+    members_ref,  # SMEM [C*G] (the index maps' own)
+    *rest,  # a q ref a member, the pool (HBM); out: m, l, acc; scratch
+    groups: int,
+    scale: float,
+    blocks_per_step: int,
+    sequences: int,
+    mxu_native: bool,
+    packed: bool,
+):
+    """The shared-prefix pass: a grid step is a group, whose sequences all
+    have the same run of blocks at the head of their tables.  It brings the
+    run in ``blocks_per_step`` blocks at a time, each block by a copy of its
+    own into one of two buffers (the next blocks arrive while these are
+    multiplied), and makes one online-softmax update of all the group's query
+    rows over them.  The statistics and the weighted values are left
+    unnormalised in the output blocks: the walk resumes each sequence's
+    softmax from them."""
+    del members_ref
+    q_refs = rest[:sequences]
+    kv_hbm = rest[sequences]
+    m_ref, l_ref, acc_ref, buf, sem, other_ref = rest[sequences + 1 :]
+    P = blocks_per_step
+    g = pl.program_id(0)
+    row, run = row_ref[g], run_ref[g]
+    H = q_refs[0].shape[1]
+
+    @pl.when(g == 0)
+    def _once():
+        # NEG_INF under the other KV heads' rows, added to every block's
+        # scores: made once a call, where the walk's few rows make theirs
+        # from iotas a step.
+        other_ref[...] = jnp.where(
+            _own_head(other_ref.shape, H, groups), 0.0, NEG_INF
+        )
+
+    m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    def copies(step, half):
+        # past the run's end its last block again: hidden below
+        return [
+            pltpu.make_async_copy(
+                kv_hbm.at[
+                    pl.ds(table_ref[row, jnp.minimum(step * P + i, run - 1)], 1)
+                ],
+                buf.at[half, i],
+                sem.at[half],
+            )
+            for i in range(P)
+        ]
+
+    @pl.when(run > 0)
+    def _first():
+        for copy in copies(0, 0):
+            copy.start()
+
+    q = jnp.concatenate([r[0] for r in q_refs], axis=0)  # [G*H, D]
+    compute_dtype = q.dtype if mxu_native else jnp.float32
+    q = (q.astype(jnp.float32) * scale).astype(compute_dtype)
+    n_steps = (run + P - 1) // P
+
+    def step(j, _):
+        half = jax.lax.rem(j, 2)
+
+        @pl.when(j + 1 < n_steps)
+        def _next():
+            for copy in copies(j + 1, 1 - half):
+                copy.start()
+
+        for copy in copies(j, half):
+            copy.wait()
+        # Every position of the run lies before every member's own: only
+        # the blocks past the run's end, in its last step, are hidden whole.
+        count = run - j * P
+        _attend_rows(
+            q,
+            [buf.at[half, i] for i in range(P)],
+            lambda i, s: jnp.where(i < count, s + other_ref[...], NEG_INF),
+            m_ref, l_ref, acc_ref, packed=packed,
+        )
+
+    jax.lax.fori_loop(0, n_steps, step, None)
+
+
+def shared_prefix_plan(
+    block_table: jnp.ndarray,
+    context_len: jnp.ndarray,
+    *,
+    block_size: int,
+    blocks_per_step: int = BLOCKS_PER_STEP,
+) -> dict:
+    """Which sequences' tables begin with the same run of full blocks, the
+    groups the shared pass takes them in, and the walk's grid as the list of
+    the steps that have something to read.  All of it is data (int32 arrays of
+    shapes fixed by the table's), found once a decode step: every layer sees
+    the same table, shifted.
+
+    A sequence's leader is the first row with the same first block; its own
+    run is the count of leading columns equal to the leader's that lie wholly
+    before its write position ((j+1)*bs <= context_len - 1); a set's run is
+    the least of its members'.  A set goes through the shared pass in groups
+    of up to ``SHARED_SEQUENCES`` in row order; a sequence alone in its
+    group, or in a set whose run is 0 (idle slots on the scratch block among
+    them), walks its whole table.  Keys: ``shared`` (each group's table row,
+    run and members) and ``walk`` (each sequence's place in the shared pass's
+    results; each step's sequence, first block and flags: bit 0 opens the
+    sequence, bit 1 closes it, bit 2 opens it after a shared pass): the two
+    kernels' scalar prefetch after the table; ``shared_steps`` and
+    ``walk_steps``: their grids' lengths; ``read_blocks`` (what the two read:
+    each group's run once and every sequence's rest) and ``walked_blocks``
+    (what a walk of every table reads)."""
+    i32 = jnp.int32
+    B, M = block_table.shape
+    G, P = SHARED_SEQUENCES, blocks_per_step
+    ctx = context_len.astype(i32)
+    rows = jnp.arange(B, dtype=i32)
+    leader = jnp.argmax(
+        block_table[:, :1] == block_table[None, :, 0], axis=1
+    ).astype(i32)
+    whole = (jnp.arange(1, M + 1, dtype=i32) * block_size)[None] < ctx[:, None]
+    agree = (block_table == block_table[leader]) & whole
+    own_run = jnp.sum(jnp.cumprod(agree.astype(i32), axis=1), axis=1)
+    mates = leader[:, None] == leader[None, :]  # [B, B]: of one set
+    run = jnp.min(jnp.where(mates, own_run[None, :], M), axis=1)
+    rank = jnp.sum(mates & (rows[None, :] < rows[:, None]), axis=1)
+    size = jnp.sum(mates, axis=1)
+    place = rank % G  # within its group
+    shares = (run > 0) & (jnp.minimum(G, size - (rank - place)) > 1)
+    skip = jnp.where(shares, run, 0)
+    heads = shares & (place == 0)
+    # Groups are numbered in the row order of their first members.
+    first_member = jnp.argmax(
+        mates & (rank[None, :] == (rank - place)[:, None]), axis=1
+    )
+    group = jnp.where(shares, (jnp.cumsum(heads) - 1)[first_member], 0)
+    slot = jnp.where(shares, group * G + place, 0).astype(i32)
+
+    C = max(B // 2, 1)  # groups there can be: each has two members or more
+    is_head = heads[None, :] & (group[None, :] == jnp.arange(C)[:, None])
+    group_row = jnp.sum(jnp.where(is_head, rows[None, :], 0), axis=1)
+    group_run = jnp.sum(jnp.where(is_head, run[None, :], 0), axis=1)
+    holds = (shares[None, :]
+             & (slot[None, :] == jnp.arange(C * G)[:, None]))  # [C*G, B]
+    members = jnp.where(
+        jnp.any(holds, axis=1),
+        jnp.sum(jnp.where(holds, rows[None, :], 0), axis=1),
+        jnp.repeat(group_row, G),  # an empty place asks again for the first
+    )
+
+    # The walk: every sequence's rest, its write position's block at least,
+    # as the list of (sequence, step) in the sequences' order.
+    blocks = jnp.maximum(ctx - 1, 0) // block_size + 1
+    rest = blocks - skip
+    walk_counts = -(-rest // P)
+    ends = jnp.cumsum(walk_counts)
+    at = jnp.arange(B * -(-M // P), dtype=i32)
+    owner = jnp.minimum(
+        jnp.sum(ends[None, :] <= at[:, None], axis=1), B - 1
+    ).astype(i32)
+    step = at - (ends - walk_counts)[owner]
+    flags = (
+        (step == 0) & (skip[owner] == 0)
+        | 2 * (step == walk_counts[owner] - 1)
+        | 4 * ((step == 0) & (skip[owner] > 0))
+    )
+    walk = (slot, owner, skip[owner] + step * P, flags)
+    return {
+        "walk": tuple(a.astype(i32) for a in walk),
+        "shared": tuple(
+            a.astype(i32) for a in (group_row, group_run, members)
+        ),
+        "walk_steps": ends[-1].astype(i32),
+        # a grid of no step at all is not asked of the compiler: one step
+        # that reads nothing where nobody shares
+        "shared_steps": jnp.maximum(jnp.sum(heads), 1).astype(i32),
+        "read_blocks": (jnp.sum(group_run) + jnp.sum(rest)).astype(i32),
+        "walked_blocks": jnp.sum(blocks).astype(i32),
+    }
+
+
 @functools.partial(
     jax.jit,
     static_argnames=(
-        "interpret", "blocks_per_step", "mxu_native", "heads_first", "packed"
+        "interpret", "blocks_per_step", "mxu_native", "heads_first", "packed",
+        "shared_blocks_per_step",
     ),
 )
 def paged_decode_attention_pallas(
@@ -257,6 +524,8 @@ def paged_decode_attention_pallas(
     start: jnp.ndarray | None = None,
     heads_first: bool = False,
     packed: bool = False,
+    plan: dict | None = None,
+    shared_blocks_per_step: int = SHARED_BLOCKS_PER_STEP,
 ) -> jnp.ndarray:
     """q: [B, H, D]; kv_layer: [num_blocks, 2, bs, Hkv, D], or
     ``heads_first``: [num_blocks, 2, Hkv, bs, D], or ``packed``:
@@ -266,6 +535,11 @@ def paged_decode_attention_pallas(
     ``start`` ([B] int32, window layers): the first position of the
     table a sequence still sees, as in ``paged_attention``; without it
     the kernel is the one it was.  Returns [B, H, D] in q.dtype.
+
+    Without ``heads_first`` and ``start``, runs of blocks that several tables
+    begin with are read once for the sequences that share them
+    (``shared_prefix_plan``, which a model's decode step makes once for all
+    its layers and hands in as ``plan``; made here when it is not).
 
     ``mxu_native=True`` keeps the attention dots in the input dtype
     (bf16 operands, f32 accumulation) instead of upcasting K/V to f32 in
@@ -289,7 +563,18 @@ def paged_decode_attention_pallas(
     max_blocks = block_table.shape[1]
     P_STEP = blocks_per_step
     n_steps = -(-max_blocks // P_STEP)
-    if max_blocks % P_STEP:
+    window = [a for a in (start,) if a is not None]
+    windowed = len(window) == 1
+    # The grid as the plan's list of steps, after its shared pass: where the
+    # table's columns are the sequence's positions from 0 (a window layer's
+    # start hides part of a prefix from each sequence on its own).
+    listed = not (heads_first or windowed)
+    if listed and not isinstance(plan, dict):
+        plan = shared_prefix_plan(
+            block_table, context_len, block_size=block_size,
+            blocks_per_step=P_STEP,
+        )
+    if not listed and max_blocks % P_STEP:
         # Pad table columns; pads resolve to the last valid block and
         # are masked by context_len in the kernel.
         block_table = jnp.pad(
@@ -297,9 +582,7 @@ def paged_decode_attention_pallas(
             ((0, 0), (0, n_steps * P_STEP - max_blocks)),
         )
 
-    scalars = [block_table, context_len] + [
-        a for a in (start,) if a is not None
-    ]
+    scalars = [block_table, context_len] + window
     if not heads_first:
         kv_layer = kv_layer.reshape(
             kv_layer.shape[: 1 if packed else 2]
@@ -311,6 +594,19 @@ def paged_decode_attention_pallas(
         # heads-first maps still divide: ROADMAP.)
         scalars.append(jnp.maximum((context_len - 1) // block_size, 0))
     zeros = (0,) * (kv_layer.ndim - 1)
+    kv_block = (1,) + kv_layer.shape[1:]
+    Dq = q.shape[2]
+    resumed = ()  # the shared pass's results, which the walk resumes from
+    if listed:
+        if plan["walk"][1].shape[0] != B * n_steps:
+            raise ValueError("the plan was made for another table or step")
+        resumed = _shared_pass(
+            q, kv_layer, block_table, plan, kv_block=kv_block,
+            groups=groups, scale=scale,
+            blocks_per_step=shared_blocks_per_step, mxu_native=mxu_native,
+            packed=packed, interpret=interpret,
+        )
+        scalars += plan["walk"]
 
     def kv_index(i):
         # Sub-block i of step j; past-context steps revisit the last
@@ -325,36 +621,49 @@ def paged_decode_attention_pallas(
                 jc = jnp.minimum(j * P_STEP + i, more[-1][b])
             return (table_ref[b, jc],) + zeros
 
-        return index
+        def listed_index(w, table_ref, ctx_ref, last_ref, slot_ref, seq_ref,
+                         first_ref, flag_ref):
+            b = seq_ref[w]
+            return (
+                table_ref[b, jnp.minimum(first_ref[w] + i, last_ref[b])],
+            ) + zeros
 
-    windowed = start is not None
+        return listed_index if listed else index
+
+    if listed:
+
+        def of_sequence(w, *refs):
+            return (refs[4][w], 0, 0)
+
+        def of_slot(w, *refs):
+            return (refs[3][refs[4][w]], 0)
+
+    else:
+
+        def of_sequence(b, j, *_):
+            return (b, 0, 0)
+
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(scalars),
-        grid=(B, n_steps),
+        grid=(plan["walk_steps"],) if listed else (B, n_steps),
         in_specs=[
-            pl.BlockSpec(
-                (1, H, q.shape[2]),
-                lambda b, j, *_: (b, 0, 0),
-                memory_space=pltpu.VMEM,
-            ),
+            pl.BlockSpec((1, H, Dq), of_sequence, memory_space=pltpu.VMEM),
         ]
         + [
-            pl.BlockSpec(
-                (1,) + kv_layer.shape[1:],
-                kv_index(i),
-                memory_space=pltpu.VMEM,
-            )
+            pl.BlockSpec(kv_block, kv_index(i), memory_space=pltpu.VMEM)
             for i in range(P_STEP)
+        ]
+        + [
+            pl.BlockSpec((H, a.shape[1]), of_slot, memory_space=pltpu.VMEM)
+            for a in resumed
         ],
         out_specs=pl.BlockSpec(
-            (1, H, q.shape[2]),
-            lambda b, j, *_: (b, 0, 0),
-            memory_space=pltpu.VMEM,
+            (1, H, Dq), of_sequence, memory_space=pltpu.VMEM
         ),
         scratch_shapes=[
             pltpu.VMEM((H, 128), jnp.float32),
             pltpu.VMEM((H, 128), jnp.float32),
-            pltpu.VMEM((H, q.shape[2]), jnp.float32),
+            pltpu.VMEM((H, Dq), jnp.float32),
         ],
     )
     kernel = functools.partial(
@@ -367,11 +676,75 @@ def paged_decode_attention_pallas(
         windowed=windowed,
         heads_first=heads_first,
         packed=packed,
+        listed=listed,
     )
     out = pl.pallas_call(
         kernel,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         grid_spec=grid_spec,
         interpret=interpret,
-    )(*(a.astype(jnp.int32) for a in scalars), q, *([kv_layer] * P_STEP))
+    )(
+        *(a.astype(jnp.int32) for a in scalars),
+        q,
+        *([kv_layer] * P_STEP),
+        *resumed,
+    )
     return out[..., D:] if packed else out
+
+
+def _shared_pass(q, kv_layer, block_table, plan, *, kv_block, blocks_per_step,
+                 interpret, **statics):
+    """The shared pass over the plan's groups: the running maximum, the sum
+    and the weighted values (float32, unnormalised) of each group member's
+    heads over its group's run, as rows ``slot * H + head`` of three arrays
+    ([.., 128], [.., 128], [.., D]).  Rows of places no sequence holds are
+    never read."""
+    B, H, Dq = q.shape
+    G = SHARED_SEQUENCES
+    C = plan["shared"][-1].shape[0] // G
+
+    def q_index(i):
+        def index(g, table_ref, row_ref, run_ref, members_ref):
+            return (members_ref[g * G + i], 0, 0)
+
+        return index
+
+    def of_group(g, *_):
+        return (g, 0)
+
+    widths = (128, 128, Dq)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1 + len(plan["shared"]),
+        grid=(plan["shared_steps"],),
+        in_specs=[
+            pl.BlockSpec((1, H, Dq), q_index(i), memory_space=pltpu.VMEM)
+            for i in range(G)
+        ]
+        + [pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=[
+            pl.BlockSpec((G * H, width), of_group, memory_space=pltpu.VMEM)
+            for width in widths
+        ],
+        scratch_shapes=[
+            pltpu.VMEM((2, blocks_per_step) + kv_block, kv_layer.dtype),
+            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.VMEM((G * H, kv_block[-2]), jnp.float32),
+        ],
+    )
+    return pl.pallas_call(
+        functools.partial(
+            _shared_kernel, blocks_per_step=blocks_per_step, sequences=G,
+            **statics,
+        ),
+        out_shape=[
+            jax.ShapeDtypeStruct((C * G * H, width), jnp.float32)
+            for width in widths
+        ],
+        grid_spec=grid_spec,
+        interpret=interpret,
+    )(
+        block_table.astype(jnp.int32),
+        *plan["shared"],
+        *([q] * G),
+        kv_layer,
+    )

@@ -306,6 +306,48 @@ def test_the_three_programs_serve_the_reference_tokens_and_report_spans():
         for a in load)
 
 
+def test_a_decode_step_counts_the_blocks_its_attention_reads():
+    """Two sequences on one cached prompt, through `jit_programs` with the
+    paged kernel (interpreted): the step's `attention.read` span, read at the
+    next decode call, carries what the kernel's plan counted on the device,
+    and that is what the pod's own tables say: the four shared blocks once
+    and each sequence's other three, against seven a sequence."""
+    shapes = {"miss": (96,), "hit": (64, 32), "decode": (2,), "max_blocks": 9}
+    programs = jit_programs(lfm2moe, CFG, shapes, interpret=True)
+    pod = Pod("pod-0", lfm2moe, CFG, 40)
+    doc = tokens_of(64, 1)
+    prompts = [np.concatenate((doc, tokens_of(32, 3))),
+               np.concatenate((doc, tokens_of(32, 2)))]
+    ids, _ = pod.alloc(6)
+    programs["miss"](PARAMS, prompts[0][None], pod.kv, np.asarray(ids)[None])
+    pod.cached.update(zip(hashes_of(prompts[0]), ids))
+    more, _ = pod.alloc(2)
+    programs["hit"](PARAMS, prompts[1][None, 64:], pod.kv,
+                    np.asarray(ids[:4] + more)[None])
+    own, _ = pod.alloc(2)
+    table = np.zeros((2, 9), np.int32)
+    table[0, :7], table[1, :7] = ids + own[:1], ids[:4] + more + own[1:]
+    nxt = np.asarray([reference(tuple(p))[-1].argmax() for p in prompts])
+    TRACER.configure(sample_rate=1.0, ring_size=64)
+    try:
+        out, kv = programs["decode"](PARAMS, nxt, pod.kv, table,
+                                     np.asarray([97, 97]))
+        seqs = [tuple(p) + (int(t),) for p, t in zip(prompts, nxt)]
+        assert [int(t) for t in np.asarray(out)[0]] == [
+            reference(s)[-1].argmax() for s in seqs]
+        assert "attention_read" not in pod.kv.arrays  # no part of the pools
+        programs["decode"](PARAMS, np.asarray(out)[0].astype(int), pod.kv,
+                           table, np.asarray([98, 98]))
+        rows, dropped = TRACER.recorder.export()
+    finally:
+        TRACER.configure(sample_rate=0.0, ring_size=64)
+    read = [r["attrs"] for r in rows if r["span"] == "attention.read"]
+    shared = sum(a == b for a, b in zip(*table)) - 2  # not the empty columns
+    assert shared == 4 and dropped == 0
+    assert read == [{"read_blocks": shared + 2 * (7 - shared),
+                     "walked_blocks": 2 * 7}]
+
+
 def test_pallas_decode_in_the_step_agrees_with_the_gather():
     """The paged kernel (interpreted here) at this family's head size, in
     the step, against the XLA gather and the reference."""
@@ -580,7 +622,12 @@ def test_a_float8_pass_fails_the_tolerance_the_decode_comparison_holds():
 # holds the router and the expert products `models/afmoe.py` had, and
 # `models/pod.py` a third kind of table: the `afmoe` and `llama` programs
 # must trace to the text they had.  A PR that changes one of those programs
-# on purpose reads its digest anew.
+# on purpose reads its digest anew: `llama.decode.True` was read anew in PR 34
+# (the paged kernel finds the shared prefixes from the table, reads each once
+# in a shared pass and walks the rest on a grid of listed steps);
+# `llama.decode.False` stands, because off the TPU and uninterpreted the step
+# keeps the XLA gather and makes no plan, and so do the six `afmoe.*` and the
+# four `llama.miss/hit.*`.
 TEXT_AT_PR_32 = {
     "afmoe.miss.False": "e6f04f9f13e0aa70", "afmoe.hit.False": "83fd523e8393f5b3",
     "afmoe.decode.False": "50748779141b4cb4",
@@ -589,7 +636,7 @@ TEXT_AT_PR_32 = {
     "llama.miss.False": "90c7da6d6fb5ae9d", "llama.hit.False": "364331e0e1475faa",
     "llama.decode.False": "53c6a3b76efc093d",
     "llama.miss.True": "90c7da6d6fb5ae9d", "llama.hit.True": "364331e0e1475faa",
-    "llama.decode.True": "6f7b4f2281a159eb",
+    "llama.decode.True": "7fe2bee41cea2f57",
 }
 
 

@@ -12,6 +12,7 @@ from llm_d_kv_cache_manager_tpu.ops.paged_attention import paged_attention
 from llm_d_kv_cache_manager_tpu.ops.paged_decode_pallas import (
     BLOCKS_PER_STEP,
     paged_decode_attention_pallas,
+    shared_prefix_plan,
 )
 
 BS = 16
@@ -157,3 +158,121 @@ def test_other_heads_rows_do_not_leak():
     others = np.asarray([h for h in range(8) if h // groups != 2])
     close(got[:, others], ref[:, others])
     assert float(jnp.min(got[:, 2 * groups : 3 * groups])) > 900.0
+
+
+# ------------------------------------------------------ the shared-prefix pass
+
+SCRATCH = 0  # the block an idle slot's table names in every column
+
+
+def make_shared_case(key, H, Hkv, D, max_blocks, prompts, sequences,
+                     packed=False, layers=1, layer=0):
+    """Tables as `Pod.cached_prefix` makes them: the sequences of one prompt
+    have its blocks' ids at the head of their rows and blocks of their own
+    behind.  prompts: blocks of each shared prompt; sequences: (prompt or
+    None, context length), or None for an idle slot (context 1, every column
+    the scratch block).  Ids are drawn in no order; with `layers` the pool is
+    the merged one of `llama._scan_layers` and the table is `layer`'s."""
+    kq, kkv, kt = jax.random.split(key, 3)
+    B = len(sequences)
+    own = [0 if s is None else -(-s[1] // BS) - (prompts[s[0]] if s[0]
+           is not None else 0) for s in sequences]
+    N = 1 + sum(prompts) + sum(own)
+    ids = list(1 + np.asarray(jax.random.permutation(kt, N - 1)))
+    shared = [[ids.pop() for _ in range(n)] for n in prompts]
+    table, ctx = [], []
+    for s, n in zip(sequences, own):
+        head = [] if s is None or s[0] is None else shared[s[0]]
+        row = head + [ids.pop() for _ in range(n)]
+        table.append(row + [SCRATCH] * (max_blocks - len(row)))
+        ctx.append(1 if s is None else s[1])
+    q = jax.random.normal(kq, (B, H, D), jnp.float32).astype(jnp.bfloat16)
+    kv = jax.random.normal(
+        kkv, (layers * N, 2, BS, Hkv, D), jnp.float32).astype(jnp.bfloat16)
+    table = jnp.asarray(table, jnp.int32)
+    ctx = jnp.asarray(ctx, jnp.int32)
+    ref = paged_attention(q, kv[layer * N:(layer + 1) * N], table, ctx)
+    if packed:  # K in the lower half of a row's lanes, V in the upper
+        kv = jnp.concatenate((kv[:, 0], kv[:, 1]), axis=-1)
+    return q, kv, table + layer * N, ctx, ref
+
+
+SHARED_CASES = {
+    # name: (prompts' blocks, sequences, blocks a step of the walk and of
+    # the shared pass, layers and layer)
+    # Sets of 3 and 2 in no slot order, a sequence on a prompt of its own
+    # with nobody to share it, one with no prompt, an idle slot.
+    "two_uneven_sets_a_loner_and_an_idle_slot": (
+        [6, 4, 5], [(0, 130), (1, 100), None, (0, 97), (2, 90), (1, 70),
+                    (None, 40), (0, 113)], 4, 2, 1, 0),
+    # The first member's context ends in block 5, the others' in 7 and 9.
+    "contexts_end_in_different_blocks": (
+        [5], [(0, 88), (0, 125), (0, 150)], 2, 2, 1, 0),
+    # 7 shared blocks, 4 a step of the shared pass: its second step holds 3.
+    "run_not_a_multiple_of_the_shared_step": (
+        [7], [(0, 160), (0, 129)], 4, 4, 1, 0),
+    # The second sharer writes position 64, the first of the block behind
+    # the run of 4; the third's context ends on the run's last position, so
+    # that block is its write position's and the set's run is 3.
+    "write_position_in_the_block_after_the_run": (
+        [4], [(0, 100), (0, 65)], 4, 2, 1, 0),
+    "write_position_inside_the_prompts_last_block": (
+        [4], [(0, 100), (0, 65), (0, 64)], 4, 2, 1, 0),
+    # Ten on one prompt: a group of eight and one of two; nine on another:
+    # eight, and the ninth walks alone.
+    "sets_larger_than_a_group": (
+        [3, 2], [(0, 50 + i) for i in range(10)]
+        + [(1, 40 + 3 * i) for i in range(9)], 4, 2, 1, 0),
+    "nobody_shares": ([], [(None, 70), (None, 33), None], 4, 2, 1, 0),
+    "merged_pool_with_a_layer_offset": (
+        [5], [(0, 100), (None, 50), (0, 90)], 4, 2, 3, 2),
+}
+
+
+@pytest.mark.parametrize("slots", ("llama", "packed"))
+@pytest.mark.parametrize("name", SHARED_CASES)
+def test_shared_prefix_pass_matches_xla_gather(name, slots):
+    prompts, sequences, step, shared_step, layers, layer = SHARED_CASES[name]
+    H, Hkv, D = (16, 8, 128) if slots == "llama" else (8, 4, 64)
+    q, kv, table, ctx, ref = make_shared_case(
+        jax.random.PRNGKey(7), H, Hkv, D, 12, prompts, sequences,
+        packed=slots == "packed", layers=layers, layer=layer)
+    got = paged_decode_attention_pallas(
+        q, kv, table, ctx, interpret=True, blocks_per_step=step,
+        shared_blocks_per_step=shared_step, packed=slots == "packed")
+    close(got, ref)
+
+
+def test_the_plan_finds_the_sets_and_counts_what_is_read():
+    """What `shared_prefix_plan` hands the kernels for the first case's
+    tables: who resumes from which place of the shared pass's results, the
+    two grids, and the step's blocks read against a walk of every table."""
+    prompts, sequences, step, _, _, _ = SHARED_CASES[
+        "two_uneven_sets_a_loner_and_an_idle_slot"]
+    _, _, table, ctx, _ = make_shared_case(
+        jax.random.PRNGKey(7), 16, 8, 128, 12, prompts, sequences)
+    plan = shared_prefix_plan(table, ctx, block_size=BS,
+                              blocks_per_step=step)
+    slot, seq, first, flags = (np.asarray(a) for a in plan["walk"])
+    # prompt 0: rows 0, 3, 7 (group 0, run 6); prompt 1: rows 1, 5 (group 1,
+    # run 4); rows 2 (idle), 4 (alone on its prompt) and 6 share nothing.
+    assert list(slot) == [0, 8, 0, 1, 0, 9, 0, 2]
+    n = int(plan["walk_steps"])
+    blocks = [-(-c // BS) for c in np.asarray(ctx)]
+    rest = [blocks[0] - 6, blocks[1] - 4, 1, blocks[3] - 6, blocks[4],
+            blocks[5] - 4, blocks[6], blocks[7] - 6]
+    assert n == sum(-(-r // step) for r in rest)
+    assert list(seq[:n]) == sorted(seq[:n])
+    opened = {int(b): int(f) for b, f, g in zip(seq[:n], first[:n], flags[:n])
+              if g & 5}
+    assert opened == {0: 6, 1: 4, 2: 0, 3: 6, 4: 0, 5: 4, 6: 0, 7: 6}
+    resumed = {int(b) for b, g in zip(seq[:n], flags[:n]) if g & 4}
+    assert resumed == {0, 1, 3, 5, 7}
+    assert sum(1 for g in flags[:n] if g & 2) == len(sequences)
+    row, run, members = (np.asarray(a) for a in plan["shared"])
+    assert int(plan["shared_steps"]) == 2
+    assert list(row[:2]) == [0, 1] and list(run[:2]) == [6, 4]
+    assert list(members[:16]) == [0, 3, 7, 0, 0, 0, 0, 0,
+                                  1, 5, 1, 1, 1, 1, 1, 1]
+    assert int(plan["read_blocks"]) == 6 + 4 + sum(rest)
+    assert int(plan["walked_blocks"]) == sum(blocks)
