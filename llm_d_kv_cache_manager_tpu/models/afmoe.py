@@ -140,14 +140,12 @@ def cache_policy(cfg: AfmoeConfig) -> dict:
     """What models/pod.py needs to know of this family's cache: the second
     group of slots (None for a model without window layers: the pod is then
     the plain one-group prefix cache) and the order of reuse."""
-    spec = cache_groups(cfg)["window"]
-    window = spec.num_layers and {
+    groups = cache_groups(cfg)
+    window = groups["window"].num_layers and {
         "slots": cfg.window_slots,
         "store_blocks": cfg.window_store_blocks,
-        "window": spec.window,
-        "block_size": spec.block_size,
     }
-    return {"window": window or None, "protect_asked": True}
+    return {"specs": groups, "window": window or None, "protect_asked": True}
 
 
 def new_pool(cfg: AfmoeConfig, pool_blocks: int) -> dict:
@@ -330,7 +328,7 @@ def _attn_out(attn, g, lp):
                       preferred_element_type=jnp.float32)
 
 
-def _dense_attention(q, k, v, q_offset, window):
+def dense_attention(q, k, v, q_offset, window):
     B, Tq, H, D = q.shape
     Tk, Hkv = k.shape[1], k.shape[2]
     qf = q.astype(jnp.float32).reshape(B, Tq, Hkv, H // Hkv, D) * D**-0.5
@@ -345,11 +343,11 @@ def _dense_attention(q, k, v, q_offset, window):
     return out.reshape(B, Tq, H, D).astype(q.dtype)
 
 
-def _prefill_attention(q, k, v, cfg, q_offset, window, interpret):
+def prefill_attention(q, k, v, cfg, q_offset, window, interpret):
     """Causal attention of a prefill, banded where ``window`` is given: the
     Pallas flash kernel at serving lengths, one dense product below."""
     if k.shape[1] < FLASH_MIN_LEN:
-        return _dense_attention(q, k, v, q_offset, window)
+        return dense_attention(q, k, v, q_offset, window)
     if not flash_pallas.fits_vmem(
         k.shape[1], k.shape[-1], jnp.dtype(k.dtype).itemsize
     ):
@@ -454,7 +452,7 @@ def prefill_paged(
         sliding = kind == "window"
         h = _rms_norm(x, lp["ln_in"], cfg.rms_eps, lp["wq"].dtype)
         q, k, v, g = _qkvg(h, lp, positions, cfg, sliding)
-        attn = _prefill_attention(
+        attn = prefill_attention(
             q, k, v, cfg, 0, cfg.window if sliding else None, interpret
         )
         x = _attn_block(x, attn, g, lp, cfg)
@@ -521,7 +519,7 @@ def prefill_continue(
             pre_k, pre_v = _gather_prefix(
                 full[i], tables["full"][:, :npre], k.dtype
             )
-        attn = _prefill_attention(
+        attn = prefill_attention(
             q,
             jnp.concatenate((pre_k, k), axis=1),
             jnp.concatenate((pre_v, v), axis=1),

@@ -32,11 +32,16 @@ class KVGroupSpec:
     number of positions a layer of the group still reads (None: all).
     A slot need not be K/V: with ``state_shape`` it is, a layer, one array
     of that shape (a recurrent layer's state after the last position of a
-    logical block), its bytes do not grow with the block, and
-    ``stride_blocks`` says which block boundaries keep one
-    (``snapshot_blocks``).  Block bytes, pool shapes and the scatter's
-    geometry are read from here by the pool below, by the pod's cache
-    (models/pod.py) and by each family's model step."""
+    logical block), or several, each with its own type (``state_shape`` as
+    ``((shape, dtype), ...)``: a state-space layer keeps its convolution's
+    inputs in the serving type and its scan's state in float32); its bytes
+    do not grow with the block, and ``stride_blocks`` says which block
+    boundaries keep one (``snapshot_blocks``).  ``readers`` is how many
+    layers read a slot where that is more than the ``num_layers`` whose K/V
+    it holds (a cache that later layers attend over without one of their
+    own).  Block bytes, pool shapes and the scatter's geometry are read from
+    here by the pool below, by the pod's cache (models/pod.py) and by each
+    family's model step."""
 
     num_layers: int
     block_size: int
@@ -56,18 +61,48 @@ class KVGroupSpec:
     # make the pool's slot axis the minor one, and every step that writes a
     # slot would re-lay-out the whole pool around it.
     packed: bool = False
-    state_shape: Optional[Tuple[int, ...]] = None
+    # A slot as [2, block * Hkv, Dh]: a position's heads as neighbouring rows
+    # (the first form with its two middle axes merged, which moves nothing).
+    # For a number of KV heads that is no multiple of 8 (10 pair-wise heads in
+    # models/phi4flash.py): as the last axis but one, the chip pads it to its
+    # tile (10 -> 16 rows) and re-lays-out the whole pool around every step.
+    rows: bool = False
+    state_shape: Optional[tuple] = None
     stride_blocks: Optional[int] = None
+    readers: Optional[int] = None
+
+    @property
+    def num_readers(self) -> int:
+        """Layers that read a slot: those whose K/V it holds, unless
+        ``readers`` says more."""
+        return self.num_layers if self.readers is None else self.readers
+
+    @property
+    def read_nbytes(self) -> int:
+        """Bytes of a slot that one step's layers read: the slot's bytes
+        once for each reader of each layer it holds."""
+        return self.block_nbytes * self.num_readers // self.num_layers
+
+    @property
+    def state_parts(self) -> tuple:
+        """A state slot's arrays of one layer as ((shape, dtype), ...), a
+        pool's being ``(slots,) + shape`` each: the one place
+        ``block_nbytes``, ``layer_shape`` and a family's ``new_pool`` read
+        them from."""
+        if self.state_shape is None:
+            raise ValueError("a K/V group's slot is not a state")
+        if isinstance(self.state_shape[0], int):
+            return ((tuple(self.state_shape), self.dtype),)
+        return tuple((tuple(shape), dtype) for shape, dtype in self.state_shape)
 
     @property
     def block_nbytes(self) -> int:
         """Bytes of one slot: K and V of ``block_size`` positions over
         the group's layers, or the layers' states."""
         if self.state_shape is not None:
-            return (
-                self.num_layers
-                * math.prod(self.state_shape)
-                * jnp.dtype(self.dtype).itemsize
+            return self.num_layers * sum(
+                math.prod(shape) * jnp.dtype(dtype).itemsize
+                for shape, dtype in self.state_parts
             )
         return (
             self.num_layers
@@ -79,12 +114,17 @@ class KVGroupSpec:
         )
 
     def layer_shape(self, num_blocks: int) -> tuple:
-        """One layer's share of a pool of ``num_blocks`` slots."""
+        """One layer's share of a pool of ``num_blocks`` slots (a state in
+        several arrays has a shape each: ``state_parts``)."""
         if self.state_shape is not None:
-            return (num_blocks,) + tuple(self.state_shape)
+            ((shape, _),) = self.state_parts
+            return (num_blocks,) + shape
         if self.packed:
             return (num_blocks, self.block_size, self.num_kv_heads,
                     2 * self.head_dim)
+        if self.rows:
+            return (num_blocks, 2, self.block_size * self.num_kv_heads,
+                    self.head_dim)
         inner = (
             (self.num_kv_heads, self.block_size)
             if self.heads_first

@@ -66,10 +66,10 @@ from jax import lax
 
 from llm_d_kv_cache_manager_tpu.models import moe_serve
 from llm_d_kv_cache_manager_tpu.models.afmoe import (
-    _prefill_attention,
     _rms_norm,
     _rope,
     _swiglu,
+    prefill_attention,
 )
 from llm_d_kv_cache_manager_tpu.models.kv_cache_pool import KVGroupSpec
 from llm_d_kv_cache_manager_tpu.ops import paged_decode_pallas
@@ -152,12 +152,8 @@ def cache_policy(cfg: Lfm2MoeConfig) -> dict:
     group (None for a model without conv layers: the pod is then the plain
     one-group prefix cache) and the order of reuse."""
     groups = cache_groups(cfg)
-    state = groups["state"].num_layers and {
-        "slots": cfg.state_slots,
-        "spec": groups["state"],
-        "kv_block_nbytes": groups["full"].block_nbytes,
-    }
-    return {"state": state or None, "protect_asked": True}
+    state = groups["state"].num_layers and {"slots": cfg.state_slots}
+    return {"specs": groups, "state": state or None, "protect_asked": True}
 
 
 def new_pool(cfg: Lfm2MoeConfig, pool_blocks: int) -> dict:
@@ -455,7 +451,7 @@ def _prefill(params, tokens, pools, tables, prefix_len, cfg, interpret):
                     full[i], tables["full"][:, :npre], k.dtype)
                 keys = jnp.concatenate((pre_k, k), axis=1)
                 values = jnp.concatenate((pre_v, v), axis=1)
-            attn = _prefill_attention(q, keys, values, cfg, prefix_len, None,
+            attn = prefill_attention(q, keys, values, cfg, prefix_len, None,
                                       interpret)
             y = _attn_out(attn, lp)
             full[i] = _scatter_blocks(

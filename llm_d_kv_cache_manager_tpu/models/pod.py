@@ -39,8 +39,8 @@ each the sliding layers' K/V of one logical block, kept by these rules:
   "servable from here" and ``BlockRemoved`` "no longer".
 
 A state group (a model whose recurrent layers keep a state that is not
-addressable by position: ``cache_policy`` names ``"state"`` in place of
-``"window"``).  A slot of it holds those layers' state after the last position
+addressable by position: ``cache_policy`` names ``"state"``, in place of
+``"window"`` or beside it).  A slot of it holds those layers' state after the last position
 of one logical block, and a prefix of n blocks can be continued only where the
 state after block n - 1 was kept:
 
@@ -68,12 +68,43 @@ state after block n - 1 was kept:
   of (the benchmark's set-up requests start ``done`` tokens into an answer) is
   still handed a slot: what it holds is whatever the slot last held.
 
+Three groups (a model with window layers and recurrent layers:
+``cache_policy`` names both, models/phi4flash.py).  ``Pod.groups`` lists the
+groups beside the full one, and every rule above holds for each:
+
+- **hit rule at one length**: ``cached_prefix`` returns n blocks only if the
+  full group holds all n, the window group the last ``need`` of them and the
+  state group the snapshot of block n - 1; else the longest n that every
+  group admits (each group's ``admits`` answers for every prefix at once, so
+  the search over the kept boundaries is one ``and``).  ``half_hits`` counts
+  the whole prefixes the window rule alone refused, ``resume_short_blocks``
+  what the length all groups agreed on gave up;
+- **coupled eviction from either group**: a reused slot of a block that is
+  still cached takes the chain's tail out of the full group and out of the
+  other group, once, and the hashes ride in ``alloc``'s list;
+- ``touch``, ``hold``, ``alloc`` and ``tables`` go through every group; a
+  program call is handed the groups' tables side by side (``full``,
+  ``window``, ``first``, ``state...``), a decode call's integers packed into
+  one host argument whatever the number of groups;
+- a window group may be ``lazy``: a block takes its slot when a program call
+  first names it in a window table, not when ``alloc`` hands it out.  An
+  answer of thousands of tokens is handed all its blocks at admission, and
+  the last ``store_blocks`` of them would each hold a slot until the answer
+  reaches them;
+- ``specs`` (of the policy): the family's ``cache_groups``, which every
+  group reads its window, its block and its bytes from.  Where the full
+  group's spec names ``readers`` (later layers attend over one layer's K/V
+  without a cache of their own) the spans price a block by them
+  (``full_readers``, ``full_read_blocks``, ``kv_bytes``); nothing else in
+  the pod depends on it.
+
 Positions are never told to a pod; they are known at each program call (a
 table is in chain order, decode brings ``context_len``), so ``jit_programs``
-returns plain functions that build the second group's tables on the host,
-record spans (``kvpool.window`` and ``kv.read``, or ``kvpool.state`` and
-``state.read``; ``moe.expert_load``, ``attention.read``) and call the inner
-compiled programs, which keep the names the trace reduction looks for.
+returns plain functions that build the groups' tables on the host, record
+spans (``kvpool.window`` and ``kv.read``, ``kvpool.state`` and
+``state.read``, each group its own; ``moe.expert_load``, ``attention.read``)
+and call the inner compiled programs, which keep the names the trace
+reduction looks for.
 """
 
 from __future__ import annotations
@@ -262,6 +293,22 @@ class SlotGroup:
         self.stamp[slot] = RESERVED
         self.counts["taken"] += 1
 
+    def _unnamed(self, keep: np.ndarray) -> np.ndarray:
+        """Per slot: its block is none of `keep` (the call at hand's)."""
+        named = np.zeros(self.pod.pool_blocks, bool)
+        named[keep.ravel()] = True
+        return ~named[np.maximum(self.block_of, 0)]
+
+    def _ensure(self, ids: np.ndarray, keep: np.ndarray) -> None:
+        """The blocks of `ids` that hold no slot take one (a block just
+        handed out has none), reclaiming none of `keep`'s.  What a reuse
+        evicts from the full group waits for the next `alloc`."""
+        missing = np.unique(ids[self.slot_of[ids] < 0])
+        if len(missing) > len(self.free):
+            self.pod.unpublished += self.reclaim(len(missing), keep)
+        for bid in missing:
+            self._assign(int(bid))
+
     def _reclaim(self, released: np.ndarray, target: int, what: str) -> list:
         """Free slots until `target` are free, of those `released` marks:
         never-asked before asked, coldest first; a cached block goes with
@@ -294,49 +341,60 @@ class WindowGroup(SlotGroup):
 
     span, read_span = "kvpool.window", "kv.read"
 
-    def __init__(self, pod: "Pod", slots: int, store_blocks: int, window: int,
-                 block_size: int) -> None:
+    def __init__(self, pod: "Pod", slots: int, store_blocks: int,
+                 lazy: bool = False) -> None:
         super().__init__(pod, slots,
                          ("taken", "released", "reclaimed", "half_hits"))
-        self.window, self.block = window, block_size
-        self.need = -(-(window - 1) // block_size)  # blocks behind a boundary
+        self.spec = pod.specs["window"]
+        self.window, self.block = self.spec.window, self.spec.block_size
+        # blocks behind a boundary
+        self.need = -(-(self.window - 1) // self.block)
         self.width = self.need + 1  # blocks a decode step's window can span
         self.store = store_blocks
+        # `lazy`: a block takes its slot when a program call first names it in
+        # a window table, not when `alloc` hands it out (an answer of
+        # thousands of tokens is handed its blocks at once, and its last
+        # `store` would each hold a slot until the answer reaches them).
+        self.lazy = lazy
 
     # -- the rules ------------------------------------------------------
 
-    def servable(self, ids: list, asked: int) -> int:
-        """The longest prefix of `ids` (cached in the full group, chain
-        order) whose last `need` blocks all hold a window slot."""
-        if not ids:
-            return 0
-        have = self.slot_of[np.asarray(ids)] >= 0
+    def admits(self, ids: list) -> np.ndarray:
+        """For each prefix of `ids` (cached in the full group, chain order),
+        whether its last `need` blocks (all if fewer) hold a window slot."""
+        have = self.slot_of[np.asarray(ids, np.int64)] >= 0
         n = np.arange(1, len(ids) + 1)
         run = n - np.maximum.accumulate(np.where(have, 0, n))
-        good = n[run >= np.minimum(n, self.need)]
-        m = int(good[-1]) if len(good) else 0
-        if len(ids) == asked and m < asked:
+        return run >= np.minimum(n, self.need)
+
+    def count_ask(self, own: np.ndarray, asked: int, m: int) -> None:
+        """An ask of `asked` blocks, all found in the full group, that this
+        group's rule alone refuses, is a half hit."""
+        if len(own) and len(own) == asked and not own[-1]:
             self.counts["half_hits"] += 1
-        return m
 
     def take(self, ids: list) -> list:
         """Slots for the blocks one `alloc` hands out (the last `store` of
-        them); returns the hashes a reuse evicted from the full group."""
+        them; none yet where the group is `lazy`); returns the hashes a
+        reuse evicted from the full group."""
         for bid in ids:
             self.forget(bid)
-        want = ids[-self.store:]
+        want = [] if self.lazy else ids[-self.store:]
         evicted = self.reclaim(len(want)) if len(self.free) < len(want) else []
         for bid in want:
             self._assign(bid)
         return evicted
 
-    def reclaim(self, target: int) -> list:
+    def reclaim(self, target: int, keep=None) -> list:
         """Free slots until `target` are free: released ones, never-asked
-        before asked, coldest first; a cached block goes with its tail."""
+        before asked, coldest first; a cached block goes with its tail.
+        `keep`: blocks the call at hand names, which stay."""
         pod = self.pod
         block = np.maximum(self.block_of, 0)
         released = (pod.refs[block] == 0) | (
             ~pod.hashed[block] & (self.stamp < self.live_tick))
+        if keep is not None:
+            released &= self._unnamed(keep)
         return self._reclaim(released, target, "window")
 
     # -- one table a program call ----------------------------------------
@@ -356,6 +414,8 @@ class WindowGroup(SlotGroup):
         kept = min(table.shape[1], self.store)
         for row in table:
             self.link(row)
+        if self.lazy:
+            self._ensure(table[:, table.shape[1] - kept:], table)
         return {"full": table,
                 "window": self._slots(table[:, table.shape[1] - kept:],
                                       "a miss prefill")}
@@ -364,6 +424,8 @@ class WindowGroup(SlotGroup):
         seen = min(prefix_blocks, self.need)
         for row in table:
             self.link(row[max(prefix_blocks - 1, 0):])
+        if self.lazy:
+            self._ensure(table[:, prefix_blocks:], table)
         return {"full": table,
                 "window": self._slots(table[:, prefix_blocks - seen:],
                                       "a hit prefill")}
@@ -406,23 +468,24 @@ class StateGroup(SlotGroup):
 
     span, read_span = "kvpool.state", "state.read"
 
-    def __init__(self, pod: "Pod", slots: int, spec, kv_block_nbytes: int
-                 ) -> None:
+    def __init__(self, pod: "Pod", slots: int) -> None:
         super().__init__(pod, slots, ("taken", "released", "reclaimed",
                                       "resume_short_blocks", "asked_blocks"))
-        self.spec, self.block = spec, spec.block_size
-        self.kv_block_nbytes = kv_block_nbytes
+        self.spec = pod.specs["state"]
+        self.block = self.spec.block_size
+        # K/V bytes a step reads of a block of context (`state.read`)
+        self.kv_read_nbytes = pod.specs["full"].read_nbytes
 
-    def servable(self, ids: list, asked: int) -> int:
-        """The longest prefix of `ids` (cached in the full group, chain
-        order) whose last block holds a snapshot."""
+    def admits(self, ids: list) -> np.ndarray:
+        """For each prefix of `ids` (cached in the full group, chain order),
+        whether its last block holds a snapshot."""
+        return self.slot_of[np.asarray(ids, np.int64)] >= 0
+
+    def count_ask(self, own: np.ndarray, asked: int, m: int) -> None:
+        """What an ask that found `len(own)` blocks in the full group gave
+        up by resuming at `m` (the pod's one length over all its groups)."""
         self.counts["asked_blocks"] += asked
-        if not ids:
-            return 0
-        have = np.flatnonzero(self.slot_of[np.asarray(ids)] >= 0)
-        m = int(have[-1]) + 1 if len(have) else 0
-        self.counts["resume_short_blocks"] += len(ids) - m
-        return m
+        self.counts["resume_short_blocks"] += len(own) - m
 
     def take(self, ids: list) -> list:
         """Blocks that `alloc` hands out start with no slot: which of them
@@ -435,22 +498,13 @@ class StateGroup(SlotGroup):
     def reclaim(self, target: int, keep: np.ndarray) -> list:
         """Free slots until `target` are free, of blocks no live sequence
         references and the call at hand does not name (`keep`)."""
-        pod = self.pod
         block = np.maximum(self.block_of, 0)
-        named = np.zeros(pod.pool_blocks, bool)
-        named[keep.ravel()] = True
-        return self._reclaim((pod.refs[block] == 0) & ~named[block], target,
-                             "state")
+        return self._reclaim((self.pod.refs[block] == 0) & self._unnamed(keep),
+                             target, "state")
 
     def _slots(self, ids: np.ndarray) -> np.ndarray:
-        """The slots of `ids`, stamped; a block without one takes one (a
-        block just handed out has none).  What a reuse evicts from the full
-        group waits for the next `alloc`."""
-        missing = np.unique(ids[self.slot_of[ids] < 0])
-        if len(missing) > len(self.free):
-            self.pod.unpublished += self.reclaim(len(missing), ids)
-        for bid in missing:
-            self._assign(int(bid))
+        """The slots of `ids`, stamped; a block without one takes one."""
+        self._ensure(ids, ids)
         slots = self.slot_of[ids]
         self.tick += 1
         self.stamp[slots] = self.tick
@@ -498,7 +552,7 @@ class StateGroup(SlotGroup):
                 {"state_slots_live": len(self.block_of) - len(self.free),
                  "blocks_live": pod.pool_blocks - len(pod.free),
                  "state_bytes": int(live.sum()) * 2 * self.spec.block_nbytes,
-                 "kv_bytes": blocks * self.kv_block_nbytes})
+                 "kv_bytes": blocks * self.kv_read_nbytes})
 
 
 class Pod:
@@ -518,16 +572,16 @@ class Pod:
         self.last_ask: frozenset = frozenset()  # the last missed ask's hashes
         self.cached = Cached(self)
         self.unpublished: list[int] = []  # evicted outside `alloc`
+        # the family's `cache_groups`: what a slot of each group holds, how
+        # many layers read it; the groups and the spans read them from here
+        self.specs = policy.get("specs") or {}
         self.window = (WindowGroup(self, **policy["window"])
                        if policy.get("window") else None)
         self.state = (StateGroup(self, **policy["state"])
                       if policy.get("state") else None)
-        if self.window is not None and self.state is not None:
-            raise NotImplementedError(
-                f"{name}: a window group and a state group in one pod (no "
-                "family has both; their hit rules would have to be met at "
-                "one length)")
-        self.second = self.window or self.state  # the group beside the full
+        # the groups beside the full one; each keeps the chains (they are
+        # told the same links), so any of them answers `tail`
+        self.groups = [g for g in (self.window, self.state) if g is not None]
         self.pending_load = None  # (a decode step's device counts, its tokens)
 
     def cached_prefix(self, hashes) -> list[int]:
@@ -536,8 +590,14 @@ class Pod:
             if h not in self.cached:
                 break
             ids.append(self.cached[h])
-        if self.second is not None:
-            ids = ids[:self.second.servable(ids, len(hashes))]
+        if self.groups:
+            # one length for all groups: the longest prefix each admits
+            own = [g.admits(ids) for g in self.groups]
+            both = np.flatnonzero(np.logical_and.reduce(own))
+            m = int(both[-1]) + 1 if len(both) else 0
+            for g, admitted in zip(self.groups, own):
+                g.count_ask(admitted, len(hashes), m)
+            ids = ids[:m]
         if len(ids) < len(hashes):
             self.last_ask = frozenset(hashes)
         return ids
@@ -562,8 +622,10 @@ class Pod:
             ids.append(bid)
         if len(ids) < n:
             raise RuntimeError(f"{self.name}: pool exhausted by live sequences")
-        if self.second is not None:
-            evicted += self.second.take(ids) + self.unpublished
+        if self.groups:
+            for group in self.groups:
+                evicted += group.take(ids)
+            evicted += self.unpublished
             self.unpublished = []
         return ids, evicted
 
@@ -571,9 +633,10 @@ class Pod:
         """Out of the full group: a block and every cached block behind it in
         a chain.  Returns their hashes; their ids go back to the free list."""
         out = []
-        for x in self.second.tail(bid):
+        for x in self.groups[0].tail(bid):
             h = self.cached.hash_of.get(x)
-            self.second.forget(x)
+            for group in self.groups:
+                group.forget(x)
             if h is None:
                 continue
             if self.refs[x]:  # a sequence holds its whole chain or none of it
@@ -589,32 +652,44 @@ class Pod:
         np.add.at(self.refs, ids, by)
         if by > 0 or not len(ids):
             return
-        if self.second is not None:  # a sequence's own blocks: no hash to keep
+        if self.groups:  # a sequence's own blocks: no hash to keep
             idle = ids[self.refs[ids] == 0]
             for bid in idle[~self.hashed[idle]]:
-                self.second.drop(bid)
+                for group in self.groups:
+                    group.drop(bid)
 
     def tables(self, kind: str, table, context_len=None, prefix_blocks=0):
         """What a program call is handed as its table: the logical table
-        alone with one group, both groups' tables with two."""
+        alone with one group, every group's tables with more."""
         table = np.asarray(table, np.int32)
-        group = self.second
-        if group is None:
+        if not self.groups:
             return table
-        with span(group.span) as s:
-            if kind == "decode":
-                tables, read = group.decode_tables(
-                    table, np.asarray(context_len, np.int64))
-            else:
-                tables, read = (group.miss_tables(table) if kind == "miss"
-                                else group.hit_tables(table, prefix_blocks)
-                                ), None
-            s.set_attr("calls", 1)
-            for key, value in group.counts.items():
-                s.set_attr(key, value - group.reported[key])
-            group.reported = dict(group.counts)
-        if read is not None:
-            with span(group.read_span) as s:
+        tables, reads = {}, {}
+        for group in self.groups:
+            with span(group.span) as s:
+                if kind == "decode":
+                    more, reads[group.read_span] = group.decode_tables(
+                        table, np.asarray(context_len, np.int64))
+                else:
+                    more = (group.miss_tables(table) if kind == "miss"
+                            else group.hit_tables(table, prefix_blocks))
+                tables.update(more)
+                s.set_attr("calls", 1)
+                for key, value in group.counts.items():
+                    s.set_attr(key, value - group.reported[key])
+                group.reported = dict(group.counts)
+        full = self.specs.get("full")
+        if full is not None and full.readers and "kv.read" in reads:
+            # later layers attend over the full group's K/V without a cache
+            # of their own: a block is priced by the layers that read it
+            read = reads["kv.read"]
+            read["full_readers"] = full.readers
+            read["full_read_blocks"] = read["full_blocks"] * full.readers
+            if "state.read" in reads:  # what the window layers read beside
+                reads["state.read"]["kv_bytes"] += (
+                    read["window_blocks"] * self.window.spec.read_nbytes)
+        for name, read in reads.items():
+            with span(name) as s:
                 for key, value in read.items():
                     s.set_attr(key, value)
         return tables
@@ -654,7 +729,8 @@ def inner_programs(program, model, shapes: dict, interpret: bool) -> dict:
     host) and, for a prefill, the last position's row of logits; the pools
     are donated whole and updated in place."""
 
-    stateful = bool(cache_policy(program, model).get("state"))
+    policy = cache_policy(program, model)
+    windowed, stateful = bool(policy.get("window")), bool(policy.get("state"))
 
     def served(logits):
         return jnp.stack((jnp.argmax(logits, -1).astype(jnp.float32),
@@ -672,20 +748,23 @@ def inner_programs(program, model, shapes: dict, interpret: bool) -> dict:
             p, t, kv, bt, shapes["hit"][0], model, interpret=interpret))
 
     def decode(p, ints, kv, table):
-        """`ints` [B, 2, 4 or 3 + window width] int32: each sequence's token,
-        its context length and, with a window group, the position its window
-        table starts at and that table's slots, or, with a state group, the
-        state slot it reads and the one it writes.  One array, because every
+        """`ints` [B, 2 + (1 + window width) + 2] int32: each sequence's
+        token, its context length and, with a window group, the position its
+        window table starts at and that table's slots, and, with a state
+        group, the state slot it reads and the one it writes, last.  One
+        array, because every
         argument that comes from the host costs a transfer of its own (0.12 ms
         each on the chip's host; my chip run, PR 29); `table` stays on the
         device between the steps that do not change it."""
+        end = ints.shape[1] - 2 * stateful
         if ints.shape[1] == 2:
             tables = table
-        elif stateful:
-            tables = {"full": table, "state": ints[:, 2:]}
         else:
-            tables = {"full": table, "first": ints[:, 2],
-                      "window": ints[:, 3:]}
+            tables = {"full": table}
+            if windowed:
+                tables.update(first=ints[:, 2], window=ints[:, 3:end])
+            if stateful:
+                tables["state"] = ints[:, end:]
         logits, kv = program.decode_step(p, ints[:, 0], kv, tables, ints[:, 1],
                                          model, interpret=interpret)
         return served(logits), kv
@@ -707,8 +786,8 @@ def example_args(key: str, shapes: dict, pod: "Pod", block: int) -> tuple:
     i32 = np.int32
     if key == "decode":
         B = shapes["decode"][0]
-        width = (4 if pod.state is not None else
-                 2 if pod.window is None else 3 + pod.window.width)
+        width = (2 + (pod.window is not None and 1 + pod.window.width)
+                 + 2 * (pod.state is not None))
         return (np.ones((B, width), i32),
                 np.zeros((B, shapes["max_blocks"]), i32))
     tokens = sum(shapes[key])
@@ -760,9 +839,11 @@ def jit_programs(program, model, shapes: dict, interpret: bool) -> dict:
             tables = pod.tables("decode", bt, context_len=n)
             if traced is not None:
                 pod.report_load(model)
-            ints = ([t, n, *tables["state"].T] if pod.state is not None else
-                    [t, n] if pod.window is None else
-                    [t, n, tables["first"], *tables["window"].T])
+            ints = [t, n]
+            if pod.window is not None:
+                ints += [tables["first"], *tables["window"].T]
+            if pod.state is not None:
+                ints += [*tables["state"].T]
             out, counted = run("decode", p, kv,
                                np.stack(ints, axis=1, dtype=np.int32),
                                kv.on_device(bt))
@@ -777,17 +858,19 @@ def jit_programs(program, model, shapes: dict, interpret: bool) -> dict:
 def _dry_tables(pod: Pod, kind: str, table, prefix_blocks=0):
     """A prefill's tables in the shapes `Pod.tables` would give, for
     compiling ahead."""
+    if pod.window is None and pod.state is None:
+        return table
+    tables = {"full": table}
     if pod.state is not None:
         kept = pod.state.spec.snapshot_blocks(
             prefix_blocks, table.shape[1] - prefix_blocks)
         rows = np.zeros(table.shape[0], np.int32)
-        return {"full": table,
-                "state_write": np.zeros((len(rows), len(kept)), np.int32),
-                **({"state_read": rows} if kind == "hit" else {})}
+        tables["state_write"] = np.zeros((len(rows), len(kept)), np.int32)
+        if kind == "hit":
+            tables["state_read"] = rows
     group = pod.window
-    if group is None:
-        return table
-    kept = (min(table.shape[1], group.store) if kind == "miss"
-            else table.shape[1] - prefix_blocks + min(prefix_blocks, group.need))
-    return {"full": table,
-            "window": np.zeros((table.shape[0], kept), np.int32)}
+    if group is not None:
+        kept = (min(table.shape[1], group.store) if kind == "miss" else
+                table.shape[1] - prefix_blocks + min(prefix_blocks, group.need))
+        tables["window"] = np.zeros((table.shape[0], kept), np.int32)
+    return tables

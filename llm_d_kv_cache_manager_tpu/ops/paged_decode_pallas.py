@@ -48,8 +48,10 @@ prefix from each sequence on its own) keep the grid of tables by steps.
 Contract matches ops/paged_attention.py::paged_attention; equivalence
 is pinned by tests/test_paged_decode_pallas.py (interpret mode on CPU);
 tests/test_tpu_compile.py compiles every form for the v5e at the served
-shapes, and the decode steps of models/llama.py, afmoe.py and lfm2moe.py
-serve through it.
+shapes, and the decode steps of models/llama.py, afmoe.py, lfm2moe.py and
+phi4flash.py serve through it (the last with four query heads a pair-wise KV
+head of twice the model's head size, the window layers by ``start`` and the
+full group by one plan for the eight layers that read it).
 """
 
 from __future__ import annotations

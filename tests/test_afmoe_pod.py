@@ -380,7 +380,7 @@ def test_flash_kernel_with_a_window_is_the_masked_product(window, q_offset):
         q, k, v, q_offset=q_offset, q_block=16, kv_chunk=16, window=window,
         interpret=True)
     close(np.asarray(got),
-          np.asarray(afmoe._dense_attention(q, k, v, q_offset, window)), 1e-5)
+          np.asarray(afmoe.dense_attention(q, k, v, q_offset, window)), 1e-5)
 
 
 def test_flash_kernel_without_a_window_lowers_as_it_did():

@@ -515,7 +515,7 @@ def test_a_pod_without_a_state_group_is_the_pod_it_was():
     assert lfm2moe.cache_policy(dataclasses.replace(
         CFG, layer_types=(A, A)))["state"] is None
     pod = Pod("p", afmoe, afmoe.AfmoeConfig(), 8)
-    assert pod.state is None and pod.second is pod.window
+    assert pod.state is None and pod.groups == [pod.window]
     table = np.asarray(pod.alloc(2)[0], np.int32)[None]
     assert set(pod.tables("miss", table)) == {"full", "window"}
 

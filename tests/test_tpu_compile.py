@@ -10,15 +10,17 @@ these tests and no other (the `on-chip-measurement` guide, section 2).
 from __future__ import annotations
 
 import functools
+import hashlib
 import math
 import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from llm_d_kv_cache_manager_tpu.models import lfm2moe, llama
+from llm_d_kv_cache_manager_tpu.models import afmoe, lfm2moe, llama, phi4flash
 from llm_d_kv_cache_manager_tpu.models import pod as pod_programs
 from llm_d_kv_cache_manager_tpu.ops import flash_pallas
 from llm_d_kv_cache_manager_tpu.ops.paged_decode_pallas import (
@@ -267,3 +269,171 @@ def test_lfm2moe_programs_compile_at_the_cells_shapes(one_chip, monkeypatch,
     pool_bytes = sum(2 * math.prod(a.shape) for a in jax.tree.leaves(pools))
     assert memory.alias_size_in_bytes >= pool_bytes  # the pools handed back
     assert memory.temp_size_in_bytes < LFM2_TEMP_LIMIT[key]
+
+
+# ------------------- the phi4flash programs: full, window and state pools donated
+
+# benchmarks/configs/phi-4-mini-flash-reasoning.json and
+# benchmarks/traffic/reasoning-longgen.json
+PHI4 = phi4flash.Phi4FlashConfig(
+    vocab_size=200064, d_model=2560, n_layers=32, n_heads=40, n_kv_heads=20,
+    d_ff=10240, window=512, d_state=16, d_conv=4, expand=2, dt_rank=160,
+    window_slots=4608, window_store_blocks=64, state_slots=256,
+    state_stride_blocks=32)
+PHI4_SHAPES = {"miss": (1536,), "hit": (1024, 512), "decode": (64,),
+               "max_blocks": 416}
+PHI4_POOL_BLOCKS = 24576
+# Temporaries a program may take beside 13.57 GB of weights and pools on a
+# 15.75-GiB chip (16.9 GB): compiled here they read 0.33 / 0.18 / 0.14 GB.
+# With slots [2, 16, 10, 128] the decode step held the window pool unpacked
+# to the chip's tile (10 rows padded to 16: 4.5 GiB) and did not fit; with a
+# view of the pool's rows apart around a prefill's scatter each prefill copied
+# the pools it wrote (3.3 GB).
+PHI4_TEMP_LIMIT = {"miss": 0.6e9, "hit": 0.4e9, "decode": 0.3e9}
+HBM_BYTES = 15.75 * 2**30
+
+
+@pytest.mark.parametrize("key", ("miss", "hit", "decode"))
+def test_phi4flash_programs_compile_at_the_cells_shapes(one_chip, monkeypatch,
+                                                        key):
+    """The cell `phi4flash-reasoning-longgen`'s three programs as
+    `models/pod.py` jits them, the three groups' pools donated: they compile
+    for the v5e (the flash kernel banded and whole, the paged kernel windowed
+    and with its shared pass, at the pair-wise head size 128), fit the chip
+    beside the weights, hand the pools back where they lie, and no
+    instruction copies or re-lays-out a pool."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    def spec(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+
+    params = jax.tree.map(spec, jax.eval_shape(
+        lambda: phi4flash.init_params(jax.random.key(0), PHI4)))
+    pools = jax.tree.map(spec, jax.eval_shape(
+        lambda: phi4flash.new_pool(PHI4, PHI4_POOL_BLOCKS)))
+    policy = phi4flash.cache_policy(PHI4)
+
+    class Shapes:  # what `example_args` reads of a pod
+        class window:
+            need = -(-(PHI4.window - 1) // BLOCK)
+            width, store = need + 1, policy["window"]["store_blocks"]
+
+        class state:
+            spec = policy["specs"]["state"]
+
+    first, second = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+        pod_programs.example_args(key, PHI4_SHAPES, Shapes, BLOCK))
+    assert first.shape == ((64, 2 + 1 + 33 + 2) if key == "decode"
+                           else (1, PHI4_SHAPES[key][-1]))
+    program = pod_programs.inner_programs(phi4flash, PHI4, PHI4_SHAPES,
+                                          False)[key]
+    compiled = program.trace(params, first, pools, second).lower(
+        lowering_platforms=("tpu",)).compile()
+    hlo = compiled.as_text()
+    assert "tpu_custom_call" in hlo
+    # the window layers' kernel in the first scan's body; the shared pass and
+    # the walk of the full group in layer 17 and in the second scan's body
+    assert len(paged_kernels(hlo)) == (5 if key == "decode" else 0)
+    sizes = {a.shape[0] for a in jax.tree.leaves(pools)}
+    assert sizes == {PHI4_POOL_BLOCKS, 8 * 4608, 9 * 256}
+    for n in sizes:
+        assert not re.search(rf"= \w+\[{n},[\d,]*\]\S* copy\(", hlo), n
+    memory = compiled.memory_analysis()
+    pool_bytes = sum(a.dtype.itemsize * math.prod(a.shape)
+                     for a in jax.tree.leaves(pools))
+    assert memory.alias_size_in_bytes >= pool_bytes  # the pools handed back
+    assert memory.temp_size_in_bytes < PHI4_TEMP_LIMIT[key]
+    assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes
+            + memory.output_size_in_bytes - memory.alias_size_in_bytes
+            ) < HBM_BYTES
+
+
+# ---------------- the two-group pods, unmoved by the list of groups (PR 35)
+
+# Read on commit 7f3671a (PR 34), where a pod had one group beside the full
+# one: sha256 of str(jax.make_jaxpr(program)) of `lfm2moe`'s three programs
+# (`afmoe`'s and `llama`'s are pinned in tests/test_lfm2moe_pod.py), and of
+# every host-side array the two families' pods hand out over the scripted
+# run below.  A PR that changes one on purpose reads its digest anew.
+AT_PR_34 = {
+    "afmoe.tables": "66348e6e9d5f9ed8", "lfm2moe.tables": "a43cc9a2ce3fa258",
+    "lfm2moe.miss.False": "6b66837c74b57be8",
+    "lfm2moe.hit.False": "a0f712e977db715f",
+    "lfm2moe.decode.False": "4855b16f775d4a8e",
+    "lfm2moe.miss.True": "6b66837c74b57be8",
+    "lfm2moe.hit.True": "a0f712e977db715f",
+    "lfm2moe.decode.True": "ea79eedce534c845",
+}
+
+
+def host_tables(module, cfg) -> str:
+    """Five prompts of six blocks with two of an answer each through a pool of
+    40, four decode steps each, every second sequence ended, then a hit:
+    everything the pod hands out or keeps on the host, digested."""
+    pod = pod_programs.Pod("p", module, cfg, 40)
+    seen = hashlib.sha256()
+
+    def note(x):
+        for leaf in jax.tree.leaves(x):
+            a = np.asarray(leaf)
+            seen.update(str(a.dtype).encode() + str(a.shape).encode()
+                        + a.tobytes())
+
+    chains = []
+    for doc in range(5):
+        hashes = [1000 * doc + i for i in range(6)]
+        found = pod.cached_prefix(hashes[:4])
+        ids, evicted = pod.alloc(6)
+        note((found, ids, evicted))
+        pod.hold(ids, +1)
+        own, more = pod.alloc(2)
+        pod.hold(own, +1)
+        note((own, more, pod.tables("miss", np.asarray(ids)[None])))
+        for h, b in zip(hashes, ids):
+            pod.cached[h] = b
+        table = np.zeros((1, 9), np.int32)
+        table[0, :8] = ids + own
+        for ctx in (97, 98, 112, 113):
+            note(pod.tables("decode", table, context_len=np.asarray([ctx])))
+        chains.append((hashes, ids, own))
+        if doc % 2:  # every second sequence ends; the others stay live
+            pod.hold(ids + own, -1)
+            pod.free.extend(own)
+    hashes, ids, own = chains[1]
+    found = pod.cached_prefix(hashes[:4])
+    note(found)
+    if len(found) == 4:
+        pod.touch(hashes[:4])
+        more, evicted = pod.alloc(2)
+        note((more, evicted, pod.tables(
+            "hit", np.asarray(found + more)[None], prefix_blocks=4)))
+    for group in pod.groups:
+        note((group.slot_of, group.block_of, group.stamp,
+              sorted(group.counts.items())))
+    note((pod.refs, pod.hashed, pod.asked, sorted(pod.cached.items())))
+    return seen.hexdigest()[:16]
+
+
+def test_afmoe_and_lfm2moe_programs_and_host_tables_are_what_they_were():
+    got = {}
+    acfg = afmoe.AfmoeConfig(dtype="float32", vocab_size=128, window_slots=24,
+                             window_store_blocks=4)
+    lcfg = lfm2moe.Lfm2MoeConfig(
+        dtype="float32", vocab_size=128,
+        layer_types=("conv", "full_attention", "conv", "conv"),
+        state_slots=24, state_stride_blocks=2)
+    got["afmoe.tables"] = host_tables(afmoe, acfg)
+    got["lfm2moe.tables"] = host_tables(lfm2moe, lcfg)
+    shapes = {"miss": (96,), "hit": (64, 32), "decode": (2,), "max_blocks": 9}
+    params = jax.eval_shape(
+        lambda: lfm2moe.init_params(jax.random.key(0), lcfg))
+    pod = pod_programs.Pod("p", lfm2moe, lcfg, 40)
+    for interpret in (False, True):
+        programs = pod_programs.inner_programs(lfm2moe, lcfg, shapes, interpret)
+        for key, fn in programs.items():
+            a, b = pod_programs.example_args(key, shapes, pod, 16)
+            text = str(jax.make_jaxpr(fn)(params, a, pod.kv.arrays, b))
+            got[f"lfm2moe.{key}.{interpret}"] = hashlib.sha256(
+                text.encode()).hexdigest()[:16]
+    assert got == AT_PR_34
