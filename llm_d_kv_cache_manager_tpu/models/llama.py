@@ -192,33 +192,29 @@ def _logits(x: jnp.ndarray, params: Params) -> jnp.ndarray:
 def _prefill_attention(
     q, k, v, cfg: LlamaConfig, q_offset=0, use_flash=True, interpret=False
 ):
-    """Dense for short sequences, blockwise flash for long (static
-    shapes make the switch a trace-time decision).
+    """Dense under ``flash_attention_min_len`` keys, blockwise flash at
+    or past it (static shapes make the switch a trace-time decision).
 
-    Flash routing on TPU: wide q tiles (full/paged prefill) go to the
-    Pallas kernel (ops/flash_pallas.py); short continuation suffixes
-    keep the scan op, whose cost is dominated by the K/V read either
-    way.  The choice is made from what the trace can see — backend,
-    shapes, the kernel's VMEM bound — and chip_smoke.py asserts which
-    one the lowered program holds.  ``interpret=True`` runs the Pallas
-    kernel in interpret mode wherever it would run compiled on TPU (CPU
-    tests of the TPU routing).  ``use_flash=False`` forces dense:
-    neither flash op has a custom VJP, so under ``grad`` they keep the
-    same O(Tq*Tk) residuals as dense while serializing the backward
-    chunk-by-chunk — training paths should differentiate through the
-    fused dense einsum instead.
+    Flash routing: on the TPU (or ``interpret=True``, the CPU tests of
+    the TPU routing: ``paged_decode_pallas.serves``, one rule for the
+    kernels of both steps) with a static ``q_offset`` and K/V within
+    the kernel's VMEM bound, the Pallas kernel; beyond the bound (e.g.
+    32k+ prompts), with a traced offset or off the TPU, the scan op
+    streams K/V from HBM at any length.  The compile test and
+    chip_smoke.py assert which one a lowered program holds.
+    ``use_flash=False`` forces dense: neither flash op has a custom
+    VJP, so under ``grad`` they keep the same O(Tq*Tk) residuals as
+    dense while serializing the backward chunk-by-chunk — training
+    paths should differentiate through the fused dense einsum instead.
     """
     if use_flash and k.shape[1] >= cfg.flash_attention_min_len:
         if (
-            q.shape[1] >= cfg.flash_attention_min_len
-            and isinstance(q_offset, int)
-            and (interpret or jax.default_backend() == "tpu")
+            isinstance(q_offset, int)
+            and paged_decode_pallas.serves(interpret)
             and flash_pallas.fits_vmem(
                 k.shape[1], k.shape[-1], jnp.dtype(k.dtype).itemsize
             )
         ):
-            # Beyond the VMEM bound the scan op streams K/V from HBM
-            # at any length (e.g. 32k+ prompts).
             return flash_pallas.flash_gqa_attention_pallas(
                 q, k, v, q_offset=q_offset, interpret=interpret
             )
@@ -375,6 +371,29 @@ def prefill_paged(
     return _logits(x, params), kv_pool
 
 
+def _gathered_prefix_attention(q, k, v, slots, prefix_ids, cfg, interpret):
+    """A continuation's attention over a copy of its prefix: the table's
+    blocks gathered from the pool ([B, npre, 2, block, Hkv, Dh]), laid out
+    as positions and joined with the suffix's own k, v.  The path of a
+    prefix under the dense bound, of every hit off the TPU, and of slots
+    the kernel's continuation entry has no room for
+    (``flash_pallas.fits_paged``: heads of another size than 128, many KV
+    heads, or a block that divides no step)."""
+    B, npre = prefix_ids.shape
+    prefix_len = npre * cfg.block_size
+    pre = jnp.take(slots, prefix_ids, axis=0)
+    pre = pre.transpose(0, 2, 1, 3, 4, 5).reshape(
+        B, 2, prefix_len, k.shape[-2], k.shape[-1]
+    )
+    k_full = jnp.concatenate(
+        (pre[:, 0].astype(k.dtype), k), axis=1
+    )  # [B, prefix+Ts, Hkv, Dh]
+    v_full = jnp.concatenate((pre[:, 1].astype(v.dtype), v), axis=1)
+    return _prefill_attention(
+        q, k_full, v_full, cfg, q_offset=prefix_len, interpret=interpret
+    )
+
+
 def prefill_continue(
     params: Params,
     tokens: jnp.ndarray,
@@ -388,8 +407,14 @@ def prefill_continue(
 
     The first ``prefix_len`` tokens' K/V already live in the pool (a
     prior request stored them, or the offload connector loaded them);
-    this computes the suffix in one dense pass attending over
-    gathered-prefix + new K/V, and scatters the suffix blocks back.
+    this computes the suffix in one pass attending over the prefix's
+    K/V and its own, and scatters the suffix blocks back.  At serving
+    lengths on the TPU the flash kernel's continuation entry reads the
+    table's blocks from the pool where they lie
+    (``flash_pallas.flash_gqa_attention_pallas_paged``: no copy of the
+    prefix is made); under ``flash_attention_min_len`` keys, off the
+    TPU, or where the entry has no room for the pool's slots, the prefix
+    is gathered (``_gathered_prefix_attention``).
     This is what turns an index hit into real TTFT savings — the
     compute analogue of vLLM's prefix-cache hit that the reference
     routes toward (SURVEY.md §6 north star).
@@ -400,7 +425,7 @@ def prefill_continue(
     (% block_size == 0); one compile per distinct padded prefix length.
     ``interpret``: Pallas kernels in interpret mode (CPU tests).
     Returns (suffix logits [B, Ts, V], new kv_pool).  The pool is
-    carried through the layers: each gathers its prefix blocks from it
+    carried through the layers: each reads its prefix blocks from it
     and writes only its suffix blocks (``_scan_layers``); donate it and
     the write is in place.
     """
@@ -415,27 +440,45 @@ def prefill_continue(
     x = jnp.take(params["embed"], tokens, axis=0)
     prefix_ids = block_table[:, :npre]  # [B, npre]
     suffix_ids = block_table[:, npre : npre + nsuf]
+    # At or past the dense bound, where a kernel serves and has room for
+    # these slots, the flash kernel's continuation entry reads the
+    # table's blocks where the pool holds them; elsewhere the prefix is
+    # gathered, for the dense product, the scan op or the flash kernel
+    # over resident K/V (``_prefill_attention``'s rule).
+    paged = (
+        npre > 0
+        and prefix_len + Ts >= cfg.flash_attention_min_len
+        and paged_decode_pallas.serves(interpret)
+        and flash_pallas.fits_paged(
+            cfg.block_size, cfg.n_kv_heads, cfg.head_dim, cfg.n_heads,
+            jnp.dtype(kv_pool.dtype).itemsize,
+        )
+    )
 
     def layer(x, slots, lp, base):
         h = _rms_norm(x, lp["ln1"])
         q, k, v = _qkv(h, lp, positions, cfg.rope_theta)
-        # Gather the prefix K/V: [B, npre, 2, block, Hkv, Dh].
-        pre = jnp.take(slots, base + prefix_ids, axis=0)
-        pre = pre.transpose(0, 2, 1, 3, 4, 5).reshape(
-            B, 2, prefix_len, k.shape[-2], k.shape[-1]
-        )
-        k_full = jnp.concatenate(
-            (pre[:, 0].astype(k.dtype), k), axis=1
-        )  # [B, prefix+Ts, Hkv, Dh]
-        v_full = jnp.concatenate((pre[:, 1].astype(v.dtype), v), axis=1)
-        attn = _prefill_attention(
-            q, k_full, v_full, cfg, q_offset=prefix_len, interpret=interpret
-        )
+        if paged:
+            # The suffix's own K/V first: the kernel then finds them in
+            # the pool like the prefix's, behind one table.
+            slots = scatter_kv_blocks(
+                slots, k, v, base + suffix_ids, cfg.block_size
+            )
+            attn = flash_pallas.flash_gqa_attention_pallas_paged(
+                q, slots, base + block_table[:, : npre + nsuf],
+                q_offset=prefix_len, interpret=interpret,
+            )
+        else:
+            attn = _gathered_prefix_attention(
+                q, k, v, slots, base + prefix_ids, cfg, interpret
+            )
         x = x + jnp.einsum("bthk,hkd->btd", attn, lp["wo"])
         x = x + _mlp(_rms_norm(x, lp["ln2"]), lp)
-        return x, scatter_kv_blocks(
-            slots, k, v, base + suffix_ids, cfg.block_size
-        )
+        if not paged:
+            slots = scatter_kv_blocks(
+                slots, k, v, base + suffix_ids, cfg.block_size
+            )
+        return x, slots
 
     x, kv_pool = _scan_layers(layer, x, params, kv_pool)
     return _logits(x, params), kv_pool
@@ -458,7 +501,7 @@ def prefill_chunked(
     chunk step regardless of prompt length, with runtime-skipped
     masked chunks.  Network activations are O(chunk) instead of O(T);
     the per-layer K/V gather still materializes the O(T) context
-    (like prefill_continue's prefix gather) — what this bounds is the
+    (like ``_gathered_prefix_attention``) — what this bounds is the
     activation side, not the KV read.
 
     tokens: [B, T] with T % chunk_tokens == 0 and chunk_tokens %

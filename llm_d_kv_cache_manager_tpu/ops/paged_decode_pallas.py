@@ -87,7 +87,8 @@ SHARED_BLOCKS_PER_STEP = 16
 def serves(interpret: bool) -> bool:
     """The one rule by which a model's decode step takes this kernel: where
     the program is compiled for the TPU or asked to be interpreted (the CPU
-    tests); elsewhere the XLA gather (ops/paged_attention.py)."""
+    tests); elsewhere the XLA gather (ops/paged_attention.py).
+    models/llama.py sends its prefills to the flash kernel by it too."""
     return interpret or jax.default_backend() == "tpu"
 
 

@@ -28,9 +28,19 @@ CFG = llama.LlamaConfig(
 )
 
 
+# Heads of 128: the only ones the flash kernel's continuation entry reads
+# where the pool holds them (`flash_pallas.fits_paged`).
+WIDE = dataclasses.replace(CFG, d_model=4 * 128)
+
+
 @pytest.fixture(scope="module")
 def params():
     return llama.init_params(jax.random.PRNGKey(0), CFG)
+
+
+@pytest.fixture(scope="module")
+def wide_params():
+    return llama.init_params(jax.random.PRNGKey(0), WIDE)
 
 
 def test_forward_shapes(params):
@@ -215,22 +225,21 @@ def test_prefill_chunked_matches_full(params):
 SEQ_T = 16  # tokens a sequence of the cases below (and one to decode)
 
 
-@pytest.fixture(scope="module")
-def dense_pass(params):
+def _dense_pass(params, cfg):
     """Two sequences of SEQ_T + 1 tokens with the logits [B, T, V] and
     every layer's K and V [L, B, T, Hkv, Dh] of a dense pass over them,
     by a plain loop over the layers: no pool, no scan."""
     tokens = jax.random.randint(
-        jax.random.PRNGKey(21), (2, SEQ_T + 1), 0, CFG.vocab_size
+        jax.random.PRNGKey(21), (2, SEQ_T + 1), 0, cfg.vocab_size
     )
     B, T = tokens.shape
     positions = jnp.broadcast_to(jnp.arange(T), (B, T))
     x = jnp.take(params["embed"], tokens, axis=0)
     ks, vs = [], []
-    for l in range(CFG.n_layers):
+    for l in range(cfg.n_layers):
         lp = jax.tree.map(lambda a: a[l], params["layers"])
         h = llama._rms_norm(x, lp["ln1"])
-        q, k, v = llama._qkv(h, lp, positions, CFG.rope_theta)
+        q, k, v = llama._qkv(h, lp, positions, cfg.rope_theta)
         attn = causal_gqa_attention(q, k, v)
         x = x + jnp.einsum("bthk,hkd->btd", attn, lp["wo"])
         x = x + llama._mlp(llama._rms_norm(x, lp["ln2"]), lp)
@@ -242,6 +251,16 @@ def dense_pass(params):
         np.asarray(jnp.stack(ks)),
         np.asarray(jnp.stack(vs)),
     )
+
+
+@pytest.fixture(scope="module")
+def dense_pass(params):
+    return _dense_pass(params, CFG)
+
+
+@pytest.fixture(scope="module")
+def wide_dense_pass(wide_params):
+    return _dense_pass(wide_params, WIDE)
 
 
 def _write_positions(pool, ks, vs, table, first, last):
@@ -262,7 +281,8 @@ PAGED_CASES = [
     (program, attention, donate)
     for program, attention in (
         ("prefill_paged", "auto"),
-        ("prefill_continue", "auto"),
+        ("prefill_continue", "auto"),  # on the CPU: the gathered prefix
+        ("prefill_continue", "interpreted"),  # the flash kernel's paged entry
         ("prefill_chunked", "auto"),
         ("decode_step", "auto"),  # on the CPU: the XLA gather
         ("decode_step", "interpreted"),  # the paged kernel, by the rule
@@ -277,16 +297,22 @@ PAGED_CASES = [
     ids=["-".join((p, a, "donated" if d else "kept")) for p, a, d in PAGED_CASES],
 )
 def test_paged_programs_update_the_pool_in_place(
-    params, dense_pass, program, attention, donate
+    request, program, attention, donate
 ):
-    tokens, dense, ks, vs = dense_pass
+    # The hit through the kernel's entry at a head size the entry reads.
+    paged_hit = (program, attention) == ("prefill_continue", "interpreted")
+    base = WIDE if paged_hit else CFG
+    params = request.getfixturevalue("wide_params" if paged_hit else "params")
+    tokens, dense, ks, vs = request.getfixturevalue(
+        "wide_dense_pass" if paged_hit else "dense_pass"
+    )
     B, T, P, bs = 2, SEQ_T, SEQ_T // 2, CFG.block_size
     pool_blocks = 24
     rng = np.random.default_rng(22)
     # Noise everywhere: a slot that the program should not touch shows
     # it if it does, in every layer.
     before = rng.standard_normal(
-        (CFG.n_layers, pool_blocks, 2, bs, CFG.n_kv_heads, CFG.head_dim)
+        (CFG.n_layers, pool_blocks, 2, bs, CFG.n_kv_heads, base.head_dim)
     ).astype(np.float32)
     # Block ids in no order, so that a layer offset applied to the
     # wrong operand cannot go unseen.
@@ -308,8 +334,13 @@ def test_paged_programs_update_the_pool_in_place(
     elif program == "prefill_continue":
         _write_positions(before, ks, vs, table, zero, np.full(B, P))
         args = (tokens[:, P:T], table[:, : T // bs])
+        # Interpreted, under a key bound that the 16 positions pass: the
+        # kernel reads the table's blocks where the pool holds them.
+        cfg = dataclasses.replace(
+            base, flash_attention_min_len=T if paged_hit else 1024
+        )
         call = lambda p, t, kv, bt: llama.prefill_continue(
-            p, t, kv, bt, P, CFG
+            p, t, kv, bt, P, cfg, interpret=paged_hit
         )
         first, last, want = np.full(B, P), whole, dense[:, P:T]
     else:  # decode_step, ragged: sequence 1 is three tokens behind
@@ -328,6 +359,9 @@ def test_paged_programs_update_the_pool_in_place(
     after = before.copy()
     written = _write_positions(after, ks, vs, table, first, last)
     kv_in = jnp.asarray(before)
+    if paged_hit:
+        traced = str(jax.make_jaxpr(call)(params, args[0], kv_in, *args[1:]))
+        assert "flash_gqa_attention_pallas_paged" in traced
     logits, kv_out = jax.jit(call, donate_argnums=(2,) if donate else ())(
         params, args[0], kv_in, *args[1:]
     )
@@ -378,6 +412,81 @@ def test_decode_attention_is_one_rule(
         )
     )(params, pool)
     assert ("pallas_call" in str(traced)) == kernel
+
+
+@pytest.mark.parametrize(
+    "keys_bound, interpret, backend, kernel",
+    (
+        (16, False, "cpu", False),  # here: the gathered prefix, dense or scanned
+        (16, False, "tpu", True),  # compiled for the chip: the kernel
+        (16, True, "cpu", True),  # asked to be interpreted: the kernel
+        (17, True, "cpu", False),  # fewer keys than the bound: one dense product
+        (17, False, "tpu", False),
+    ),
+)
+def test_hit_attention_is_one_rule(
+    wide_params, monkeypatch, keys_bound, interpret, backend, kernel
+):
+    """`prefill_continue` attends in the flash kernel's continuation entry,
+    over the table's blocks where the pool holds them, at or past
+    `flash_attention_min_len` keys (prefix and suffix together: 8 + 8 here,
+    however few the queries) where it is compiled for the TPU or
+    interpreted; elsewhere over a gathered copy of the prefix."""
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    cfg = dataclasses.replace(WIDE, flash_attention_min_len=keys_bound)
+    pool = jnp.zeros(
+        (cfg.n_layers, 8, 2, cfg.block_size, cfg.n_kv_heads, cfg.head_dim)
+    )
+    traced = str(jax.make_jaxpr(
+        lambda p, kv: llama.prefill_continue(
+            p, jnp.zeros((1, 8), jnp.int32), kv,
+            jnp.arange(4, dtype=jnp.int32)[None], 8, cfg,
+            interpret=interpret,
+        )
+    )(wide_params, pool))
+    assert ("flash_gqa_attention_pallas_paged" in traced) == kernel
+    assert ("pallas_call" in traced) == kernel
+    assert ("gather" in traced.split("scan[", 1)[1]) != kernel  # in the layers
+
+
+@pytest.mark.parametrize("backend, interpret", (("tpu", False), ("cpu", True)))
+@pytest.mark.parametrize("slots", ("blocks_of_6", "heads_of_16"))
+def test_hit_gathers_for_slots_the_entry_has_no_room_for(
+    request, monkeypatch, slots, backend, interpret
+):
+    """Where `flash_pallas.fits_paged` refuses the pool's slots (blocks of 6
+    positions, which make up no step of the entry; heads of 16, which fill
+    no lane tile), a hit past the key bound attends as it did before the
+    entry: over the gathered prefix, through `_prefill_attention`'s rule
+    (the flash kernel over resident K/V where a kernel serves), and gives
+    the dense pass's rows."""
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    if slots == "blocks_of_6":
+        cfg = dataclasses.replace(WIDE, block_size=6)
+        params = request.getfixturevalue("wide_params")
+    else:
+        cfg, params = CFG, request.getfixturevalue("params")
+    bs = cfg.block_size
+    cfg = dataclasses.replace(cfg, flash_attention_min_len=2 * bs)
+    tokens = jax.random.randint(jax.random.PRNGKey(11), (1, 2 * bs), 0, 128)
+    pool = jnp.zeros((cfg.n_layers, 4, 2, bs, cfg.n_kv_heads, cfg.head_dim))
+    table = jnp.asarray([[2, 0]], jnp.int32)
+    hit = lambda p, t, kv: llama.prefill_continue(
+        p, t, kv, table, bs, cfg, interpret=interpret
+    )
+    traced = str(jax.make_jaxpr(hit)(params, tokens[:, bs:], pool))
+    assert "flash_gqa_attention_pallas_paged" not in traced
+    assert "pallas_call" in traced  # the kernel over the gathered K/V
+    assert "gather" in traced.split("scan[", 1)[1]
+    if interpret:
+        _, pool = llama.prefill_paged(
+            params, tokens[:, :bs], pool, table[:, :1], cfg
+        )
+        got, _ = hit(params, tokens[:, bs:], pool)
+        want = llama.forward(params, tokens, cfg, use_flash=False)[:, bs:]
+        np.testing.assert_allclose(
+            np.asarray(got), np.asarray(want), rtol=2e-4, atol=2e-4
+        )
 
 
 def test_decode_attention_refuses_an_unknown_path(params):

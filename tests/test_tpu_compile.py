@@ -149,6 +149,30 @@ def pool_sized_moves(hlo: str, pool_shape: tuple) -> list:
     return found
 
 
+def flash_kernels(hlo: str) -> set:
+    """The compiled program's flash kernels by the name the trace reduction
+    finds them under (`reduce.op_time_per_program`), less the instance
+    number."""
+    return {k.rsplit(".", 1)[0] for k in re.findall(
+        r"%(flash_gqa_attention_pallas\S*) = .*tpu_custom_call", hlo)}
+
+
+def gathered_prefix_moves(hlo: str, elements: int) -> list:
+    """The instructions of a compiled hit prefill that make or move a copy of
+    the cached prefix's K or V (``elements`` each, a layer): what the gather
+    of the table's blocks, its select and the concatenation with the
+    suffix's K/V lowered to, with the pads and re-layouts fused into them."""
+    found = []
+    for line in hlo.splitlines():
+        row = INSTRUCTION.search(line)
+        made_by = re.search(r'op_name="[^"]*/(\w+)"', line)
+        if row and made_by and made_by.group(1) in (
+                "gather", "select_n", "concatenate"):
+            if math.prod(int(d) for d in row.group(2).split(",") if d) >= elements:
+                found.append(f"{row.group(3)} {row.group(1)} [{row.group(2)}]")
+    return found
+
+
 SERVED = (  # name, widths, pool blocks, tokens, table, static prefix
     ("miss_prefill_T8448", MISTRAL, 4096, (1, 8448), (1, 528), None),
     ("hit_prefill_P8192_S256", MISTRAL, 4096, (1, 256), (1, 528), 8192),
@@ -198,11 +222,19 @@ def test_llama_programs_update_a_donated_pool_in_place(
         lowering_platforms=("tpu",)).compile()
 
     hlo = compiled.as_text()
-    # The miss prefill holds the flash kernel and the decode step the paged
-    # kernel (llama.decode_step's rule); the hit prefills are XLA's alone.
-    assert ("tpu_custom_call" in hlo) == (not name.startswith("hit"))
+    # Every program holds its kernel: a miss prefill the flash kernel, a hit
+    # its continuation entry over the table's blocks where the pool holds
+    # them (`llama.prefill_continue`), the decode step the paged kernel
+    # (`llama.decode_step`'s rule).
+    assert flash_kernels(hlo) == {
+        "miss": {"flash_gqa_attention_pallas"},
+        "hit": {"flash_gqa_attention_pallas_paged"},
+        "decode": set()}[name.split("_")[0]]
     # the shared pass and the walk, once in the layer scan's body
     assert len(paged_kernels(hlo)) == (2 if name.startswith("decode") else 0)
+    if prefix:  # 33.5 MB and 8.4 MB of K and V a layer that nothing copies
+        assert gathered_prefix_moves(
+            hlo, prefix * cfg.n_kv_heads * cfg.head_dim) == []
     assert pool_sized_moves(hlo, pool_shape) == []
     pool_bytes = 2 * math.prod(pool_shape)
     memory = compiled.memory_analysis()
