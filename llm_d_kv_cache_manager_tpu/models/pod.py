@@ -104,11 +104,18 @@ returns plain functions that build the groups' tables on the host, record
 spans (``kvpool.window`` and ``kv.read``, ``kvpool.state`` and
 ``state.read``, each group its own; ``moe.expert_load``, ``attention.read``)
 and call the inner compiled programs, which keep the names the trace
-reduction looks for.
+reduction looks for.  The rest of a call is spans too, each a real interval
+stamped where the work happens: ``pod.counts_read`` (the read of the last
+decode step's device counts), ``pod.pack`` (a decode call's packed argument
+and its table on the device), ``pod.compile`` (one a program, on the call
+that compiles) and ``pod.launch.decode`` / ``.hit`` / ``.miss`` (the compiled
+call alone; a decode launch that follows a decode launch of the same pod
+carries the period between the two).
 """
 
 from __future__ import annotations
 
+import time
 import weakref
 from collections import OrderedDict
 
@@ -141,15 +148,17 @@ class PodKV:
         self.arrays, self._pod = arrays, weakref.ref(pod)
         self._table = (None, None)  # the last decode table, host and device
 
-    def on_device(self, table) -> jax.Array:
-        """The decode steps' logical table on the device: sent again only
-        when a row changed (an admission or a finish, not every step)."""
+    def on_device(self, table) -> tuple[jax.Array, bool]:
+        """The decode steps' logical table on the device, and whether this
+        call sent it: again only when a row changed (an admission or a
+        finish, not every step)."""
         table = np.asarray(table, np.int32)
-        host, device = self._table
-        if host is None or host.shape != table.shape or not np.array_equal(
-                host, table):
+        host, _ = self._table
+        sent = (host is None or host.shape != table.shape
+                or not np.array_equal(host, table))
+        if sent:
             self._table = (table.copy(), jax.device_put(table))
-        return self._table[1]
+        return self._table[1], sent
 
     @property
     def pod(self) -> "Pod":
@@ -583,6 +592,7 @@ class Pod:
         # told the same links), so any of them answers `tail`
         self.groups = [g for g in (self.window, self.state) if g is not None]
         self.pending_load = None  # (a decode step's device counts, its tokens)
+        self.last_launch = None  # (kind, `perf_counter`) of the last program call
 
     def cached_prefix(self, hashes) -> list[int]:
         ids = []
@@ -697,21 +707,24 @@ class Pod:
     def report_load(self, model) -> None:
         """What the last decode step counted on the device, as spans: the
         blocks its attention read against a walk of every table, and the
-        expert layers' loads.  The step that made them has long ended (its
-        tokens were read back), so this waits for nothing."""
+        expert layers' loads.  They were sent on their way to the host when
+        the step was launched (`keep_load`) and that step has long ended (its
+        tokens were read back), so the read finds them there:
+        `pod.counts_read` is what it waited all the same."""
         if self.pending_load is None:
             return
         counted, tokens = self.pending_load
         self.pending_load = None
-        # to the host in one go: a transfer of its own costs each ≈0.5 ms
-        counted = jax.device_get(counted)
+        with span("pod.counts_read") as s:
+            counted = {k: np.asarray(a) for k, a in counted.items()}
+            s.set_attr("arrays", len(counted))
+            s.set_attr("bytes", sum(a.nbytes for a in counted.values()))
         if "attention_read" in counted:
-            read, walked = np.asarray(counted["attention_read"])
+            read, walked = counted["attention_read"]
             with span("attention.read") as s:
                 s.set_attr("read_blocks", int(read))
                 s.set_attr("walked_blocks", int(walked))
-        for layer, (touched, most) in enumerate(
-                np.asarray(counted.get("load", ()))):
+        for layer, (touched, most) in enumerate(counted.get("load", ())):
             with span("moe.expert_load") as s:
                 s.set_attr("layer", layer)
                 s.set_attr("experts_held", model.n_experts)
@@ -719,6 +732,16 @@ class Pod:
                 s.set_attr("max_tokens", int(most))
                 s.set_attr("mean_tokens",
                            tokens * model.top_k / model.n_experts)
+
+    def keep_load(self, counted: dict, tokens: int) -> None:
+        """A traced decode step's device counts, kept for the next call's
+        `report_load` and started on their way to the host now, behind the
+        step that makes them: a read that asks only then is a round trip to
+        the device of its own (0.55–0.63 ms on a v5e: PERF.md section 6,
+        PR 37), which an untraced step never makes."""
+        for array in counted.values():
+            array.copy_to_host_async()
+        self.pending_load = (counted, tokens)
 
 
 def inner_programs(program, model, shapes: dict, interpret: bool) -> dict:
@@ -797,26 +820,59 @@ def example_args(key: str, shapes: dict, pod: "Pod", block: int) -> tuple:
                         prefix_blocks=pre))
 
 
+def _launch_span(key: str):
+    """The span around one compiled call.  Three literal names: a reader
+    filters by trace and span name alone, so the kind is in the name, and
+    `hack/kvlint` (KV007) holds each to its row of docs/observability.md."""
+    if key == "decode":
+        return span("pod.launch.decode")
+    if key == "hit":
+        return span("pod.launch.hit")
+    return span("pod.launch.miss")
+
+
 def jit_programs(program, model, shapes: dict, interpret: bool) -> dict:
     """The cell's steps as plain functions over a pod's `kv` handle.  Each
     builds its tables on the host (`Pod.tables`), then calls the inner
     compiled program (`inner_programs`).  All three are compiled at the first
     call of any (set-up), so that a shape first used inside a measured window
-    does not compile there."""
+    does not compile there: a `pod.compile` span a program on that call, and
+    none after it."""
     block = model.block_size
     inner, compiled = inner_programs(program, model, shapes, interpret), {}
 
     def spec(x):
         return jax.ShapeDtypeStruct(np.shape(x), np.asarray(x).dtype)
 
-    def run(key, p, kv, first, second):
+    def run(key, p, kv, first, second, traced):
+        pod, fresh = kv.pod, 0
         if not compiled:
             for k, fn in inner.items():
                 a, b = ((first, second) if k == key
-                        else example_args(k, shapes, kv.pod, block))
-                compiled[k] = fn.lower(p, spec(a), kv.arrays,
-                                       jax.tree.map(spec, b)).compile()
-        *out, kv.arrays = compiled[key](p, first, kv.arrays, second)
+                        else example_args(k, shapes, pod, block))
+                with span("pod.compile") as s:
+                    s.set_attr("program", fn.__name__)
+                    compiled[k] = fn.lower(p, spec(a), kv.arrays,
+                                           jax.tree.map(spec, b)).compile()
+            fresh = len(compiled)
+        # the period between two decode launches with no prefill between, as
+        # the program itself reads it; kept on every call, so that a sample
+        # rate under 1 still knows what the call before was
+        name = inner[key].__name__
+        last, now = pod.last_launch, time.perf_counter()
+        pod.last_launch = (key, now)
+        with _launch_span(key) as s:
+            s.set_attr("program", name)
+            if key == "decode":
+                after = last is not None and last[0] == "decode"
+                s.set_attr("after_decode", int(after))
+                if after:
+                    s.set_attr("since_prev_launch_s", now - last[1])
+            *out, kv.arrays = compiled[key](p, first, kv.arrays, second)
+        if traced is not None:
+            traced.set_attr("kind", key)
+            traced.set_attr("program", name)
+            traced.set_attr("compiled", fresh)
         # what a step counted on the device is no part of the pools: it is
         # not handed back in, so reading it later finds it alive
         counted = ({k: kv.arrays.pop(k) for k in COUNTED if k in kv.arrays}
@@ -827,9 +883,10 @@ def jit_programs(program, model, shapes: dict, interpret: bool) -> dict:
         prefix_blocks = shapes[key][0] // block if key == "hit" else 0
 
         def run_prefill(p, t, kv, bt):
-            with root_trace("pod.step"):
+            with root_trace("pod.step") as traced:
                 tables = kv.pod.tables(key, bt, prefix_blocks=prefix_blocks)
-                return run(key, p, kv, np.asarray(t, np.int32), tables)[0]
+                return run(key, p, kv, np.asarray(t, np.int32), tables,
+                           traced)[0]
 
         return run_prefill
 
@@ -839,16 +896,20 @@ def jit_programs(program, model, shapes: dict, interpret: bool) -> dict:
             tables = pod.tables("decode", bt, context_len=n)
             if traced is not None:
                 pod.report_load(model)
-            ints = [t, n]
-            if pod.window is not None:
-                ints += [tables["first"], *tables["window"].T]
-            if pod.state is not None:
-                ints += [*tables["state"].T]
-            out, counted = run("decode", p, kv,
-                               np.stack(ints, axis=1, dtype=np.int32),
-                               kv.on_device(bt))
+            with span("pod.pack") as s:
+                ints = [t, n]
+                if pod.window is not None:
+                    ints += [tables["first"], *tables["window"].T]
+                if pod.state is not None:
+                    ints += [*tables["state"].T]
+                ints = np.stack(ints, axis=1, dtype=np.int32)
+                table, sent = kv.on_device(bt)
+                s.set_attr("calls", 1)
+                s.set_attr("table_sent", int(sent))
+                s.set_attr("h2d_bytes", table.nbytes if sent else 0)
+            out, counted = run("decode", p, kv, ints, table, traced)
             if traced is not None and counted:
-                pod.pending_load = (counted, len(t))
+                pod.keep_load(counted, len(t))
             return out
 
     return {key: run_decode if key == "decode" else prefill(key)

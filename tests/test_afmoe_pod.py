@@ -285,7 +285,9 @@ def test_the_three_programs_serve_the_reference_tokens_and_keep_their_names():
         TRACER.configure(sample_rate=0.0, ring_size=64)
     spans = [r for r in rows if r["span"] is not None]
     names = {r["span"] for r in spans}
-    assert {"kvpool.window", "kv.read", "moe.expert_load"} <= names
+    assert {"kvpool.window", "kv.read", "moe.expert_load", "pod.compile",
+            "pod.counts_read", "pod.pack", "pod.launch.miss", "pod.launch.hit",
+            "pod.launch.decode"} == names
     assert {r["trace"] for r in rows if r["span"] is None} == {"pod.step"}
     window = [r["attrs"] for r in spans if r["span"] == "kvpool.window"]
     assert sum(a["taken"] for a in window) == 4 + 2 + 2 and all(

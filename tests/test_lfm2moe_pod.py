@@ -287,8 +287,10 @@ def test_the_three_programs_serve_the_reference_tokens_and_report_spans():
     finally:
         TRACER.configure(sample_rate=0.0, ring_size=64)
     spans = [r for r in rows if r["span"] is not None]
-    assert {r["span"] for r in spans} == {"kvpool.state", "state.read",
-                                          "moe.expert_load"}
+    assert {r["span"] for r in spans} == {
+        "kvpool.state", "state.read", "moe.expert_load", "pod.compile",
+        "pod.counts_read", "pod.pack", "pod.launch.miss", "pod.launch.hit",
+        "pod.launch.decode"}  # the call's own parts: tests/test_pod_step_spans.py
     assert {r["trace"] for r in rows if r["span"] is None} == {"pod.step"}
     state = [r["attrs"] for r in spans if r["span"] == "kvpool.state"]
     assert [a["taken"] for a in state] == [3, 1, 2, 0]

@@ -348,7 +348,12 @@ def test_the_three_programs_serve_the_reference_tokens_and_report_spans():
     for r in rows:
         if r.get("span"):
             spans.setdefault(r["span"], []).append(r.get("attrs", {}))
+    assert {r["trace"] for r in rows if r["span"] is None} == {"pod.step"}
     assert len(spans["kvpool.window"]) == len(spans["kvpool.state"]) == 4
+    assert len(spans["pod.compile"]) == 3 and len(spans["pod.pack"]) == 2
+    assert [len(spans[f"pod.launch.{kind}"])
+            for kind in ("miss", "hit", "decode")] == [1, 1, 2]
+    assert [a["arrays"] for a in spans["pod.counts_read"]] == [1]
     read = spans["kv.read"][-1]
     assert read["full_readers"] == 2 == phi4flash.cache_groups(
         CFG)["full"].num_readers
