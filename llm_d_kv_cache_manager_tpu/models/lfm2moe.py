@@ -88,10 +88,6 @@ ROUTE_NORM_EPS = 1e-6  # + the published code's, under the picked scores' sum
 # 1792, a layer: 64 tokens 1.03 ms batched / 1.90 ms grouped, 512 tokens
 # 2.03 / 3.04 (my chip run, PR 33; PERF.md section 6).
 BATCHED_EXPERTS_MAX_TOKENS = 512
-# Pool blocks the paged decode kernel takes a grid step, read on the chip at
-# the cell's shapes (64 sequences of 8.7-9.2 k, three layers): 8 / 16 / 32 /
-# 64 blocks gave 12.6 / 10.6 / 9.2 / 8.7 ms (my chip run, PR 33).
-DECODE_BLOCKS_PER_STEP = 64
 
 
 @dataclass(frozen=True)
@@ -346,8 +342,7 @@ def _decode_attention(q, pool, table, context_len, interpret, plan):
     the XLA gather over the slots unpacked."""
     if plan is not None:
         return paged_decode_attention_pallas(
-            q, pool, table, context_len, packed=True,
-            blocks_per_step=DECODE_BLOCKS_PER_STEP, interpret=interpret,
+            q, pool, table, context_len, packed=True, interpret=interpret,
             plan=plan,
         )
     Dh = q.shape[-1]
@@ -537,8 +532,7 @@ def decode_step(
     plan = None
     if paged_decode_pallas.serves(interpret):
         plan = paged_decode_pallas.shared_prefix_plan(
-            tables["full"], context_len, block_size=bs,
-            blocks_per_step=DECODE_BLOCKS_PER_STEP)
+            tables["full"], context_len, block_size=bs)
     for l, lp in enumerate(params["layers"]):
         kind, i = cfg.slot_of_layer(l)
         h = _rms_norm(x, lp["ln_op"], cfg.rms_eps, lp["ln_op"].dtype)

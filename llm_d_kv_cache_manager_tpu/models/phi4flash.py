@@ -118,10 +118,11 @@ from llm_d_kv_cache_manager_tpu.ops.paged_decode_pallas import (
 Params = Dict[str, Any]
 HI = lax.Precision.HIGHEST
 SUB_NORM_EPS = 1e-5  # + the published code's, in the norm behind the difference
-# Pool blocks the paged decode kernel takes a grid step, read on the chip at
-# the cell's shapes (64 sequences of 1.5-6.7 k over 8 shared prompts, a whole
-# decode step): 8 / 16 / 32 / 64 blocks gave 45.6 / 42.3 / 42.1 / 42.9 ms (my chip
-# run, PR 35).
+# Pool blocks the paged decode kernel takes a grid step over a window layer's
+# table (the full group's is walked in waves the kernel sizes itself, PR 41),
+# read on the chip at the cell's shapes when both took it (64 sequences of
+# 1.5-6.7 k over 8 shared prompts, a whole decode step): 8 / 16 / 32 / 64
+# blocks gave 45.6 / 42.3 / 42.1 / 42.9 ms (my chip run, PR 35).
 DECODE_BLOCKS_PER_STEP = 32
 
 
@@ -780,8 +781,7 @@ def decode_step(
     plan = None
     if paged_decode_pallas.serves(interpret):
         plan = paged_decode_pallas.shared_prefix_plan(
-            tables["full"], context_len, block_size=bs,
-            blocks_per_step=DECODE_BLOCKS_PER_STEP)
+            tables["full"], context_len, block_size=bs)
 
     def mamba(x, lp, conv, ssm, base):
         y, m, conv, ssm = _mamba_decode(_mix_in(x, lp, cfg), lp, conv, ssm,

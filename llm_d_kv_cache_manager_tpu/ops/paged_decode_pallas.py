@@ -4,49 +4,61 @@ The XLA version (ops/paged_attention.py) gathers every table block into
 a dense [B, T, Hkv, D] tensor before attending — the whole context's
 KV crosses HBM twice (pool -> gathered copy -> compute reads).  This
 kernel is the TPU analogue of vLLM's paged-attention CUDA kernel: the
-block table rides in as a scalar-prefetch operand, each grid step's
-``index_map`` points straight at that sequence's next pool block, and
-Pallas's pipeline DMAs exactly the referenced blocks HBM->VMEM
-(double-buffered) while the MXU works on the previous one.  Past the
-context length the index map pins to the last valid block — an
-unchanged index skips the redundant DMA — and the flash accumulators
-(f32, VMEM scratch) carry the online softmax across grid steps.
+block table rides in as a scalar-prefetch operand, the pool's blocks are
+brought HBM->VMEM where the table says they lie while the MXU works on the
+ones before, and the flash accumulators (f32, VMEM scratch) carry the
+online softmax.  It has two forms, read from the slot layout
+(``heads_first``, ``packed``: as the pool's ``KVGroupSpec`` states it) and
+from ``start``.
 
-A grid step takes ``blocks_per_step`` pool blocks, each through its own
-pipelined DMA, and makes ONE online-softmax update over all their keys.
-How the step's operand is assembled is read from the slot layout
-(``heads_first``, as the pool's ``KVGroupSpec`` states it), the algorithm
-is one: slots [2, Hkv, bs, D] (models/afmoe.py) side by side are one
-[Hkv, P*bs, D] operand; slots [2, bs, Hkv, D] (models/llama.py) are taken
-as they lie, [bs*Hkv, D] rows against every query head with the other KV
-heads' columns masked (K and V pass the MXU once either way, and nothing
-is re-laid-out in VMEM).  ``packed`` slots [bs*Hkv, 2*D] (models/lfm2moe.py,
-head size 64) are the second form with a position's K in the lower half of a
-row's lanes and its V in the upper: the pool's minor axis is then 128 wide, as
-the chip lays arrays out (with 64 the compiler makes the slot axis the minor
-one and re-lays-out the whole pool around every step), one operand is both K
-and V, the query comes padded with zeros over V's lanes and the output is read
-from them.
+**The grid of tables by steps** (heads-first slots, window layers).  A grid
+step takes ``blocks_per_step`` pool blocks of one sequence, each an operand
+whose ``index_map`` points at the sequence's next block, so that Pallas's
+pipeline DMAs exactly the referenced blocks (double-buffered), and makes ONE
+online-softmax update over all their keys.  Past the context length the index
+map pins to the last valid block — an unchanged index skips the redundant
+DMA.  Slots [2, Hkv, bs, D] (models/afmoe.py) side by side are one
+[Hkv, P*bs, D] operand; a window layer's slots [2, bs, Hkv, D]
+(models/phi4flash.py) are taken as rows, as below, with the positions before
+``start`` hidden.
 
-Over the second and third kind of slot a **shared-prefix pass** comes first.
+**The shared pass and the walk** (slots [2, bs, Hkv, D], models/llama.py and
+models/phi4flash.py's full group; ``packed`` slots, models/lfm2moe.py).  A
+block is taken as it lies, [bs*Hkv, D] rows against every query head with the
+other KV heads' columns masked (K and V pass the MXU once either way, and
+nothing is re-laid-out in VMEM).  ``packed`` slots [bs*Hkv, 2*D] (head size
+64) are that form with a position's K in the lower half of a row's lanes and
+its V in the upper: the pool's minor axis is then 128 wide, as the chip lays
+arrays out (with 64 the compiler makes the slot axis the minor one and
+re-lays-out the whole pool around every step), one operand is both K and V,
+the query comes padded with zeros over V's lanes and the output is read from
+them.
+
 Live sequences whose tables begin with the same run of full blocks (a system
 prompt that `Pod.cached_prefix` gave them all) are found from the table
 itself, once a decode step (``shared_prefix_plan``: data, never shapes).  The
-pass brings each block of such a run from HBM once for up to
+shared pass brings each block of such a run from HBM once for up to
 ``SHARED_SEQUENCES`` sequences and multiplies it against all their query rows
-together (``_shared_kernel``); then each sequence walks only the rest of its
-own table, its online softmax resumed from what the pass left (running
-maximum, sum, weighted values, float32): the same attention over the same
-keys in the same types, the float32 sums in another order.  The walk's grid is
-then the plan's list of the steps that have something to read, its length a
-value and not a shape, so a sequence with 40 blocks of its own takes two steps
-and not the table's six.  A sequence that shares nothing walks its whole table
-as before, and a table where nobody shares costs the set-finding and one empty
-step.  Heads-first slots and window layers (a window's start hides part of a
-prefix from each sequence on its own) keep the grid of tables by steps.
+together (``_shared_kernel``).  Then the walk (``_walk_kernel``): a grid step
+is a sequence, which brings its own blocks, from the end of its run to its
+last block in context, out of the pool by copies of its own
+(``pltpu.make_async_copy``, a wave of blocks at a time into one of
+``WALK_BUFFERS`` VMEM buffers, the waves behind on their way while this one
+is multiplied, in a rolled loop), its online softmax resumed from what the
+pass left (running maximum, sum, weighted values, float32): the same
+attention over the same keys in the same types, the float32 sums in another
+order.  A sequence copies as many
+blocks as it has in context past its run and none past the context (a block
+an operand of the pipeline, 32 a grid step, read ≈1.4 times the blocks in
+chat-sysprompt at ≈55 % of the bandwidth: PERF.md section 6, PR 41), and
+near its end it starts the next sequence's first waves into the free buffers,
+so that no sequence begins by waiting for a copy with nothing to hide it.
+A sequence that shares nothing walks its whole table, and a table where
+nobody shares costs the set-finding and the shared pass's one empty step.
 
 Contract matches ops/paged_attention.py::paged_attention; equivalence
-is pinned by tests/test_paged_decode_pallas.py (interpret mode on CPU);
+is pinned by tests/test_paged_decode_pallas.py (interpret mode on CPU), the
+walk's copies and what it traces to by tests/test_paged_decode_walk.py;
 tests/test_tpu_compile.py compiles every form for the v5e at the served
 shapes, and the decode steps of models/llama.py, afmoe.py, lfm2moe.py and
 phi4flash.py serve through it (the last with four query heads a pair-wise KV
@@ -57,6 +69,7 @@ full group by one plan for the eight layers that read it).
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -66,12 +79,9 @@ from jax.experimental.pallas import tpu as pltpu
 NEG_INF = -1e30
 
 
-# What the measurement chose for slots [2, bs, Hkv, D] (PERF.md section 6,
-# PR 32; the cell internlm2-chat-sysprompt: B 32, 192 table columns, Hkv 8,
-# D 128): pool blocks a grid step, each through its own pipelined DMA, and
-# the products' operands in the query's type (bfloat16 in serving) with
-# float32 accumulation.  models/afmoe.py passes its own for its slots.
-BLOCKS_PER_STEP = 32
+# The products' operands in the query's type (bfloat16 in serving) with
+# float32 accumulation: what the measurement chose for slots [2, bs, Hkv, D]
+# (PERF.md section 6, PR 32).  models/afmoe.py passes its own for its slots.
 MXU_NATIVE = True
 # The shared pass's own, read on the chip at both cells' shapes (kernel alone,
 # a decode step's layers; PERF.md section 6, PR 34): sequences a group (their
@@ -82,6 +92,30 @@ MXU_NATIVE = True
 # read 6.96 / 6.55 / 6.53 ms and 3.73 / 3.51 / 3.57 ms).
 SHARED_SEQUENCES = 8
 SHARED_BLOCKS_PER_STEP = 16
+# The walk's own (PERF.md section 6, PR 41): blocks a wave, as many as make
+# WALK_WAVE_BYTES and WALK_WAVE_BLOCKS at most, and the buffers a wave is
+# copied into (WALK_BUFFERS - 1 waves are on their way while one is
+# multiplied).  Read on the chip at the three cells' shapes, kernel alone
+# (shared pass and walk) over a decode step's layers, ms, in the order
+# chat-sysprompt (slots of 64 KB, 16 query rows) / chat-agents (32 KB, 32) /
+# reasoning-longgen (80 KB, 40): the walk before, 32 / 64 / 32 blocks a grid
+# step as operands, 6.63-6.71 / 3.53-3.60 / 69.3-69.4; two buffers of 4 / 8 /
+# 16 / 24 / 32 blocks 7.80 / 6.26 / 5.66-5.71 / 5.58-5.61 / 5.73, 3.83 / 3.44 /
+# 3.26-3.33 / 3.28-3.31 / 3.22, 78.7 / 71.8 / 68.9-69.1 / 68.8-68.9 / 68.7;
+# three buffers of 8 / 12 / 16 / 24 blocks 6.14 / 5.64 / 5.40 / 5.43, 3.54 /
+# 3.44 / 3.37 / 3.28, 73.2 / 70.4 / 69.1 / 68.4; four of 8 6.22 / 3.60 / 73.1.
+# With copies and no products 5.47, products and no copies 3.91, neither 2.27
+# (chat-sysprompt, two buffers of 16): the copies hold the walk, and a third
+# buffer keeps them coming across a sequence's end.  An online-softmax update
+# every 8 blocks of a wave, so that no product is made past the context, read
+# 6.11-6.15 / 3.55 / 72.6: the last wave's masked products cost less than the
+# updates.  A wave's products are unrolled in the loop's body, which set-up
+# pays for: a body of 24 blocks traced in 0.8 s on the chip's host where the
+# walk before took 0.4, so 16 serves (its copies are a rolled loop: unrolled
+# under `pl.when`, 16 of them traced 0.9 s).
+WALK_WAVE_BYTES = 2 << 20
+WALK_WAVE_BLOCKS = 16
+WALK_BUFFERS = 3
 
 
 def serves(interpret: bool) -> bool:
@@ -179,7 +213,7 @@ def _decode_kernel(
     table_ref,  # SMEM [B, max_blocks] int32 (scalar prefetch)
     ctx_ref,  # SMEM [B] int32 (scalar prefetch)
     *rest,  # more scalar prefetch (below), q ref (VMEM [1, H, D]),
-    # blocks_per_step kv refs, [the shared pass's three,] out ref, scratch
+    # blocks_per_step kv refs, out ref, scratch
     block_size: int,
     groups: int,
     scale: float,
@@ -187,48 +221,23 @@ def _decode_kernel(
     mxu_native: bool,
     windowed: bool = False,
     heads_first: bool = False,
-    packed: bool = False,
-    listed: bool = False,
 ):
+    """The grid of tables by steps (heads-first slots, window layers): a
+    grid step takes ``blocks_per_step`` pool blocks of one sequence, each an
+    operand with its own pipelined DMA."""
     # Scalar prefetch after the context: [start (SMEM [B]) if windowed,]
-    # [the last block (SMEM [B]) unless heads_first: the index maps' own,]
-    # [if listed, ``shared_prefix_plan``'s walk: each sequence's place in the
-    # shared pass's results, then each grid step's sequence, first block and
-    # flags.]
+    # [the last block (SMEM [B]) unless heads_first: the index maps' own.]
     start_ref = rest[0] if windowed else None
-    if listed:  # never windowed nor heads_first: [last, place, then these]
-        seq_ref, first_ref, flag_ref = rest[2:5]
-    q_ref, *rest = rest[windowed + (not heads_first) + 4 * listed :]
+    q_ref, *rest = rest[windowed + (not heads_first) :]
     kv_refs = rest[:blocks_per_step]
-    rest = rest[blocks_per_step:]
-    if listed:  # what the shared pass left of this sequence's softmax
-        m0_ref, l0_ref, acc0_ref, *rest = rest
-    out_ref, m_ref, l_ref, acc_ref = rest
+    out_ref, m_ref, l_ref, acc_ref = rest[blocks_per_step:]
 
-    if listed:
-        # The grid is the list of steps that have something to read: this
-        # one's sequence and first block, and whether it opens the sequence
-        # (bit 0), closes it (bit 1), or opens it after a shared pass (bit 2).
-        w = pl.program_id(0)
-        b = seq_ref[w]
-        flags = flag_ref[w]
-        opens = (flags & 1) != 0
-
-        @pl.when((flags & 4) != 0)
-        def _resume():
-            m_ref[...] = m0_ref[...]
-            l_ref[...] = l0_ref[...]
-            acc_ref[...] = acc0_ref[...]
-
-    else:
-        b = pl.program_id(0)
-        j = pl.program_id(1)
-        n_steps = pl.num_programs(1)
+    b = pl.program_id(0)
+    j = pl.program_id(1)
+    n_steps = pl.num_programs(1)
     ctx = ctx_ref[b]
-    if not listed:  # here, so that the heads-first kernel traces as it did
-        opens = j == 0
 
-    @pl.when(opens)
+    @pl.when(j == 0)
     def _init():
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
@@ -279,11 +288,11 @@ def _decode_kernel(
         )  # [Hkv, G, D]
         acc_ref[...] = acc_ref[...] * correction + o.reshape(H, D)
 
+    first = j * blocks_per_step * block_size
     if heads_first:
         # Slots are [2, Hkv, bs, D]: the step's blocks side by side are one
         # [Hkv, P*bs, D] operand, so a step is one update (P*bs = 128 keys
         # at 8 blocks a step) and not P small ones.
-        first = j * blocks_per_step * block_size
 
         @pl.when(first < ctx)
         def _attend_step():
@@ -297,7 +306,6 @@ def _decode_kernel(
             )
 
     else:
-        first = (first_ref[w] if listed else j * blocks_per_step) * block_size
 
         @pl.when(first < ctx)
         def _attend_step():
@@ -313,14 +321,157 @@ def _decode_kernel(
 
             _attend_rows(
                 q.astype(compute_dtype), kv_refs, hide, m_ref, l_ref,
-                acc_ref, packed=packed,
+                acc_ref, packed=False,
             )
 
-    @pl.when((flags & 2) != 0 if listed else j == n_steps - 1)
+    @pl.when(j == n_steps - 1)
     def _finalize():
-        l = l_ref[:, :1]
-        out = acc_ref[...] / jnp.where(l == 0.0, 1.0, l)
-        out_ref[0] = out.astype(out_ref.dtype)
+        _normalised(out_ref, l_ref, acc_ref)
+
+
+def _normalised(out_ref, l_ref, acc_ref):
+    l = l_ref[:, :1]
+    out = acc_ref[...] / jnp.where(l == 0.0, 1.0, l)
+    out_ref[0] = out.astype(out_ref.dtype)
+
+
+def _walk_kernel(
+    table_ref,  # SMEM [B, max_blocks] int32 (scalar prefetch)
+    ctx_ref,  # SMEM [B]
+    last_ref,  # SMEM [B]: each sequence's last block in context
+    place_ref,  # SMEM [B]: its place in the shared pass's results
+    skip_ref,  # SMEM [B]: its shared run, the first block of its own
+    q_ref,  # VMEM [1, H, D]
+    kv_hbm,  # the pool, where it lies
+    m0_ref, l0_ref, acc0_ref,  # what the shared pass left of this sequence
+    out_ref,
+    m_ref, l_ref, acc_ref, buf, sem, wave_ref,  # scratch
+    *,
+    block_size: int,
+    groups: int,
+    scale: float,
+    mxu_native: bool,
+    packed: bool,
+):
+    """The walk of each sequence's own blocks: a grid step is a sequence.  It
+    brings the blocks from the end of the sequence's shared run to its last
+    block in context, a wave at a time (``buf``: [buffers, blocks a wave, a
+    slot]), each block by a copy of its own into one of the buffers, the
+    waves behind this one on their way while it is multiplied: as many copies
+    as the sequence has blocks of its own.  The waves of a call are one
+    stream, in the sequences' order: a wave asks for the one buffers - 1
+    behind it, which near a sequence's end is the first of the next
+    sequence's (scratch lives across grid steps, and the grid is sequential),
+    so that a sequence does not begin by waiting for a copy with nothing to
+    hide it.  ``wave_ref`` counts the waves since the call began: a wave's
+    buffer is its number's remainder."""
+    del place_ref  # the index maps' own
+    N, P = buf.shape[:2]
+    b = pl.program_id(0)
+    B = pl.num_programs(0)
+    H = q_ref.shape[1]
+    Hkv = H // groups
+    ctx = ctx_ref[b]
+
+    def own(seq):
+        """A sequence's first own block and how many it has (one at least:
+        a run ends before the block of the write position)."""
+        return skip_ref[seq], last_ref[seq] + 1 - skip_ref[seq]
+
+    def copy(seq, block, slot, i):
+        return pltpu.make_async_copy(
+            kv_hbm.at[pl.ds(table_ref[seq, block], 1)],
+            buf.at[slot, i],
+            sem.at[slot],
+        )
+
+    def start(seq, j, number):
+        """Ask for wave j of a sequence, the call's wave ``number``: a copy a
+        block, none past the sequence's last and none behind the last
+        sequence.  Rolled, as the waves are: one body to trace, lower and
+        compile whatever the wave's size."""
+        at = jnp.minimum(seq, B - 1)
+        first, n = own(at)
+        count = jnp.where(seq < B, jnp.minimum(n - j * P, P), 0)
+        slot = jax.lax.rem(number, N)
+
+        def one(i, _):
+            copy(at, first + j * P + i, slot, i).start()
+
+        jax.lax.fori_loop(0, count, one, None)
+
+    def behind(seq, j):
+        """The wave behind wave j of a sequence in the call's stream."""
+        _, n = own(jnp.minimum(seq, B - 1))
+        closes = (j + 1) * P >= n
+        return jnp.where(closes, seq + 1, seq), jnp.where(closes, 0, j + 1)
+
+    def stream(seq, j):
+        """The ``N - 1`` waves behind wave j of a sequence, nearest first."""
+        waves = []
+        for _ in range(1, N):
+            seq, j = behind(seq, j)
+            waves.append((seq, j))
+        return waves
+
+    @pl.when(b == 0)
+    def _once():
+        # Places of a buffer that no copy has written yet are multiplied
+        # under weights of zero: they must hold numbers.
+        buf[...] = jnp.zeros_like(buf)
+        wave_ref[0] = 0
+        # the call's first waves, but the last of those wave 0 has behind it
+        for number, at in enumerate([(0, 0)] + stream(0, 0)[:-1]):
+            start(*at, number)
+
+    resumes = skip_ref[b] > 0
+
+    @pl.when(resumes)
+    def _resume():
+        m_ref[...] = m0_ref[...]
+        l_ref[...] = l0_ref[...]
+        acc_ref[...] = acc0_ref[...]
+
+    @pl.when(jnp.logical_not(resumes))
+    def _init():
+        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    compute_dtype = q_ref.dtype if mxu_native else jnp.float32
+    q = (q_ref[0].astype(jnp.float32) * scale).astype(compute_dtype)
+    shape = (H, block_size * Hkv)
+    own_head = _own_head(shape, H, groups)
+    position = jax.lax.div(jax.lax.broadcasted_iota(jnp.int32, shape, 1), Hkv)
+    first, n = own(b)
+    waves = (n + P - 1) // P
+    before = wave_ref[0]
+
+    def wave(j, _):
+        number = before + j
+        slot = jax.lax.rem(number, N)
+        start(*stream(b, j)[-1], number + N - 1)
+        count = jnp.minimum(n - j * P, P)
+
+        def arrived(i, _):
+            copy(b, first, slot, i).wait()
+
+        jax.lax.fori_loop(0, count, arrived, None)
+        at = (first + j * P) * block_size
+
+        def hide(i, s):
+            # blocks past the sequence's last lie past its context too
+            seen = position < ctx - (at + i * block_size)
+            return jnp.where(own_head & seen, s, NEG_INF)
+
+        _attend_rows(
+            q, [buf.at[slot, i] for i in range(P)], hide, m_ref, l_ref,
+            acc_ref, packed=packed,
+        )
+
+    jax.lax.fori_loop(0, waves, wave, None)
+    wave_ref[0] = before + waves
+    _normalised(out_ref, l_ref, acc_ref)
 
 
 def _shared_kernel(
@@ -417,13 +568,11 @@ def shared_prefix_plan(
     context_len: jnp.ndarray,
     *,
     block_size: int,
-    blocks_per_step: int = BLOCKS_PER_STEP,
 ) -> dict:
     """Which sequences' tables begin with the same run of full blocks, the
-    groups the shared pass takes them in, and the walk's grid as the list of
-    the steps that have something to read.  All of it is data (int32 arrays of
-    shapes fixed by the table's), found once a decode step: every layer sees
-    the same table, shifted.
+    groups the shared pass takes them in, and where each sequence's walk
+    begins.  All of it is data (int32 arrays of shapes fixed by the table's),
+    found once a decode step: every layer sees the same table, shifted.
 
     A sequence's leader is the first row with the same first block; its own
     run is the count of leading columns equal to the leader's that lie wholly
@@ -433,15 +582,14 @@ def shared_prefix_plan(
     group, or in a set whose run is 0 (idle slots on the scratch block among
     them), walks its whole table.  Keys: ``shared`` (each group's table row,
     run and members) and ``walk`` (each sequence's place in the shared pass's
-    results; each step's sequence, first block and flags: bit 0 opens the
-    sequence, bit 1 closes it, bit 2 opens it after a shared pass): the two
-    kernels' scalar prefetch after the table; ``shared_steps`` and
-    ``walk_steps``: their grids' lengths; ``read_blocks`` (what the two read:
-    each group's run once and every sequence's rest) and ``walked_blocks``
-    (what a walk of every table reads)."""
+    results and the first block of its own: its run, 0 where it shares
+    nothing): the two kernels' scalar prefetch after the table;
+    ``shared_steps``: the shared pass's grid; ``read_blocks`` (what the two
+    read: each group's run once and every sequence's rest, which is what the
+    walk copies) and ``walked_blocks`` (what a walk of every table reads)."""
     i32 = jnp.int32
     B, M = block_table.shape
-    G, P = SHARED_SEQUENCES, blocks_per_step
+    G = SHARED_SEQUENCES
     ctx = context_len.astype(i32)
     rows = jnp.arange(B, dtype=i32)
     leader = jnp.argmax(
@@ -477,33 +625,17 @@ def shared_prefix_plan(
         jnp.repeat(group_row, G),  # an empty place asks again for the first
     )
 
-    # The walk: every sequence's rest, its write position's block at least,
-    # as the list of (sequence, step) in the sequences' order.
+    # The walk: every sequence's rest, its write position's block at least.
     blocks = jnp.maximum(ctx - 1, 0) // block_size + 1
-    rest = blocks - skip
-    walk_counts = -(-rest // P)
-    ends = jnp.cumsum(walk_counts)
-    at = jnp.arange(B * -(-M // P), dtype=i32)
-    owner = jnp.minimum(
-        jnp.sum(ends[None, :] <= at[:, None], axis=1), B - 1
-    ).astype(i32)
-    step = at - (ends - walk_counts)[owner]
-    flags = (
-        (step == 0) & (skip[owner] == 0)
-        | 2 * (step == walk_counts[owner] - 1)
-        | 4 * ((step == 0) & (skip[owner] > 0))
-    )
-    walk = (slot, owner, skip[owner] + step * P, flags)
     return {
-        "walk": tuple(a.astype(i32) for a in walk),
+        "walk": (slot, skip.astype(i32)),
         "shared": tuple(
             a.astype(i32) for a in (group_row, group_run, members)
         ),
-        "walk_steps": ends[-1].astype(i32),
         # a grid of no step at all is not asked of the compiler: one step
         # that reads nothing where nobody shares
         "shared_steps": jnp.maximum(jnp.sum(heads), 1).astype(i32),
-        "read_blocks": (jnp.sum(group_run) + jnp.sum(rest)).astype(i32),
+        "read_blocks": (jnp.sum(group_run) + jnp.sum(blocks - skip)).astype(i32),
         "walked_blocks": jnp.sum(blocks).astype(i32),
     }
 
@@ -512,7 +644,7 @@ def shared_prefix_plan(
     jax.jit,
     static_argnames=(
         "interpret", "blocks_per_step", "mxu_native", "heads_first", "packed",
-        "shared_blocks_per_step",
+        "shared_blocks_per_step", "walk_blocks_per_wave",
     ),
 )
 def paged_decode_attention_pallas(
@@ -522,13 +654,14 @@ def paged_decode_attention_pallas(
     context_len: jnp.ndarray,
     *,
     interpret: bool = False,
-    blocks_per_step: int = BLOCKS_PER_STEP,
+    blocks_per_step: int | None = None,
     mxu_native: bool = MXU_NATIVE,
     start: jnp.ndarray | None = None,
     heads_first: bool = False,
     packed: bool = False,
     plan: dict | None = None,
     shared_blocks_per_step: int = SHARED_BLOCKS_PER_STEP,
+    walk_blocks_per_wave: int | None = None,
 ) -> jnp.ndarray:
     """q: [B, H, D]; kv_layer: [num_blocks, 2, bs, Hkv, D], or
     ``heads_first``: [num_blocks, 2, Hkv, bs, D], or ``packed``:
@@ -539,10 +672,14 @@ def paged_decode_attention_pallas(
     table a sequence still sees, as in ``paged_attention``; without it
     the kernel is the one it was.  Returns [B, H, D] in q.dtype.
 
-    Without ``heads_first`` and ``start``, runs of blocks that several tables
-    begin with are read once for the sequences that share them
-    (``shared_prefix_plan``, which a model's decode step makes once for all
-    its layers and hands in as ``plan``; made here when it is not).
+    With ``heads_first`` or ``start`` the grid is tables by steps of
+    ``blocks_per_step`` blocks, which the caller states.  Without them, runs
+    of blocks that several tables begin with are read once for the sequences
+    that share them, and each sequence's own blocks are copied by the walk,
+    ``walk_blocks_per_wave`` at a time (``walk_wave``'s where not given: the
+    tests' small tables ask for small waves); ``shared_prefix_plan``, which a
+    model's decode step makes once for all its layers and hands in as
+    ``plan``, is made here when it is not.
 
     ``mxu_native=True`` keeps the attention dots in the input dtype
     (bf16 operands, f32 accumulation) instead of upcasting K/V to f32 in
@@ -563,21 +700,38 @@ def paged_decode_attention_pallas(
     else:
         _, _, block_size, Hkv, _ = kv_layer.shape
     groups = H // Hkv
+    window = [a for a in (start,) if a is not None]
+    windowed = len(window) == 1
+    if not heads_first:
+        kv_layer = kv_layer.reshape(
+            kv_layer.shape[: 1 if packed else 2]
+            + (block_size * Hkv, q.shape[2])
+        )
+    # The shared pass and the walk: where the table's columns are the
+    # sequence's positions from 0 (a window layer's start hides part of a
+    # prefix from each sequence on its own).
+    if not (heads_first or windowed):
+        if not isinstance(plan, dict):
+            plan = shared_prefix_plan(
+                block_table, context_len, block_size=block_size
+            )
+        out = _shared_pass_and_walk(
+            q, kv_layer, block_table, context_len, plan,
+            block_size=block_size, groups=groups, scale=scale,
+            shared_blocks_per_step=shared_blocks_per_step,
+            blocks_per_wave=walk_blocks_per_wave, mxu_native=mxu_native,
+            packed=packed, interpret=interpret,
+        )
+        return out[..., D:] if packed else out
+
+    if packed:
+        raise ValueError("packed slots are walked, not stepped through")
+    if blocks_per_step is None:
+        raise ValueError("the grid of tables by steps: blocks_per_step")
     max_blocks = block_table.shape[1]
     P_STEP = blocks_per_step
     n_steps = -(-max_blocks // P_STEP)
-    window = [a for a in (start,) if a is not None]
-    windowed = len(window) == 1
-    # The grid as the plan's list of steps, after its shared pass: where the
-    # table's columns are the sequence's positions from 0 (a window layer's
-    # start hides part of a prefix from each sequence on its own).
-    listed = not (heads_first or windowed)
-    if listed and not isinstance(plan, dict):
-        plan = shared_prefix_plan(
-            block_table, context_len, block_size=block_size,
-            blocks_per_step=P_STEP,
-        )
-    if not listed and max_blocks % P_STEP:
+    if max_blocks % P_STEP:
         # Pad table columns; pads resolve to the last valid block and
         # are masked by context_len in the kernel.
         block_table = jnp.pad(
@@ -587,29 +741,13 @@ def paged_decode_attention_pallas(
 
     scalars = [block_table, context_len] + window
     if not heads_first:
-        kv_layer = kv_layer.reshape(
-            kv_layer.shape[: 1 if packed else 2]
-            + (block_size * Hkv, q.shape[2])
-        )
         # The sequence's last valid block, once for all index maps: the
         # scalar core runs every operand's map twice a grid step, and a
         # division in each was a tenth of the kernel's time.  (The
         # heads-first maps still divide: ROADMAP.)
-        scalars.append(jnp.maximum((context_len - 1) // block_size, 0))
+        scalars.append(_last_block(context_len, block_size))
     zeros = (0,) * (kv_layer.ndim - 1)
     kv_block = (1,) + kv_layer.shape[1:]
-    Dq = q.shape[2]
-    resumed = ()  # the shared pass's results, which the walk resumes from
-    if listed:
-        if plan["walk"][1].shape[0] != B * n_steps:
-            raise ValueError("the plan was made for another table or step")
-        resumed = _shared_pass(
-            q, kv_layer, block_table, plan, kv_block=kv_block,
-            groups=groups, scale=scale,
-            blocks_per_step=shared_blocks_per_step, mxu_native=mxu_native,
-            packed=packed, interpret=interpret,
-        )
-        scalars += plan["walk"]
 
     def kv_index(i):
         # Sub-block i of step j; past-context steps revisit the last
@@ -624,49 +762,28 @@ def paged_decode_attention_pallas(
                 jc = jnp.minimum(j * P_STEP + i, more[-1][b])
             return (table_ref[b, jc],) + zeros
 
-        def listed_index(w, table_ref, ctx_ref, last_ref, slot_ref, seq_ref,
-                         first_ref, flag_ref):
-            b = seq_ref[w]
-            return (
-                table_ref[b, jnp.minimum(first_ref[w] + i, last_ref[b])],
-            ) + zeros
+        return index
 
-        return listed_index if listed else index
-
-    if listed:
-
-        def of_sequence(w, *refs):
-            return (refs[4][w], 0, 0)
-
-        def of_slot(w, *refs):
-            return (refs[3][refs[4][w]], 0)
-
-    else:
-
-        def of_sequence(b, j, *_):
-            return (b, 0, 0)
+    def of_sequence(b, j, *_):
+        return (b, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(scalars),
-        grid=(plan["walk_steps"],) if listed else (B, n_steps),
+        grid=(B, n_steps),
         in_specs=[
-            pl.BlockSpec((1, H, Dq), of_sequence, memory_space=pltpu.VMEM),
+            pl.BlockSpec((1, H, D), of_sequence, memory_space=pltpu.VMEM),
         ]
         + [
             pl.BlockSpec(kv_block, kv_index(i), memory_space=pltpu.VMEM)
             for i in range(P_STEP)
-        ]
-        + [
-            pl.BlockSpec((H, a.shape[1]), of_slot, memory_space=pltpu.VMEM)
-            for a in resumed
         ],
         out_specs=pl.BlockSpec(
-            (1, H, Dq), of_sequence, memory_space=pltpu.VMEM
+            (1, H, D), of_sequence, memory_space=pltpu.VMEM
         ),
         scratch_shapes=[
             pltpu.VMEM((H, 128), jnp.float32),
             pltpu.VMEM((H, 128), jnp.float32),
-            pltpu.VMEM((H, Dq), jnp.float32),
+            pltpu.VMEM((H, D), jnp.float32),
         ],
     )
     kernel = functools.partial(
@@ -678,10 +795,8 @@ def paged_decode_attention_pallas(
         mxu_native=mxu_native,
         windowed=windowed,
         heads_first=heads_first,
-        packed=packed,
-        listed=listed,
     )
-    out = pl.pallas_call(
+    return pl.pallas_call(
         kernel,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         grid_spec=grid_spec,
@@ -690,9 +805,77 @@ def paged_decode_attention_pallas(
         *(a.astype(jnp.int32) for a in scalars),
         q,
         *([kv_layer] * P_STEP),
-        *resumed,
     )
-    return out[..., D:] if packed else out
+
+
+def _last_block(context_len, block_size: int):
+    return jnp.maximum((context_len - 1) // block_size, 0)
+
+
+def walk_wave(slot_bytes: int) -> int:
+    """Blocks a wave of the walk, from what a slot weighs (the readings are
+    beside the constants)."""
+    return min(max(WALK_WAVE_BYTES // slot_bytes, 1), WALK_WAVE_BLOCKS)
+
+
+def _shared_pass_and_walk(q, kv_layer, block_table, context_len, plan, *,
+                          block_size, shared_blocks_per_step, blocks_per_wave,
+                          interpret, **statics):
+    """The shared pass over the plan's groups, then every sequence's walk of
+    its own blocks, resumed from what the pass left: one sequence a grid
+    step, the pool handed in where it lies."""
+    B, H, Dq = q.shape
+    kv_block = (1,) + kv_layer.shape[1:]
+    place, skip = plan["walk"]
+    if place.shape != (B,):
+        raise ValueError("the plan was made for another table")
+    resumed = _shared_pass(
+        q, kv_layer, block_table, plan, kv_block=kv_block,
+        blocks_per_step=shared_blocks_per_step, interpret=interpret,
+        **statics,
+    )
+    if blocks_per_wave is None:
+        blocks_per_wave = walk_wave(
+            kv_layer.dtype.itemsize * math.prod(kv_block))
+
+    def of_sequence(b, *_):
+        return (b, 0, 0)
+
+    def of_place(b, table_ref, ctx_ref, last_ref, place_ref, skip_ref):
+        return (place_ref[b], 0)
+
+    scalars = (block_table, context_len,
+               _last_block(context_len, block_size), place, skip)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=len(scalars),
+        grid=(B,),
+        in_specs=[
+            pl.BlockSpec((1, H, Dq), of_sequence, memory_space=pltpu.VMEM),
+            pl.BlockSpec(memory_space=pl.ANY),
+        ]
+        + [
+            pl.BlockSpec((H, a.shape[1]), of_place, memory_space=pltpu.VMEM)
+            for a in resumed
+        ],
+        out_specs=pl.BlockSpec(
+            (1, H, Dq), of_sequence, memory_space=pltpu.VMEM
+        ),
+        scratch_shapes=[
+            pltpu.VMEM((H, 128), jnp.float32),
+            pltpu.VMEM((H, 128), jnp.float32),
+            pltpu.VMEM((H, Dq), jnp.float32),
+            pltpu.VMEM((WALK_BUFFERS, blocks_per_wave) + kv_block,
+                       kv_layer.dtype),
+            pltpu.SemaphoreType.DMA((WALK_BUFFERS,)),
+            pltpu.SMEM((1,), jnp.int32),
+        ],
+    )
+    return pl.pallas_call(
+        functools.partial(_walk_kernel, block_size=block_size, **statics),
+        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        grid_spec=grid_spec,
+        interpret=interpret,
+    )(*(a.astype(jnp.int32) for a in scalars), q, kv_layer, *resumed)
 
 
 def _shared_pass(q, kv_layer, block_table, plan, *, kv_block, blocks_per_step,

@@ -366,12 +366,12 @@ def test_pallas_decode_in_the_step_agrees_with_the_gather():
     close(np.asarray(one[0]), reference(tuple(seq["tokens"]))[-1])
 
 
-@pytest.mark.parametrize("blocks_per_step", (2, 4))
-def test_paged_kernel_over_packed_slots_is_the_gather(blocks_per_step):
+@pytest.mark.parametrize("blocks_per_wave", (2, 4))
+def test_paged_kernel_over_packed_slots_is_the_gather(blocks_per_wave):
     """Slots [block, Hkv, 2 Dh], K in the lower half of a row and V in the
     upper: the kernel's output (the query padded over V's lanes, the result
     read from them) against the XLA gather over the slots unpacked, with a
-    table whose columns the blocks a step do not divide."""
+    table whose columns the blocks a wave of the walk do not divide."""
     from llm_d_kv_cache_manager_tpu.ops.paged_attention import paged_attention
     from llm_d_kv_cache_manager_tpu.ops.paged_decode_pallas import (
         paged_decode_attention_pallas,
@@ -383,8 +383,8 @@ def test_paged_kernel_over_packed_slots_is_the_gather(blocks_per_step):
     table = jnp.asarray(rng.permutation(12)[:9].reshape(3, 3), jnp.int32)
     ctx = jnp.asarray([40, 17, 33])
     got = paged_decode_attention_pallas(
-        q, pool, table, ctx, packed=True, blocks_per_step=blocks_per_step,
-        interpret=True)
+        q, pool, table, ctx, packed=True,
+        walk_blocks_per_wave=blocks_per_wave, interpret=True)
     want = paged_attention(
         q, jnp.stack((pool[..., :16], pool[..., 16:]), axis=1), table, ctx)
     close(np.asarray(got), np.asarray(want), 1e-5)
@@ -626,7 +626,8 @@ def test_a_float8_pass_fails_the_tolerance_the_decode_comparison_holds():
 # must trace to the text they had.  A PR that changes one of those programs
 # on purpose reads its digest anew: `llama.decode.True` was read anew in PR 34
 # (the paged kernel finds the shared prefixes from the table, reads each once
-# in a shared pass and walks the rest on a grid of listed steps);
+# in a shared pass and walks the rest) and in PR 41 (the walk is a sequence a
+# grid step and copies its own blocks; the plan lists sequences, not steps);
 # `llama.decode.False` stands, because off the TPU and uninterpreted the step
 # keeps the XLA gather and makes no plan, and so do the six `afmoe.*` and the
 # four `llama.miss/hit.*`.
@@ -638,7 +639,7 @@ TEXT_AT_PR_32 = {
     "llama.miss.False": "90c7da6d6fb5ae9d", "llama.hit.False": "364331e0e1475faa",
     "llama.decode.False": "53c6a3b76efc093d",
     "llama.miss.True": "90c7da6d6fb5ae9d", "llama.hit.True": "364331e0e1475faa",
-    "llama.decode.True": "7fe2bee41cea2f57",
+    "llama.decode.True": "e0e9daad8ea04ecb",
 }
 
 

@@ -1,7 +1,9 @@
 """Pallas paged-decode kernel vs the XLA gather implementation: slots
-[2, block, Hkv, D] (the `llama` pool's), many blocks a grid step as one
-online-softmax update.  (Heads-first slots and window starts:
-tests/test_afmoe_pod.py.)"""
+[2, block, Hkv, D] (the `llama` pool's; `phi4flash`'s with four query heads a
+KV head) and `packed` ones (`lfm2moe`'s), the shared pass and the walk that
+copies each sequence's own blocks, a wave as one online-softmax update.
+(Heads-first slots and window starts, the grid of tables by steps:
+tests/test_afmoe_pod.py, tests/test_phi4flash_pod.py.)"""
 
 import jax
 import jax.numpy as jnp
@@ -9,8 +11,8 @@ import numpy as np
 import pytest
 
 from llm_d_kv_cache_manager_tpu.ops.paged_attention import paged_attention
+from llm_d_kv_cache_manager_tpu.ops import paged_decode_pallas
 from llm_d_kv_cache_manager_tpu.ops.paged_decode_pallas import (
-    BLOCKS_PER_STEP,
     paged_decode_attention_pallas,
     shared_prefix_plan,
 )
@@ -48,34 +50,36 @@ def close(got, ref):
     )
 
 
+SERVED_WAVE = paged_decode_pallas.walk_wave(2 * BS * 8 * 128 * 2)
+
 CASES = {
-    # name: (B, H, Hkv, D, max_blocks, blocks a step, contexts)
+    # name: (B, H, Hkv, D, max_blocks, blocks a wave, contexts)
     "exact_block_multiple": (1, 8, 4, 64, 8, 4, [64]),
     "ragged_inside_a_block": (2, 8, 2, 64, 8, 4, [61, 33]),
     "mha_tiny_and_table_full": (3, 4, 4, 128, 8, 4, [16, 7, 128]),
     "columns_not_a_multiple_of_the_step": (2, 8, 4, 64, 7, 4, [97, 112]),
     # The chat cell's heads (internlm2-1.8b: 16 query, 8 KV, 128 wide).
-    # Contexts end inside a block, inside a grid step (block 5 of 0..7 in
-    # the second step of 4), on a step's edge, and at the table's last
-    # column.
+    # Contexts end inside a block, inside a wave (block 5 of 0..7 in the
+    # second wave of 4), on a wave's edge, and at the table's last column.
     "chat_heads_ragged": (4, 16, 8, 128, 12, 4, [83, 96, 128, 192]),
     "chat_heads_one_token": (2, 16, 8, 128, 12, 4, [1, 17]),
     "chat_heads_columns_not_a_multiple": (2, 16, 8, 128, 11, 4, [176, 70]),
-    # The served blocks a step, two steps, the second partly past the end.
-    "chat_heads_served_step": (1, 16, 8, 128, 2 * BLOCKS_PER_STEP - 3,
-                               BLOCKS_PER_STEP, [BS * BLOCKS_PER_STEP + 40]),
+    # The served blocks a wave (None: `walk_wave` of the slot's bytes), two
+    # waves, the second partly past the end.
+    "chat_heads_served_wave": (1, 16, 8, 128, 2 * SERVED_WAVE - 3, None,
+                               [BS * SERVED_WAVE + 40]),
 }
 
 
 @pytest.mark.parametrize("name", CASES)
 def test_matches_xla_gather(name):
-    B, H, Hkv, D, max_blocks, step, ctx = CASES[name]
+    B, H, Hkv, D, max_blocks, wave, ctx = CASES[name]
     q, kv, table, ctx_arr = make_case(
         jax.random.PRNGKey(0), B, H, Hkv, D, 64, max_blocks, ctx
     )
     ref = paged_attention(q, kv, table, ctx_arr)
     got = paged_decode_attention_pallas(
-        q, kv, table, ctx_arr, interpret=True, blocks_per_step=step
+        q, kv, table, ctx_arr, interpret=True, walk_blocks_per_wave=wave
     )
     close(got, ref)
 
@@ -92,17 +96,18 @@ def test_merged_pool_with_a_layer_offset(layer):
     )
     table = table % N
     got = paged_decode_attention_pallas(
-        q, kv, table + layer * N, ctx_arr, interpret=True, blocks_per_step=4
+        q, kv, table + layer * N, ctx_arr, interpret=True,
+        walk_blocks_per_wave=4,
     )
     close(got, paged_attention(q, kv[layer * N : (layer + 1) * N], table,
                                ctx_arr))
 
 
-@pytest.mark.parametrize("blocks_per_step", [1, 2, 8])
-def test_blocks_per_step_variants_match(blocks_per_step):
-    """The blocks a grid step takes (a static argument; `BLOCKS_PER_STEP`
-    serves) must be correctness-neutral at every value (ragged contexts +
-    non-divisible tables)."""
+@pytest.mark.parametrize("blocks_per_wave", [1, 2, 8])
+def test_blocks_per_wave_variants_match(blocks_per_wave):
+    """The blocks a wave of the walk takes (a static argument; `walk_wave`
+    of the slot's bytes serves) must be correctness-neutral at every value
+    (ragged contexts + non-divisible tables)."""
     q, kv, table, ctx_arr = make_case(
         jax.random.PRNGKey(2), 2, 8, 4, 64, 64, 7, [97, 33]
     )
@@ -110,7 +115,7 @@ def test_blocks_per_step_variants_match(blocks_per_step):
     got = paged_decode_attention_pallas(
         q, kv, table, ctx_arr,
         interpret=True,
-        blocks_per_step=blocks_per_step,
+        walk_blocks_per_wave=blocks_per_wave,
     )
     close(got, ref)
 
@@ -125,7 +130,7 @@ def test_mxu_native_variants_match(mxu_native):
     ref = paged_attention(q, kv, table, ctx_arr)
     got = paged_decode_attention_pallas(
         q, kv, table, ctx_arr, interpret=True, mxu_native=mxu_native,
-        blocks_per_step=4,
+        walk_blocks_per_wave=4,
     )
     close(got, ref)
 
@@ -151,7 +156,7 @@ def test_other_heads_rows_do_not_leak():
     )
     loud = kv.at[:, 1, :, 2].set(1000.0)  # V of KV head 2
     got = paged_decode_attention_pallas(
-        q, loud, table, ctx_arr, interpret=True, blocks_per_step=2
+        q, loud, table, ctx_arr, interpret=True, walk_blocks_per_wave=2
     )
     ref = paged_attention(q, kv, table, ctx_arr)
     groups = 2
@@ -198,8 +203,8 @@ def make_shared_case(key, H, Hkv, D, max_blocks, prompts, sequences,
 
 
 SHARED_CASES = {
-    # name: (prompts' blocks, sequences, blocks a step of the walk and of
-    # the shared pass, layers and layer)
+    # name: (prompts' blocks, sequences, blocks a wave of the walk and a step
+    # of the shared pass, layers and layer)
     # Sets of 3 and 2 in no slot order, a sequence on a prompt of its own
     # with nobody to share it, one with no prompt, an idle slot.
     "two_uneven_sets_a_loner_and_an_idle_slot": (
@@ -226,53 +231,85 @@ SHARED_CASES = {
     "nobody_shares": ([], [(None, 70), (None, 33), None], 4, 2, 1, 0),
     "merged_pool_with_a_layer_offset": (
         [5], [(0, 100), (None, 50), (0, 90)], 4, 2, 3, 2),
+    # The walk that copies its own blocks (PR 41).  Contexts that fill their
+    # last block: of the sharers' own blocks, of a wave (2 own blocks of 2 a
+    # wave), and of a sequence with no prompt.
+    "contexts_end_on_a_block_boundary": (
+        [3], [(0, 80), (0, 96), (None, 64), (0, 112)], 2, 2, 1, 0),
+    # One block a sequence: a sharer's only own block and lone sequences of a
+    # token, of a few and of a block, one wave each and the next sequence's
+    # asked for while it is multiplied.
+    "contexts_of_a_single_block": (
+        [2], [(None, 1), (0, 40), (None, 9), (0, 33), (None, 16)], 4, 2, 1, 0),
+    # The run is the whole table but the last block: the walk copies one
+    # block, resumed from the shared pass; the third has two of its own.
+    "shared_run_is_all_but_the_last_block": (
+        [11], [(0, 180), (0, 177), (0, 192)], 2, 4, 1, 0),
+    # Idle slots first, between and last: each copies the scratch block once,
+    # and the first wave of the sequence behind one is asked for by it.
+    "idle_slots_on_the_scratch_block": (
+        [4], [None, (0, 90), None, None, (0, 120), (None, 37), None],
+        2, 2, 1, 0),
+    # No first block in common: the shared pass's one empty step, and every
+    # table walked whole, 1 to 11 blocks in waves of 4.
+    "nobody_shares_ragged": (
+        [], [(None, 176), (None, 16), (None, 65), (None, 129), (None, 3)],
+        4, 2, 1, 0),
+    "every_sequence_in_one_group": (
+        [5], [(0, 81 + 13 * i) for i in range(8)], 4, 2, 1, 0),
+    # 7, 5 and 6 blocks of their own in waves of 2 (three waves and four: the
+    # buffers change hands inside a sequence and between two), 9 with no
+    # prompt, and one wave.
+    "more_own_blocks_than_two_waves": (
+        [3], [(0, 160), (0, 128), (None, 140), (0, 139), (0, 50)], 2, 2, 1, 0),
 }
 
+# The three slot layouts the walk serves: (H, Hkv, D), `packed` or not.
+# `llama`: internlm2-1.8b's heads; `packed`: K and V side by side in the lanes
+# at head size 64 (models/lfm2moe.py); `pairwise`: four query heads a KV head
+# (models/phi4flash.py's differential attention).
+LAYOUTS = {"llama": (16, 8, 128), "packed": (8, 4, 64), "pairwise": (8, 2, 128)}
 
-@pytest.mark.parametrize("slots", ("llama", "packed"))
+
+def shared_case(name, slots, key=7):
+    prompts, sequences, wave, shared_step, layers, layer = SHARED_CASES[name]
+    H, Hkv, D = LAYOUTS[slots]
+    q, kv, table, ctx, ref = make_shared_case(
+        jax.random.PRNGKey(key), H, Hkv, D, 12, prompts, sequences,
+        packed=slots == "packed", layers=layers, layer=layer)
+    statics = dict(interpret=True, walk_blocks_per_wave=wave,
+                   shared_blocks_per_step=shared_step,
+                   packed=slots == "packed")
+    return (q, kv, table, ctx), statics, ref
+
+
+@pytest.mark.parametrize("slots", LAYOUTS)
 @pytest.mark.parametrize("name", SHARED_CASES)
 def test_shared_prefix_pass_matches_xla_gather(name, slots):
-    prompts, sequences, step, shared_step, layers, layer = SHARED_CASES[name]
-    H, Hkv, D = (16, 8, 128) if slots == "llama" else (8, 4, 64)
-    q, kv, table, ctx, ref = make_shared_case(
-        jax.random.PRNGKey(7), H, Hkv, D, 12, prompts, sequences,
-        packed=slots == "packed", layers=layers, layer=layer)
-    got = paged_decode_attention_pallas(
-        q, kv, table, ctx, interpret=True, blocks_per_step=step,
-        shared_blocks_per_step=shared_step, packed=slots == "packed")
-    close(got, ref)
+    args, statics, ref = shared_case(name, slots)
+    close(paged_decode_attention_pallas(*args, **statics), ref)
 
 
 def test_the_plan_finds_the_sets_and_counts_what_is_read():
     """What `shared_prefix_plan` hands the kernels for the first case's
-    tables: who resumes from which place of the shared pass's results, the
-    two grids, and the step's blocks read against a walk of every table."""
-    prompts, sequences, step, _, _, _ = SHARED_CASES[
+    tables: who resumes from which place of the shared pass's results and
+    where each walk begins, the shared pass's grid, and the step's blocks
+    read against a walk of every table."""
+    prompts, sequences, _, _, _, _ = SHARED_CASES[
         "two_uneven_sets_a_loner_and_an_idle_slot"]
     _, _, table, ctx, _ = make_shared_case(
         jax.random.PRNGKey(7), 16, 8, 128, 12, prompts, sequences)
-    plan = shared_prefix_plan(table, ctx, block_size=BS,
-                              blocks_per_step=step)
-    slot, seq, first, flags = (np.asarray(a) for a in plan["walk"])
+    plan = shared_prefix_plan(table, ctx, block_size=BS)
+    place, skip = (np.asarray(a) for a in plan["walk"])
     # prompt 0: rows 0, 3, 7 (group 0, run 6); prompt 1: rows 1, 5 (group 1,
     # run 4); rows 2 (idle), 4 (alone on its prompt) and 6 share nothing.
-    assert list(slot) == [0, 8, 0, 1, 0, 9, 0, 2]
-    n = int(plan["walk_steps"])
+    assert list(place) == [0, 8, 0, 1, 0, 9, 0, 2]
+    assert list(skip) == [6, 4, 0, 6, 0, 4, 0, 6]
     blocks = [-(-c // BS) for c in np.asarray(ctx)]
-    rest = [blocks[0] - 6, blocks[1] - 4, 1, blocks[3] - 6, blocks[4],
-            blocks[5] - 4, blocks[6], blocks[7] - 6]
-    assert n == sum(-(-r // step) for r in rest)
-    assert list(seq[:n]) == sorted(seq[:n])
-    opened = {int(b): int(f) for b, f, g in zip(seq[:n], first[:n], flags[:n])
-              if g & 5}
-    assert opened == {0: 6, 1: 4, 2: 0, 3: 6, 4: 0, 5: 4, 6: 0, 7: 6}
-    resumed = {int(b) for b, g in zip(seq[:n], flags[:n]) if g & 4}
-    assert resumed == {0, 1, 3, 5, 7}
-    assert sum(1 for g in flags[:n] if g & 2) == len(sequences)
     row, run, members = (np.asarray(a) for a in plan["shared"])
     assert int(plan["shared_steps"]) == 2
     assert list(row[:2]) == [0, 1] and list(run[:2]) == [6, 4]
     assert list(members[:16]) == [0, 3, 7, 0, 0, 0, 0, 0,
                                   1, 5, 1, 1, 1, 1, 1, 1]
-    assert int(plan["read_blocks"]) == 6 + 4 + sum(rest)
+    assert int(plan["read_blocks"]) == 6 + 4 + sum(blocks) - sum(skip)
     assert int(plan["walked_blocks"]) == sum(blocks)

@@ -92,23 +92,44 @@ def paged_kernels(hlo: str) -> set:
         r"%(\S*paged_decode_attention_pallas\S*) = .*tpu_custom_call", hlo))
 
 
-def test_paged_decode_kernel_compiles_for_the_llama_pool(one_chip):
-    """Slots [2, block, Hkv, Dh] as `llama._scan_layers` merges them: the
-    chat cell's pool of 24 layers x 3072 blocks, 32 sequences, 192 table
-    columns, at the served blocks a step, the shared pass (its strided reads
-    by KV head among what must compile) and the walk.  Both take the pool
-    where it lies: the [block * Hkv, Dh] view of a slot is a bitcast, and
-    what is copied and kept beside is the plan's integers, a group's query
-    rows and the shared pass's float32 results, a few megabytes."""
-    i32, B = jnp.int32, 32
+WALKED = {  # name: sequences, table columns, query heads, the pool, packed
+    # `llama._scan_layers` merges the chat cell's 24 layers x 3072 blocks
+    "internlm2-1.8b": (32, 192, (16, DH), (24 * 3072, 2, BLOCK, 8, DH), False),
+    # a layer's pool of `lfm2moe-chat-agents`, K and V side by side at 64
+    "lfm2-8b-a1b-l13": (64, 576, (32, 64), (16384, BLOCK, 8, 2 * 64), True),
+    # the full group of `phi4flash-reasoning-longgen`, ten pair-wise KV heads:
+    # stored with a block's rows merged (ten is no multiple of the chip's
+    # tile) and handed over with them apart, as `phi4flash._decode_attention`
+    "phi-4-mini-flash-reasoning": (64, 416, (40, DH),
+                                   (24576, 2, BLOCK * 10, DH), False),
+}
+
+
+@pytest.mark.parametrize("name", WALKED)
+def test_paged_decode_kernel_compiles_for_the_walked_pools(one_chip, name):
+    """The shared pass (its strided reads by KV head among what must compile)
+    and the walk that copies a sequence's own blocks a wave at a time, at the
+    three served slot layouts and the waves `walk_wave` gives them.  Both
+    take the pool where it lies: the [block * Hkv, Dh] view of a slot is a
+    bitcast, and what is copied and kept beside is the plan's integers, a
+    group's query rows and the shared pass's float32 results, a few
+    megabytes; the walk's two buffers of a wave are VMEM."""
+    i32 = jnp.int32
+    B, columns, heads, pool, packed = WALKED[name]
+
+    def walked(q, kv, table, context_len):
+        if kv.ndim == 4 and not packed:
+            kv = kv.reshape(kv.shape[:2] + (BLOCK, -1, DH))
+        return paged_decode_attention_pallas(q, kv, table, context_len,
+                                             packed=packed)
+
     compiled = compile_for(
-        one_chip, paged_decode_attention_pallas,
-        ((B, 16, DH), jnp.bfloat16),
-        ((24 * 3072, 2, BLOCK, 8, DH), jnp.bfloat16),
-        ((B, 192), i32), ((B,), i32))
+        one_chip, walked,
+        ((B,) + heads, jnp.bfloat16), (pool, jnp.bfloat16),
+        ((B, columns), i32), ((B,), i32))
     hlo = compiled.as_text()
     assert len(paged_kernels(hlo)) == 2
-    assert not re.search(rf"= bf16\[{24 * 3072},\S* copy\(", hlo)
+    assert not re.search(rf"= bf16\[{pool[0]},\S* copy\(", hlo)
     assert compiled.memory_analysis().temp_size_in_bytes < 8 << 20
 
 
@@ -387,7 +408,9 @@ def test_phi4flash_programs_compile_at_the_cells_shapes(one_chip, monkeypatch,
 # one: sha256 of str(jax.make_jaxpr(program)) of `lfm2moe`'s three programs
 # (`afmoe`'s and `llama`'s are pinned in tests/test_lfm2moe_pod.py), and of
 # every host-side array the two families' pods hand out over the scripted
-# run below.  A PR that changes one on purpose reads its digest anew.
+# run below.  A PR that changes one on purpose reads its digest anew:
+# `lfm2moe.decode.True` in PR 41 (the interpreted step holds the paged
+# kernel, whose walk became a sequence a grid step with its own copies).
 AT_PR_34 = {
     "afmoe.tables": "66348e6e9d5f9ed8", "lfm2moe.tables": "a43cc9a2ce3fa258",
     "lfm2moe.miss.False": "6b66837c74b57be8",
@@ -395,7 +418,7 @@ AT_PR_34 = {
     "lfm2moe.decode.False": "4855b16f775d4a8e",
     "lfm2moe.miss.True": "6b66837c74b57be8",
     "lfm2moe.hit.True": "a0f712e977db715f",
-    "lfm2moe.decode.True": "ea79eedce534c845",
+    "lfm2moe.decode.True": "6e7cb9a10c02e18c",
 }
 
 
