@@ -39,9 +39,13 @@ class KVGroupSpec:
     boundaries keep one (``snapshot_blocks``).  ``readers`` is how many
     layers read a slot where that is more than the ``num_layers`` whose K/V
     it holds (a cache that later layers attend over without one of their
-    own).  Block bytes, pool shapes and the scatter's geometry are read from
-    here by the pool below, by the pod's cache (models/pod.py) and by each
-    family's model step."""
+    own).  With ``latent_dim`` a slot is no K and V per head but, a position
+    a layer, ONE vector that is key and value at once (latent attention:
+    every query head scores over all ``latent_dim`` lanes of it and takes
+    its first ``value_dim`` as the value; ``num_kv_heads`` is 1 and
+    ``head_dim`` the latent's width).  Block bytes, pool shapes and the
+    scatter's geometry are read from here by the pool below, by the pod's
+    cache (models/pod.py) and by each family's model step."""
 
     num_layers: int
     block_size: int
@@ -70,6 +74,27 @@ class KVGroupSpec:
     state_shape: Optional[tuple] = None
     stride_blocks: Optional[int] = None
     readers: Optional[int] = None
+    # The latent kind: a slot as [block / 2, 2 * latent_dim], row r the
+    # positions r and r + block / 2 of the block, mirrored: [value_r | rest_r
+    # | rest_r+ | value_r+] (``pack_latent_blocks``).  A position's
+    # ``latent_dim`` lanes alone (576 = 4.5 x 128 in models/glm4moelite.py) are
+    # no whole number of the chip's 128-lane tiles: as [block, 576] the
+    # compiler either pads the pool to 640 lanes or makes its slot axis the
+    # minor one, and Mosaic refuses to slice it; two positions a row are 9
+    # tiles, both values start on a tile, and the pool lies as it is written
+    # (compiled for the v5e, PR 42: tests/test_tpu_compile.py).
+    latent_dim: Optional[int] = None
+    value_dim: Optional[int] = None
+
+    def __post_init__(self) -> None:
+        if self.latent_dim is None:
+            return
+        if (self.num_kv_heads != 1 or self.head_dim != self.latent_dim
+                or self.block_size % 2 or self.state_shape is not None
+                or not 0 < (self.value_dim or 0) <= self.latent_dim):
+            raise ValueError(
+                "a latent slot is one vector a position: one KV head of "
+                "latent_dim, value_dim of its lanes the value, an even block")
 
     @property
     def num_readers(self) -> int:
@@ -106,7 +131,7 @@ class KVGroupSpec:
             )
         return (
             self.num_layers
-            * 2
+            * (1 if self.latent_dim else 2)  # one vector, or K and V
             * self.block_size
             * self.num_kv_heads
             * self.head_dim
@@ -119,6 +144,8 @@ class KVGroupSpec:
         if self.state_shape is not None:
             ((shape, _),) = self.state_parts
             return (num_blocks,) + shape
+        if self.latent_dim:
+            return (num_blocks, self.block_size // 2, 2 * self.latent_dim)
         if self.packed:
             return (num_blocks, self.block_size, self.num_kv_heads,
                     2 * self.head_dim)
@@ -184,6 +211,43 @@ def scatter_kv_blocks(
     )
 
 
+def pack_latent_blocks(latent, block_size: int, value_dim: int):
+    """Per-position latents [..., T, latent_dim] (T a multiple of
+    ``block_size``) as the slots of a latent group
+    (``KVGroupSpec.layer_shape``): [..., T/block_size, block_size/2,
+    2*latent_dim], row r of a block its positions r and r + block_size/2 as
+    [value_r | rest_r | rest_r+ | value_r+]."""
+    *lead, T, _ = latent.shape
+    x = latent.reshape(*lead, T // block_size, 2, block_size // 2, -1)
+    a, b = x[..., 0, :, :], x[..., 1, :, :]
+    return jnp.concatenate(
+        (a, b[..., value_dim:], b[..., :value_dim]), axis=-1
+    )
+
+
+def unpack_latent_blocks(slots, value_dim: int):
+    """``pack_latent_blocks`` undone: slots [..., n, block/2, 2*latent_dim]
+    -> the positions' latents [..., n*block, latent_dim], in order."""
+    *lead, n, half, width = slots.shape
+    a, b = slots[..., : width // 2], slots[..., width // 2:]
+    b = jnp.concatenate(
+        (b[..., width // 2 - value_dim:], b[..., : width // 2 - value_dim]),
+        axis=-1,
+    )
+    return jnp.stack((a, b), axis=-3).reshape(*lead, n * 2 * half, width // 2)
+
+
+def scatter_latent_blocks(kv_layer, latent, block_ids, block_size, value_dim):
+    """``scatter_kv_blocks`` for a latent group: per-token latents
+    [B, T, latent_dim] into the slots of one layer's pool named by
+    ``block_ids`` ([B, T/block_size]), in the one layout
+    ``pack_latent_blocks`` states.  Only the named slots are written."""
+    slots = pack_latent_blocks(latent, block_size, value_dim)
+    return kv_layer.at[block_ids.reshape(-1)].set(
+        slots.reshape((-1,) + slots.shape[2:]).astype(kv_layer.dtype)
+    )
+
+
 @dataclass
 class KVCachePoolConfig:
     num_layers: int
@@ -192,6 +256,10 @@ class KVCachePoolConfig:
     num_kv_heads: int
     head_dim: int
     dtype: str = "bfloat16"
+    # a pool of latent slots (``KVGroupSpec``'s latent kind): one KV head of
+    # ``head_dim == latent_dim``, ``value_dim`` of its lanes the value
+    latent_dim: Optional[int] = None
+    value_dim: Optional[int] = None
 
     @property
     def spec(self) -> KVGroupSpec:
@@ -202,6 +270,8 @@ class KVCachePoolConfig:
             self.num_kv_heads,
             self.head_dim,
             self.dtype,
+            latent_dim=self.latent_dim,
+            value_dim=self.value_dim,
         )
 
 

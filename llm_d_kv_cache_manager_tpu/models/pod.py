@@ -8,7 +8,14 @@ the pool while it is cached; the allocator hands out free blocks first, then
 least-recently-used cached blocks that no live sequence references, and gives
 their hashes back as evicted (the engine publishes them as ``BlockRemoved``).
 With no ``cache_policy`` this is all there is, rule for rule the benchmark's
-``harness/pod.py``.
+``harness/pod.py``.  What a slot holds is the family's ``cache_groups`` spec's
+business, not the pod's: where the one group is of the latent kind (one vector
+a position a layer, key and value at once: models/glm4moelite.py) a block of
+16 tokens is still a block, and the only thing the pod adds is that a decode
+call says what the step reads of the group (``kv.read``: ``full_blocks``,
+``latent_bytes`` from the spec's ``read_nbytes``, and ``step_bytes``, those
+and the weights a step reads, which the policy's ``step_weight_nbytes``
+states), since no window or state group is there to say it.
 
 Two groups (a model that mixes window and full attention layers, after vLLM's
 hybrid KV-cache manager).  The *full group* is the above: one slot per logical
@@ -584,6 +591,7 @@ class Pod:
         # the family's `cache_groups`: what a slot of each group holds, how
         # many layers read it; the groups and the spans read them from here
         self.specs = policy.get("specs") or {}
+        self.step_weight_nbytes = policy.get("step_weight_nbytes", 0)
         self.window = (WindowGroup(self, **policy["window"])
                        if policy.get("window") else None)
         self.state = (StateGroup(self, **policy["state"])
@@ -673,6 +681,16 @@ class Pod:
         alone with one group, every group's tables with more."""
         table = np.asarray(table, np.int32)
         if not self.groups:
+            full = self.specs.get("full")
+            if kind == "decode" and full is not None and full.latent_dim:
+                # the full group alone: what the step reads of it
+                current = (np.asarray(context_len, np.int64) - 1) // full.block_size
+                blocks = int((current + 1).sum())
+                with span("kv.read") as s:
+                    s.set_attr("full_blocks", blocks)
+                    s.set_attr("latent_bytes", blocks * full.read_nbytes)
+                    s.set_attr("step_bytes", blocks * full.read_nbytes
+                               + self.step_weight_nbytes)
             return table
         tables, reads = {}, {}
         for group in self.groups:

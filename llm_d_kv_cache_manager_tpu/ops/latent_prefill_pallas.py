@@ -1,0 +1,222 @@
+"""Pallas TPU prefill attention in the latent space, over the paged pool.
+
+Latent attention (models/glm4moelite.py) caches one vector a position a
+layer, key and value at once: every query head scores over all its ``W``
+lanes and takes its first ``value_dim`` lanes, weighted, as the output (the
+per-head keys and values are products of that vector, folded into the query
+and applied to the output outside).  A prefill writes its positions' vectors
+into the pool first and then attends here, over the table's blocks **where
+they lie**: the cached prefix of a hit is neither gathered nor up-projected
+to heads (15 872 positions x 20 heads x 448 would be 285 MB a layer), and a
+miss is the same kernel from position 0, so no ``[T, T]`` scores exist at any
+length and no VMEM bound on the context either (ops/flash_pallas.py's
+``_flash_kernel`` stages a head's whole K and V: 8192 positions at head
+size 256).
+
+A grid step is a tile of ``q_tile`` query positions, all heads: its rows
+(position-major) keep their online-softmax state (float32) in VMEM while the
+blocks stream past, ``blocks_per_step`` at a time, each block by a copy of
+its own into one of two buffers (the next step's arrive while this one's are
+multiplied), as ops/flash_pallas.py's ``_paged_kernel`` and
+ops/paged_decode_pallas.py's shared pass do.  A step's blocks are ONE operand
+as they lie, [P*bs/2, 2*W], a row two positions
+(``kv_cache_pool.pack_latent_blocks``), scored and weighed by
+``paged_decode_pallas._attend_latent``: products in the serving type,
+float32 sums.  Steps wholly before the tile's first position take no mask;
+from there to its last position the causal mask.  The pool crosses HBM once
+a tile.
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from llm_d_kv_cache_manager_tpu.ops.paged_decode_pallas import (
+    NEG_INF,
+    _attend_latent,
+    _latent_queries,
+    latent_query_layouts,
+)
+
+# Query positions a tile and pool blocks a step (512 keys), where the caller
+# states none: 64 x 20 heads = 1280 rows against 512 keys is 2.6 MB of
+# float32 scores; the tile's state, its query rows in both layouts and two
+# steps' blocks come to about 18 MB of VMEM.
+Q_TILE = 64
+BLOCKS_PER_STEP = 32
+VMEM_LIMIT_BYTES = 64 * 1024 * 1024
+
+
+def _prefill_kernel(
+    table_ref,  # SMEM [B, n_blocks] int32 (scalar prefetch)
+    offset_ref,  # SMEM [1] int32: the position of the first query
+    q_ref,  # VMEM [1, 2, tq*H, 2*W - value]: the rows' two layouts
+    pool_ref,  # HBM [slots, bs/2, 2*W]: the pool where it lies
+    out_ref,  # VMEM [1, tq*H, value]
+    buf,  # VMEM [2, P, 1, bs/2, 2*W]: two steps' blocks as they lie
+    sem,  # DMA [2]
+    m_ref, l_ref, acc_ref,  # VMEM [tq*H, 128], [tq*H, 128], [tq*H, value] f32
+    *,
+    q_tile: int,
+    heads: int,
+    value: int,
+    scale: float,
+):
+    b, qi = pl.program_id(0), pl.program_id(1)
+    P, half = buf.shape[1], buf.shape[3]
+    width = P * 2 * half  # positions a step
+    n_blocks = table_ref.shape[1]
+    q_start = offset_ref[0] + qi * q_tile  # the position of the tile's first rows
+
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+    m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+
+    n_clear = q_start // width  # steps every row sees whole
+    n_steps = pl.cdiv(q_start + q_tile, width)
+
+    def copy(j, i, slot):
+        # past the table's end its last block again, as if it lay after
+        # every query's own position: the causal mask hides it
+        return pltpu.make_async_copy(
+            pool_ref.at[
+                pl.ds(table_ref[b, jnp.minimum(j * P + i, n_blocks - 1)], 1)
+            ],
+            buf.at[slot, i],
+            sem.at[slot],
+        )
+
+    def start(j, slot):
+        def one(i, _):
+            copy(j, i, slot).start()
+            return 0
+
+        jax.lax.fori_loop(0, P, one, 0)
+
+    q = _latent_queries([q_ref], scale, q_ref.dtype)
+    start(0, 0)
+
+    def step(j, _):
+        slot = jax.lax.rem(j, 2)
+
+        @pl.when(j + 1 < n_steps)
+        def _next():
+            start(j + 1, 1 - slot)
+
+        def one(i, _):
+            copy(j, i, slot).wait()
+            return 0
+
+        jax.lax.fori_loop(0, P, one, 0)
+        slab = buf[slot].reshape(P * half, -1)
+
+        def causal(i, s):
+            row = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+            col = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+            # column c is row c % half of block c // half: its first
+            # position (i = 0) or its second
+            key = j * width + col + jax.lax.div(col, half) * half + i * half
+            return jnp.where(key <= q_start + jax.lax.div(row, heads), s,
+                             NEG_INF)
+
+        def attend(hide):
+            _attend_latent(q, slab, hide, m_ref, l_ref, acc_ref, value=value)
+
+        @pl.when(j < n_clear)
+        def _clear():
+            attend(lambda i, s: s)
+
+        @pl.when(j >= n_clear)
+        def _own():
+            attend(causal)
+
+        return 0
+
+    jax.lax.fori_loop(0, n_steps, step, 0)
+    out_ref[0] = (acc_ref[...] / l_ref[:, :1]).astype(out_ref.dtype)
+
+
+@functools.partial(
+    jax.jit,
+    static_argnames=("value_dim", "scale", "q_tile", "blocks_per_step",
+                     "interpret"),
+)
+def latent_prefill_attention_pallas(
+    q: jnp.ndarray,
+    kv_pool: jnp.ndarray,
+    block_table: jnp.ndarray,
+    *,
+    q_offset,
+    value_dim: int,
+    scale: float,
+    q_tile: int = Q_TILE,
+    blocks_per_step: int = BLOCKS_PER_STEP,
+    interpret: bool = False,
+) -> jnp.ndarray:
+    """Causal attention in the latent space of the queries at positions
+    ``q_offset ..`` (data: a long prefill calls one compiled kernel a chunk
+    of its queries) over the pool's blocks where they lie.
+    q: [B, Tq, H, W], each head's query in the latent space; kv_pool:
+    [slots, bs/2, 2*W], one layer's pool of a latent group
+    (``KVGroupSpec.layer_shape``); block_table: [B, n] int32, the slots that
+    hold positions 0 .. n*bs - 1 >= q_offset + Tq - 1 (the caller's to
+    see to: the offset is data), the queries' own latents among them (the
+    caller writes them first).  Only the table's
+    blocks are read, each once a tile; they hold numbers.  Returns
+    [B, Tq, H, value_dim] in q.dtype: sum over the positions a query sees of
+    softmax(q . latent * scale) times the latent's first ``value_dim``
+    lanes."""
+    B, Tq, H, W = q.shape
+    _, half, width = kv_pool.shape
+    if width != 2 * W or not 0 < value_dim <= W:
+        raise ValueError("a latent slot is two positions of q's width a row")
+    tq = min(q_tile, -(-Tq // 8) * 8)
+    pad = (-Tq) % tq
+    q = latent_query_layouts(
+        jnp.pad(q, ((0, 0), (0, pad), (0, 0), (0, 0))), value_dim
+    ).reshape(B, 2, (Tq + pad) * H, 2 * W - value_dim)
+    rows = tq * H
+    kernel = functools.partial(
+        _prefill_kernel, q_tile=tq, heads=H, value=value_dim, scale=scale,
+    )
+    out = pl.pallas_call(
+        kernel,
+        out_shape=jax.ShapeDtypeStruct((B, (Tq + pad) * H, value_dim),
+                                       q.dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(B, (Tq + pad) // tq),
+            in_specs=[
+                pl.BlockSpec((1, 2, rows, q.shape[-1]),
+                             lambda b, qi, *_: (b, 0, qi, 0),
+                             memory_space=pltpu.VMEM),
+                pl.BlockSpec(memory_space=pl.ANY),
+            ],
+            out_specs=pl.BlockSpec((1, rows, value_dim),
+                                   lambda b, qi, *_: (b, qi, 0),
+                                   memory_space=pltpu.VMEM),
+            scratch_shapes=[
+                pltpu.VMEM((2, blocks_per_step, 1, half, width),
+                           kv_pool.dtype),
+                pltpu.SemaphoreType.DMA((2,)),
+                pltpu.VMEM((rows, 128), jnp.float32),
+                pltpu.VMEM((rows, 128), jnp.float32),
+                pltpu.VMEM((rows, value_dim), jnp.float32),
+            ],
+        ),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=VMEM_LIMIT_BYTES
+        ),
+        interpret=interpret,
+    )(
+        block_table.astype(jnp.int32),
+        jnp.asarray(q_offset, jnp.int32).reshape(1),
+        q,
+        kv_pool,
+    )
+    return out.reshape(B, Tq + pad, H, value_dim)[:, :Tq]

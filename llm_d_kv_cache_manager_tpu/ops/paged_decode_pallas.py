@@ -56,6 +56,20 @@ so that no sequence begins by waiting for a copy with nothing to hide it.
 A sequence that shares nothing walks its whole table, and a table where
 nobody shares costs the set-finding and the shared pass's one empty step.
 
+**Latent slots** (``latent``: models/glm4moelite.py; ``KVGroupSpec``'s latent
+kind).  A position holds no K and V per head but one vector that is key and
+value at once: every query head scores over all its lanes and takes the first
+``latent`` of them, weighted, as its output.  A slot is [bs/2, 2*W], row r the
+positions r and r + bs/2 as [value_r | rest_r | rest_r+ | value_r+]
+(``kv_cache_pool.pack_latent_blocks``: W = 576 alone is no whole number of
+128-lane tiles).  The same shared pass and walk, a wave's blocks as ONE
+[P*bs/2, 2*W] operand as they lie: the rows' first positions are scored by
+the queries laid over the lanes [value | rest | 0], their second positions by
+the queries as [0 | rest | value] over the lanes from ``latent`` on (both
+slices start on a tile), one online-softmax update over both, and the output
+is the weights against the first and the last ``latent`` lanes: a block is
+read once for both products (``_attend_latent``).
+
 Contract matches ops/paged_attention.py::paged_attention; equivalence
 is pinned by tests/test_paged_decode_pallas.py (interpret mode on CPU), the
 walk's copies and what it traces to by tests/test_paged_decode_walk.py;
@@ -209,6 +223,71 @@ def _attend_rows(q, kv_refs, hide, m_ref, l_ref, acc_ref, *, packed: bool):
     acc_ref[...] = acc_ref[...] * correction + o
 
 
+def _attend_latent(q, slab, hide, m_ref, l_ref, acc_ref, *, value: int):
+    """One online-softmax update over a step's latent blocks as they lie:
+    slab [n, 2*W], a row two positions ([value | rest | rest+ | value+]);
+    q = (the query rows [R, 2*W - value] laid out for a row's first position,
+    and for its second).  ``hide(i, scores)`` puts what the query rows do not
+    see of the rows' first (i = 0) or second (i = 1) positions at NEG_INF.
+    Two products score the slab, two weigh it; each reads lanes that start
+    on a tile."""
+    dims = (((1,), (1,)), ((), ()))
+    keys = (slab[:, : slab.shape[1] - value], slab[:, value:])
+    s = [
+        hide(i, jax.lax.dot_general(
+            x, k.astype(x.dtype), dims, preferred_element_type=jnp.float32))
+        for i, (x, k) in enumerate(zip(q, keys))
+    ]  # [R, n] each
+    m_prev = m_ref[:, :1]
+    m_new = jnp.maximum(
+        m_prev,
+        jnp.maximum(jnp.max(s[0], axis=1, keepdims=True),
+                    jnp.max(s[1], axis=1, keepdims=True)),
+    )
+    p = [jnp.exp(x - m_new) for x in s]
+    correction = jnp.exp(m_prev - m_new)
+    l_ref[...] = l_ref[...] * correction + (
+        jnp.sum(p[0], axis=1, keepdims=True)
+        + jnp.sum(p[1], axis=1, keepdims=True)
+    )
+    m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
+    o = sum(
+        jax.lax.dot_general(
+            w.astype(q[0].dtype),
+            v.astype(q[0].dtype),
+            (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+        for w, v in zip(p, (slab[:, :value], slab[:, slab.shape[1] - value:]))
+    )  # [R, value]
+    acc_ref[...] = acc_ref[...] * correction + o
+
+
+def latent_query_layouts(q, value: int):
+    """Latent queries [B, ..., W] over the lanes of a slot row's first
+    position and of its second, [value | rest | 0] and [0 | rest | value]:
+    [B, 2, ..., 2*W - value]."""
+    zeros = jnp.zeros_like(q[..., value:])
+    return jnp.stack(
+        (
+            jnp.concatenate((q, zeros), axis=-1),
+            jnp.concatenate((zeros, q[..., value:], q[..., :value]), axis=-1),
+        ),
+        axis=1,
+    )
+
+
+def _latent_queries(q_refs, scale: float, compute_dtype):
+    """The two layouts of the query rows ([.., 2, H, 2*W - value] each ref),
+    scaled, the refs' rows one under the other."""
+    return tuple(
+        (jnp.concatenate(
+            [r[0, i].astype(jnp.float32) for r in q_refs], axis=0
+        ) * scale).astype(compute_dtype)
+        for i in range(2)
+    )
+
+
 def _decode_kernel(
     table_ref,  # SMEM [B, max_blocks] int32 (scalar prefetch)
     ctx_ref,  # SMEM [B] int32 (scalar prefetch)
@@ -341,7 +420,7 @@ def _walk_kernel(
     last_ref,  # SMEM [B]: each sequence's last block in context
     place_ref,  # SMEM [B]: its place in the shared pass's results
     skip_ref,  # SMEM [B]: its shared run, the first block of its own
-    q_ref,  # VMEM [1, H, D]
+    q_ref,  # VMEM [1, H, D] (latent: [1, 2, H, 2*W - value])
     kv_hbm,  # the pool, where it lies
     m0_ref, l0_ref, acc0_ref,  # what the shared pass left of this sequence
     out_ref,
@@ -352,6 +431,7 @@ def _walk_kernel(
     scale: float,
     mxu_native: bool,
     packed: bool,
+    latent: int | None = None,
 ):
     """The walk of each sequence's own blocks: a grid step is a sequence.  It
     brings the blocks from the end of the sequence's shared run to its last
@@ -369,7 +449,7 @@ def _walk_kernel(
     N, P = buf.shape[:2]
     b = pl.program_id(0)
     B = pl.num_programs(0)
-    H = q_ref.shape[1]
+    H = q_ref.shape[-2]
     Hkv = H // groups
     ctx = ctx_ref[b]
 
@@ -439,10 +519,18 @@ def _walk_kernel(
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     compute_dtype = q_ref.dtype if mxu_native else jnp.float32
-    q = (q_ref[0].astype(jnp.float32) * scale).astype(compute_dtype)
-    shape = (H, block_size * Hkv)
-    own_head = _own_head(shape, H, groups)
-    position = jax.lax.div(jax.lax.broadcasted_iota(jnp.int32, shape, 1), Hkv)
+    if not isinstance(latent, int):
+        q = (q_ref[0].astype(jnp.float32) * scale).astype(compute_dtype)
+        shape = (H, block_size * Hkv)
+        own_head = _own_head(shape, H, groups)
+        position = jax.lax.div(
+            jax.lax.broadcasted_iota(jnp.int32, shape, 1), Hkv)
+    else:
+        q = _latent_queries([q_ref], scale, compute_dtype)
+        half = block_size // 2  # rows a block: column c is row c % half of
+        col = jax.lax.broadcasted_iota(jnp.int32, (H, P * half), 1)
+        # block c // half; its first position, counted from the wave's first
+        position = col + jax.lax.div(col, half) * half
     first, n = own(b)
     waves = (n + P - 1) // P
     before = wave_ref[0]
@@ -464,10 +552,18 @@ def _walk_kernel(
             seen = position < ctx - (at + i * block_size)
             return jnp.where(own_head & seen, s, NEG_INF)
 
-        _attend_rows(
-            q, [buf.at[slot, i] for i in range(P)], hide, m_ref, l_ref,
-            acc_ref, packed=packed,
-        )
+        if not isinstance(latent, int):
+            _attend_rows(
+                q, [buf.at[slot, i] for i in range(P)], hide, m_ref, l_ref,
+                acc_ref, packed=packed,
+            )
+        else:
+            _attend_latent(
+                q, buf[slot].reshape(P * half, -1),
+                lambda i, s: jnp.where(
+                    position + i * half < ctx - at, s, NEG_INF),
+                m_ref, l_ref, acc_ref, value=latent,
+            )
 
     jax.lax.fori_loop(0, waves, wave, None)
     wave_ref[0] = before + waves
@@ -486,6 +582,7 @@ def _shared_kernel(
     sequences: int,
     mxu_native: bool,
     packed: bool,
+    latent: int | None = None,
 ):
     """The shared-prefix pass: a grid step is a group, whose sequences all
     have the same run of blocks at the head of their tables.  It brings the
@@ -502,7 +599,7 @@ def _shared_kernel(
     P = blocks_per_step
     g = pl.program_id(0)
     row, run = row_ref[g], run_ref[g]
-    H = q_refs[0].shape[1]
+    H = q_refs[0].shape[-2]
 
     @pl.when(g == 0)
     def _once():
@@ -535,9 +632,16 @@ def _shared_kernel(
         for copy in copies(0, 0):
             copy.start()
 
-    q = jnp.concatenate([r[0] for r in q_refs], axis=0)  # [G*H, D]
-    compute_dtype = q.dtype if mxu_native else jnp.float32
-    q = (q.astype(jnp.float32) * scale).astype(compute_dtype)
+    compute_dtype = q_refs[0].dtype if mxu_native else jnp.float32
+    if not isinstance(latent, int):
+        q = jnp.concatenate([r[0] for r in q_refs], axis=0)  # [G*H, D]
+        q = (q.astype(jnp.float32) * scale).astype(compute_dtype)
+    else:
+        q = _latent_queries(q_refs, scale, compute_dtype)
+        rows = buf.shape[-2]  # a block's: column c is of block c // rows
+        block = jax.lax.div(
+            jax.lax.broadcasted_iota(
+                jnp.int32, (sequences * H, P * rows), 1), rows)
     n_steps = (run + P - 1) // P
 
     def step(j, _):
@@ -553,12 +657,19 @@ def _shared_kernel(
         # Every position of the run lies before every member's own: only
         # the blocks past the run's end, in its last step, are hidden whole.
         count = run - j * P
-        _attend_rows(
-            q,
-            [buf.at[half, i] for i in range(P)],
-            lambda i, s: jnp.where(i < count, s + other_ref[...], NEG_INF),
-            m_ref, l_ref, acc_ref, packed=packed,
-        )
+        if not isinstance(latent, int):
+            _attend_rows(
+                q,
+                [buf.at[half, i] for i in range(P)],
+                lambda i, s: jnp.where(i < count, s + other_ref[...], NEG_INF),
+                m_ref, l_ref, acc_ref, packed=packed,
+            )
+        else:  # one KV "head": only the blocks past the run's end hide
+            _attend_latent(
+                q, buf[half].reshape(P * rows, -1),
+                lambda i, s: jnp.where(block < count, s, NEG_INF),
+                m_ref, l_ref, acc_ref, value=latent,
+            )
 
     jax.lax.fori_loop(0, n_steps, step, None)
 
@@ -568,6 +679,7 @@ def shared_prefix_plan(
     context_len: jnp.ndarray,
     *,
     block_size: int,
+    min_sequences: int = 2,
 ) -> dict:
     """Which sequences' tables begin with the same run of full blocks, the
     groups the shared pass takes them in, and where each sequence's walk
@@ -578,8 +690,9 @@ def shared_prefix_plan(
     run is the count of leading columns equal to the leader's that lie wholly
     before its write position ((j+1)*bs <= context_len - 1); a set's run is
     the least of its members'.  A set goes through the shared pass in groups
-    of up to ``SHARED_SEQUENCES`` in row order; a sequence alone in its
-    group, or in a set whose run is 0 (idle slots on the scratch block among
+    of up to ``SHARED_SEQUENCES`` in row order; a sequence whose group has
+    fewer than ``min_sequences`` members (alone in it, as the default has
+    it), or in a set whose run is 0 (idle slots on the scratch block among
     them), walks its whole table.  Keys: ``shared`` (each group's table row,
     run and members) and ``walk`` (each sequence's place in the shared pass's
     results and the first block of its own: its run, 0 where it shares
@@ -603,7 +716,8 @@ def shared_prefix_plan(
     rank = jnp.sum(mates & (rows[None, :] < rows[:, None]), axis=1)
     size = jnp.sum(mates, axis=1)
     place = rank % G  # within its group
-    shares = (run > 0) & (jnp.minimum(G, size - (rank - place)) > 1)
+    shares = (run > 0) & (
+        jnp.minimum(G, size - (rank - place)) > min_sequences - 1)
     skip = jnp.where(shares, run, 0)
     heads = shares & (place == 0)
     # Groups are numbered in the row order of their first members.
@@ -644,7 +758,7 @@ def shared_prefix_plan(
     jax.jit,
     static_argnames=(
         "interpret", "blocks_per_step", "mxu_native", "heads_first", "packed",
-        "shared_blocks_per_step", "walk_blocks_per_wave",
+        "shared_blocks_per_step", "walk_blocks_per_wave", "latent", "scale",
     ),
 )
 def paged_decode_attention_pallas(
@@ -662,6 +776,8 @@ def paged_decode_attention_pallas(
     plan: dict | None = None,
     shared_blocks_per_step: int = SHARED_BLOCKS_PER_STEP,
     walk_blocks_per_wave: int | None = None,
+    latent: int | None = None,
+    scale: float | None = None,
 ) -> jnp.ndarray:
     """q: [B, H, D]; kv_layer: [num_blocks, 2, bs, Hkv, D], or
     ``heads_first``: [num_blocks, 2, Hkv, bs, D], or ``packed``:
@@ -686,9 +802,36 @@ def paged_decode_attention_pallas(
     VMEM.  The default serves ``llama`` (the same time on the chip as
     float32 operands and the same ``decode_logit_rel_err``: PERF.md
     section 6, PR 32); models/afmoe.py passes False, as it was measured.
+
+    ``latent`` (the value's width): q is [B, H, W] in the latent space and
+    kv_layer [num_blocks, bs/2, 2*W], a latent group's slots; returns
+    [B, H, latent].  ``scale`` is the scores' (the query's width ** -0.5
+    where not given: a latent query is wider than the head it stands for).
     """
     B, H, D = q.shape
-    scale = D**-0.5
+    if scale is None:
+        scale = D**-0.5
+    if isinstance(latent, int):
+        if heads_first or packed or not isinstance(start, type(None)):
+            raise ValueError("latent slots are walked, whole contexts only")
+        _, half, width = kv_layer.shape
+        if width != 2 * D or not 0 < latent <= D:
+            raise ValueError("a latent slot is two positions of q's width a row")
+        if not isinstance(plan, dict):
+            plan = shared_prefix_plan(
+                block_table, context_len, block_size=2 * half
+            )
+        # whole sublanes of heads (a block of the resumed state is a
+        # sequence's heads): rows of zeros, whose output is dropped
+        q = latent_query_layouts(
+            jnp.pad(q, ((0, 0), (0, -H % 8), (0, 0))), latent)
+        return _shared_pass_and_walk(
+            q, kv_layer, block_table, context_len, plan,
+            block_size=2 * half, groups=q.shape[-2], scale=scale,
+            shared_blocks_per_step=shared_blocks_per_step,
+            blocks_per_wave=walk_blocks_per_wave, mxu_native=mxu_native,
+            packed=False, latent=latent, interpret=interpret,
+        )[:, :H]
     if packed:
         if heads_first:
             raise ValueError("packed slots are rows of positions, not heads")
@@ -824,7 +967,10 @@ def _shared_pass_and_walk(q, kv_layer, block_table, context_len, plan, *,
     """The shared pass over the plan's groups, then every sequence's walk of
     its own blocks, resumed from what the pass left: one sequence a grid
     step, the pool handed in where it lies."""
-    B, H, Dq = q.shape
+    B, H = q.shape[0], q.shape[-2]
+    # what a head keeps of a block: the query's own lanes, or a latent's value
+    Dq = statics.get("latent") or q.shape[-1]
+    q_block, q_zeros = (1,) + q.shape[1:], (0,) * (q.ndim - 1)
     kv_block = (1,) + kv_layer.shape[1:]
     place, skip = plan["walk"]
     if place.shape != (B,):
@@ -841,6 +987,9 @@ def _shared_pass_and_walk(q, kv_layer, block_table, context_len, plan, *,
     def of_sequence(b, *_):
         return (b, 0, 0)
 
+    def q_of_sequence(b, *_):
+        return (b,) + q_zeros
+
     def of_place(b, table_ref, ctx_ref, last_ref, place_ref, skip_ref):
         return (place_ref[b], 0)
 
@@ -850,7 +999,7 @@ def _shared_pass_and_walk(q, kv_layer, block_table, context_len, plan, *,
         num_scalar_prefetch=len(scalars),
         grid=(B,),
         in_specs=[
-            pl.BlockSpec((1, H, Dq), of_sequence, memory_space=pltpu.VMEM),
+            pl.BlockSpec(q_block, q_of_sequence, memory_space=pltpu.VMEM),
             pl.BlockSpec(memory_space=pl.ANY),
         ]
         + [
@@ -872,7 +1021,7 @@ def _shared_pass_and_walk(q, kv_layer, block_table, context_len, plan, *,
     )
     return pl.pallas_call(
         functools.partial(_walk_kernel, block_size=block_size, **statics),
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, H, Dq), q.dtype),
         grid_spec=grid_spec,
         interpret=interpret,
     )(*(a.astype(jnp.int32) for a in scalars), q, kv_layer, *resumed)
@@ -885,13 +1034,14 @@ def _shared_pass(q, kv_layer, block_table, plan, *, kv_block, blocks_per_step,
     heads over its group's run, as rows ``slot * H + head`` of three arrays
     ([.., 128], [.., 128], [.., D]).  Rows of places no sequence holds are
     never read."""
-    B, H, Dq = q.shape
+    H = q.shape[-2]
+    Dq = statics.get("latent") or q.shape[-1]
     G = SHARED_SEQUENCES
     C = plan["shared"][-1].shape[0] // G
 
     def q_index(i):
         def index(g, table_ref, row_ref, run_ref, members_ref):
-            return (members_ref[g * G + i], 0, 0)
+            return (members_ref[g * G + i],) + (0,) * (q.ndim - 1)
 
         return index
 
@@ -903,7 +1053,8 @@ def _shared_pass(q, kv_layer, block_table, plan, *, kv_block, blocks_per_step,
         num_scalar_prefetch=1 + len(plan["shared"]),
         grid=(plan["shared_steps"],),
         in_specs=[
-            pl.BlockSpec((1, H, Dq), q_index(i), memory_space=pltpu.VMEM)
+            pl.BlockSpec((1,) + q.shape[1:], q_index(i),
+                         memory_space=pltpu.VMEM)
             for i in range(G)
         ]
         + [pl.BlockSpec(memory_space=pl.ANY)],

@@ -290,6 +290,34 @@ def test_shared_prefix_pass_matches_xla_gather(name, slots):
     close(paged_decode_attention_pallas(*args, **statics), ref)
 
 
+@pytest.mark.parametrize("least", (3, 4, 9))
+@pytest.mark.parametrize("name", (
+    "two_uneven_sets_a_loner_and_an_idle_slot", "sets_larger_than_a_group"))
+def test_groups_under_the_least_size_are_walked(name, least):
+    """`min_sequences`: a group of fewer members goes through no shared pass.
+    Its sequences walk their whole tables, the groups left are numbered from
+    0, the counts say what is read, and the kernels give what they gave."""
+    (q, kv, table, ctx), statics, ref = shared_case(name, "llama")
+    every = shared_prefix_plan(table, ctx, block_size=BS)
+    plan = shared_prefix_plan(table, ctx, block_size=BS, min_sequences=least)
+    place, skip = (np.asarray(a) for a in every["walk"])
+    group = np.where(skip > 0, place // 8, -1)
+    size = np.asarray([np.sum(group == g) if g >= 0 else 0 for g in group])
+    kept = size >= least
+    got_place, got_skip = (np.asarray(a) for a in plan["walk"])
+    assert list(got_skip) == list(np.where(kept, skip, 0))
+    groups = sorted(set(group[kept]))
+    assert list(got_place[kept]) == [
+        8 * groups.index(g) + p % 8 for g, p in zip(group[kept], place[kept])]
+    assert int(plan["shared_steps"]) == max(len(groups), 1)
+    runs = sum(int(skip[group == g][0]) for g in groups)
+    blocks = [-(-c // BS) for c in np.asarray(ctx)]
+    assert int(plan["read_blocks"]) == runs + sum(blocks) - sum(got_skip)
+    assert int(plan["walked_blocks"]) == sum(blocks)
+    close(paged_decode_attention_pallas(q, kv, table, ctx, plan=plan,
+                                        **statics), ref)
+
+
 def test_the_plan_finds_the_sets_and_counts_what_is_read():
     """What `shared_prefix_plan` hands the kernels for the first case's
     tables: who resumes from which place of the shared pass's results and

@@ -20,9 +20,14 @@ import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from llm_d_kv_cache_manager_tpu.models import afmoe, lfm2moe, llama, phi4flash
+from llm_d_kv_cache_manager_tpu.models import (
+    afmoe, glm4moelite, lfm2moe, llama, phi4flash,
+)
 from llm_d_kv_cache_manager_tpu.models import pod as pod_programs
 from llm_d_kv_cache_manager_tpu.ops import flash_pallas
+from llm_d_kv_cache_manager_tpu.ops.latent_prefill_pallas import (
+    latent_prefill_attention_pallas,
+)
 from llm_d_kv_cache_manager_tpu.ops.paged_decode_pallas import (
     paged_decode_attention_pallas,
 )
@@ -397,6 +402,130 @@ def test_phi4flash_programs_compile_at_the_cells_shapes(one_chip, monkeypatch,
                      for a in jax.tree.leaves(pools))
     assert memory.alias_size_in_bytes >= pool_bytes  # the pools handed back
     assert memory.temp_size_in_bytes < PHI4_TEMP_LIMIT[key]
+    assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes
+            + memory.output_size_in_bytes - memory.alias_size_in_bytes
+            ) < HBM_BYTES
+
+
+# ------------------------------ the latent cache's kernels and programs
+
+# benchmarks/configs/glm-4.7-flash-l5.json; the pool and the shapes of
+# benchmarks/traffic/chat-repos.json
+GLM = glm4moelite.Glm4MoeLiteConfig(
+    vocab_size=154880, d_model=2048, n_layers=5, n_heads=20, q_rank=768,
+    kv_rank=512, nope_dim=192, rope_dim=64, v_dim=256, d_ff=10240,
+    d_expert=1536, n_experts=64, top_k=4)
+GLM_SHAPES = {"miss": (16384,), "hit": (15872, 512), "decode": (64,),
+              "max_blocks": 1056}
+GLM_POOL_BLOCKS = 73728
+# Temporaries beside 13.32 GB of weights and pool: compiled here they read
+# 2.30 / 0.06 / 0.01 GB (a miss holds 16 384 positions' stream and a chunk's
+# queries in both layouts).
+GLM_TEMP_LIMIT = {"miss": 2.6e9, "hit": 0.2e9, "decode": 0.1e9}
+
+
+def test_a_latent_slot_lies_in_the_pool_as_it_is_written(one_chip):
+    """Slots [8, 1152] (two positions a row) are whole tiles: the pool takes
+    its own bytes on the chip and a scatter writes it where it lies.  (As
+    [16, 576] the compiler pads the lanes to 640 or makes the slot axis the
+    minor one, and Mosaic refuses to slice it: PR 42.)"""
+    spec = glm4moelite.cache_groups(GLM)["full"]
+    shape = spec.layer_shape(8192)
+    assert shape == (8192, 8, 1152)
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in (
+        (shape, jnp.bfloat16), ((32,), jnp.int32),
+        ((32,) + shape[1:], jnp.bfloat16))]
+    compiled = jax.jit(lambda pool, ids, new: pool.at[ids].set(new),
+                       donate_argnums=(0,)).trace(*args).lower(
+        lowering_platforms=("tpu",)).compile()
+    nbytes = 2 * math.prod(shape)
+    assert nbytes * spec.num_layers // 8192 == spec.block_nbytes
+    assert compiled.memory_analysis().argument_size_in_bytes < 1.01 * nbytes
+    assert "bf16[8192,8,1152]{2,1,0:" in compiled.as_text()
+
+
+@pytest.mark.parametrize("tq, q_offset", ((512, 15872), (2048, 14336)))
+def test_latent_prefill_kernel_compiles_at_the_served_shapes(one_chip, tq,
+                                                             q_offset):
+    """A hit's suffix over its cached prefix, and a chunk of a miss's
+    queries: one kernel (the offset is data), the pool where it lies."""
+    compiled = compile_for(
+        one_chip, functools.partial(
+            latent_prefill_attention_pallas, q_offset=q_offset,
+            value_dim=512, scale=1 / 16,
+            q_tile=glm4moelite.PREFILL_Q_TILE,
+            blocks_per_step=glm4moelite.PREFILL_BLOCKS_PER_STEP),
+        ((1, tq, 20, 576), jnp.bfloat16),
+        ((GLM_POOL_BLOCKS, 8, 1152), jnp.bfloat16),
+        ((1, 1024), jnp.int32))
+    hlo = compiled.as_text()
+    assert re.search(r"%latent_prefill_attention_pallas\S* = .*tpu_custom_call",
+                     hlo)
+    assert not re.search(rf"= bf16\[{GLM_POOL_BLOCKS},\S* copy\(", hlo)
+
+
+def test_paged_decode_kernel_compiles_for_latent_slots(one_chip):
+    """The shared pass and the walk in the latent form at the cell's shapes:
+    20 heads (padded to whole sublanes) of 576 against a wave of blocks as one
+    operand, the pool where it lies."""
+    i32 = jnp.int32
+    compiled = compile_for(
+        one_chip, functools.partial(
+            paged_decode_attention_pallas, latent=512, scale=1 / 16,
+            walk_blocks_per_wave=glm4moelite.DECODE_BLOCKS_PER_WAVE,
+            shared_blocks_per_step=glm4moelite.DECODE_BLOCKS_PER_WAVE),
+        ((64, 20, 576), jnp.bfloat16),
+        ((GLM_POOL_BLOCKS, 8, 1152), jnp.bfloat16), ((64, 1056), i32),
+        ((64,), i32))
+    hlo = compiled.as_text()
+    assert len(paged_kernels(hlo)) == 2
+    assert not re.search(rf"= bf16\[{GLM_POOL_BLOCKS},\S* copy\(", hlo)
+    assert compiled.memory_analysis().temp_size_in_bytes < 8 << 20
+
+
+@pytest.mark.parametrize("key", ("miss", "hit", "decode"))
+def test_glm4moelite_programs_compile_at_the_cells_shapes(one_chip,
+                                                          monkeypatch, key):
+    """The cell `glm47flash-chat-repos`'s three programs as `models/pod.py`
+    jits them, the pool of latent slots donated: they compile for the v5e
+    (the latent prefill kernel a layer, the paged kernel's latent form with
+    its shared pass), fit the chip beside the weights, hand the pool back
+    where it lies, and no instruction copies or re-lays-out a layer of it."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    def spec(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+
+    params = jax.tree.map(spec, jax.eval_shape(
+        lambda: glm4moelite.init_params(jax.random.key(0), GLM)))
+    pools = jax.tree.map(spec, jax.eval_shape(
+        lambda: glm4moelite.new_pool(GLM, GLM_POOL_BLOCKS)))
+
+    class Shapes:  # what `example_args` reads of a pod: one group
+        window = state = None
+
+    first, second = jax.tree.map(
+        spec, pod_programs.example_args(key, GLM_SHAPES, Shapes, BLOCK))
+    assert first.shape == ((64, 2) if key == "decode"
+                           else (1, GLM_SHAPES[key][-1]))
+    program = pod_programs.inner_programs(glm4moelite, GLM, GLM_SHAPES,
+                                          False)[key]
+    compiled = program.trace(params, first, pools, second).lower(
+        lowering_platforms=("tpu",)).compile()
+    hlo = compiled.as_text()
+    assert len(paged_kernels(hlo)) == (2 * GLM.n_layers if key == "decode"
+                                       else 0)
+    assert len(set(re.findall(
+        r"%(latent_prefill_attention_pallas\S*) = .*tpu_custom_call", hlo))
+    ) == (0 if key == "decode" else GLM.n_layers)
+    assert not re.search(rf"= \w+\[{GLM_POOL_BLOCKS},[\d,]*\]\S* copy\(", hlo)
+    assert not pool_sized_moves(hlo, (GLM.n_layers, GLM_POOL_BLOCKS, 8, 1152))
+    memory = compiled.memory_analysis()
+    pool_bytes = sum(a.dtype.itemsize * math.prod(a.shape)
+                     for a in jax.tree.leaves(pools))
+    assert pool_bytes == GLM_POOL_BLOCKS * 92160
+    assert memory.alias_size_in_bytes >= pool_bytes  # the pool handed back
+    assert memory.temp_size_in_bytes < GLM_TEMP_LIMIT[key]
     assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes
             + memory.output_size_in_bytes - memory.alias_size_in_bytes
             ) < HBM_BYTES
