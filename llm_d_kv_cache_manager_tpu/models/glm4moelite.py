@@ -100,7 +100,10 @@ ATTN_CHUNK_TOKENS = 2048
 # wave).  Read on the chip, kernel alone at the cell's shapes (PERF.md
 # section 6, PR 42): a hit's layer 2.93 / 2.74 ms at tiles of 64 / 128
 # positions, 32 blocks a step (64 a step: 2.88 / 3.78); a decode step's
-# layer 5.10 / 4.19 / 3.81 ms at waves of 16 / 32 / 64 blocks.
+# layer 5.10 / 4.19 / 3.81 ms at waves of 16 / 32 / 64 blocks.  With a wave
+# that is a run in the pool brought by one copy (PR 43; 15 of a 16k-token
+# document's 16 waves are), waves of 32 / 64 / 128: 2.02 / 1.97 / 2.02 ms
+# over the cell's kind of table, 4.27 / 3.84 / 3.65 over blocks in no order.
 PREFILL_Q_TILE = 128
 PREFILL_BLOCKS_PER_STEP = 32
 DECODE_BLOCKS_PER_WAVE = 64
@@ -566,7 +569,8 @@ def decode_step(
     # Which sequences' tables begin with the same blocks, once for all
     # layers: every layer sees this table.
     plan = paged_decode_pallas.shared_prefix_plan(
-        table, context_len, block_size=bs, min_sequences=SHARED_MIN_SEQUENCES)
+        table, context_len, block_size=bs, min_sequences=SHARED_MIN_SEQUENCES,
+        blocks_per_wave=DECODE_BLOCKS_PER_WAVE)
     for l, lp in enumerate(params["layers"]):
         h = _rms_norm(x, lp["ln_in"], cfg.rms_eps, lp["w_qa"].dtype)
         full[l] = _write_token(
@@ -584,7 +588,7 @@ def decode_step(
             loads.append(load)
     logits, pools = _finish(x[:, 0], params, cfg, full, loads)
     pools["attention_read"] = jnp.stack(
-        (plan["read_blocks"], plan["walked_blocks"]))
+        (plan["read_blocks"], plan["walked_blocks"], plan["run_blocks"]))
     return logits, pools
 
 

@@ -532,7 +532,8 @@ def decode_step(
     plan = None
     if paged_decode_pallas.serves(interpret):
         plan = paged_decode_pallas.shared_prefix_plan(
-            tables["full"], context_len, block_size=bs)
+            tables["full"], context_len, block_size=bs,
+            blocks_per_wave=paged_decode_pallas.walk_wave(full[0]))
     for l, lp in enumerate(params["layers"]):
         kind, i = cfg.slot_of_layer(l)
         h = _rms_norm(x, lp["ln_op"], cfg.rms_eps, lp["ln_op"].dtype)
@@ -556,7 +557,7 @@ def decode_step(
     logits, pools = _finish(x[:, 0], params, cfg, full, state, loads)
     if plan is not None:
         pools["attention_read"] = jnp.stack(
-            (plan["read_blocks"], plan["walked_blocks"]))
+            (plan["read_blocks"], plan["walked_blocks"], plan["run_blocks"]))
     return logits, pools
 
 

@@ -635,7 +635,10 @@ def decode_step(
     plan = None
     if use_kernel:
         plan = paged_decode_pallas.shared_prefix_plan(
-            block_table, context_len, block_size=cfg.block_size
+            block_table, context_len, block_size=cfg.block_size,
+            # a layer's pool: `_scan_layers` merges the two leading axes
+            blocks_per_wave=paged_decode_pallas.walk_wave(jax.ShapeDtypeStruct(
+                kv_pool.shape[1:], kv_pool.dtype)),
         )
 
     def layer(x, slots, lp, base):

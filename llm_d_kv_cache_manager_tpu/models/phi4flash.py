@@ -781,7 +781,8 @@ def decode_step(
     plan = None
     if paged_decode_pallas.serves(interpret):
         plan = paged_decode_pallas.shared_prefix_plan(
-            tables["full"], context_len, block_size=bs)
+            tables["full"], context_len, block_size=bs,
+            blocks_per_wave=paged_decode_pallas.walk_wave(full))
 
     def mamba(x, lp, conv, ssm, base):
         y, m, conv, ssm = _mamba_decode(_mix_in(x, lp, cfg), lp, conv, ssm,
@@ -832,7 +833,7 @@ def decode_step(
     pools = {"full": [full], "window": [win], "state": [conv, ssm]}
     if plan is not None:
         pools["attention_read"] = jnp.stack(
-            (plan["read_blocks"], plan["walked_blocks"]))
+            (plan["read_blocks"], plan["walked_blocks"], plan["run_blocks"]))
     return _logits(x[:, 0], params, cfg), pools
 
 

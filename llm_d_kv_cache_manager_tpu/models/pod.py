@@ -134,7 +134,8 @@ from llm_d_kv_cache_manager_tpu.obs.trace import root_trace, span
 
 RESERVED = np.iinfo(np.int64).max  # a slot taken and not yet in any window
 # What a decode step may hand back beside its pools: counts made on the device
-# ("load": a row an expert layer; "attention_read": blocks read, blocks walked).
+# ("load": a row an expert layer; "attention_read": blocks read, blocks walked,
+# blocks the walk brought by runs).
 COUNTED = ("load", "attention_read")
 
 
@@ -738,10 +739,11 @@ class Pod:
             s.set_attr("arrays", len(counted))
             s.set_attr("bytes", sum(a.nbytes for a in counted.values()))
         if "attention_read" in counted:
-            read, walked = counted["attention_read"]
+            read, walked, by_runs = counted["attention_read"]
             with span("attention.read") as s:
                 s.set_attr("read_blocks", int(read))
                 s.set_attr("walked_blocks", int(walked))
+                s.set_attr("run_blocks", int(by_runs))
         for layer, (touched, most) in enumerate(counted.get("load", ())):
             with span("moe.expert_load") as s:
                 s.set_attr("layer", layer)

@@ -53,6 +53,17 @@ an operand of the pipeline, 32 a grid step, read ≈1.4 times the blocks in
 chat-sysprompt at ≈55 % of the bandwidth: PERF.md section 6, PR 41), and
 near its end it starts the next sequence's first waves into the free buffers,
 so that no sequence begins by waiting for a copy with nothing to hide it.
+**A run** is a wave of a walk, all of it in context, whose blocks lie one
+after another in the pool in the table's order (column c + 1 names the block
+after column c's: what `Pod.alloc` deals out of a fresh pool, so a document
+prefilled once and asked again lies in runs).  The plan finds the runs from
+the table, once a decode step (``runs``: data, never a shape or a layout's
+name), and the walk brings a run by ONE copy of the wave's bytes and one
+wait; any other wave, a sequence's last partial one among them, by a copy a
+block.  Starting a copy and waiting for it are trips of the same scalar core
+that issues the products, ≈50 cycles each whatever the copy carries, so a
+slot of 18 KB held the walk by the number of its copies (PERF.md section 6,
+PR 43): the same bytes into the same places by fewer descriptors.
 A sequence that shares nothing walks its whole table, and a table where
 nobody shares costs the set-finding and the shared pass's one empty step.
 
@@ -84,6 +95,7 @@ from __future__ import annotations
 
 import functools
 import math
+import operator
 
 import jax
 import jax.numpy as jnp
@@ -127,6 +139,12 @@ SHARED_BLOCKS_PER_STEP = 16
 # pays for: a body of 24 blocks traced in 0.8 s on the chip's host where the
 # walk before took 0.4, so 16 serves (its copies are a rolled loop: unrolled
 # under `pl.when`, 16 of them traced 0.9 s).
+# Read again with runs (PR 43, kernel alone, one latent layer at chat-repos'
+# shapes, ms at waves of 32 / 64 / 128 blocks): tables where 992 of a
+# sequence's ≈1040 blocks ascend 2.02 / 1.97 / 2.02 (a copy a block: 3.88 at
+# 64), tables in no order 4.27 / 3.84 / 3.65 (3.94).  The `llama` forms'
+# tables hold few whole ascending waves (chat-sysprompt 1 % of its own
+# blocks after the first round) and their readings above stand.
 WALK_WAVE_BYTES = 2 << 20
 WALK_WAVE_BLOCKS = 16
 WALK_BUFFERS = 3
@@ -420,6 +438,7 @@ def _walk_kernel(
     last_ref,  # SMEM [B]: each sequence's last block in context
     place_ref,  # SMEM [B]: its place in the shared pass's results
     skip_ref,  # SMEM [B]: its shared run, the first block of its own
+    runs_ref,  # SMEM [B, waves]: which waves of its walk are runs
     q_ref,  # VMEM [1, H, D] (latent: [1, 2, H, 2*W - value])
     kv_hbm,  # the pool, where it lies
     m0_ref, l0_ref, acc0_ref,  # what the shared pass left of this sequence
@@ -436,9 +455,12 @@ def _walk_kernel(
     """The walk of each sequence's own blocks: a grid step is a sequence.  It
     brings the blocks from the end of the sequence's shared run to its last
     block in context, a wave at a time (``buf``: [buffers, blocks a wave, a
-    slot]), each block by a copy of its own into one of the buffers, the
-    waves behind this one on their way while it is multiplied: as many copies
-    as the sequence has blocks of its own.  The waves of a call are one
+    slot]) into one of the buffers, the waves behind this one on their way
+    while it is multiplied.  A wave that the plan found to be a run (its
+    blocks lie one after another in the pool, in the table's order:
+    ``runs_ref``) comes by one copy of the whole wave, any other by a copy a
+    block, none past the sequence's last: every block of its own is brought
+    once either way.  The waves of a call are one
     stream, in the sequences' order: a wave asks for the one buffers - 1
     behind it, which near a sequence's end is the first of the next
     sequence's (scratch lives across grid steps, and the grid is sequential),
@@ -461,15 +483,35 @@ def _walk_kernel(
     def copy(seq, block, slot, i):
         return pltpu.make_async_copy(
             kv_hbm.at[pl.ds(table_ref[seq, block], 1)],
-            buf.at[slot, i],
+            buf.at[slot, pl.ds(i, 1)],
             sem.at[slot],
         )
 
+    def loose(seq, j, slot, count, act):
+        """How many of the ``count`` blocks of wave j of a sequence come by a
+        copy each: all of them, or none where the plan found the wave to be a
+        run, whose one copy ``act`` starts or waits for here (the flag the
+        start read decides the wait).  A pool of fewer blocks than a wave
+        holds no run, and no slice of a wave's blocks could be taken of it."""
+        if kv_hbm.shape[0] < P:
+            return count
+        is_run = runs_ref[seq, j] == 1
+
+        @pl.when(is_run & (count > 0))
+        def _run():
+            act(pltpu.make_async_copy(
+                kv_hbm.at[pl.ds(table_ref[seq, skip_ref[seq] + j * P], P)],
+                buf.at[slot],
+                sem.at[slot],
+            ))
+
+        return jnp.where(is_run, 0, count)
+
     def start(seq, j, number):
-        """Ask for wave j of a sequence, the call's wave ``number``: a copy a
-        block, none past the sequence's last and none behind the last
-        sequence.  Rolled, as the waves are: one body to trace, lower and
-        compile whatever the wave's size."""
+        """Ask for wave j of a sequence, the call's wave ``number``: a run by
+        one copy, any other wave by a copy a block, none past the sequence's
+        last and none behind the last sequence.  Rolled, as the waves are:
+        one body to trace, lower and compile whatever the wave's size."""
         at = jnp.minimum(seq, B - 1)
         first, n = own(at)
         count = jnp.where(seq < B, jnp.minimum(n - j * P, P), 0)
@@ -478,7 +520,9 @@ def _walk_kernel(
         def one(i, _):
             copy(at, first + j * P + i, slot, i).start()
 
-        jax.lax.fori_loop(0, count, one, None)
+        jax.lax.fori_loop(
+            0, loose(at, j, slot, count, operator.methodcaller("start")),
+            one, None)
 
     def behind(seq, j):
         """The wave behind wave j of a sequence in the call's stream."""
@@ -501,8 +545,12 @@ def _walk_kernel(
         buf[...] = jnp.zeros_like(buf)
         wave_ref[0] = 0
         # the call's first waves, but the last of those wave 0 has behind it
-        for number, at in enumerate([(0, 0)] + stream(0, 0)[:-1]):
+        # (rolled: `start` is traced here once however many buffers)
+        def first_waves(number, at):
             start(*at, number)
+            return behind(*at)
+
+        jax.lax.fori_loop(0, N - 1, first_waves, (jnp.int32(0), jnp.int32(0)))
 
     resumes = skip_ref[b] > 0
 
@@ -544,7 +592,9 @@ def _walk_kernel(
         def arrived(i, _):
             copy(b, first, slot, i).wait()
 
-        jax.lax.fori_loop(0, count, arrived, None)
+        jax.lax.fori_loop(
+            0, loose(b, j, slot, count, operator.methodcaller("wait")),
+            arrived, None)
         at = (first + j * P) * block_size
 
         def hide(i, s):
@@ -554,8 +604,8 @@ def _walk_kernel(
 
         if not isinstance(latent, int):
             _attend_rows(
-                q, [buf.at[slot, i] for i in range(P)], hide, m_ref, l_ref,
-                acc_ref, packed=packed,
+                q, [buf.at[slot, pl.ds(i, 1)] for i in range(P)], hide,
+                m_ref, l_ref, acc_ref, packed=packed,
             )
         else:
             _attend_latent(
@@ -679,6 +729,7 @@ def shared_prefix_plan(
     context_len: jnp.ndarray,
     *,
     block_size: int,
+    blocks_per_wave: int,
     min_sequences: int = 2,
 ) -> dict:
     """Which sequences' tables begin with the same run of full blocks, the
@@ -693,13 +744,19 @@ def shared_prefix_plan(
     of up to ``SHARED_SEQUENCES`` in row order; a sequence whose group has
     fewer than ``min_sequences`` members (alone in it, as the default has
     it), or in a set whose run is 0 (idle slots on the scratch block among
-    them), walks its whole table.  Keys: ``shared`` (each group's table row,
-    run and members) and ``walk`` (each sequence's place in the shared pass's
-    results and the first block of its own: its run, 0 where it shares
-    nothing): the two kernels' scalar prefetch after the table;
-    ``shared_steps``: the shared pass's grid; ``read_blocks`` (what the two
-    read: each group's run once and every sequence's rest, which is what the
-    walk copies) and ``walked_blocks`` (what a walk of every table reads)."""
+    them), walks its whole table.  The walk takes a sequence's own blocks
+    ``blocks_per_wave`` at a time (`walk_wave` of the pool, where the caller
+    names no other), and a wave all in context whose blocks lie one after
+    another in the pool (each column's id the one before's + 1, as an
+    allocator deals a fresh pool out) is a run, which one copy brings.
+    Keys: ``shared`` (each group's table row, run and members) and ``walk``
+    (each sequence's place in the shared pass's results, the first block of
+    its own: its run, 0 where it shares nothing, and which of its waves are
+    runs, [B, waves a table]): the two kernels' scalar prefetch after the
+    table; ``shared_steps``: the shared pass's grid; ``read_blocks`` (what
+    the two read: each group's run once and every sequence's rest, which is
+    what the walk copies), ``run_blocks`` (what of it the walk brings by runs)
+    and ``walked_blocks`` (what a walk of every table reads)."""
     i32 = jnp.int32
     B, M = block_table.shape
     G = SHARED_SEQUENCES
@@ -741,8 +798,19 @@ def shared_prefix_plan(
 
     # The walk: every sequence's rest, its write position's block at least.
     blocks = jnp.maximum(ctx - 1, 0) // block_size + 1
+    skip = skip.astype(i32)
+    # Wave w of a walk is columns skip + w*P .. + P - 1: a run where the last
+    # of them is in context and no step from one of them to the next breaks
+    # the ascent.
+    P = blocks_per_wave
+    start = skip[:, None] + jnp.arange(-(-M // P), dtype=i32)[None] * P
+    column = jnp.arange(M - 1, dtype=i32)  # the step from it to the next
+    steps = (column >= start[..., None]) & (column < start[..., None] + P - 1)
+    breaks = block_table[:, 1:] != block_table[:, :-1] + 1
+    runs = (start + P <= blocks[:, None]) & ~jnp.any(
+        breaks[:, None] & steps, axis=2)
     return {
-        "walk": (slot, skip.astype(i32)),
+        "walk": (slot, skip, runs.astype(i32)),
         "shared": tuple(
             a.astype(i32) for a in (group_row, group_run, members)
         ),
@@ -750,6 +818,7 @@ def shared_prefix_plan(
         # that reads nothing where nobody shares
         "shared_steps": jnp.maximum(jnp.sum(heads), 1).astype(i32),
         "read_blocks": (jnp.sum(group_run) + jnp.sum(blocks - skip)).astype(i32),
+        "run_blocks": (jnp.sum(runs) * P).astype(i32),
         "walked_blocks": jnp.sum(blocks).astype(i32),
     }
 
@@ -793,8 +862,9 @@ def paged_decode_attention_pallas(
     of blocks that several tables begin with are read once for the sequences
     that share them, and each sequence's own blocks are copied by the walk,
     ``walk_blocks_per_wave`` at a time (``walk_wave``'s where not given: the
-    tests' small tables ask for small waves); ``shared_prefix_plan``, which a
-    model's decode step makes once for all its layers and hands in as
+    tests' small tables ask for small waves), a wave that is a run in the
+    pool by one copy; ``shared_prefix_plan``, which a model's decode step
+    makes once for all its layers, for the same wave, and hands in as
     ``plan``, is made here when it is not.
 
     ``mxu_native=True`` keeps the attention dots in the input dtype
@@ -817,10 +887,6 @@ def paged_decode_attention_pallas(
         _, half, width = kv_layer.shape
         if width != 2 * D or not 0 < latent <= D:
             raise ValueError("a latent slot is two positions of q's width a row")
-        if not isinstance(plan, dict):
-            plan = shared_prefix_plan(
-                block_table, context_len, block_size=2 * half
-            )
         # whole sublanes of heads (a block of the resumed state is a
         # sequence's heads): rows of zeros, whose output is dropped
         q = latent_query_layouts(
@@ -854,10 +920,6 @@ def paged_decode_attention_pallas(
     # sequence's positions from 0 (a window layer's start hides part of a
     # prefix from each sequence on its own).
     if not (heads_first or windowed):
-        if not isinstance(plan, dict):
-            plan = shared_prefix_plan(
-                block_table, context_len, block_size=block_size
-            )
         out = _shared_pass_and_walk(
             q, kv_layer, block_table, context_len, plan,
             block_size=block_size, groups=groups, scale=scale,
@@ -955,9 +1017,10 @@ def _last_block(context_len, block_size: int):
     return jnp.maximum((context_len - 1) // block_size, 0)
 
 
-def walk_wave(slot_bytes: int) -> int:
-    """Blocks a wave of the walk, from what a slot weighs (the readings are
-    beside the constants)."""
+def walk_wave(kv_layer) -> int:
+    """Blocks a wave of the walk of a pool [blocks, *slot], from what a slot
+    weighs (the readings are beside the constants)."""
+    slot_bytes = kv_layer.dtype.itemsize * math.prod(kv_layer.shape[1:])
     return min(max(WALK_WAVE_BYTES // slot_bytes, 1), WALK_WAVE_BLOCKS)
 
 
@@ -972,29 +1035,33 @@ def _shared_pass_and_walk(q, kv_layer, block_table, context_len, plan, *,
     Dq = statics.get("latent") or q.shape[-1]
     q_block, q_zeros = (1,) + q.shape[1:], (0,) * (q.ndim - 1)
     kv_block = (1,) + kv_layer.shape[1:]
-    place, skip = plan["walk"]
-    if place.shape != (B,):
-        raise ValueError("the plan was made for another table")
+    if blocks_per_wave is None:
+        blocks_per_wave = walk_wave(kv_layer)
+    if not isinstance(plan, dict):
+        plan = shared_prefix_plan(
+            block_table, context_len, block_size=block_size,
+            blocks_per_wave=blocks_per_wave,
+        )
+    place, skip, runs = plan["walk"]
+    waves = -(-block_table.shape[1] // blocks_per_wave)
+    if place.shape != (B,) or runs.shape != (B, waves):
+        raise ValueError("the plan was made for another table or wave")
     resumed = _shared_pass(
         q, kv_layer, block_table, plan, kv_block=kv_block,
         blocks_per_step=shared_blocks_per_step, interpret=interpret,
         **statics,
     )
-    if blocks_per_wave is None:
-        blocks_per_wave = walk_wave(
-            kv_layer.dtype.itemsize * math.prod(kv_block))
-
     def of_sequence(b, *_):
         return (b, 0, 0)
 
     def q_of_sequence(b, *_):
         return (b,) + q_zeros
 
-    def of_place(b, table_ref, ctx_ref, last_ref, place_ref, skip_ref):
+    def of_place(b, table_ref, ctx_ref, last_ref, place_ref, *_):
         return (place_ref[b], 0)
 
     scalars = (block_table, context_len,
-               _last_block(context_len, block_size), place, skip)
+               _last_block(context_len, block_size), place, skip, runs)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(scalars),
         grid=(B,),
@@ -1013,7 +1080,8 @@ def _shared_pass_and_walk(q, kv_layer, block_table, context_len, plan, *,
             pltpu.VMEM((H, 128), jnp.float32),
             pltpu.VMEM((H, 128), jnp.float32),
             pltpu.VMEM((H, Dq), jnp.float32),
-            pltpu.VMEM((WALK_BUFFERS, blocks_per_wave) + kv_block,
+            # a wave's blocks as they lie in the pool: a run's one target
+            pltpu.VMEM((WALK_BUFFERS, blocks_per_wave) + kv_layer.shape[1:],
                        kv_layer.dtype),
             pltpu.SemaphoreType.DMA((WALK_BUFFERS,)),
             pltpu.SMEM((1,), jnp.int32),

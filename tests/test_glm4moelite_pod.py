@@ -172,8 +172,8 @@ def test_prefill_continue_and_decode_repeat_the_reference(prefix_blocks):
         close(np.stack(s["rows"]), reference(tuple(s["tokens"]))[-25:-1])
     # the two tables begin with the same blocks, and two are too few for the
     # shared pass (`SHARED_MIN_SEQUENCES`): each walks its whole table
-    read, walked = eng.read
-    assert read == walked
+    read, walked, by_runs = eng.read
+    assert read == walked and by_runs == 0  # no table holds a wave of 64
     assert eng.load.shape == (2, 2) and (eng.load[:, 0] <= 4).all()
 
 
@@ -196,10 +196,31 @@ def test_a_prefix_that_enough_sequences_share_is_read_once():
         for s, row in zip(seqs, eng.decode(seqs)):
             s["rows"].append(row)
             s["tokens"].append(int(np.argmax(row)))
-        read, walked = eng.read
-        assert read == walked - 3 * 5
+        read, walked, by_runs = eng.read
+        assert read == walked - 3 * 5 and by_runs == 0
     for s in seqs:
         close(np.stack(s["rows"]), reference(tuple(s["tokens"]))[-7:-1])
+
+
+def test_a_decode_step_counts_the_blocks_its_walk_brings_by_runs(monkeypatch):
+    """`attention_read`'s third count, through the pod's own allocator: a
+    fresh pool deals its blocks out ascending, so with waves of two (the
+    family's 64 hold no table of a test) every whole wave of a sequence is a
+    run, a last wave of one block is not, and the rows are the reference's
+    all the same."""
+    monkeypatch.setattr(glm4moelite, "DECODE_BLOCKS_PER_WAVE", 2)
+    monkeypatch.setitem(STEPS, "decode", jax.jit(
+        functools.partial(glm4moelite.decode_step, cfg=CFG)))
+    eng = Engine()
+    seqs = [eng.prefill(tokens_of(16 * n, n), 0, own=1) for n in (5, 4)]
+    assert [s["blocks"] for s in seqs] == [[0, 1, 2, 3, 4, 5],
+                                           [6, 7, 8, 9, 10]]
+    for s in seqs:
+        s["tokens"].append(int(np.argmax(s["row"])))
+    for s, row in zip(seqs, eng.decode(seqs)):
+        close(row, reference(tuple(s["tokens"]))[-1])
+    # (0, 1), (2, 3), (4, 5); (6, 7), (8, 9) and block 10 alone
+    assert list(eng.read) == [11, 11, 10]
 
 
 def test_a_long_prefill_attends_a_chunk_of_queries_at_a_time(monkeypatch):
@@ -310,7 +331,8 @@ def test_the_three_programs_serve_the_reference_tokens_and_record_the_read():
         "step_bytes": 15 * spec.read_nbytes + CFG.decode_weight_nbytes}
     walked = [r["attrs"] for r in spans if r["span"] == "attention.read"]
     # a pair over one run is walked whole (`SHARED_MIN_SEQUENCES`)
-    assert walked == [{"read_blocks": 7 + 8, "walked_blocks": 7 + 8}]
+    assert walked == [{"read_blocks": 7 + 8, "walked_blocks": 7 + 8,
+                       "run_blocks": 0}]
     load = [r["attrs"] for r in spans if r["span"] == "moe.expert_load"]
     assert len(load) == 2 and all(
         a["experts_held"] == 8 and 1 <= a["experts_touched"] <= 4

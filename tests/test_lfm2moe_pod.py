@@ -346,8 +346,9 @@ def test_a_decode_step_counts_the_blocks_its_attention_reads():
     read = [r["attrs"] for r in rows if r["span"] == "attention.read"]
     shared = sum(a == b for a, b in zip(*table)) - 2  # not the empty columns
     assert shared == 4 and dropped == 0
+    # a tiny pool's waves of 16 hold no table of nine columns: no run
     assert read == [{"read_blocks": shared + 2 * (7 - shared),
-                     "walked_blocks": 2 * 7}]
+                     "walked_blocks": 2 * 7, "run_blocks": 0}]
 
 
 def test_pallas_decode_in_the_step_agrees_with_the_gather():
@@ -627,7 +628,9 @@ def test_a_float8_pass_fails_the_tolerance_the_decode_comparison_holds():
 # on purpose reads its digest anew: `llama.decode.True` was read anew in PR 34
 # (the paged kernel finds the shared prefixes from the table, reads each once
 # in a shared pass and walks the rest) and in PR 41 (the walk is a sequence a
-# grid step and copies its own blocks; the plan lists sequences, not steps);
+# grid step and copies its own blocks; the plan lists sequences, not steps)
+# and in PR 43 (the plan flags the waves that are runs in the pool and the
+# walk brings such a wave by one copy);
 # `llama.decode.False` stands, because off the TPU and uninterpreted the step
 # keeps the XLA gather and makes no plan, and so do the six `afmoe.*` and the
 # four `llama.miss/hit.*`.
@@ -639,7 +642,7 @@ TEXT_AT_PR_32 = {
     "llama.miss.False": "90c7da6d6fb5ae9d", "llama.hit.False": "364331e0e1475faa",
     "llama.decode.False": "53c6a3b76efc093d",
     "llama.miss.True": "90c7da6d6fb5ae9d", "llama.hit.True": "364331e0e1475faa",
-    "llama.decode.True": "e0e9daad8ea04ecb",
+    "llama.decode.True": "e2042b2557c47a3f",
 }
 
 

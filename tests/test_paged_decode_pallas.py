@@ -50,7 +50,8 @@ def close(got, ref):
     )
 
 
-SERVED_WAVE = paged_decode_pallas.walk_wave(2 * BS * 8 * 128 * 2)
+SERVED_WAVE = paged_decode_pallas.walk_wave(
+    jax.ShapeDtypeStruct((64, 2, BS, 8, 128), jnp.bfloat16))
 
 CASES = {
     # name: (B, H, Hkv, D, max_blocks, blocks a wave, contexts)
@@ -298,13 +299,15 @@ def test_groups_under_the_least_size_are_walked(name, least):
     Its sequences walk their whole tables, the groups left are numbered from
     0, the counts say what is read, and the kernels give what they gave."""
     (q, kv, table, ctx), statics, ref = shared_case(name, "llama")
-    every = shared_prefix_plan(table, ctx, block_size=BS)
-    plan = shared_prefix_plan(table, ctx, block_size=BS, min_sequences=least)
-    place, skip = (np.asarray(a) for a in every["walk"])
+    wave = statics["walk_blocks_per_wave"]
+    every = shared_prefix_plan(table, ctx, block_size=BS, blocks_per_wave=wave)
+    plan = shared_prefix_plan(table, ctx, block_size=BS, blocks_per_wave=wave,
+                              min_sequences=least)
+    place, skip = (np.asarray(a) for a in every["walk"][:2])
     group = np.where(skip > 0, place // 8, -1)
     size = np.asarray([np.sum(group == g) if g >= 0 else 0 for g in group])
     kept = size >= least
-    got_place, got_skip = (np.asarray(a) for a in plan["walk"])
+    got_place, got_skip = (np.asarray(a) for a in plan["walk"][:2])
     assert list(got_skip) == list(np.where(kept, skip, 0))
     groups = sorted(set(group[kept]))
     assert list(got_place[kept]) == [
@@ -327,8 +330,9 @@ def test_the_plan_finds_the_sets_and_counts_what_is_read():
         "two_uneven_sets_a_loner_and_an_idle_slot"]
     _, _, table, ctx, _ = make_shared_case(
         jax.random.PRNGKey(7), 16, 8, 128, 12, prompts, sequences)
-    plan = shared_prefix_plan(table, ctx, block_size=BS)
-    place, skip = (np.asarray(a) for a in plan["walk"])
+    plan = shared_prefix_plan(table, ctx, block_size=BS, blocks_per_wave=4)
+    place, skip, runs = (np.asarray(a) for a in plan["walk"])
+    assert runs.shape == (8, 3) and not runs.any()  # ids drawn in no order
     # prompt 0: rows 0, 3, 7 (group 0, run 6); prompt 1: rows 1, 5 (group 1,
     # run 4); rows 2 (idle), 4 (alone on its prompt) and 6 share nothing.
     assert list(place) == [0, 8, 0, 1, 0, 9, 0, 2]

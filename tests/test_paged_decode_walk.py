@@ -1,5 +1,6 @@
 """The paged kernel's walk of each sequence's own blocks (PR 41): what it
-copies, counted as the interpreted kernel runs, and what it traces to, which
+copies, counted as the interpreted kernel runs (a wave whose blocks lie one
+after another in the pool by one copy, PR 43), and what it traces to, which
 is set-up's time.  (Against the XLA gather: tests/test_paged_decode_pallas.py.)
 """
 
@@ -9,27 +10,31 @@ import numpy as np
 import pytest
 from jax.experimental.pallas import tpu as pltpu
 
+from llm_d_kv_cache_manager_tpu.models import glm4moelite
 from llm_d_kv_cache_manager_tpu.ops import paged_decode_pallas
 from llm_d_kv_cache_manager_tpu.ops.paged_decode_pallas import (
     paged_decode_attention_pallas,
     shared_prefix_plan,
 )
+from tests.test_latent_attention import SCALE, VALUE, W
 from tests.test_paged_decode_pallas import BS, LAYOUTS, close, shared_case
 
 
 class CountedCopy:
     """`pltpu.make_async_copy`'s descriptor, its `start` noted with the pool
-    block it names as the interpreted kernel runs it: a host callback beside
-    the copy, inside whatever `pl.when` and loop the kernel put it in."""
+    block it names and how many blocks it carries as the interpreted kernel
+    runs it: a host callback beside the copy, inside whatever `pl.when` and
+    loop the kernel put it in."""
 
     started: list = []
 
-    def __init__(self, copy, block):
-        self.copy, self.block = copy, block
+    def __init__(self, copy, block, blocks):
+        self.copy, self.block, self.blocks = copy, block, blocks
 
     def start(self):
         jax.debug.callback(
-            lambda block: CountedCopy.started.append(int(block)), self.block)
+            lambda block: CountedCopy.started.append((int(block), self.blocks)),
+            self.block)
         self.copy.start()
 
     def wait(self):
@@ -42,9 +47,9 @@ def counted_walk(monkeypatch):
     make, walk = pltpu.make_async_copy, paged_decode_pallas._walk_kernel
 
     def counted(src, dst, sem):
-        # the pool block a descriptor names: where its source's slice begins
-        return CountedCopy(make(src, dst, sem),
-                           src.transforms[0].indices[0].start)
+        # the pool blocks a descriptor names: its source's slice of the pool
+        blocks = src.transforms[0].indices[0]
+        return CountedCopy(make(src, dst, sem), blocks.start, blocks.size)
 
     def walk_counted(*refs, **statics):
         with monkeypatch.context() as m:
@@ -66,31 +71,148 @@ def counted_walk(monkeypatch):
 ))
 def test_the_walk_copies_each_block_in_context_past_the_runs_once(
         counted_walk, name, slots):
-    """Blocks copied / blocks in context past the runs = 1: the walk starts
-    one copy for each of a sequence's blocks from the end of its shared run
-    to its last block in context, in the table's order, and none for a column
-    past the context (the walk before it multiplied whole steps of 32)."""
+    """Blocks copied / blocks in context past the runs = 1: the walk brings
+    each of a sequence's blocks from the end of its shared run to its last
+    block in context once, in the table's order, and none for a column past
+    the context (the walk before it multiplied whole steps of 32)."""
     args, statics, ref = shared_case(name, slots)
     _, _, table, ctx = args
-    plan = shared_prefix_plan(table, ctx, block_size=BS)
-    skip = np.asarray(plan["walk"][1])
-    own = [int(block) for b, c in enumerate(np.asarray(ctx))
-           for block in np.asarray(table)[b, skip[b]:-(-int(c) // BS)]]
+    plan = shared_prefix_plan(table, ctx, block_size=BS,
+                              blocks_per_wave=statics["walk_blocks_per_wave"])
+    own = [block for copy in expected_copies(
+        table, ctx, plan, statics["walk_blocks_per_wave"]) for block in copy]
     # not through the jit's cache: the counted kernel must be traced
     got = paged_decode_attention_pallas.__wrapped__(*args, **statics)
     jax.effects_barrier()
     close(got, ref)
-    assert counted_walk == own
+    assert brought(counted_walk) == own
     runs = int(np.sum(np.asarray(plan["shared"][1])))
     assert len(own) == int(plan["read_blocks"]) - runs
+
+
+def brought(copies) -> list:
+    """The pool blocks a list of counted descriptors names, in their order."""
+    return [block + i for block, blocks in copies for i in range(blocks)]
+
+
+def expected_copies(table, ctx, plan, wave) -> list:
+    """What the walk should ask for, read from the table alone: a sequence's
+    own blocks (from its run's end to its last in context) a wave at a time,
+    a whole wave whose ids ascend by one as one copy, any other a copy a
+    block; the sequences and their waves in order.  Each copy is the list of
+    the blocks it carries."""
+    table, skip = np.asarray(table), np.asarray(plan["walk"][1])
+    copies = []
+    for b, c in enumerate(np.asarray(ctx)):
+        own = table[b, skip[b]:-(-int(c) // BS)]
+        for at in range(0, len(own), wave):
+            ids = [int(i) for i in own[at:at + wave]]
+            if len(ids) == wave and ids == list(range(ids[0], ids[0] + wave)):
+                copies.append(ids)
+            else:
+                copies.extend([i] for i in ids)
+    return copies
+
+
+# ------------------------------------------------ a wave that is a run in the pool
+
+WAVE = 4
+# name: each sequence's (shared blocks, own blocks in context, blocks the table
+# holds behind them) and how its own ids lie in the pool.  A fresh pool's
+# allocator deals ascending ids (`Pod.alloc`), a free list used as a stack
+# descending ones.
+RUN_CASES = {
+    # 2 whole waves and 2 loose blocks; 2 whole waves; less than a wave
+    "all_ascending": ([(0, 10, 0), (0, 8, 0), (0, 3, 0)], "ascending"),
+    "in_no_order": ([(0, 10, 0), (0, 8, 0), (0, 3, 0)], "no_order"),
+    "one_break_inside_each_wave": ([(0, 10, 0), (0, 8, 0)], "broken"),
+    "descending_ids": ([(0, 10, 0), (0, 8, 0)], "descending"),
+    # the ids go on ascending past the context, which ends inside a wave
+    "a_run_crosses_the_last_block": ([(0, 6, 4), (0, 9, 3)], "ascending"),
+    # two sequences begin with the same 3 blocks: their walks' waves are
+    # columns 3..6, 7..10 of tables of 12
+    "a_run_starts_at_a_shared_runs_end": ([(3, 8, 0), (3, 5, 1)], "ascending"),
+}
+RUN_LAYOUTS = {**LAYOUTS, "latent": (5, 1, W)}
+
+
+def run_case(name, slots):
+    """(q, pool, table, ctx), the call's static arguments, and the same
+    content with the pool's blocks permuted into no order and the table
+    naming them there."""
+    sequences, order = RUN_CASES[name]
+    H, Hkv, D = RUN_LAYOUTS[slots]
+    rng = np.random.default_rng(43)
+    columns, N = 12, 1 + 3 + sum(own + more for _, own, more in sequences)
+    free = iter(range(4, N))
+    table, ctx = [], []
+    for shared, own, more in sequences:
+        ids = [next(free) for _ in range(own + more)]
+        if order == "descending":
+            ids = ids[::-1]
+        elif order == "no_order":
+            ids = list(rng.permutation(ids))
+        elif order == "broken":  # a swap inside each wave
+            for at in range(1, len(ids) - 1, WAVE):
+                ids[at], ids[at + 1] = ids[at + 1], ids[at]
+        row = [1, 2, 3][:shared] + ids
+        table.append(row + [0] * (columns - len(row)))
+        ctx.append((shared + own) * BS - int(rng.integers(0, BS)))
+    table, ctx = np.asarray(table, np.int32), jnp.asarray(ctx, jnp.int32)
+    kq, kkv = jax.random.split(jax.random.PRNGKey(43))
+    shape = {"packed": (N, BS, Hkv, 2 * D), "latent": (N, BS // 2, 2 * W)}.get(
+        slots, (N, 2, BS, Hkv, D))
+    pool = jax.random.normal(kkv, shape, jnp.float32).astype(jnp.bfloat16)
+    q = jax.random.normal(kq, (len(ctx), H, D), jnp.float32).astype(jnp.bfloat16)
+    statics = dict(interpret=True, walk_blocks_per_wave=WAVE,
+                   shared_blocks_per_step=2, packed=slots == "packed")
+    if slots == "latent":
+        statics.update(latent=VALUE, scale=SCALE)
+    to = np.concatenate(([0], 1 + rng.permutation(N - 1)))  # where a block goes
+    scattered = jnp.zeros_like(pool).at[to].set(pool)
+    return ((q, pool, jnp.asarray(table), ctx), statics,
+            (q, scattered, jnp.asarray(to[table], jnp.int32), ctx))
+
+
+@pytest.mark.parametrize("slots", RUN_LAYOUTS)
+@pytest.mark.parametrize("name", RUN_CASES)
+def test_a_wave_that_is_a_run_in_the_pool_comes_by_one_copy(
+        counted_walk, name, slots):
+    """Copies issued = whole ascending waves + loose blocks: a wave of the
+    walk whose blocks are all in context and lie one after another in the
+    pool, in the table's order, is one descriptor of a wave's blocks, any
+    other wave a descriptor a block; every block in context past the shared
+    runs is brought once, none past the context; the plan's `run_blocks` is
+    what came by runs; and the output is, bit for bit, that of the same
+    content lying in no order."""
+    args, statics, in_no_order = run_case(name, slots)
+    _, _, table, ctx = args
+    plan = shared_prefix_plan(table, ctx, block_size=BS, blocks_per_wave=WAVE)
+    want = expected_copies(table, ctx, plan, WAVE)
+    got = paged_decode_attention_pallas.__wrapped__(*args, plan=plan, **statics)
+    jax.effects_barrier()
+    copies = list(counted_walk)
+    assert copies == [(ids[0], len(ids)) for ids in want]
+    by_runs = sum(blocks for _, blocks in copies if blocks > 1)
+    assert int(plan["run_blocks"]) == by_runs <= int(plan["read_blocks"])
+    assert (by_runs > 0) == (RUN_CASES[name][1] == "ascending")
+    counted_walk.clear()
+    scattered = paged_decode_attention_pallas.__wrapped__(
+        *in_no_order, **statics)
+    jax.effects_barrier()
+    assert len(counted_walk) == len(brought(copies))
+    np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                  np.asarray(scattered, np.float32))
 
 
 # ------------------------------------------------ what the walk costs set-up
 
 # The served shapes' heads (`internlm2-1.8b`, `lfm2-8b-a1b-l13`,
-# `phi-4-mini-flash-reasoning` pair-wise), at the waves `walk_wave` gives them.
+# `phi-4-mini-flash-reasoning` pair-wise), at the waves `walk_wave` gives them,
+# and `glm-4.7-flash`'s latent form (20 heads over slots [8, 1152]) at its
+# family's waves of 64.
 SERVED_HEADS = {"llama": (16, 8, 128), "packed": (32, 8, 64),
-                "pairwise": (40, 10, 128)}
+                "pairwise": (40, 10, 128), "latent": (20, 1, 576)}
 
 
 def equations(jaxpr) -> int:
@@ -115,15 +237,22 @@ def test_the_walk_traces_to_the_same_kernel_whatever_the_table(slots):
     the kernel's compile): nothing in the plan, the shared pass or the walk
     is unrolled over a table's columns, its waves or its sequences, so a
     48-column table of 8 sequences traces to as many equations as a
-    192-column one of 32.  (A wave's own copies and products are unrolled:
-    `walk_wave` caps them.)"""
+    192-column one of 32, with the run's one copy and one wait in it.  (A
+    wave's products are unrolled: `walk_wave` caps them.)"""
     H, Hkv, D = SERVED_HEADS[slots]
-    pool = (64, BS, Hkv, 2 * D) if slots == "packed" else (64, 2, BS, Hkv, D)
+    pool = {"packed": (64, BS, Hkv, 2 * D), "latent": (64, BS // 2, 2 * D)}.get(
+        slots, (64, 2, BS, Hkv, D))
+    statics = {"packed": slots == "packed"}
+    if slots == "latent":
+        statics = dict(
+            latent=512, scale=D**-0.5,
+            walk_blocks_per_wave=glm4moelite.DECODE_BLOCKS_PER_WAVE,
+            shared_blocks_per_step=glm4moelite.DECODE_BLOCKS_PER_WAVE)
 
     def traced(B, columns):
         spec = jax.ShapeDtypeStruct
         jaxpr = jax.make_jaxpr(lambda *a: paged_decode_attention_pallas(
-            *a, packed=slots == "packed"))(
+            *a, **statics))(
             spec((B, H, D), jnp.bfloat16), spec(pool, jnp.bfloat16),
             spec((B, columns), jnp.int32), spec((B,), jnp.int32))
         assert "pallas_call" in str(jaxpr)
@@ -132,5 +261,6 @@ def test_the_walk_traces_to_the_same_kernel_whatever_the_table(slots):
     counts = {shape: traced(*shape)
               for shape in ((8, 48), (8, 192), (32, 48), (32, 192))}
     assert len(set(counts.values())) == 1, counts
-    wave = paged_decode_pallas.walk_wave(2 * BS * Hkv * D * 2)
+    wave = paged_decode_pallas.walk_wave(
+        jax.ShapeDtypeStruct(pool, jnp.bfloat16))
     assert wave <= paged_decode_pallas.WALK_WAVE_BLOCKS

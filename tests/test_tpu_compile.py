@@ -539,7 +539,9 @@ def test_glm4moelite_programs_compile_at_the_cells_shapes(one_chip,
 # every host-side array the two families' pods hand out over the scripted
 # run below.  A PR that changes one on purpose reads its digest anew:
 # `lfm2moe.decode.True` in PR 41 (the interpreted step holds the paged
-# kernel, whose walk became a sequence a grid step with its own copies).
+# kernel, whose walk became a sequence a grid step with its own copies) and
+# in PR 43 (a wave of the walk that is a run in the pool comes by one copy,
+# and the step counts `run_blocks`).
 AT_PR_34 = {
     "afmoe.tables": "66348e6e9d5f9ed8", "lfm2moe.tables": "a43cc9a2ce3fa258",
     "lfm2moe.miss.False": "6b66837c74b57be8",
@@ -547,7 +549,7 @@ AT_PR_34 = {
     "lfm2moe.decode.False": "4855b16f775d4a8e",
     "lfm2moe.miss.True": "6b66837c74b57be8",
     "lfm2moe.hit.True": "a0f712e977db715f",
-    "lfm2moe.decode.True": "6e7cb9a10c02e18c",
+    "lfm2moe.decode.True": "a1a34d0c45c97e86",
 }
 
 
