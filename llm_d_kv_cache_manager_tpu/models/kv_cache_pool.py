@@ -43,9 +43,13 @@ class KVGroupSpec:
     a layer, ONE vector that is key and value at once (latent attention:
     every query head scores over all ``latent_dim`` lanes of it and takes
     its first ``value_dim`` as the value; ``num_kv_heads`` is 1 and
-    ``head_dim`` the latent's width).  Block bytes, pool shapes and the
-    scatter's geometry are read from here by the pool below, by the pod's
-    cache (models/pod.py) and by each family's model step."""
+    ``head_dim`` the latent's width).  With ``selector_dim`` a slot holds, a
+    position a layer, K and V per head AND one selector key of that many
+    lanes (learned sparse attention: an indexer scores every cached position
+    by its key and attention reads the best only), kept and evicted together.
+    Block bytes, pool shapes and the scatter's geometry are read from here by
+    the pool below, by the pod's cache (models/pod.py) and by each family's
+    model step."""
 
     num_layers: int
     block_size: int
@@ -85,8 +89,30 @@ class KVGroupSpec:
     # (compiled for the v5e, PR 42: tests/test_tpu_compile.py).
     latent_dim: Optional[int] = None
     value_dim: Optional[int] = None
+    # The selected kind: a slot as [block + t, 2 * Hkv, Dh], a tile a
+    # position (its K heads' rows, then its V heads'), then the t tiles that
+    # hold the block's selector keys, ``Dh / selector_dim`` positions a row
+    # (``pack_selected_blocks``).  A position's K and V are then ONE piece of
+    # the pool (2 KB at 4 KV heads of 128 in bfloat16, a whole tile of the
+    # chip's), which a step that reads picked positions only fetches by one
+    # copy or one row of a gather; a key of 64 lanes alone is half a tile,
+    # the trouble ``packed`` and the latent kind each solved their way.
+    selector_dim: Optional[int] = None
+    # how many positions a query reads of the group, the best by the
+    # selector's score (None: every position the window or context admits)
+    selected: Optional[int] = None
 
     def __post_init__(self) -> None:
+        if self.selector_dim is not None:
+            tile = 2 * self.num_kv_heads * self.head_dim
+            if (self.latent_dim is not None or self.state_shape is not None
+                    or self.selector_dim <= 0
+                    or self.head_dim % self.selector_dim
+                    or (self.block_size * self.selector_dim) % tile):
+                raise ValueError(
+                    "a selected slot is K/V tiles and whole tiles of "
+                    "selector keys: selector_dim divides head_dim, and a "
+                    "block's keys fill tiles of 2 * num_kv_heads * head_dim")
         if self.latent_dim is None:
             return
         if (self.num_kv_heads != 1 or self.head_dim != self.latent_dim
@@ -131,12 +157,18 @@ class KVGroupSpec:
             )
         return (
             self.num_layers
-            * (1 if self.latent_dim else 2)  # one vector, or K and V
             * self.block_size
-            * self.num_kv_heads
-            * self.head_dim
+            # one vector, or K and V, or K and V and the selector's key
+            * ((1 if self.latent_dim else 2) * self.num_kv_heads
+               * self.head_dim + (self.selector_dim or 0))
             * jnp.dtype(self.dtype).itemsize
         )
+
+    @property
+    def selector_tiles(self) -> int:
+        """Tiles of [2 * Hkv, Dh] that hold a block's selector keys."""
+        return (self.block_size * self.selector_dim
+                // (2 * self.num_kv_heads * self.head_dim))
 
     def layer_shape(self, num_blocks: int) -> tuple:
         """One layer's share of a pool of ``num_blocks`` slots (a state in
@@ -146,6 +178,9 @@ class KVGroupSpec:
             return (num_blocks,) + shape
         if self.latent_dim:
             return (num_blocks, self.block_size // 2, 2 * self.latent_dim)
+        if self.selector_dim:
+            return (num_blocks, self.block_size + self.selector_tiles,
+                    2 * self.num_kv_heads, self.head_dim)
         if self.packed:
             return (num_blocks, self.block_size, self.num_kv_heads,
                     2 * self.head_dim)
@@ -248,6 +283,46 @@ def scatter_latent_blocks(kv_layer, latent, block_ids, block_size, value_dim):
     )
 
 
+def pack_selected_blocks(k, v, key, block_size: int):
+    """Per-position K and V ([..., T, Hkv, Dh] each) and selector keys
+    ([..., T, dI]), T a multiple of ``block_size``, as the slots of a
+    selected group (``KVGroupSpec.layer_shape``): [..., T/block_size,
+    block_size + t, 2*Hkv, Dh].  Tile p of a block is its position p (K
+    heads' rows, then V heads'); the last t tiles are the block's keys as
+    rows of Dh lanes, row r the positions r, r + R, r + 2R, ... side by side
+    (R = block_size * dI / Dh rows in all)."""
+    *lead, T, Hkv, Dh = k.shape
+    dI = key.shape[-1]
+    nb = T // block_size
+    kv = jnp.concatenate((k, v), axis=-2).reshape(
+        *lead, nb, block_size, 2 * Hkv, Dh)
+    per = Dh // dI
+    rows = key.reshape(*lead, nb, per, block_size // per, dI)
+    rows = jnp.moveaxis(rows, -3, -2).reshape(*lead, nb, -1, 2 * Hkv, Dh)
+    return jnp.concatenate((kv, rows.astype(kv.dtype)), axis=-3)
+
+
+def unpack_selector_keys(tiles, selector_dim: int):
+    """The selector keys of slots' key tiles ([..., n, t, 2*Hkv, Dh], the
+    tiles after a slot's positions): [..., n*block, dI], in order."""
+    *lead, n, t, rows, Dh = tiles.shape
+    per = Dh // selector_dim
+    keys = tiles.reshape(*lead, n, t * rows, per, selector_dim)
+    return jnp.moveaxis(keys, -2, -3).reshape(*lead, -1, selector_dim)
+
+
+def scatter_selected_blocks(kv_layer, k, v, key, block_ids, block_size):
+    """``scatter_kv_blocks`` for a selected group: per-token K/V
+    [B, T, Hkv, Dh] and selector keys [B, T, dI] into the slots of one
+    layer's pool named by ``block_ids`` ([B, T/block_size]), in the one
+    layout ``pack_selected_blocks`` states.  Only the named slots are
+    written."""
+    slots = pack_selected_blocks(k, v, key, block_size)
+    return kv_layer.at[block_ids.reshape(-1)].set(
+        slots.reshape((-1,) + slots.shape[2:]).astype(kv_layer.dtype)
+    )
+
+
 @dataclass
 class KVCachePoolConfig:
     num_layers: int
@@ -260,6 +335,9 @@ class KVCachePoolConfig:
     # ``head_dim == latent_dim``, ``value_dim`` of its lanes the value
     latent_dim: Optional[int] = None
     value_dim: Optional[int] = None
+    # a pool of selected slots (``KVGroupSpec``'s selected kind): K and V per
+    # head and a selector key of ``selector_dim`` lanes a position
+    selector_dim: Optional[int] = None
 
     @property
     def spec(self) -> KVGroupSpec:
@@ -272,6 +350,7 @@ class KVCachePoolConfig:
             self.dtype,
             latent_dim=self.latent_dim,
             value_dim=self.value_dim,
+            selector_dim=self.selector_dim,
         )
 
 

@@ -1,8 +1,9 @@
-"""The serve path's expert layer, shared by the families that route by
-sigmoid scores with a selection bias (`models/afmoe.py`, `models/lfm2moe.py`):
-the router, and the sum over each token's picked experts without a capacity.
-(`models/moe.py` is the training path's: softmax scores, a static capacity,
-tokens over it dropped.)
+"""The serve path's expert layer: the router, and the sum over each token's
+picked experts without a capacity.  Shared by the families of two score
+kinds: sigmoid scores an expert, with a selection bias (`models/afmoe.py`,
+`models/lfm2moe.py`, `models/glm4moelite.py`), and softmax scores over all
+experts, with none (`models/keyevl2.py`).  (`models/moe.py` is the training
+path's: softmax scores, a static capacity, tokens over it dropped.)
 
 What differs between the families is an argument, and where an argument would
 add an operation to a family's trace the branch is taken in Python, so that
@@ -21,20 +22,23 @@ MOE_CHUNK_TOKENS = 4096
 
 
 def route(h, router, bias, top_k: int, norm: bool, scale: float,
-          norm_eps: float | None = None):
+          norm_eps: float | None = None, scores: str = "sigmoid"):
     """h: [N, D] float32 -> (experts picked [N, k], their weights [N, k]
-    float32).  Scores ``sigmoid(h . router)`` in float32 at precision
-    highest; the bias enters the selection only; with ``norm`` the picked
-    scores are divided by their sum (plus ``norm_eps`` where a family's
-    published code adds one), then multiplied by ``scale``."""
-    s = jax.nn.sigmoid(
-        jnp.dot(
-            h.astype(jnp.float32),
-            router.astype(jnp.float32),
-            precision=HI,
-        )
+    float32).  Scores in float32 at precision highest, ``sigmoid(h .
+    router)`` an expert or, with ``scores="softmax"``, the softmax of
+    ``h . router`` over all experts; the bias (None: the family has none)
+    enters the selection only; with ``norm`` the picked scores are divided by
+    their sum (plus ``norm_eps`` where a family's published code adds one),
+    then multiplied by ``scale``."""
+    if scores not in ("sigmoid", "softmax"):
+        raise ValueError(f"route: scores={scores!r} is not implemented")
+    s = jnp.dot(
+        h.astype(jnp.float32),
+        router.astype(jnp.float32),
+        precision=HI,
     )
-    _, picked = lax.top_k(s + bias, top_k)
+    s = jax.nn.sigmoid(s) if scores == "sigmoid" else jax.nn.softmax(s, -1)
+    _, picked = lax.top_k(s if bias is None else s + bias, top_k)
     w = jnp.take_along_axis(s, picked, axis=1)
     if norm:
         total = jnp.sum(w, axis=1, keepdims=True)

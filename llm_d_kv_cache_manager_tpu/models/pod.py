@@ -15,7 +15,12 @@ a position a layer, key and value at once: models/glm4moelite.py) a block of
 call says what the step reads of the group (``kv.read``: ``full_blocks``,
 ``latent_bytes`` from the spec's ``read_nbytes``, and ``step_bytes``, those
 and the weights a step reads, which the policy's ``step_weight_nbytes``
-states), since no window or state group is there to say it.
+states), since no window or state group is there to say it.  Where it is of
+the selected kind (K and V per head and a selector key a position, of which
+a query reads the keys of every position and K and V of the ``selected``
+best: models/keyevl2.py) the call says that: ``index_bytes``,
+``picked_bytes``, their sum ``sparse_bytes``, ``dense_bytes`` (what reading
+every live position's K and V would be) and ``step_bytes``.
 
 Two groups (a model that mixes window and full attention layers, after vLLM's
 hybrid KV-cache manager).  The *full group* is the above: one slot per logical
@@ -118,6 +123,13 @@ and its table on the device), ``pod.compile`` (one a program, on the call
 that compiles) and ``pod.launch.decode`` / ``.hit`` / ``.miss`` (the compiled
 call alone; a decode launch that follows a decode launch of the same pod
 carries the period between the two).
+
+A pod of one group may say ``decode_ahead`` in its policy
+(models/keyevl2.py): a decode call that goes on from the call before it
+launches the step after its own as well, and the next call is handed that
+step (``jit_programs.run_ahead`` has the rule, and what a step that is not
+taken leaves behind).  The groups beside the full one change their tables on
+the host with every decode call, so a pod that has one refuses it.
 """
 
 from __future__ import annotations
@@ -602,6 +614,14 @@ class Pod:
         self.groups = [g for g in (self.window, self.state) if g is not None]
         self.pending_load = None  # (a decode step's device counts, its tokens)
         self.last_launch = None  # (kind, `perf_counter`) of the last program call
+        # `jit_programs`' decode call launches the step after its own too,
+        # and what the last such call left for the next (`run_ahead`)
+        self.decode_ahead = bool(policy.get("decode_ahead"))
+        self.last_decode = None
+        if self.decode_ahead and self.groups:
+            raise ValueError(
+                f"{name}: decode_ahead is for a pod of one group (a window or "
+                "state group's tables change on the host with every step)")
 
     def cached_prefix(self, hashes) -> list[int]:
         ids = []
@@ -683,15 +703,33 @@ class Pod:
         table = np.asarray(table, np.int32)
         if not self.groups:
             full = self.specs.get("full")
-            if kind == "decode" and full is not None and full.latent_dim:
+            if kind == "decode" and full is not None and (
+                    full.latent_dim or full.selector_dim):
                 # the full group alone: what the step reads of it
-                current = (np.asarray(context_len, np.int64) - 1) // full.block_size
-                blocks = int((current + 1).sum())
+                live = np.asarray(context_len, np.int64)
+                blocks = int(((live - 1) // full.block_size + 1).sum())
                 with span("kv.read") as s:
                     s.set_attr("full_blocks", blocks)
-                    s.set_attr("latent_bytes", blocks * full.read_nbytes)
-                    s.set_attr("step_bytes", blocks * full.read_nbytes
-                               + self.step_weight_nbytes)
+                    if full.latent_dim:
+                        read = blocks * full.read_nbytes
+                        s.set_attr("latent_bytes", read)
+                    else:
+                        # every live position's selector key, and K and V of
+                        # the positions a query picks; beside them what
+                        # reading every live position's K and V would be
+                        position = full.read_nbytes // full.block_size
+                        key = (full.num_readers * full.selector_dim
+                               * jnp.dtype(full.dtype).itemsize)
+                        index = int(live.sum()) * key
+                        picked = int(np.minimum(live, full.selected).sum()) \
+                            * (position - key)
+                        read = index + picked
+                        s.set_attr("index_bytes", index)
+                        s.set_attr("picked_bytes", picked)
+                        s.set_attr("sparse_bytes", read)
+                        s.set_attr("dense_bytes",
+                                   int(live.sum()) * (position - key))
+                    s.set_attr("step_bytes", read + self.step_weight_nbytes)
             return table
         tables, reads = {}, {}
         for group in self.groups:
@@ -774,6 +812,7 @@ def inner_programs(program, model, shapes: dict, interpret: bool) -> dict:
 
     policy = cache_policy(program, model)
     windowed, stateful = bool(policy.get("window")), bool(policy.get("state"))
+    ahead = bool(policy.get("decode_ahead"))
 
     def served(logits):
         return jnp.stack((jnp.argmax(logits, -1).astype(jnp.float32),
@@ -798,7 +837,14 @@ def inner_programs(program, model, shapes: dict, interpret: bool) -> dict:
         array, because every
         argument that comes from the host costs a transfer of its own (0.12 ms
         each on the chip's host; my chip run, PR 29); `table` stays on the
-        device between the steps that do not change it."""
+        device between the steps that do not change it.  With the policy's
+        `decode_ahead` the first argument is a pair, what a step before
+        served (`served`'s array, where it lies on the device) and the
+        integers: a row whose token is -1 takes the token served there."""
+        if ahead:
+            before, ints = ints
+            ints = ints.at[:, 0].set(jnp.where(
+                ints[:, 0] < 0, before[0].astype(jnp.int32), ints[:, 0]))
         end = ints.shape[1] - 2 * stateful
         if ints.shape[1] == 2:
             tables = table
@@ -831,8 +877,10 @@ def example_args(key: str, shapes: dict, pod: "Pod", block: int) -> tuple:
         B = shapes["decode"][0]
         width = (2 + (pod.window is not None and 1 + pod.window.width)
                  + 2 * (pod.state is not None))
-        return (np.ones((B, width), i32),
-                np.zeros((B, shapes["max_blocks"]), i32))
+        ints = np.ones((B, width), i32)
+        if pod.decode_ahead:
+            ints = (np.zeros((2, B), np.float32), ints)
+        return ints, np.zeros((B, shapes["max_blocks"]), i32)
     tokens = sum(shapes[key])
     pre = shapes[key][0] // block if key == "hit" else 0
     return (np.zeros((1, tokens - pre * block), i32),
@@ -857,14 +905,23 @@ def jit_programs(program, model, shapes: dict, interpret: bool) -> dict:
     compiled program (`inner_programs`).  All three are compiled at the first
     call of any (set-up), so that a shape first used inside a measured window
     does not compile there: a `pod.compile` span a program on that call, and
-    none after it."""
+    none after it.
+
+    Where the family's policy says `decode_ahead` (a pod of one group: its
+    tables keep nothing on the host that a step not taken would have to give
+    back), a decode call that goes on from the call before it (`run_ahead`)
+    launches the step after its own as well, on the tokens its own step
+    serves, which are on the device before the host has them; the next call,
+    if it goes on in turn, is handed that step.  The host's share of a step
+    (the launch, the tokens' way to the host and back) then lies beside the
+    device's work and not between two steps."""
     block = model.block_size
     inner, compiled = inner_programs(program, model, shapes, interpret), {}
 
     def spec(x):
         return jax.ShapeDtypeStruct(np.shape(x), np.asarray(x).dtype)
 
-    def run(key, p, kv, first, second, traced):
+    def run(key, p, kv, first, second, traced, ahead=False):
         pod, fresh = kv.pod, 0
         if not compiled:
             for k, fn in inner.items():
@@ -872,7 +929,7 @@ def jit_programs(program, model, shapes: dict, interpret: bool) -> dict:
                         else example_args(k, shapes, pod, block))
                 with span("pod.compile") as s:
                     s.set_attr("program", fn.__name__)
-                    compiled[k] = fn.lower(p, spec(a), kv.arrays,
+                    compiled[k] = fn.lower(p, jax.tree.map(spec, a), kv.arrays,
                                            jax.tree.map(spec, b)).compile()
             fresh = len(compiled)
         # the period between two decode launches with no prefill between, as
@@ -888,6 +945,8 @@ def jit_programs(program, model, shapes: dict, interpret: bool) -> dict:
                 s.set_attr("after_decode", int(after))
                 if after:
                     s.set_attr("since_prev_launch_s", now - last[1])
+                if ahead:  # the step after the call's own
+                    s.set_attr("ahead", 1)
             *out, kv.arrays = compiled[key](p, first, kv.arrays, second)
         if traced is not None:
             traced.set_attr("kind", key)
@@ -905,10 +964,55 @@ def jit_programs(program, model, shapes: dict, interpret: bool) -> dict:
         def run_prefill(p, t, kv, bt):
             with root_trace("pod.step") as traced:
                 tables = kv.pod.tables(key, bt, prefix_blocks=prefix_blocks)
+                kv.pod.last_decode = None  # no decode call goes on from here
                 return run(key, p, kv, np.asarray(t, np.int32), tables,
                            traced)[0]
 
         return run_prefill
+
+    def follows(last, ints, sent) -> bool:
+        """Whether the decode call of `ints` goes on from the call that left
+        `last`: the same table (`sent`: it was not sent again), each context
+        one longer, each token the one that call served.  (Reading what it
+        served costs nothing where the engine has read it.)"""
+        return (last is not None and not sent
+                and np.array_equal(ints[:, 1], last[0][:, 1] + 1)
+                and np.array_equal(ints[:, 0], np.asarray(last[1])[0]))
+
+    def run_ahead(p, kv, ints, table, goes_on, traced):
+        """A decode call of a `decode_ahead` pod: (what `run` returns of the
+        call's own step).  `pod.last_decode` is what the last call left,
+        (its integers, the array it served, the step it launched ahead or
+        None), and None after a prefill.  This call goes on from that one
+        (`goes_on`, `follows`) where the table is the same, every context is
+        one longer and the tokens are those served: then the step launched
+        ahead, if there is one, is this call's, and this call launches the
+        next.  Where it does not go on (an admission, a finish, the first
+        step) it launches its own step on the host's tokens and none ahead: a
+        step launched ahead and not taken costs the device a step, so one is
+        launched only where the last call shows that the engine is in the
+        middle of its sequences.  Such a step has written, for each row, the
+        position after the row's last in the table it was launched with:
+        where the row goes on, the step that takes its place writes the same
+        there; where it ended, the place is the ended sequence's own or the
+        engine's scratch block, which whoever is handed the block next writes
+        before reading it."""
+        pod = kv.pod
+        last, pod.last_decode = pod.last_decode, None
+        if goes_on and last[2] is not None:
+            out, counted = last[2]
+        else:
+            before = (last[1] if last is not None
+                      else np.zeros((2, len(ints)), np.float32))
+            out, counted = run("decode", p, kv, (before, ints), table, traced)
+        ahead = None
+        if goes_on:
+            after = ints + np.asarray([0, 1], np.int32)
+            after[:, 0] = -1  # the tokens `out` serves, where they lie
+            ahead = run("decode", p, kv, (out[0], after), table, traced,
+                        ahead=True)
+        pod.last_decode = (ints, out[0], ahead)
+        return out, counted
 
     def run_decode(p, t, kv, bt, n):
         pod = kv.pod
@@ -927,7 +1031,15 @@ def jit_programs(program, model, shapes: dict, interpret: bool) -> dict:
                 s.set_attr("calls", 1)
                 s.set_attr("table_sent", int(sent))
                 s.set_attr("h2d_bytes", table.nbytes if sent else 0)
-            out, counted = run("decode", p, kv, ints, table, traced)
+                if pod.decode_ahead:
+                    goes_on = follows(pod.last_decode, ints, sent)
+                    # the call's own step was launched by the call before
+                    s.set_attr("ahead", int(
+                        goes_on and pod.last_decode[2] is not None))
+            if pod.decode_ahead:
+                out, counted = run_ahead(p, kv, ints, table, goes_on, traced)
+            else:
+                out, counted = run("decode", p, kv, ints, table, traced)
             if traced is not None and counted:
                 pod.keep_load(counted, len(t))
             return out

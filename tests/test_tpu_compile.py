@@ -21,10 +21,11 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from llm_d_kv_cache_manager_tpu.models import (
-    afmoe, glm4moelite, lfm2moe, llama, phi4flash,
+    afmoe, glm4moelite, keyevl2, lfm2moe, llama, phi4flash,
 )
 from llm_d_kv_cache_manager_tpu.models import pod as pod_programs
 from llm_d_kv_cache_manager_tpu.ops import flash_pallas
+from llm_d_kv_cache_manager_tpu.ops import sparse_attention_pallas as sparse
 from llm_d_kv_cache_manager_tpu.ops.latent_prefill_pallas import (
     latent_prefill_attention_pallas,
 )
@@ -305,7 +306,7 @@ def test_lfm2moe_programs_compile_at_the_cells_shapes(one_chip, monkeypatch,
         lambda: lfm2moe.new_pool(LFM2, 16384)))
 
     class Shapes:  # what `example_args` reads of a pod
-        window = None
+        window, decode_ahead = None, False
 
         class state:
             spec = lfm2moe.cache_groups(LFM2)["state"]
@@ -372,6 +373,8 @@ def test_phi4flash_programs_compile_at_the_cells_shapes(one_chip, monkeypatch,
     policy = phi4flash.cache_policy(PHI4)
 
     class Shapes:  # what `example_args` reads of a pod
+        decode_ahead = False
+
         class window:
             need = -(-(PHI4.window - 1) // BLOCK)
             width, store = need + 1, policy["window"]["store_blocks"]
@@ -503,6 +506,7 @@ def test_glm4moelite_programs_compile_at_the_cells_shapes(one_chip,
 
     class Shapes:  # what `example_args` reads of a pod: one group
         window = state = None
+        decode_ahead = False
 
     first, second = jax.tree.map(
         spec, pod_programs.example_args(key, GLM_SHAPES, Shapes, BLOCK))
@@ -526,6 +530,129 @@ def test_glm4moelite_programs_compile_at_the_cells_shapes(one_chip,
     assert pool_bytes == GLM_POOL_BLOCKS * 92160
     assert memory.alias_size_in_bytes >= pool_bytes  # the pool handed back
     assert memory.temp_size_in_bytes < GLM_TEMP_LIMIT[key]
+    assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes
+            + memory.output_size_in_bytes - memory.alias_size_in_bytes
+            ) < HBM_BYTES
+
+
+# --------------------- the selected cache's kernels and programs (PR 44)
+
+# benchmarks/configs/keye-vl-2.0-30b-a3b-l4.json; the pool and the shapes of
+# benchmarks/traffic/chat-longctx.json
+KEYE = keyevl2.KeyeVl2Config(
+    vocab_size=151936, d_model=2048, n_layers=4, n_heads=32, n_kv_heads=4,
+    head_dim=128, index_heads=16, index_dim=64, index_topk=2048, d_expert=768,
+    n_experts=128, top_k=8)
+KEYE_SHAPES = {"miss": (32768,), "hit": (32256, 512), "decode": (24,),
+               "max_blocks": 2080}
+KEYE_POOL_BLOCKS = 51200
+KEYE_SLOT = (17, 8, 128)
+# the kept decode form's kernels (keyevl2._decode_attention)
+KEYE_DECODE_KERNELS = {"sparse_decode_scores_pallas"}
+
+
+def test_a_selected_slot_lies_in_the_pool_as_it_is_written(one_chip):
+    """Slots [16 + 1, 8, 128] (a tile a position: 4 K heads' rows, then 4 V
+    heads'; then a tile of the block's sixteen 64-lane selector keys, two a
+    row) are whole tiles: the pool takes its own bytes on the chip, a scatter
+    writes it where it lies, and a gather of single tiles reads it as it
+    lies."""
+    spec = keyevl2.cache_groups(KEYE)["full"]
+    shape = spec.layer_shape(8192)
+    assert shape == (8192,) + KEYE_SLOT
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in (
+        (shape, jnp.bfloat16), ((32,), jnp.int32),
+        ((32,) + shape[1:], jnp.bfloat16), ((24, 2048), jnp.int32))]
+
+    def step(pool, ids, new, tiles):
+        pool = pool.at[ids].set(new)
+        return pool, jnp.take(keyevl2._tiles(pool), tiles, axis=0)
+
+    compiled = jax.jit(step, donate_argnums=(0,)).trace(*args).lower(
+        lowering_platforms=("tpu",)).compile()
+    nbytes = 2 * math.prod(shape)
+    assert nbytes * spec.num_layers // 8192 == spec.block_nbytes == 139264
+    memory = compiled.memory_analysis()
+    assert memory.argument_size_in_bytes < 1.01 * nbytes
+    assert memory.alias_size_in_bytes >= nbytes
+    hlo = compiled.as_text()
+    assert "bf16[8192,17,8,128]{3,2,1,0:" in hlo
+    assert not re.search(r"= bf16\[8192,\S* copy\(", hlo)
+
+
+def test_sparse_kernels_compile_at_the_served_shapes(one_chip):
+    """A chunk's index scores over a whole table, the prefill kernel under
+    the picks over the pool where it lies (a hit's suffix and a chunk of a
+    miss's queries are one kernel: the offset is data), and a decode step's
+    scores walked over each sequence's own table."""
+    bf16, f32, i32 = jnp.bfloat16, jnp.float32, jnp.int32
+    pool = ((KEYE_POOL_BLOCKS,) + KEYE_SLOT, bf16)
+    compile_for(
+        one_chip, lambda q, w, k, at: sparse.sparse_index_scores_pallas(
+            q, w, k, q_offset=at),
+        ((512, 16, 64), bf16), ((512, 16), f32), ((32768, 64), bf16),
+        ((), i32))
+    hlo = compile_for(
+        one_chip, lambda q, p, t, m, at: sparse.sparse_prefill_attention_pallas(
+            q, p, t, m, q_offset=at),
+        ((1, 512, 32, 128), bf16), pool, ((1, 2048), i32),
+        ((1, 512, 32768), jnp.bool_), ((), i32)).as_text()
+    assert not re.search(rf"= bf16\[{KEYE_POOL_BLOCKS},\S* copy\(", hlo)
+    hlo = compile_for(
+        one_chip, functools.partial(sparse.sparse_decode_scores_pallas,
+                                    selector_dim=64),
+        ((24, 16, 64), bf16), ((24, 16), f32), pool, ((24, 2080), i32),
+        ((24,), i32)).as_text()
+    assert not re.search(rf"= bf16\[{KEYE_POOL_BLOCKS},\S* copy\(", hlo)
+
+
+@pytest.mark.parametrize("key", ("miss", "hit", "decode"))
+def test_keyevl2_programs_compile_at_the_cells_shapes(one_chip, monkeypatch,
+                                                      key):
+    """The cell `keyevl2-chat-longctx`'s three programs as `models/pod.py`
+    jits them, the pool of selected slots donated: they compile for the v5e,
+    fit the chip beside 6.25 GB of weights and a 7.13-GB pool (a 32 768-token
+    miss is the risk: its chunks make their own queries, and what it holds
+    beside the pool the compiler trades against recomputing, down to 1.5 GB
+    where the pool is larger), hand the pool back where it lies, and no
+    instruction copies or re-lays-out a layer of it."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    def spec(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+
+    params = jax.tree.map(spec, jax.eval_shape(
+        lambda: keyevl2.init_params(jax.random.key(0), KEYE)))
+    pools = jax.tree.map(spec, jax.eval_shape(
+        lambda: keyevl2.new_pool(KEYE, KEYE_POOL_BLOCKS)))
+
+    class Shapes:  # what `example_args` reads of a pod: one group, and a
+        # decode call that launches the step after its own (`decode_ahead`)
+        window = state = None
+        decode_ahead = True
+
+    first, second = jax.tree.map(
+        spec, pod_programs.example_args(key, KEYE_SHAPES, Shapes, BLOCK))
+    assert jax.tree.map(lambda x: x.shape, first) == (
+        ((2, 24), (24, 2)) if key == "decode"
+        else (1, KEYE_SHAPES[key][-1]))
+    program = pod_programs.inner_programs(keyevl2, KEYE, KEYE_SHAPES,
+                                          False)[key]
+    compiled = program.trace(params, first, pools, second).lower(
+        lowering_platforms=("tpu",)).compile()
+    hlo = compiled.as_text()
+    kernels = set(re.findall(r"%(sparse_\w+_pallas)\S* = .*tpu_custom_call",
+                             hlo))
+    assert kernels == (KEYE_DECODE_KERNELS if key == "decode" else {
+        "sparse_index_scores_pallas", "sparse_prefill_attention_pallas"})
+    assert not re.search(rf"= \w+\[{KEYE_POOL_BLOCKS},[\d,]*\]\S* copy\(", hlo)
+    assert not pool_sized_moves(
+        hlo, (KEYE.n_layers, KEYE_POOL_BLOCKS) + KEYE_SLOT)
+    memory = compiled.memory_analysis()
+    pool_bytes = sum(a.dtype.itemsize * math.prod(a.shape)
+                     for a in jax.tree.leaves(pools))
+    assert pool_bytes == KEYE_POOL_BLOCKS * 139264
+    assert memory.alias_size_in_bytes >= pool_bytes  # the pool handed back
     assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes
             + memory.output_size_in_bytes - memory.alias_size_in_bytes
             ) < HBM_BYTES
