@@ -70,10 +70,6 @@ MOE_CHUNK_TOKENS = moe_serve.MOE_CHUNK_TOKENS
 # at and above it the Pallas flash kernel, whose VMEM bound
 # (flash_pallas.fits_vmem) is then the longest context a prefill takes.
 FLASH_MIN_LEN = 1024
-# Pool blocks the paged decode kernel takes a grid step: 32 x 16 = 512 keys.
-# Read on the chip at the cell's shapes (64 sequences of 13 k): 8 / 16 / 32
-# blocks gave 6.6 / 5.3 / 4.6 ms for the full layer (my chip run, PR 29).
-DECODE_BLOCKS_PER_STEP = 32
 
 
 @dataclass(frozen=True)
@@ -565,14 +561,15 @@ def _write_token(pool, ids, at, new):
     return lax.fori_loop(0, ids.shape[0], one, pool)
 
 
-def _decode_attention(q, pool, table, context_len, start, interpret):
-    """The paged kernel, which reads each table block once, where it serves
-    (compiled for the TPU, or interpreted); elsewhere the XLA gather."""
-    if paged_decode_pallas.serves(interpret):
+def _decode_attention(q, pool, table, context_len, start, interpret, plan):
+    """The paged kernel's walk, which copies each table block once, a run of
+    them by one copy, where it serves (compiled for the TPU, or interpreted;
+    `plan`: the runs of this table, `shared_prefix_plan`'s with nobody
+    sharing); elsewhere the XLA gather."""
+    if plan is not None:
         return paged_decode_attention_pallas(
             q, pool, table, context_len, start=start, heads_first=True,
-            blocks_per_step=DECODE_BLOCKS_PER_STEP, mxu_native=False,
-            interpret=interpret,
+            mxu_native=False, plan=plan, interpret=interpret,
         )
     return paged_attention(
         q, pool, table, context_len, start=start, heads_first=True
@@ -611,6 +608,16 @@ def decode_step(
     win_ctx = context_len - first
     win_start = jnp.maximum(context_len - cfg.window, 0) - first
     full, win, loads = list(pools["full"]), list(pools["window"]), []
+    # Which waves of a table's walk are runs in the pool, once a group: its
+    # layers all see the one table.
+    plans = {}
+    if paged_decode_pallas.serves(interpret):
+        for kind, ctx in (("full", context_len), ("window", win_ctx)):
+            if pools[kind]:  # a model may have no layer of a kind
+                plans[kind] = paged_decode_pallas.shared_prefix_plan(
+                    tables[kind], ctx, block_size=bs, min_sequences=None,
+                    blocks_per_wave=paged_decode_pallas.walk_wave(
+                        pools[kind][0]))
     for l, lp in enumerate(params["layers"]):
         kind, i = cfg.slot_of_layer(l)
         sliding = kind == "window"
@@ -620,16 +627,24 @@ def decode_step(
         if sliding:
             win[i] = _write_token(win[i], win_id, at, new)
             attn = _decode_attention(q[:, 0], win[i], tables["window"],
-                                     win_ctx, win_start, interpret)
+                                     win_ctx, win_start, interpret,
+                                     plans.get(kind))
         else:
             full[i] = _write_token(full[i], full_id, at, new)
             attn = _decode_attention(q[:, 0], full[i], tables["full"],
-                                     context_len, None, interpret)
+                                     context_len, None, interpret,
+                                     plans.get(kind))
         x = _attn_block(x, attn[:, None], g, lp, cfg)
         x, load = _mlp_block(x, lp, cfg)
         if load is not None:
             loads.append(load)
     logits, pools = _finish(x[:, 0], params, cfg, full, win, loads)
+    if plans:  # what the walks read: a group's plan by the layers that read it
+        pools["attention_read"] = sum(
+            len(pools[kind]) * jnp.stack(
+                (plan["read_blocks"], plan["walked_blocks"],
+                 plan["run_blocks"]))
+            for kind, plan in plans.items())
     return logits, pools
 
 

@@ -11,17 +11,6 @@ online softmax.  It has two forms, read from the slot layout
 (``heads_first``, ``packed``: as the pool's ``KVGroupSpec`` states it) and
 from ``start``.
 
-**The grid of tables by steps** (heads-first slots, window layers).  A grid
-step takes ``blocks_per_step`` pool blocks of one sequence, each an operand
-whose ``index_map`` points at the sequence's next block, so that Pallas's
-pipeline DMAs exactly the referenced blocks (double-buffered), and makes ONE
-online-softmax update over all their keys.  Past the context length the index
-map pins to the last valid block — an unchanged index skips the redundant
-DMA.  Slots [2, Hkv, bs, D] (models/afmoe.py) side by side are one
-[Hkv, P*bs, D] operand; a window layer's slots [2, bs, Hkv, D]
-(models/phi4flash.py) are taken as rows, as below, with the positions before
-``start`` hidden.
-
 **The shared pass and the walk** (slots [2, bs, Hkv, D], models/llama.py and
 models/phi4flash.py's full group; ``packed`` slots, models/lfm2moe.py).  A
 block is taken as it lies, [bs*Hkv, D] rows against every query head with the
@@ -67,6 +56,33 @@ PR 43): the same bytes into the same places by fewer descriptors.
 A sequence that shares nothing walks its whole table, and a table where
 nobody shares costs the set-finding and the shared pass's one empty step.
 
+**Heads-first slots** [2, Hkv, bs, D] (``heads_first``: models/afmoe.py, its
+full layers and, with a ``start``, its window layers) are walked too, the
+copies, waves, runs and the stream across sequences the same (a copy does
+not know a slot's shape); the products are their own (``_attend_heads``): a
+wave is multiplied a KV head at a time, that head's keys of the wave
+``buf[:, 0, h]`` being [P, bs, D] = [P*bs, D] with no re-layout (a block's
+[bs, D] is whole tiles) against the head's own query rows, one online-softmax
+update a wave over [H, P*bs] scores with no other head's columns to mask.
+They go through no shared pass (its products for this layout wait for traffic
+that shares a document: ROADMAP), and a window layer's table, which begins at
+the block that holds the window's first position ``start``, shares nothing by
+nature (a ``start`` hides part of a prefix from each sequence on its own):
+their plan is ``shared_prefix_plan``'s with ``min_sequences`` None, the runs
+alone, and the walk hides what lies before ``start`` like what lies past the
+context.
+
+**The grid of tables by steps** is what is left of the kernel's first form,
+for the window layers of slots [2, bs, Hkv, D] (models/phi4flash.py: rows,
+as above, with the positions before ``start`` hidden).  A grid step takes
+``blocks_per_step`` pool blocks of one sequence, each an operand whose
+``index_map`` points at the sequence's next block, so that Pallas's pipeline
+DMAs exactly the referenced blocks (double-buffered), and makes ONE
+online-softmax update over all their keys.  Past the context length the index
+map pins to the last valid block: an unchanged index skips the redundant
+DMA.  (Moving them to the walk is a change of its own, judged on
+`phi4flash-reasoning-longgen`: ROADMAP.)
+
 **Latent slots** (``latent``: models/glm4moelite.py; ``KVGroupSpec``'s latent
 kind).  A position holds no K and V per head but one vector that is key and
 value at once: every query head scores over all its lanes and takes the first
@@ -85,10 +101,12 @@ Contract matches ops/paged_attention.py::paged_attention; equivalence
 is pinned by tests/test_paged_decode_pallas.py (interpret mode on CPU), the
 walk's copies and what it traces to by tests/test_paged_decode_walk.py;
 tests/test_tpu_compile.py compiles every form for the v5e at the served
-shapes, and the decode steps of models/llama.py, afmoe.py, lfm2moe.py and
-phi4flash.py serve through it (the last with four query heads a pair-wise KV
-head of twice the model's head size, the window layers by ``start`` and the
-full group by one plan for the eight layers that read it).
+shapes, and the decode steps of models/llama.py, afmoe.py (one plan a group
+of slots: the full layer's and the four window layers'), lfm2moe.py,
+glm4moelite.py and phi4flash.py serve through it (the last with four query
+heads a pair-wise KV head of twice the model's head size, the window layers
+by ``start`` and the full group by one plan for the eight layers that read
+it).
 """
 
 from __future__ import annotations
@@ -241,6 +259,49 @@ def _attend_rows(q, kv_refs, hide, m_ref, l_ref, acc_ref, *, packed: bool):
     acc_ref[...] = acc_ref[...] * correction + o
 
 
+def _attend_heads(q, wave, seen, m_ref, l_ref, acc_ref):
+    """One online-softmax update over a wave of heads-first slots as they
+    lie, ``wave``: [P, 2, Hkv, bs, D].  A KV head's keys ``wave[:, 0, h]`` are
+    [P, bs, D], whose leading axes collapse to [P*bs, D] with no re-layout (a
+    block's [bs, D] is whole tiles), against that head's own query rows
+    (q: [H, D] in the products' type, head h's ``groups`` rows together):
+    the scores are [H, P*bs], column c the wave's position c, ``seen`` where
+    the sequence sees it.  No other head's columns to mask, nothing
+    concatenated along the keys."""
+    P, _, Hkv, bs, D = wave.shape
+    groups = q.shape[0] // Hkv
+
+    def of_head(x, h):
+        return x[h * groups : (h + 1) * groups]
+
+    def slab(i, h):
+        return wave[:, i, h].reshape(P * bs, D).astype(q.dtype)
+
+    s = jnp.concatenate(
+        [
+            jax.lax.dot_general(
+                of_head(q, h), slab(0, h), (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
+            for h in range(Hkv)
+        ],
+        axis=0,
+    )  # [H, P*bs]
+    p, correction = _softmax_update(s, seen, m_ref, l_ref)
+    p = p.astype(q.dtype)
+    o = jnp.concatenate(
+        [
+            jax.lax.dot_general(
+                of_head(p, h), slab(1, h), (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
+            for h in range(Hkv)
+        ],
+        axis=0,
+    )  # [H, D]
+    acc_ref[...] = acc_ref[...] * correction + o
+
+
 def _attend_latent(q, slab, hide, m_ref, l_ref, acc_ref, *, value: int):
     """One online-softmax update over a step's latent blocks as they lie:
     slab [n, 2*W], a row two positions ([value | rest | rest+ | value+]);
@@ -309,23 +370,20 @@ def _latent_queries(q_refs, scale: float, compute_dtype):
 def _decode_kernel(
     table_ref,  # SMEM [B, max_blocks] int32 (scalar prefetch)
     ctx_ref,  # SMEM [B] int32 (scalar prefetch)
-    *rest,  # more scalar prefetch (below), q ref (VMEM [1, H, D]),
-    # blocks_per_step kv refs, out ref, scratch
+    start_ref,  # SMEM [B]: the first position of the table a sequence sees
+    last_ref,  # SMEM [B]: its last block in context (the index maps' own)
+    q_ref,  # VMEM [1, H, D]
+    *rest,  # blocks_per_step kv refs, out ref, scratch
     block_size: int,
     groups: int,
     scale: float,
     blocks_per_step: int,
     mxu_native: bool,
-    windowed: bool = False,
-    heads_first: bool = False,
 ):
-    """The grid of tables by steps (heads-first slots, window layers): a
-    grid step takes ``blocks_per_step`` pool blocks of one sequence, each an
-    operand with its own pipelined DMA."""
-    # Scalar prefetch after the context: [start (SMEM [B]) if windowed,]
-    # [the last block (SMEM [B]) unless heads_first: the index maps' own.]
-    start_ref = rest[0] if windowed else None
-    q_ref, *rest = rest[windowed + (not heads_first) :]
+    """The grid of tables by steps (window layers of slots [2, bs, Hkv, D]):
+    a grid step takes ``blocks_per_step`` pool blocks of one sequence, each
+    an operand with its own pipelined DMA."""
+    del last_ref
     kv_refs = rest[:blocks_per_step]
     out_ref, m_ref, l_ref, acc_ref = rest[blocks_per_step:]
 
@@ -341,7 +399,6 @@ def _decode_kernel(
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     H = q_ref.shape[1]
-    D = q_ref.shape[2]
     Hkv = H // groups
     # mxu_native: feed the dots bf16 operands with f32 accumulation (the
     # MXU's native mode) instead of upcasting K/V after the DMA — saves
@@ -349,77 +406,31 @@ def _decode_kernel(
     # statistics and accumulators stay f32 either way.
     compute_dtype = q_ref.dtype if mxu_native else jnp.float32
     q = q_ref[0].astype(jnp.float32) * scale  # [H, D]
-    if heads_first:
-        qb = q.reshape(Hkv, groups, D).astype(compute_dtype)
 
     def in_context(position, first):
         """Which of the step's positions (an iota, counted from the step's
-        first) the sequence sees."""
-        seen = position < ctx - first
-        if windowed:
-            # Positions before the window's first (all in the table's
-            # first block) are masked like those past ctx.
-            seen &= position >= start_ref[b] - first
-        return seen
-
-    def attend(kb, vb, first, width):
-        """One online-softmax update over the keys at positions
-        first .. first+width-1; kb, vb: [Hkv, width, D]."""
-        s = jax.lax.dot_general(
-            qb,
-            kb,
-            (((2,), (2,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32,
-        )  # [Hkv, G, width]
-        s = s.reshape(H, width)
-        col = jax.lax.broadcasted_iota(jnp.int32, (H, width), 1)
-        p, correction = _softmax_update(
-            s, in_context(col, first), m_ref, l_ref
-        )
-        pb = p.reshape(Hkv, groups, width).astype(compute_dtype)
-        o = jax.lax.dot_general(
-            pb,
-            vb,
-            (((2,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32,
-        )  # [Hkv, G, D]
-        acc_ref[...] = acc_ref[...] * correction + o.reshape(H, D)
+        first) the sequence sees: those before the window's first (all in
+        the table's first block) are masked like those past ctx."""
+        return (position < ctx - first) & (position >= start_ref[b] - first)
 
     first = j * blocks_per_step * block_size
-    if heads_first:
-        # Slots are [2, Hkv, bs, D]: the step's blocks side by side are one
-        # [Hkv, P*bs, D] operand, so a step is one update (P*bs = 128 keys
-        # at 8 blocks a step) and not P small ones.
 
-        @pl.when(first < ctx)
-        def _attend_step():
-            kb = jnp.concatenate([r[0, 0] for r in kv_refs], axis=1)
-            vb = jnp.concatenate([r[0, 1] for r in kv_refs], axis=1)
-            attend(
-                kb.astype(compute_dtype),
-                vb.astype(compute_dtype),
-                first,
-                blocks_per_step * block_size,
-            )
+    @pl.when(first < ctx)
+    def _attend_step():
+        shape = (H, block_size * Hkv)
+        own = _own_head(shape, H, groups)
+        position = jax.lax.div(
+            jax.lax.broadcasted_iota(jnp.int32, shape, 1), Hkv
+        )
 
-    else:
+        def hide(i, s):
+            seen = in_context(position, first + i * block_size)
+            return jnp.where(own & seen, s, NEG_INF)
 
-        @pl.when(first < ctx)
-        def _attend_step():
-            shape = (H, block_size * Hkv)
-            own = _own_head(shape, H, groups)
-            position = jax.lax.div(
-                jax.lax.broadcasted_iota(jnp.int32, shape, 1), Hkv
-            )
-
-            def hide(i, s):
-                seen = in_context(position, first + i * block_size)
-                return jnp.where(own & seen, s, NEG_INF)
-
-            _attend_rows(
-                q.astype(compute_dtype), kv_refs, hide, m_ref, l_ref,
-                acc_ref, packed=False,
-            )
+        _attend_rows(
+            q.astype(compute_dtype), kv_refs, hide, m_ref, l_ref,
+            acc_ref, packed=False,
+        )
 
     @pl.when(j == n_steps - 1)
     def _finalize():
@@ -439,18 +450,17 @@ def _walk_kernel(
     place_ref,  # SMEM [B]: its place in the shared pass's results
     skip_ref,  # SMEM [B]: its shared run, the first block of its own
     runs_ref,  # SMEM [B, waves]: which waves of its walk are runs
-    q_ref,  # VMEM [1, H, D] (latent: [1, 2, H, 2*W - value])
-    kv_hbm,  # the pool, where it lies
-    m0_ref, l0_ref, acc0_ref,  # what the shared pass left of this sequence
-    out_ref,
-    m_ref, l_ref, acc_ref, buf, sem, wave_ref,  # scratch
-    *,
+    *rest,  # [start (SMEM [B]) if windowed,] q (VMEM [1, H, D]; latent:
+    # [1, 2, H, 2*W - value]), the pool where it lies, [what the shared pass
+    # left of this sequence: m0, l0, acc0,] out, scratch
     block_size: int,
     groups: int,
     scale: float,
     mxu_native: bool,
     packed: bool,
     latent: int | None = None,
+    heads_first: bool = False,
+    windowed: bool = False,
 ):
     """The walk of each sequence's own blocks: a grid step is a sequence.  It
     brings the blocks from the end of the sequence's shared run to its last
@@ -466,8 +476,18 @@ def _walk_kernel(
     sequence's (scratch lives across grid steps, and the grid is sequential),
     so that a sequence does not begin by waiting for a copy with nothing to
     hide it.  ``wave_ref`` counts the waves since the call began: a wave's
-    buffer is its number's remainder."""
+    buffer is its number's remainder.
+
+    Heads-first slots [2, Hkv, bs, D] are multiplied a KV head at a time
+    (``_attend_heads``); with ``windowed`` the table begins at the block that
+    holds the window's first position, ``start``, and what lies before it is
+    hidden like what lies past the context.  A call that nothing resumes (no
+    shared pass came before it: the plan's every ``skip`` is 0) starts every
+    sequence's softmax anew."""
     del place_ref  # the index maps' own
+    *ins, out_ref, m_ref, l_ref, acc_ref, buf, sem, wave_ref = rest
+    start_ref = ins.pop(0) if windowed else None
+    q_ref, kv_hbm, *resumed = ins
     N, P = buf.shape[:2]
     b = pl.program_id(0)
     B = pl.num_programs(0)
@@ -552,27 +572,34 @@ def _walk_kernel(
 
         jax.lax.fori_loop(0, N - 1, first_waves, (jnp.int32(0), jnp.int32(0)))
 
-    resumes = skip_ref[b] > 0
-
-    @pl.when(resumes)
-    def _resume():
-        m_ref[...] = m0_ref[...]
-        l_ref[...] = l0_ref[...]
-        acc_ref[...] = acc0_ref[...]
-
-    @pl.when(jnp.logical_not(resumes))
     def _init():
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
+    if len(resumed) == 3:
+        resumes = skip_ref[b] > 0
+
+        @pl.when(resumes)
+        def _resume():
+            for ref, left in zip((m_ref, l_ref, acc_ref), resumed):
+                ref[...] = left[...]
+
+        pl.when(jnp.logical_not(resumes))(_init)
+    else:
+        _init()
+
     compute_dtype = q_ref.dtype if mxu_native else jnp.float32
     if not isinstance(latent, int):
         q = (q_ref[0].astype(jnp.float32) * scale).astype(compute_dtype)
-        shape = (H, block_size * Hkv)
-        own_head = _own_head(shape, H, groups)
-        position = jax.lax.div(
-            jax.lax.broadcasted_iota(jnp.int32, shape, 1), Hkv)
+        if heads_first:  # column c of a wave's scores is the wave's position c
+            position = jax.lax.broadcasted_iota(
+                jnp.int32, (H, P * block_size), 1)
+        else:
+            shape = (H, block_size * Hkv)
+            own_head = _own_head(shape, H, groups)
+            position = jax.lax.div(
+                jax.lax.broadcasted_iota(jnp.int32, shape, 1), Hkv)
     else:
         q = _latent_queries([q_ref], scale, compute_dtype)
         half = block_size // 2  # rows a block: column c is row c % half of
@@ -602,7 +629,12 @@ def _walk_kernel(
             seen = position < ctx - (at + i * block_size)
             return jnp.where(own_head & seen, s, NEG_INF)
 
-        if not isinstance(latent, int):
+        if heads_first:
+            seen = position < ctx - at
+            if windowed:
+                seen &= position >= start_ref[b] - at
+            _attend_heads(q, buf.at[slot], seen, m_ref, l_ref, acc_ref)
+        elif not isinstance(latent, int):
             _attend_rows(
                 q, [buf.at[slot, pl.ds(i, 1)] for i in range(P)], hide,
                 m_ref, l_ref, acc_ref, packed=packed,
@@ -730,7 +762,7 @@ def shared_prefix_plan(
     *,
     block_size: int,
     blocks_per_wave: int,
-    min_sequences: int = 2,
+    min_sequences: int | None = 2,
 ) -> dict:
     """Which sequences' tables begin with the same run of full blocks, the
     groups the shared pass takes them in, and where each sequence's walk
@@ -744,7 +776,11 @@ def shared_prefix_plan(
     of up to ``SHARED_SEQUENCES`` in row order; a sequence whose group has
     fewer than ``min_sequences`` members (alone in it, as the default has
     it), or in a set whose run is 0 (idle slots on the scratch block among
-    them), walks its whole table.  The walk takes a sequence's own blocks
+    them), walks its whole table.  With ``min_sequences`` None nobody shares
+    and no sets are looked for: the plan of a call with no shared pass
+    (heads-first slots; a window layer's table, whose ``start`` hides part of
+    a prefix from each sequence on its own), which has no ``shared`` and no
+    ``shared_steps``.  The walk takes a sequence's own blocks
     ``blocks_per_wave`` at a time (`walk_wave` of the pool, where the caller
     names no other), and a wave all in context whose blocks lie one after
     another in the pool (each column's id the one before's + 1, as an
@@ -759,8 +795,49 @@ def shared_prefix_plan(
     and ``walked_blocks`` (what a walk of every table reads)."""
     i32 = jnp.int32
     B, M = block_table.shape
-    G = SHARED_SEQUENCES
     ctx = context_len.astype(i32)
+    if min_sequences is None:
+        slot = skip = jnp.zeros((B,), i32)
+    else:
+        slot, skip, heads, groups = _shared_sets(
+            block_table, ctx, block_size, min_sequences)
+
+    # The walk: every sequence's rest, its write position's block at least.
+    blocks = jnp.maximum(ctx - 1, 0) // block_size + 1
+    skip = skip.astype(i32)
+    # Wave w of a walk is columns skip + w*P .. + P - 1: a run where the last
+    # of them is in context and no step from one of them to the next breaks
+    # the ascent.
+    P = blocks_per_wave
+    start = skip[:, None] + jnp.arange(-(-M // P), dtype=i32)[None] * P
+    column = jnp.arange(M - 1, dtype=i32)  # the step from it to the next
+    steps = (column >= start[..., None]) & (column < start[..., None] + P - 1)
+    breaks = block_table[:, 1:] != block_table[:, :-1] + 1
+    runs = (start + P <= blocks[:, None]) & ~jnp.any(
+        breaks[:, None] & steps, axis=2)
+    plan = {"walk": (slot, skip, runs.astype(i32))}
+    shared_blocks = 0
+    if min_sequences is not None:
+        plan["shared"] = tuple(a.astype(i32) for a in groups)
+        # a grid of no step at all is not asked of the compiler: one step
+        # that reads nothing where nobody shares
+        plan["shared_steps"] = jnp.maximum(jnp.sum(heads), 1).astype(i32)
+        shared_blocks = jnp.sum(groups[1])
+    return {
+        **plan,
+        "read_blocks": (shared_blocks + jnp.sum(blocks - skip)).astype(i32),
+        "run_blocks": (jnp.sum(runs) * P).astype(i32),
+        "walked_blocks": jnp.sum(blocks).astype(i32),
+    }
+
+
+def _shared_sets(block_table, ctx, block_size: int, min_sequences: int):
+    """`shared_prefix_plan`'s sets: each sequence's place in the shared
+    pass's results and its run, which sequences head a group, and the
+    groups' (table row, run, members)."""
+    i32 = jnp.int32
+    B, M = block_table.shape
+    G = SHARED_SEQUENCES
     rows = jnp.arange(B, dtype=i32)
     leader = jnp.argmax(
         block_table[:, :1] == block_table[None, :, 0], axis=1
@@ -795,32 +872,7 @@ def shared_prefix_plan(
         jnp.sum(jnp.where(holds, rows[None, :], 0), axis=1),
         jnp.repeat(group_row, G),  # an empty place asks again for the first
     )
-
-    # The walk: every sequence's rest, its write position's block at least.
-    blocks = jnp.maximum(ctx - 1, 0) // block_size + 1
-    skip = skip.astype(i32)
-    # Wave w of a walk is columns skip + w*P .. + P - 1: a run where the last
-    # of them is in context and no step from one of them to the next breaks
-    # the ascent.
-    P = blocks_per_wave
-    start = skip[:, None] + jnp.arange(-(-M // P), dtype=i32)[None] * P
-    column = jnp.arange(M - 1, dtype=i32)  # the step from it to the next
-    steps = (column >= start[..., None]) & (column < start[..., None] + P - 1)
-    breaks = block_table[:, 1:] != block_table[:, :-1] + 1
-    runs = (start + P <= blocks[:, None]) & ~jnp.any(
-        breaks[:, None] & steps, axis=2)
-    return {
-        "walk": (slot, skip, runs.astype(i32)),
-        "shared": tuple(
-            a.astype(i32) for a in (group_row, group_run, members)
-        ),
-        # a grid of no step at all is not asked of the compiler: one step
-        # that reads nothing where nobody shares
-        "shared_steps": jnp.maximum(jnp.sum(heads), 1).astype(i32),
-        "read_blocks": (jnp.sum(group_run) + jnp.sum(blocks - skip)).astype(i32),
-        "run_blocks": (jnp.sum(runs) * P).astype(i32),
-        "walked_blocks": jnp.sum(blocks).astype(i32),
-    }
+    return slot, skip, heads, (group_row, group_run, members)
 
 
 @functools.partial(
@@ -854,18 +906,22 @@ def paged_decode_attention_pallas(
     is made of each);
     block_table: [B, max_blocks] int32; context_len: [B] int32.
     ``start`` ([B] int32, window layers): the first position of the
-    table a sequence still sees, as in ``paged_attention``; without it
-    the kernel is the one it was.  Returns [B, H, D] in q.dtype.
+    table a sequence still sees, as in ``paged_attention``.  Returns
+    [B, H, D] in q.dtype.
 
-    With ``heads_first`` or ``start`` the grid is tables by steps of
-    ``blocks_per_step`` blocks, which the caller states.  Without them, runs
-    of blocks that several tables begin with are read once for the sequences
-    that share them, and each sequence's own blocks are copied by the walk,
+    Every layout is walked: each sequence's own blocks are copied
     ``walk_blocks_per_wave`` at a time (``walk_wave``'s where not given: the
     tests' small tables ask for small waves), a wave that is a run in the
-    pool by one copy; ``shared_prefix_plan``, which a model's decode step
-    makes once for all its layers, for the same wave, and hands in as
-    ``plan``, is made here when it is not.
+    pool by one copy.  Without ``heads_first`` and ``start``, runs of blocks
+    that several tables begin with are first read once for the sequences that
+    share them (the shared pass); heads-first slots, with a ``start`` or
+    without, share nothing.  ``shared_prefix_plan``, which a model's decode
+    step makes once for all the layers that see a table, for the same wave
+    (with ``min_sequences`` None for a call that shares nothing), and hands
+    in as ``plan``, is made here when it is not.  What is left of the grid of
+    tables by steps serves a ``start`` over slots [2, bs, Hkv, D]
+    (models/phi4flash.py's window layers): ``blocks_per_step`` blocks a grid
+    step, which the caller states.
 
     ``mxu_native=True`` keeps the attention dots in the input dtype
     (bf16 operands, f32 accumulation) instead of upcasting K/V to f32 in
@@ -892,11 +948,12 @@ def paged_decode_attention_pallas(
         q = latent_query_layouts(
             jnp.pad(q, ((0, 0), (0, -H % 8), (0, 0))), latent)
         return _shared_pass_and_walk(
-            q, kv_layer, block_table, context_len, plan,
+            q, kv_layer, block_table, context_len, plan, [],
             block_size=2 * half, groups=q.shape[-2], scale=scale,
             shared_blocks_per_step=shared_blocks_per_step,
             blocks_per_wave=walk_blocks_per_wave, mxu_native=mxu_native,
-            packed=False, latent=latent, interpret=interpret,
+            packed=False, latent=latent, heads_first=False,
+            interpret=interpret,
         )[:, :H]
     if packed:
         if heads_first:
@@ -910,25 +967,24 @@ def paged_decode_attention_pallas(
         _, _, block_size, Hkv, _ = kv_layer.shape
     groups = H // Hkv
     window = [a for a in (start,) if a is not None]
-    windowed = len(window) == 1
     if not heads_first:
         kv_layer = kv_layer.reshape(
             kv_layer.shape[: 1 if packed else 2]
             + (block_size * Hkv, q.shape[2])
         )
-    # The shared pass and the walk: where the table's columns are the
-    # sequence's positions from 0 (a window layer's start hides part of a
-    # prefix from each sequence on its own).
-    if not (heads_first or windowed):
+    # The shared pass and the walk; the walk alone for heads-first slots and
+    # their window layers' tables.
+    if heads_first or len(window) == 0:
         out = _shared_pass_and_walk(
-            q, kv_layer, block_table, context_len, plan,
+            q, kv_layer, block_table, context_len, plan, window,
             block_size=block_size, groups=groups, scale=scale,
             shared_blocks_per_step=shared_blocks_per_step,
             blocks_per_wave=walk_blocks_per_wave, mxu_native=mxu_native,
-            packed=packed, interpret=interpret,
+            packed=packed, heads_first=heads_first, interpret=interpret,
         )
         return out[..., D:] if packed else out
 
+    # The grid of tables by steps: a window layer of slots [2, bs, Hkv, D].
     if packed:
         raise ValueError("packed slots are walked, not stepped through")
     if blocks_per_step is None:
@@ -944,28 +1000,20 @@ def paged_decode_attention_pallas(
             ((0, 0), (0, n_steps * P_STEP - max_blocks)),
         )
 
-    scalars = [block_table, context_len] + window
-    if not heads_first:
-        # The sequence's last valid block, once for all index maps: the
-        # scalar core runs every operand's map twice a grid step, and a
-        # division in each was a tenth of the kernel's time.  (The
-        # heads-first maps still divide: ROADMAP.)
-        scalars.append(_last_block(context_len, block_size))
+    # The sequence's last valid block, once for all index maps: the scalar
+    # core runs every operand's map twice a grid step, and a division in
+    # each was a tenth of the kernel's time.
+    scalars = [block_table, context_len, start,
+               _last_block(context_len, block_size)]
     zeros = (0,) * (kv_layer.ndim - 1)
     kv_block = (1,) + kv_layer.shape[1:]
 
     def kv_index(i):
         # Sub-block i of step j; past-context steps revisit the last
         # valid block (an unchanged index skips the DMA).
-        def index(b, j, table_ref, ctx_ref, *more):
-            if heads_first:
-                jc = jnp.minimum(
-                    j * P_STEP + i,
-                    jnp.maximum((ctx_ref[b] - 1) // block_size, 0),
-                )
-            else:
-                jc = jnp.minimum(j * P_STEP + i, more[-1][b])
-            return (table_ref[b, jc],) + zeros
+        def index(b, j, table_ref, ctx_ref, start_ref, last_ref):
+            return (table_ref[b, jnp.minimum(j * P_STEP + i, last_ref[b])],
+                    ) + zeros
 
         return index
 
@@ -998,8 +1046,6 @@ def paged_decode_attention_pallas(
         scale=scale,
         blocks_per_step=P_STEP,
         mxu_native=mxu_native,
-        windowed=windowed,
-        heads_first=heads_first,
     )
     return pl.pallas_call(
         kernel,
@@ -1024,12 +1070,15 @@ def walk_wave(kv_layer) -> int:
     return min(max(WALK_WAVE_BYTES // slot_bytes, 1), WALK_WAVE_BLOCKS)
 
 
-def _shared_pass_and_walk(q, kv_layer, block_table, context_len, plan, *,
-                          block_size, shared_blocks_per_step, blocks_per_wave,
-                          interpret, **statics):
+def _shared_pass_and_walk(q, kv_layer, block_table, context_len, plan, window,
+                          *, block_size, shared_blocks_per_step,
+                          blocks_per_wave, interpret, heads_first, **statics):
     """The shared pass over the plan's groups, then every sequence's walk of
     its own blocks, resumed from what the pass left: one sequence a grid
-    step, the pool handed in where it lies."""
+    step, the pool handed in where it lies.  A plan with no ``shared``
+    (`shared_prefix_plan`'s ``min_sequences`` None) is walked with no pass
+    before it: the only kind for heads-first slots, whose pass has no products
+    yet, and for a ``window`` ([start] or []), which shares nothing."""
     B, H = q.shape[0], q.shape[-2]
     # what a head keeps of a block: the query's own lanes, or a latent's value
     Dq = statics.get("latent") or q.shape[-1]
@@ -1037,20 +1086,28 @@ def _shared_pass_and_walk(q, kv_layer, block_table, context_len, plan, *,
     kv_block = (1,) + kv_layer.shape[1:]
     if blocks_per_wave is None:
         blocks_per_wave = walk_wave(kv_layer)
+    windowed = len(window) == 1
+    walk_only = heads_first or windowed
     if not isinstance(plan, dict):
         plan = shared_prefix_plan(
             block_table, context_len, block_size=block_size,
             blocks_per_wave=blocks_per_wave,
+            **({"min_sequences": None} if walk_only else {}),
         )
     place, skip, runs = plan["walk"]
     waves = -(-block_table.shape[1] // blocks_per_wave)
     if place.shape != (B,) or runs.shape != (B, waves):
         raise ValueError("the plan was made for another table or wave")
-    resumed = _shared_pass(
-        q, kv_layer, block_table, plan, kv_block=kv_block,
-        blocks_per_step=shared_blocks_per_step, interpret=interpret,
-        **statics,
-    )
+    resumed = []
+    if "shared" in plan:
+        if walk_only:
+            raise ValueError("heads-first slots and window starts share "
+                             "nothing: a plan of min_sequences None")
+        resumed = _shared_pass(
+            q, kv_layer, block_table, plan, kv_block=kv_block,
+            blocks_per_step=shared_blocks_per_step, interpret=interpret,
+            **statics,
+        )
     def of_sequence(b, *_):
         return (b, 0, 0)
 
@@ -1061,7 +1118,8 @@ def _shared_pass_and_walk(q, kv_layer, block_table, context_len, plan, *,
         return (place_ref[b], 0)
 
     scalars = (block_table, context_len,
-               _last_block(context_len, block_size), place, skip, runs)
+               _last_block(context_len, block_size), place, skip, runs,
+               *window)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(scalars),
         grid=(B,),
@@ -1088,7 +1146,9 @@ def _shared_pass_and_walk(q, kv_layer, block_table, context_len, plan, *,
         ],
     )
     return pl.pallas_call(
-        functools.partial(_walk_kernel, block_size=block_size, **statics),
+        functools.partial(
+            _walk_kernel, block_size=block_size, heads_first=heads_first,
+            windowed=windowed, **statics),
         out_shape=jax.ShapeDtypeStruct((B, H, Dq), q.dtype),
         grid_spec=grid_spec,
         interpret=interpret,

@@ -412,9 +412,11 @@ def test_paged_decode_attention_reads_from_the_window_start(kernel,
         got = paged_attention(q, stored, table, ctx, start=start,
                               heads_first=heads_first)
     else:
+        # heads-first slots are walked, two blocks a wave; the others go
+        # through the grid of tables by steps, two blocks a step
         got = paged_decode_attention_pallas(
             q, stored, table, ctx, start=start, heads_first=heads_first,
-            blocks_per_step=2, interpret=True)
+            blocks_per_step=2, walk_blocks_per_wave=2, interpret=True)
     for b in range(3):
         kv = pool[table[b]]  # [3, 2, 16, Hkv, D]
         k = kv[:, 0].reshape(48, 2, 16)[int(start[b]):int(ctx[b])]

@@ -631,14 +631,17 @@ def test_a_float8_pass_fails_the_tolerance_the_decode_comparison_holds():
 # grid step and copies its own blocks; the plan lists sequences, not steps)
 # and in PR 43 (the plan flags the waves that are runs in the pool and the
 # walk brings such a wave by one copy);
+# `afmoe.decode.True` was read anew in PR 45 (heads-first slots and their
+# window layers' tables are walked: a plan of the runs a group of slots, the
+# step counts `attention_read`);
 # `llama.decode.False` stands, because off the TPU and uninterpreted the step
-# keeps the XLA gather and makes no plan, and so do the six `afmoe.*` and the
-# four `llama.miss/hit.*`.
+# keeps the XLA gather and makes no plan, and so do the other five `afmoe.*`
+# and the four `llama.miss/hit.*`.
 TEXT_AT_PR_32 = {
     "afmoe.miss.False": "e6f04f9f13e0aa70", "afmoe.hit.False": "83fd523e8393f5b3",
     "afmoe.decode.False": "50748779141b4cb4",
     "afmoe.miss.True": "e6f04f9f13e0aa70", "afmoe.hit.True": "83fd523e8393f5b3",
-    "afmoe.decode.True": "40fcc09b43a20425",
+    "afmoe.decode.True": "25e78136eac99456",
     "llama.miss.False": "90c7da6d6fb5ae9d", "llama.hit.False": "364331e0e1475faa",
     "llama.decode.False": "53c6a3b76efc093d",
     "llama.miss.True": "90c7da6d6fb5ae9d", "llama.hit.True": "364331e0e1475faa",

@@ -1,9 +1,11 @@
 """Pallas paged-decode kernel vs the XLA gather implementation: slots
 [2, block, Hkv, D] (the `llama` pool's; `phi4flash`'s with four query heads a
-KV head) and `packed` ones (`lfm2moe`'s), the shared pass and the walk that
-copies each sequence's own blocks, a wave as one online-softmax update.
-(Heads-first slots and window starts, the grid of tables by steps:
-tests/test_afmoe_pod.py, tests/test_phi4flash_pod.py.)"""
+KV head), `packed` ones (`lfm2moe`'s) and heads-first ones (`afmoe`'s, which
+go through no shared pass), the shared pass and the walk that copies each
+sequence's own blocks, a wave as one online-softmax update.
+(Window starts: tests/test_paged_decode_walk.py and tests/test_afmoe_pod.py
+for the walk over heads-first slots, tests/test_phi4flash_pod.py for the grid
+of tables by steps.)"""
 
 import jax
 import jax.numpy as jnp
@@ -172,7 +174,7 @@ SCRATCH = 0  # the block an idle slot's table names in every column
 
 
 def make_shared_case(key, H, Hkv, D, max_blocks, prompts, sequences,
-                     packed=False, layers=1, layer=0):
+                     packed=False, layers=1, layer=0, heads_first=False):
     """Tables as `Pod.cached_prefix` makes them: the sequences of one prompt
     have its blocks' ids at the head of their rows and blocks of their own
     behind.  prompts: blocks of each shared prompt; sequences: (prompt or
@@ -200,6 +202,8 @@ def make_shared_case(key, H, Hkv, D, max_blocks, prompts, sequences,
     ref = paged_attention(q, kv[layer * N:(layer + 1) * N], table, ctx)
     if packed:  # K in the lower half of a row's lanes, V in the upper
         kv = jnp.concatenate((kv[:, 0], kv[:, 1]), axis=-1)
+    if heads_first:  # a block's positions under each KV head
+        kv = kv.transpose(0, 1, 3, 2, 4)
     return q, kv, table + layer * N, ctx, ref
 
 
@@ -265,11 +269,14 @@ SHARED_CASES = {
         [3], [(0, 160), (0, 128), (None, 140), (0, 139), (0, 50)], 2, 2, 1, 0),
 }
 
-# The three slot layouts the walk serves: (H, Hkv, D), `packed` or not.
+# The slot layouts the walk serves: (H, Hkv, D), `packed` or not.
 # `llama`: internlm2-1.8b's heads; `packed`: K and V side by side in the lanes
 # at head size 64 (models/lfm2moe.py); `pairwise`: four query heads a KV head
-# (models/phi4flash.py's differential attention).
-LAYOUTS = {"llama": (16, 8, 128), "packed": (8, 4, 64), "pairwise": (8, 2, 128)}
+# (models/phi4flash.py's differential attention); `heads_first`: slots
+# [2, Hkv, bs, D] (models/afmoe.py), which no shared pass serves: the
+# sequences of a prompt each walk its blocks.
+LAYOUTS = {"llama": (16, 8, 128), "packed": (8, 4, 64), "pairwise": (8, 2, 128),
+           "heads_first": (8, 2, 128)}
 
 
 def shared_case(name, slots, key=7):
@@ -277,10 +284,11 @@ def shared_case(name, slots, key=7):
     H, Hkv, D = LAYOUTS[slots]
     q, kv, table, ctx, ref = make_shared_case(
         jax.random.PRNGKey(key), H, Hkv, D, 12, prompts, sequences,
-        packed=slots == "packed", layers=layers, layer=layer)
+        packed=slots == "packed", layers=layers, layer=layer,
+        heads_first=slots == "heads_first")
     statics = dict(interpret=True, walk_blocks_per_wave=wave,
                    shared_blocks_per_step=shared_step,
-                   packed=slots == "packed")
+                   packed=slots == "packed", heads_first=slots == "heads_first")
     return (q, kv, table, ctx), statics, ref
 
 

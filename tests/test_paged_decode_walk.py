@@ -1,8 +1,11 @@
 """The paged kernel's walk of each sequence's own blocks (PR 41): what it
 copies, counted as the interpreted kernel runs (a wave whose blocks lie one
-after another in the pool by one copy, PR 43), and what it traces to, which
-is set-up's time.  (Against the XLA gather: tests/test_paged_decode_pallas.py.)
+after another in the pool by one copy, PR 43; heads-first slots and a window
+layer's `start`, which share nothing, PR 45), and what it traces to, which is
+set-up's time.  (Against the XLA gather: tests/test_paged_decode_pallas.py.)
 """
+
+import hashlib
 
 import jax
 import jax.numpy as jnp
@@ -12,6 +15,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from llm_d_kv_cache_manager_tpu.models import glm4moelite
 from llm_d_kv_cache_manager_tpu.ops import paged_decode_pallas
+from llm_d_kv_cache_manager_tpu.ops.paged_attention import paged_attention
 from llm_d_kv_cache_manager_tpu.ops.paged_decode_pallas import (
     paged_decode_attention_pallas,
     shared_prefix_plan,
@@ -61,6 +65,14 @@ def counted_walk(monkeypatch):
     return CountedCopy.started
 
 
+def plan_of(slots, table, ctx, wave) -> dict:
+    """The plan a call over such slots makes: heads-first ones share
+    nothing."""
+    least = {"min_sequences": None} if slots == "heads_first" else {}
+    return shared_prefix_plan(table, ctx, block_size=BS, blocks_per_wave=wave,
+                              **least)
+
+
 @pytest.mark.parametrize("slots", LAYOUTS)
 @pytest.mark.parametrize("name", (
     "two_uneven_sets_a_loner_and_an_idle_slot",
@@ -77,8 +89,7 @@ def test_the_walk_copies_each_block_in_context_past_the_runs_once(
     the context (the walk before it multiplied whole steps of 32)."""
     args, statics, ref = shared_case(name, slots)
     _, _, table, ctx = args
-    plan = shared_prefix_plan(table, ctx, block_size=BS,
-                              blocks_per_wave=statics["walk_blocks_per_wave"])
+    plan = plan_of(slots, table, ctx, statics["walk_blocks_per_wave"])
     own = [block for copy in expected_copies(
         table, ctx, plan, statics["walk_blocks_per_wave"]) for block in copy]
     # not through the jit's cache: the counted kernel must be traced
@@ -86,7 +97,7 @@ def test_the_walk_copies_each_block_in_context_past_the_runs_once(
     jax.effects_barrier()
     close(got, ref)
     assert brought(counted_walk) == own
-    runs = int(np.sum(np.asarray(plan["shared"][1])))
+    runs = int(np.sum(np.asarray(plan.get("shared", (0, 0))[1])))
     assert len(own) == int(plan["read_blocks"]) - runs
 
 
@@ -160,12 +171,13 @@ def run_case(name, slots):
         ctx.append((shared + own) * BS - int(rng.integers(0, BS)))
     table, ctx = np.asarray(table, np.int32), jnp.asarray(ctx, jnp.int32)
     kq, kkv = jax.random.split(jax.random.PRNGKey(43))
-    shape = {"packed": (N, BS, Hkv, 2 * D), "latent": (N, BS // 2, 2 * W)}.get(
-        slots, (N, 2, BS, Hkv, D))
+    shape = {"packed": (N, BS, Hkv, 2 * D), "latent": (N, BS // 2, 2 * W),
+             "heads_first": (N, 2, Hkv, BS, D)}.get(slots, (N, 2, BS, Hkv, D))
     pool = jax.random.normal(kkv, shape, jnp.float32).astype(jnp.bfloat16)
     q = jax.random.normal(kq, (len(ctx), H, D), jnp.float32).astype(jnp.bfloat16)
     statics = dict(interpret=True, walk_blocks_per_wave=WAVE,
-                   shared_blocks_per_step=2, packed=slots == "packed")
+                   shared_blocks_per_step=2, packed=slots == "packed",
+                   heads_first=slots == "heads_first")
     if slots == "latent":
         statics.update(latent=VALUE, scale=SCALE)
     to = np.concatenate(([0], 1 + rng.permutation(N - 1)))  # where a block goes
@@ -187,7 +199,7 @@ def test_a_wave_that_is_a_run_in_the_pool_comes_by_one_copy(
     content lying in no order."""
     args, statics, in_no_order = run_case(name, slots)
     _, _, table, ctx = args
-    plan = shared_prefix_plan(table, ctx, block_size=BS, blocks_per_wave=WAVE)
+    plan = plan_of(slots, table, ctx, WAVE)
     want = expected_copies(table, ctx, plan, WAVE)
     got = paged_decode_attention_pallas.__wrapped__(*args, plan=plan, **statics)
     jax.effects_barrier()
@@ -205,6 +217,51 @@ def test_a_wave_that_is_a_run_in_the_pool_comes_by_one_copy(
                                   np.asarray(scattered, np.float32))
 
 
+# ------------------------------------------------ a window layer's table
+
+@pytest.mark.parametrize("order", ("runs", "no_order"))
+def test_a_window_tables_walk_hides_what_lies_before_its_start(
+        counted_walk, order):
+    """Heads-first slots with a `start` (models/afmoe.py's window layers): the
+    table begins at the block that holds the window's first position, so the
+    walk copies every block of it in context, a run by one copy, and what the
+    first block holds before `start` is hidden like what lies past the
+    context: `paged_attention(..., start=, heads_first=True)` to 1e-5 in
+    float32, whole waves and a partial one, a start of 0 and a context of
+    one block among them."""
+    rng = np.random.default_rng(45)
+    H, Hkv, D, columns = 8, 2, 16, 11
+    ctx = jnp.asarray([10 * BS + 5, 8 * BS, 3 * BS + 1, 7], jnp.int32)
+    start = jnp.asarray([9, 0, BS - 1, 3], jnp.int32)
+    N = 1 + len(ctx) * columns
+    ids = np.arange(1, N).reshape(len(ctx), columns)
+    if order == "no_order":
+        ids = rng.permutation(ids.ravel()).reshape(ids.shape)
+    table = jnp.asarray(ids, jnp.int32)
+    pool = jnp.asarray(rng.normal(size=(N, 2, Hkv, BS, D)), jnp.float32)
+    q = jnp.asarray(rng.normal(size=(len(ctx), H, D)), jnp.float32)
+    plan = plan_of("heads_first", table, ctx, WAVE)
+    got = paged_decode_attention_pallas.__wrapped__(
+        q, pool, table, ctx, start=start, heads_first=True, plan=plan,
+        walk_blocks_per_wave=WAVE, interpret=True, mxu_native=False)
+    jax.effects_barrier()
+    np.testing.assert_allclose(
+        np.asarray(got),
+        np.asarray(paged_attention(q, pool, table, ctx, start=start,
+                                   heads_first=True)),
+        rtol=1e-5, atol=1e-5)
+    want = expected_copies(table, ctx, plan, WAVE)
+    assert list(counted_walk) == [(ids[0], len(ids)) for ids in want]
+    whole = [-(-int(c) // BS) // WAVE * WAVE for c in ctx]  # 8, 8, 4, 0 blocks
+    assert int(plan["run_blocks"]) == (sum(whole) if order == "runs" else 0)
+    with pytest.raises(ValueError, match="share nothing"):
+        paged_decode_attention_pallas(
+            q, pool, table, ctx, start=start, heads_first=True, interpret=True,
+            plan=shared_prefix_plan(table, ctx, block_size=BS,
+                                    blocks_per_wave=WAVE),
+            walk_blocks_per_wave=WAVE)
+
+
 # ------------------------------------------------ what the walk costs set-up
 
 # The served shapes' heads (`internlm2-1.8b`, `lfm2-8b-a1b-l13`,
@@ -212,7 +269,14 @@ def test_a_wave_that_is_a_run_in_the_pool_comes_by_one_copy(
 # and `glm-4.7-flash`'s latent form (20 heads over slots [8, 1152]) at its
 # family's waves of 64.
 SERVED_HEADS = {"llama": (16, 8, 128), "packed": (32, 8, 64),
-                "pairwise": (40, 10, 128), "latent": (20, 1, 576)}
+                "pairwise": (40, 10, 128), "latent": (20, 1, 576),
+                "heads_first": (32, 4, 128), "heads_first_window": (32, 4, 128)}
+# sha256 of the traced call's text at 8 sequences of 48 columns, read on commit
+# 81cfceb (PR 44), before the walk knew heads-first slots or a `start`: the
+# four layouts it served trace to what they traced to (a PR that changes the
+# walk for them on purpose reads these anew, and measures their cells).
+TEXT_AT_PR_44 = {"llama": "696644170fc7344a", "packed": "708fa3ec9b129802",
+                 "pairwise": "9616ef93b6d5ab9a", "latent": "b896062ff3f1ec4c"}
 
 
 def equations(jaxpr) -> int:
@@ -238,29 +302,39 @@ def test_the_walk_traces_to_the_same_kernel_whatever_the_table(slots):
     is unrolled over a table's columns, its waves or its sequences, so a
     48-column table of 8 sequences traces to as many equations as a
     192-column one of 32, with the run's one copy and one wait in it.  (A
-    wave's products are unrolled: `walk_wave` caps them.)"""
+    wave's products are unrolled: `walk_wave` caps them.)  Heads-first slots,
+    with a window's `start` and without, are the walk alone: one kernel."""
     H, Hkv, D = SERVED_HEADS[slots]
+    heads_first = slots.startswith("heads_first")
     pool = {"packed": (64, BS, Hkv, 2 * D), "latent": (64, BS // 2, 2 * D)}.get(
-        slots, (64, 2, BS, Hkv, D))
-    statics = {"packed": slots == "packed"}
+        slots, (64, 2, Hkv, BS, D) if heads_first else (64, 2, BS, Hkv, D))
+    statics = {"packed": slots == "packed", "heads_first": heads_first}
     if slots == "latent":
         statics = dict(
             latent=512, scale=D**-0.5,
             walk_blocks_per_wave=glm4moelite.DECODE_BLOCKS_PER_WAVE,
             shared_blocks_per_step=glm4moelite.DECODE_BLOCKS_PER_WAVE)
 
+    texts = {}
+
     def traced(B, columns):
         spec = jax.ShapeDtypeStruct
-        jaxpr = jax.make_jaxpr(lambda *a: paged_decode_attention_pallas(
-            *a, **statics))(
+        start = spec((B,), jnp.int32) if slots.endswith("window") else None
+        jaxpr = jax.make_jaxpr(
+            lambda q, kv, table, ctx, start: paged_decode_attention_pallas(
+                q, kv, table, ctx, start=start, **statics))(
             spec((B, H, D), jnp.bfloat16), spec(pool, jnp.bfloat16),
-            spec((B, columns), jnp.int32), spec((B,), jnp.int32))
-        assert "pallas_call" in str(jaxpr)
+            spec((B, columns), jnp.int32), spec((B,), jnp.int32), start)
+        texts[B, columns] = str(jaxpr)
+        assert texts[B, columns].count("pallas_call") == 2 - heads_first
         return equations(jaxpr.jaxpr)
 
     counts = {shape: traced(*shape)
               for shape in ((8, 48), (8, 192), (32, 48), (32, 192))}
     assert len(set(counts.values())) == 1, counts
+    if slots in TEXT_AT_PR_44:
+        assert hashlib.sha256(texts[8, 48].encode()).hexdigest()[:16] == (
+            TEXT_AT_PR_44[slots])
     wave = paged_decode_pallas.walk_wave(
         jax.ShapeDtypeStruct(pool, jnp.bfloat16))
     assert wave <= paged_decode_pallas.WALK_WAVE_BLOCKS

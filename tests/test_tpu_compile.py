@@ -78,17 +78,22 @@ def test_flash_kernel_compiles_at_the_served_shapes(one_chip, tq, tk,
 ))
 def test_paged_decode_kernel_compiles_heads_first(one_chip, slots, columns,
                                                   windowed):
+    """The walk over heads-first slots as `afmoe.decode_step` calls it: waves
+    of `walk_wave`'s 16 blocks [2, Hkv, 16, 128], a KV head's keys of a wave
+    [256, 128] with no re-layout, float32 operands, no shared pass."""
     i32, B = jnp.int32, 64
     fn = functools.partial(paged_decode_attention_pallas, heads_first=True,
-                           blocks_per_step=32)
+                           mxu_native=False)
     shapes = [((B, H, DH), jnp.bfloat16),
               ((slots, 2, HKV, BLOCK, DH), jnp.bfloat16),
               ((B, columns), i32), ((B,), i32)]
     if windowed:
-        compile_for(one_chip, lambda q, kv, t, c, s: fn(q, kv, t, c, start=s),
-                    *shapes, ((B,), i32))
+        compiled = compile_for(
+            one_chip, lambda q, kv, t, c, s: fn(q, kv, t, c, start=s),
+            *shapes, ((B,), i32))
     else:
-        compile_for(one_chip, fn, *shapes)
+        compiled = compile_for(one_chip, fn, *shapes)
+    assert len(re.findall(r"= .*tpu_custom_call", compiled.as_text())) == 1
 
 
 def paged_kernels(hlo: str) -> set:
