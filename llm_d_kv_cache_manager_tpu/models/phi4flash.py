@@ -194,7 +194,12 @@ def cache_groups(cfg: Phi4FlashConfig) -> Dict[str, KVGroupSpec]:
 def cache_policy(cfg: Phi4FlashConfig) -> dict:
     """What models/pod.py needs to know of this family's cache: a window
     group and a state group beside the full one, each group's spec (the
-    full group's says how many layers read it), and the order of reuse."""
+    full group's says how many layers read it), the order of reuse, and
+    that a decode call launches the step after its own (`decode_ahead`:
+    the family's deployments are long generations, thousands of steps
+    between two of a sequence's events, so a step launched ahead is nearly
+    always taken).  `_mamba_decode` writes the slot its table says, which
+    under that key is never the one it reads (`pod.StateGroup._alternate`)."""
     return {
         "specs": cache_groups(cfg),
         "window": {
@@ -204,6 +209,7 @@ def cache_policy(cfg: Phi4FlashConfig) -> dict:
         },
         "state": {"slots": cfg.state_slots},
         "protect_asked": True,
+        "decode_ahead": True,
     }
 
 

@@ -124,12 +124,48 @@ that compiles) and ``pod.launch.decode`` / ``.hit`` / ``.miss`` (the compiled
 call alone; a decode launch that follows a decode launch of the same pod
 carries the period between the two).
 
-A pod of one group may say ``decode_ahead`` in its policy
-(models/keyevl2.py): a decode call that goes on from the call before it
+A family may say ``decode_ahead`` in its policy (models/keyevl2.py,
+models/phi4flash.py): a decode call that goes on from the call before it
 launches the step after its own as well, and the next call is handed that
 step (``jit_programs.run_ahead`` has the rule, and what a step that is not
-taken leaves behind).  The groups beside the full one change their tables on
-the host with every decode call, so a pod that has one refuses it.
+taken leaves behind).  Beside a window or a state group the step's tables
+are made a call early too (``decode_slots``: a block takes its slot there;
+the call that takes the step says that it is served, ``decode_tables``, and
+records the groups' spans, once a served step), and nothing a step wrote may
+have to be taken back:
+
+- a window layer's K/V, as the full group's, is the same values at the same
+  place when the step is run again, so the window group has no rule to add;
+- a state slot is written in place of the state it held, so under the key a
+  sequence **alternates between two slots**: a step reads the slot the last
+  served step wrote (``slot_of`` its block) and writes the other
+  (``spare_of``), and serving the step exchanges them.  A step that is run
+  and not taken has written a slot nobody reads, and the step that takes its
+  place reads and writes the same two.  They are the two slots a live
+  sequence holds anyway (its current block's and the one before): the step
+  that enters a block writes the spare, which is the block's slot from then
+  on, and the first step inside the block takes the slot of the block before
+  for its spare, unless a hash keeps that snapshot (a sequence's own blocks
+  carry none).  Without the key a block has one slot and the tables are what
+  they were;
+- a slot that a reuse evicts on behalf of a step made ahead goes the way of
+  every eviction outside ``alloc`` (``unpublished``, on the next ``alloc``'s
+  list), taken or not.
+
+**Who says the key.**  A call that does not go on (a sequence ended, one was
+admitted) launches its own step alone, so an event costs two calls and the
+share of calls served by a step launched ahead is about 1 − 2 × (events a
+step); every step launched and not taken costs the device a whole step.
+``keyevl2`` (24 slots, 191 tokens out on average: 0.126 events a step) reads
+0.75, ``phi4flash`` (64 slots, 3072–5120 out: one event in 64 steps) ≈ 0.97.
+``afmoe``, ``lfm2moe`` and ``glm4moelite`` do **not** say it: their
+deployments' traffic as the benchmark has it (64 slots, 64–512 out, mean
+192) ends a sequence every third step, the share would be 0.33–0.46, under
+the half a median needs, and the steps not taken would cost a seventh to a
+fifth of the chip (≈ 15 ms × 119 admissions in a 12-s window of
+chat-longdocs; PERF.md section 7 has the counts).  They
+wait for an engine that says which rows end, so that the step ahead can
+leave those rows out (ROADMAP S12 i, D17: the key goes then).
 """
 
 from __future__ import annotations
@@ -168,14 +204,19 @@ class PodKV:
         self.arrays, self._pod = arrays, weakref.ref(pod)
         self._table = (None, None)  # the last decode table, host and device
 
+    def holds(self, table) -> bool:
+        """Whether the table on the device is this one: no row changed since
+        the decode call that sent it."""
+        host, _ = self._table
+        return (host is not None and host.shape == np.shape(table)
+                and np.array_equal(host, table))
+
     def on_device(self, table) -> tuple[jax.Array, bool]:
         """The decode steps' logical table on the device, and whether this
         call sent it: again only when a row changed (an admission or a
         finish, not every step)."""
         table = np.asarray(table, np.int32)
-        host, _ = self._table
-        sent = (host is None or host.shape != table.shape
-                or not np.array_equal(host, table))
+        sent = not self.holds(table)
         if sent:
             self._table = (table.copy(), jax.device_put(table))
         return self._table[1], sent
@@ -316,11 +357,20 @@ class SlotGroup:
         slots = self.slot_of[np.asarray(ids, np.int64)]
         self.stamp[slots[slots >= 0]] = self.tick
 
-    def _assign(self, bid: int) -> None:
+    def _assign(self, bid: int, of: np.ndarray | None = None) -> None:
+        """A free slot to the block: its slot, or what `of` maps (a state
+        group's spare)."""
         slot = self.free.pop()
-        self.slot_of[bid], self.block_of[slot] = slot, bid
+        (self.slot_of if of is None else of)[bid] = slot
+        self.block_of[slot] = bid
         self.stamp[slot] = RESERVED
         self.counts["taken"] += 1
+
+    def _stamp(self, slots: np.ndarray) -> np.ndarray:
+        """One table a program call: its slots were live at this tick."""
+        self.tick += 1
+        self.stamp[slots] = self.tick
+        return slots
 
     def _unnamed(self, keep: np.ndarray) -> np.ndarray:
         """Per slot: its block is none of `keep` (the call at hand's)."""
@@ -435,8 +485,6 @@ class WindowGroup(SlotGroup):
                 f"{self.pod.name}: {what} reads a block that holds no window "
                 "slot (a prefix the window rule refuses, or blocks used out "
                 "of the order they were handed out)")
-        self.tick += 1
-        self.stamp[slots] = self.tick
         return slots.astype(np.int32)
 
     def miss_tables(self, table: np.ndarray) -> dict:
@@ -446,8 +494,8 @@ class WindowGroup(SlotGroup):
         if self.lazy:
             self._ensure(table[:, table.shape[1] - kept:], table)
         return {"full": table,
-                "window": self._slots(table[:, table.shape[1] - kept:],
-                                      "a miss prefill")}
+                "window": self._stamp(self._slots(
+                    table[:, table.shape[1] - kept:], "a miss prefill"))}
 
     def hit_tables(self, table: np.ndarray, prefix_blocks: int) -> dict:
         seen = min(prefix_blocks, self.need)
@@ -456,14 +504,20 @@ class WindowGroup(SlotGroup):
         if self.lazy:
             self._ensure(table[:, prefix_blocks:], table)
         return {"full": table,
-                "window": self._slots(table[:, prefix_blocks - seen:],
-                                      "a hit prefill")}
+                "window": self._stamp(self._slots(
+                    table[:, prefix_blocks - seen:], "a hit prefill"))}
 
-    def decode_tables(self, table: np.ndarray, context_len: np.ndarray):
-        """The window table of one decode step and what the step reads:
-        (tables, {"full_blocks", "window_blocks", "uniform_blocks"})."""
-        first = np.maximum(context_len - self.window, 0) // self.block
-        current = (context_len - 1) // self.block
+    def _span_of(self, context_len: np.ndarray) -> tuple:
+        """The first and the last block of each sequence's window."""
+        return (np.maximum(context_len - self.window, 0) // self.block,
+                (context_len - 1) // self.block)
+
+    def decode_slots(self, table: np.ndarray, context_len: np.ndarray) -> dict:
+        """The window table of the decode step at `context_len`.  A block
+        that enters a window takes its slot here; nothing says yet that the
+        step is served (`decode_tables`), so a pod that launches ahead makes
+        a step's table on the call before the one that takes it."""
+        first, current = self._span_of(context_len)
         cols = first[:, None] + np.arange(self.width)[None, :]
         ids = np.take_along_axis(
             table, np.minimum(cols, table.shape[1] - 1), axis=1)
@@ -478,17 +532,25 @@ class WindowGroup(SlotGroup):
                 if not self.free:
                     self.pod.unpublished += self.reclaim(1)
                 self._assign(int(bid))
+        return {"full": table, "window": self._slots(ids, "a decode step"),
+                "first": (first * self.block).astype(np.int32)}
+
+    def decode_tables(self, table: np.ndarray, context_len: np.ndarray,
+                      made: dict | None = None):
+        """The window table of one served decode step (`made`: as the call
+        before made it, `decode_slots`) and what the step reads: (tables,
+        {"full_blocks", "window_blocks", "uniform_blocks"})."""
+        tables = made or self.decode_slots(table, context_len)
         before = self.live_tick
-        slots = self._slots(ids, "a decode step")
+        self._stamp(tables["window"])
         self.live_tick = self.tick
         # live at the last decode step, in no window now
         self.counts["released"] += int((self.stamp == before).sum()) if before else 0
-        window_blocks = int((current - first + 1).sum())
+        first, current = self._span_of(context_len)
         whole = int((current + 1).sum())
-        return ({"full": table, "window": slots,
-                 "first": (first * self.block).astype(np.int32)},
-                {"full_blocks": whole, "window_blocks": window_blocks,
-                 "uniform_blocks": whole})
+        return tables, {"full_blocks": whole,
+                        "window_blocks": int((current - first + 1).sum()),
+                        "uniform_blocks": whole}
 
 
 class StateGroup(SlotGroup):
@@ -504,6 +566,15 @@ class StateGroup(SlotGroup):
         self.block = self.spec.block_size
         # K/V bytes a step reads of a block of context (`state.read`)
         self.kv_read_nbytes = pod.specs["full"].read_nbytes
+        # a pod that launches ahead: the block's other slot (`_alternate`)
+        self.spare_of = np.full(pod.pool_blocks, -1, np.int32)
+
+    def drop(self, bid: int) -> None:
+        super().drop(bid)
+        slot = self.spare_of[bid]
+        if slot >= 0:
+            self.spare_of[bid], self.block_of[slot] = -1, -1
+            self.free.append(int(slot))
 
     def admits(self, ids: list) -> np.ndarray:
         """For each prefix of `ids` (cached in the full group, chain order),
@@ -532,18 +603,16 @@ class StateGroup(SlotGroup):
                              target, "state")
 
     def _slots(self, ids: np.ndarray) -> np.ndarray:
-        """The slots of `ids`, stamped; a block without one takes one."""
+        """The slots of `ids`; a block without one takes one."""
         self._ensure(ids, ids)
-        slots = self.slot_of[ids]
-        self.tick += 1
-        self.stamp[slots] = self.tick
-        return slots.astype(np.int32)
+        return self.slot_of[ids].astype(np.int32)
 
     def _prefill_tables(self, table: np.ndarray, first: int) -> dict:
         kept = self.spec.snapshot_blocks(first, table.shape[1] - first)
         for row in table:
             self.link(row[max(first - 1, 0):])
-        return {"full": table, "state_write": self._slots(table[:, kept])}
+        return {"full": table,
+                "state_write": self._stamp(self._slots(table[:, kept]))}
 
     def miss_tables(self, table: np.ndarray) -> dict:
         return self._prefill_tables(table, 0)
@@ -559,12 +628,19 @@ class StateGroup(SlotGroup):
         self.stamp[read] = self.tick
         return {**tables, "state_read": read.astype(np.int32)}
 
-    def decode_tables(self, table: np.ndarray, context_len: np.ndarray):
-        """The state slots of one decode step, [B, (read, written)], and
-        what the step reads."""
-        pod, rows = self.pod, np.arange(len(table))
-        pos = context_len - 1
+    def _blocks_of(self, table: np.ndarray, context_len: np.ndarray) -> tuple:
+        """Each sequence's current block's place in its chain, and the blocks
+        [B, (read, written)] a decode step at `context_len` goes between."""
+        rows, pos = np.arange(len(table)), context_len - 1
         cur, prev = pos // self.block, np.maximum(pos - 1, 0) // self.block
+        return cur, np.stack((table[rows, prev], table[rows, cur]), axis=1)
+
+    def decode_slots(self, table: np.ndarray, context_len: np.ndarray) -> dict:
+        """The state slots of the decode step at `context_len`, [B, (read,
+        written)].  A block without a slot takes one here; nothing says yet
+        that the step is served (`decode_tables`)."""
+        pod, pos = self.pod, context_len - 1
+        cur, ids = self._blocks_of(table, context_len)
         # A sequence that enters a new block: the block two back is no
         # longer one of its two; its own blocks carry no hash to keep it for.
         entered = np.flatnonzero((pos % self.block == 0) & (cur >= 2))
@@ -572,12 +648,68 @@ class StateGroup(SlotGroup):
         for bid in old[~pod.hashed[old] & (self.slot_of[old] >= 0)]:
             self.drop(int(bid))
             self.counts["released"] += 1
-        ids = np.stack((table[rows, prev], table[rows, cur]), axis=1)
-        slots = self._slots(ids)
+        slots = (self._alternate(table, cur, ids) if pod.decode_ahead
+                 else self._slots(ids))
+        return {"full": table, "state": slots}
+
+    def _alternate(self, table, cur, ids: np.ndarray) -> np.ndarray:
+        """The slots of a pod that launches ahead.  A step that is run and
+        not taken must leave the state its sequence goes on from as it was,
+        so no step writes the slot it reads: a sequence alternates between
+        two, `slot_of` its block holds the state after the last served step
+        and `spare_of` is where the next step writes, whichever call
+        launches it and however often (serving the step exchanges them:
+        `decode_tables`).  They are the two slots a live sequence holds
+        anyway.  The step that enters a block writes the spare of the block
+        before, which is the new block's slot from then on; the first step
+        inside the block finds no spare and takes the slot of the block
+        before (the step that read it is served), unless a hash keeps that
+        snapshot."""
+        pod, inside = self.pod, ids[:, 0] == ids[:, 1]
+        for before, block in ids[~inside]:
+            slot = self.spare_of[before]
+            if self.slot_of[block] < 0 and slot >= 0:
+                self.spare_of[before] = -1
+                self.slot_of[block], self.block_of[slot] = slot, block
+        slots, bare = self._slots(ids), []
+        for row in np.flatnonzero(inside & (self.spare_of[ids[:, 1]] < 0)):
+            block = ids[row, 1]
+            before = table[row, cur[row] - 1] if cur[row] else block
+            slot = self.slot_of[before]
+            if self.spare_of[block] >= 0 or block in bare:
+                continue  # rows on one block: the engine's idle ones
+            if before == block or slot < 0 or pod.hashed[before]:
+                bare.append(block)
+                continue
+            self.slot_of[before] = -1
+            self.spare_of[block], self.block_of[slot] = slot, block
+            self.counts["released"] += 1
+        if len(bare) > len(self.free):
+            pod.unpublished += self.reclaim(len(bare), ids)
+        for bid in bare:
+            self._assign(int(bid), self.spare_of)
+        slots[inside, 1] = self.spare_of[ids[inside, 1]]
+        return slots
+
+    def decode_tables(self, table: np.ndarray, context_len: np.ndarray,
+                      made: dict | None = None):
+        """The state slots of one served decode step (`made`: as the call
+        before made them, `decode_slots`) and what the step reads."""
+        pod = self.pod
+        tables = made or self.decode_slots(table, context_len)
+        slots = self._stamp(tables["state"])
         self.live_tick = self.tick
+        if pod.decode_ahead:
+            # served: inside a block, the slot the step wrote is the block's
+            # and the one it read the spare
+            _, ids = self._blocks_of(table, context_len)
+            inside = ids[:, 0] == ids[:, 1]
+            within = ids[inside, 1]
+            self.slot_of[within], self.spare_of[within] = (slots[inside, 1],
+                                                           slots[inside, 0])
         live = context_len > 1
-        blocks = int((cur[live] + 1).sum())
-        return ({"full": table, "state": slots},
+        blocks = int(((context_len[live] - 1) // self.block + 1).sum())
+        return (tables,
                 {"state_slots_live": len(self.block_of) - len(self.free),
                  "blocks_live": pod.pool_blocks - len(pod.free),
                  "state_bytes": int(live.sum()) * 2 * self.spec.block_nbytes,
@@ -618,10 +750,6 @@ class Pod:
         # and what the last such call left for the next (`run_ahead`)
         self.decode_ahead = bool(policy.get("decode_ahead"))
         self.last_decode = None
-        if self.decode_ahead and self.groups:
-            raise ValueError(
-                f"{name}: decode_ahead is for a pod of one group (a window or "
-                "state group's tables change on the host with every step)")
 
     def cached_prefix(self, hashes) -> list[int]:
         ids = []
@@ -697,9 +825,16 @@ class Pod:
                 for group in self.groups:
                     group.drop(bid)
 
-    def tables(self, kind: str, table, context_len=None, prefix_blocks=0):
+    def tables(self, kind: str, table, context_len=None, prefix_blocks=0,
+               made=None, ahead=None):
         """What a program call is handed as its table: the logical table
-        alone with one group, every group's tables with more."""
+        alone with one group, every group's tables with more.  A decode call
+        of a pod that launches ahead (`jit_programs.run_ahead`) brings
+        `made`, its step's tables where the call before made them, and
+        `ahead`, the context lengths of the step it will launch after its
+        own: it is handed (tables, that step's tables).  A group's span
+        covers both, and what it says is of the call's own step alone, which
+        is the one it serves."""
         table = np.asarray(table, np.int32)
         if not self.groups:
             full = self.specs.get("full")
@@ -730,13 +865,16 @@ class Pod:
                         s.set_attr("dense_bytes",
                                    int(live.sum()) * (position - key))
                     s.set_attr("step_bytes", read + self.step_weight_nbytes)
-            return table
-        tables, reads = {}, {}
+            return table if ahead is None else (table, table)
+        tables, reads, after = {}, {}, {}
         for group in self.groups:
             with span(group.span) as s:
                 if kind == "decode":
                     more, reads[group.read_span] = group.decode_tables(
-                        table, np.asarray(context_len, np.int64))
+                        table, np.asarray(context_len, np.int64), made)
+                    if ahead is not None:
+                        after.update(group.decode_slots(
+                            table, np.asarray(ahead, np.int64)))
                 else:
                     more = (group.miss_tables(table) if kind == "miss"
                             else group.hit_tables(table, prefix_blocks))
@@ -759,7 +897,7 @@ class Pod:
             with span(name) as s:
                 for key, value in read.items():
                     s.set_attr(key, value)
-        return tables
+        return tables if ahead is None else (tables, after)
 
     def report_load(self, model) -> None:
         """What the last decode step counted on the device, as spans: the
@@ -907,14 +1045,14 @@ def jit_programs(program, model, shapes: dict, interpret: bool) -> dict:
     does not compile there: a `pod.compile` span a program on that call, and
     none after it.
 
-    Where the family's policy says `decode_ahead` (a pod of one group: its
-    tables keep nothing on the host that a step not taken would have to give
-    back), a decode call that goes on from the call before it (`run_ahead`)
-    launches the step after its own as well, on the tokens its own step
-    serves, which are on the device before the host has them; the next call,
-    if it goes on in turn, is handed that step.  The host's share of a step
-    (the launch, the tokens' way to the host and back) then lies beside the
-    device's work and not between two steps."""
+    Where the family's policy says `decode_ahead`, a decode call that goes
+    on from the call before it (`run_ahead`) launches the step after its own
+    as well, on the tokens its own step serves, which are on the device
+    before the host has them, and with tables made now (`Pod.tables`'
+    `ahead`); the next call, if it goes on in turn, is handed that step and
+    its tables.  The host's share of a step (the groups' tables, the launch,
+    the tokens' way to the host and back) then lies beside the device's work
+    and not between two steps."""
     block = model.block_size
     inner, compiled = inner_programs(program, model, shapes, interpret), {}
 
@@ -970,74 +1108,98 @@ def jit_programs(program, model, shapes: dict, interpret: bool) -> dict:
 
         return run_prefill
 
-    def follows(last, ints, sent) -> bool:
-        """Whether the decode call of `ints` goes on from the call that left
-        `last`: the same table (`sent`: it was not sent again), each context
-        one longer, each token the one that call served.  (Reading what it
-        served costs nothing where the engine has read it.)"""
-        return (last is not None and not sent
-                and np.array_equal(ints[:, 1], last[0][:, 1] + 1)
-                and np.array_equal(ints[:, 0], np.asarray(last[1])[0]))
+    def follows(last, t, n, same: bool) -> bool:
+        """Whether the decode call of tokens `t` at contexts `n` goes on from
+        the call that left `last`: the same table (`same`: no row of it
+        changed), each context one longer, each token the one that call
+        served.  (Reading what it served costs nothing where the engine has
+        read it.)"""
+        return (last is not None and same
+                and np.array_equal(n, last[0] + 1)
+                and np.array_equal(t, np.asarray(last[1])[0]))
 
-    def run_ahead(p, kv, ints, table, goes_on, traced):
-        """A decode call of a `decode_ahead` pod: (what `run` returns of the
-        call's own step).  `pod.last_decode` is what the last call left,
-        (its integers, the array it served, the step it launched ahead or
-        None), and None after a prefill.  This call goes on from that one
-        (`goes_on`, `follows`) where the table is the same, every context is
-        one longer and the tokens are those served: then the step launched
-        ahead, if there is one, is this call's, and this call launches the
-        next.  Where it does not go on (an admission, a finish, the first
-        step) it launches its own step on the host's tokens and none ahead: a
-        step launched ahead and not taken costs the device a step, so one is
-        launched only where the last call shows that the engine is in the
-        middle of its sequences.  Such a step has written, for each row, the
-        position after the row's last in the table it was launched with:
-        where the row goes on, the step that takes its place writes the same
-        there; where it ended, the place is the ended sequence's own or the
-        engine's scratch block, which whoever is handed the block next writes
-        before reading it."""
-        pod = kv.pod
-        last, pod.last_decode = pod.last_decode, None
-        if goes_on and last[2] is not None:
-            out, counted = last[2]
+    def pack(pod, t, n, tables) -> np.ndarray:
+        """A decode step's integers, a row a sequence (`inner_programs`'
+        `decode` has the columns)."""
+        ints = [t, n]
+        if pod.window is not None:
+            ints += [tables["first"], *tables["window"].T]
+        if pod.state is not None:
+            ints += [*tables["state"].T]
+        return np.stack(ints, axis=1, dtype=np.int32)
+
+    def run_ahead(p, kv, ints, table, last, after, traced):
+        """A decode call of a `decode_ahead` pod: what `run` returns of the
+        call's own step, and the step it launched ahead or None: (what `run`
+        returns of that one, its tables).  `last` is what the last call left
+        in `pod.last_decode`, (its contexts, the array it served,
+        the step it launched ahead or None), and None after a prefill.
+        This call goes on from that one (`follows`) where the table is the
+        same, every context is one longer and the tokens are those served:
+        then `ints` is None where a step launched ahead is this call's, and
+        `after` is the next step, which this call launches: (its integers,
+        the tokens -1, which is what the call's own step serves, where that
+        lies on the device; its tables).  Where it does not go on
+        (an admission, a finish, the first step) it launches its own step
+        on the host's tokens and none ahead: a step launched ahead and not
+        taken costs the device a step, so one is launched only where the
+        last call shows that the engine is in the middle of its sequences.
+        Such a step has written, for each row, the position after the row's
+        last in the table it was launched with: where the row goes on, the
+        step that takes its place writes the same there; where it ended,
+        the place is the ended sequence's own or the engine's scratch
+        block, which whoever is handed the block next writes before reading
+        it.  The same holds of a window slot, which a block keeps from the
+        call that first names it; a state group's slot is written in place
+        of the state the step read, so such a pod's sequences alternate
+        between two (`StateGroup._alternate`), and a step not taken has
+        written the one that nobody reads."""
+        if ints is None:
+            out, counted, _ = last[2]
         else:
             before = (last[1] if last is not None
                       else np.zeros((2, len(ints)), np.float32))
             out, counted = run("decode", p, kv, (before, ints), table, traced)
-        ahead = None
-        if goes_on:
-            after = ints + np.asarray([0, 1], np.int32)
-            after[:, 0] = -1  # the tokens `out` serves, where they lie
-            ahead = run("decode", p, kv, (out[0], after), table, traced,
-                        ahead=True)
-        pod.last_decode = (ints, out[0], ahead)
-        return out, counted
+        if after is None:
+            return out, counted, None
+        ints, tables = after
+        return out, counted, (*run("decode", p, kv, (out[0], ints), table,
+                                   traced, ahead=True), tables)
 
     def run_decode(p, t, kv, bt, n):
         pod = kv.pod
         with root_trace("pod.step") as traced:
-            tables = pod.tables("decode", bt, context_len=n)
+            last, pod.last_decode = pod.last_decode, None
+            goes_on = pod.decode_ahead and follows(last, t, n, kv.holds(bt))
+            handed = last[2] if goes_on else None  # the call before launched
+            after = None
+            if goes_on:
+                # a sequence that ends at its table's end goes no further
+                n_after = np.minimum(np.asarray(n) + 1,
+                                     np.shape(bt)[1] * block)
+                tables, made = pod.tables(
+                    "decode", bt, context_len=n,
+                    made=handed and handed[2], ahead=n_after)
+            else:
+                tables = pod.tables("decode", bt, context_len=n)
             if traced is not None:
                 pod.report_load(model)
             with span("pod.pack") as s:
-                ints = [t, n]
-                if pod.window is not None:
-                    ints += [tables["first"], *tables["window"].T]
-                if pod.state is not None:
-                    ints += [*tables["state"].T]
-                ints = np.stack(ints, axis=1, dtype=np.int32)
+                ints = None if handed else pack(pod, t, n, tables)
+                if goes_on:
+                    after = (pack(pod, np.full(len(n_after), -1), n_after,
+                                  made), made)
                 table, sent = kv.on_device(bt)
                 s.set_attr("calls", 1)
                 s.set_attr("table_sent", int(sent))
                 s.set_attr("h2d_bytes", table.nbytes if sent else 0)
                 if pod.decode_ahead:
-                    goes_on = follows(pod.last_decode, ints, sent)
                     # the call's own step was launched by the call before
-                    s.set_attr("ahead", int(
-                        goes_on and pod.last_decode[2] is not None))
+                    s.set_attr("ahead", int(handed is not None))
             if pod.decode_ahead:
-                out, counted = run_ahead(p, kv, ints, table, goes_on, traced)
+                out, counted, ahead = run_ahead(p, kv, ints, table, last,
+                                                after, traced)
+                pod.last_decode = (np.array(n), out[0], ahead)
             else:
                 out, counted = run("decode", p, kv, ints, table, traced)
             if traced is not None and counted:

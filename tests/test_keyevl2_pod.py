@@ -396,17 +396,6 @@ def test_a_decode_call_that_goes_on_is_handed_the_step_launched_ahead(
             for c in decode_calls(plain)] == [1] * 7
 
 
-def test_decode_ahead_is_refused_beside_a_window_or_state_group(monkeypatch):
-    from llm_d_kv_cache_manager_tpu.models import afmoe
-
-    cfg = afmoe.AfmoeConfig(dtype="float32", vocab_size=VOCAB, window_slots=24,
-                            window_store_blocks=4)
-    policy = {**afmoe.cache_policy(cfg), "decode_ahead": True}
-    monkeypatch.setattr(afmoe, "cache_policy", lambda cfg: policy)
-    with pytest.raises(ValueError, match="one group"):
-        Pod("pod-0", afmoe, cfg, 40)
-
-
 def test_bfloat16_serving_stays_near_the_reference():
     """The serving type end to end at the small size: the program in
     bfloat16 against the float32 reference of the same (bfloat16-valued)

@@ -378,7 +378,9 @@ def test_phi4flash_programs_compile_at_the_cells_shapes(one_chip, monkeypatch,
     policy = phi4flash.cache_policy(PHI4)
 
     class Shapes:  # what `example_args` reads of a pod
-        decode_ahead = False
+        # a decode call's first argument is the pair: what the step before
+        # served, where it lies on the device, and the integers
+        decode_ahead = policy["decode_ahead"]
 
         class window:
             need = -(-(PHI4.window - 1) // BLOCK)
@@ -390,8 +392,12 @@ def test_phi4flash_programs_compile_at_the_cells_shapes(one_chip, monkeypatch,
     first, second = jax.tree.map(
         lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
         pod_programs.example_args(key, PHI4_SHAPES, Shapes, BLOCK))
-    assert first.shape == ((64, 2 + 1 + 33 + 2) if key == "decode"
-                           else (1, PHI4_SHAPES[key][-1]))
+    if key == "decode":
+        served, ints = first
+        assert Shapes.decode_ahead and served.shape == (2, 64)
+        assert ints.shape == (64, 2 + 1 + 33 + 2)
+    else:
+        assert first.shape == (1, PHI4_SHAPES[key][-1])
     program = pod_programs.inner_programs(phi4flash, PHI4, PHI4_SHAPES,
                                           False)[key]
     compiled = program.trace(params, first, pools, second).lower(
