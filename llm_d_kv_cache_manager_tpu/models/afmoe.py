@@ -48,12 +48,13 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from llm_d_kv_cache_manager_tpu.models import moe_serve
+from llm_d_kv_cache_manager_tpu.models import layers, moe_serve
 from llm_d_kv_cache_manager_tpu.models.kv_cache_pool import (
-    KVGroupSpec,
-    scatter_kv_blocks,
+    KVGroupSpec, decode_view, gather_prefix, write_blocks, write_token,
 )
-from llm_d_kv_cache_manager_tpu.ops import flash_pallas
+from llm_d_kv_cache_manager_tpu.models.layers import (
+    logits, prefill_attention, rms_norm, rope, swiglu,
+)
 from llm_d_kv_cache_manager_tpu.ops.paged_attention import paged_attention
 from llm_d_kv_cache_manager_tpu.ops import paged_decode_pallas
 from llm_d_kv_cache_manager_tpu.ops.paged_decode_pallas import (
@@ -62,14 +63,9 @@ from llm_d_kv_cache_manager_tpu.ops.paged_decode_pallas import (
 
 Params = Dict[str, Any]
 SLIDING, FULL = "sliding_attention", "full_attention"
-NEG_INF = -1e30
 HI = lax.Precision.HIGHEST
 # An expert layer's prefill runs over at most this many tokens at a time.
 MOE_CHUNK_TOKENS = moe_serve.MOE_CHUNK_TOKENS
-# Prefill attention below this key length is one dense masked product (XLA);
-# at and above it the Pallas flash kernel, whose VMEM bound
-# (flash_pallas.fits_vmem) is then the longest context a prefill takes.
-FLASH_MIN_LEN = 1024
 
 
 @dataclass(frozen=True)
@@ -149,14 +145,8 @@ def new_pool(cfg: AfmoeConfig, pool_blocks: int) -> dict:
     place.  (A step hands them back with one more leaf, `load`, the expert
     layers' counts of that step: [expert layer, (experts touched, most picks
     on one expert)]; it is not handed in again.)"""
-    sizes = {"full": pool_blocks, "window": cfg.window_slots}
-    return {
-        kind: [
-            jnp.zeros(spec.layer_shape(sizes[kind]), jnp.dtype(spec.dtype))
-            for _ in range(spec.num_layers)
-        ]
-        for kind, spec in cache_groups(cfg).items()
-    }
+    return layers.new_pool(
+        cache_groups(cfg), {"full": pool_blocks, "window": cfg.window_slots})
 
 
 def from_published(cfg: dict, block_size: int) -> AfmoeConfig:
@@ -263,41 +253,10 @@ def init_params(rng: jax.Array, cfg: AfmoeConfig) -> Params:
 # ------------------------------------------------------------ the model step
 
 
-def _rms_norm(x, w, eps, dtype=None):
-    xf = x.astype(jnp.float32)
-    norm = xf * lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
-    return (norm * w.astype(jnp.float32)).astype(dtype or x.dtype)
-
-
-def _rope(x, positions, theta):
-    """x: [B, T, H, D] (D even); positions: [B, T]."""
-    D = x.shape[-1]
-    freqs = theta ** (-jnp.arange(0, D // 2, dtype=jnp.float32) / (D // 2))
-    angles = positions[..., None].astype(jnp.float32) * freqs
-    cos, sin = jnp.cos(angles)[:, :, None], jnp.sin(angles)[:, :, None]
-    x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
-    return jnp.concatenate(
-        (x1 * cos - x2 * sin, x2 * cos + x1 * sin), axis=-1
-    ).astype(x.dtype)
-
-
 def _embed(params, tokens, cfg):
-    """The residual stream is float32 from here to the head: matrix products
-    take their operands in the serving type, what they add to the stream is
-    not rounded again.  (Under bfloat16 sums the expert selection of one token
-    in twelve flipped at a near-tie in some layer; the router now reads the
-    stream's own float32 norm.)"""
-    x = jnp.take(params["embed"], tokens, axis=0).astype(jnp.float32)
+    """`layers.embed`'s float32 stream, scaled where the model says so."""
+    x = layers.embed(params, tokens)
     return x * cfg.d_model**0.5 if cfg.mup else x
-
-
-def _logits(x, params, cfg):
-    """Final norm and the untied head; float32 logits."""
-    x = _rms_norm(x, params["ln_f"], cfg.rms_eps, params["head"].dtype)
-    return jnp.einsum(
-        "...d,vd->...v", x, params["head"],
-        preferred_element_type=jnp.float32,
-    )
 
 
 def _qkvg(h, lp, positions, cfg, sliding):
@@ -310,11 +269,11 @@ def _qkvg(h, lp, positions, cfg, sliding):
     k = jnp.einsum("btd,dhk->bthk", h, lp["wk"], preferred_element_type=f32)
     v = jnp.einsum("btd,dhk->bthk", h, lp["wv"], preferred_element_type=f32)
     g = jnp.einsum("btd,dhk->bthk", h, lp["wg"], preferred_element_type=f32)
-    q = _rms_norm(q, lp["q_norm"], cfg.rms_eps)
-    k = _rms_norm(k, lp["k_norm"], cfg.rms_eps)
+    q = rms_norm(q, lp["q_norm"], cfg.rms_eps)
+    k = rms_norm(k, lp["k_norm"], cfg.rms_eps)
     if sliding:
-        q = _rope(q, positions, cfg.rope_theta)
-        k = _rope(k, positions, cfg.rope_theta)
+        q = rope(q, positions, cfg.rope_theta)
+        k = rope(k, positions, cfg.rope_theta)
     return q, k.astype(h.dtype), v.astype(h.dtype), g
 
 
@@ -322,49 +281,6 @@ def _attn_out(attn, g, lp):
     o = attn.astype(jnp.float32) * jax.nn.sigmoid(g)
     return jnp.einsum("bthk,hkd->btd", o.astype(lp["wo"].dtype), lp["wo"],
                       preferred_element_type=jnp.float32)
-
-
-def dense_attention(q, k, v, q_offset, window):
-    B, Tq, H, D = q.shape
-    Tk, Hkv = k.shape[1], k.shape[2]
-    qf = q.astype(jnp.float32).reshape(B, Tq, Hkv, H // Hkv, D) * D**-0.5
-    s = jnp.einsum("bqhgd,bkhd->bhgqk", qf, k.astype(jnp.float32))
-    q_pos = q_offset + jnp.arange(Tq)[:, None]
-    k_pos = jnp.arange(Tk)[None, :]
-    seen = k_pos <= q_pos
-    if window is not None:
-        seen &= k_pos > q_pos - window
-    p = jax.nn.softmax(jnp.where(seen, s, NEG_INF), axis=-1)
-    out = jnp.einsum("bhgqk,bkhd->bqhgd", p, v.astype(jnp.float32))
-    return out.reshape(B, Tq, H, D).astype(q.dtype)
-
-
-def prefill_attention(q, k, v, cfg, q_offset, window, interpret):
-    """Causal attention of a prefill, banded where ``window`` is given: the
-    Pallas flash kernel at serving lengths, one dense product below."""
-    if k.shape[1] < FLASH_MIN_LEN:
-        return dense_attention(q, k, v, q_offset, window)
-    if not flash_pallas.fits_vmem(
-        k.shape[1], k.shape[-1], jnp.dtype(k.dtype).itemsize
-    ):
-        raise ValueError(
-            f"a prefill over {k.shape[1]} positions is past the flash "
-            "kernel's VMEM bound"
-        )
-    return flash_pallas.flash_gqa_attention_pallas(
-        q, k, v, q_offset=q_offset, window=window, interpret=interpret
-    )
-
-
-def _swiglu(x, w):
-    """x in the serving type; what goes into the stream is float32."""
-    f32 = jnp.float32
-    gate = jnp.einsum("...d,df->...f", x, w["w_gate"],
-                      preferred_element_type=f32)
-    up = jnp.einsum("...d,df->...f", x, w["w_up"], preferred_element_type=f32)
-    hidden = (jax.nn.silu(gate) * up).astype(x.dtype)
-    return jnp.einsum("...f,fd->...d", hidden, w["w_down"],
-                      preferred_element_type=f32)
 
 
 def route(h, lp, cfg):
@@ -396,30 +312,30 @@ def _moe(h, lp, cfg):
         return routed_experts(rows.astype(act), picked, w, lp["experts"], cfg)
 
     out, sizes = moe_serve.in_chunks(h, chunk, MOE_CHUNK_TOKENS)
-    return _swiglu(h.astype(act), lp["shared"]) + out.reshape(h.shape), sizes
+    return swiglu(h.astype(act), lp["shared"]) + out.reshape(h.shape), sizes
 
 
 def _mlp_block(x, lp, cfg):
     """a -> a + RMSNorm_post_mlp(MLP(RMSNorm_pre_mlp(a))), and the expert
     layer's load (None on a dense layer)."""
-    h = _rms_norm(x, lp["ln_pre_mlp"], cfg.rms_eps)
+    h = rms_norm(x, lp["ln_pre_mlp"], cfg.rms_eps)
     if "mlp" in lp:
-        y, load = _swiglu(h.astype(lp["mlp"]["w_up"].dtype), lp["mlp"]), None
+        y, load = swiglu(h.astype(lp["mlp"]["w_up"].dtype), lp["mlp"]), None
     else:
         y, sizes = _moe(h, lp, cfg)
         load = jnp.stack((jnp.sum(sizes > 0), jnp.max(sizes)))
-    return x + _rms_norm(y, lp["ln_post_mlp"], cfg.rms_eps), load
+    return x + rms_norm(y, lp["ln_post_mlp"], cfg.rms_eps), load
 
 
 def _attn_block(x, attn, g, lp, cfg):
-    return x + _rms_norm(_attn_out(attn, g, lp), lp["ln_post_attn"],
+    return x + rms_norm(_attn_out(attn, g, lp), lp["ln_post_attn"],
                          cfg.rms_eps)
 
 
 def _finish(x, params, cfg, full, win, loads):
     pools = {"full": full, "window": win,
              "load": jnp.stack(loads).astype(jnp.int32)}
-    return _logits(x, params, cfg), pools
+    return logits(x, params, cfg), pools
 
 
 def prefill_paged(
@@ -442,37 +358,28 @@ def prefill_paged(
     stored = tables["window"].shape[1] * bs
     positions = jnp.broadcast_to(jnp.arange(T), (B, T))
     x = _embed(params, tokens, cfg)
+    specs = cache_groups(cfg)
     full, win, loads = list(pools["full"]), list(pools["window"]), []
     for l, lp in enumerate(params["layers"]):
         kind, i = cfg.slot_of_layer(l)
         sliding = kind == "window"
-        h = _rms_norm(x, lp["ln_in"], cfg.rms_eps, lp["wq"].dtype)
+        h = rms_norm(x, lp["ln_in"], cfg.rms_eps, lp["wq"].dtype)
         q, k, v, g = _qkvg(h, lp, positions, cfg, sliding)
         attn = prefill_attention(
             q, k, v, cfg, 0, cfg.window if sliding else None, interpret
         )
         x = _attn_block(x, attn, g, lp, cfg)
         if sliding:
-            win[i] = scatter_kv_blocks(
-                win[i], k[:, T - stored:], v[:, T - stored:],
-                tables["window"], bs, heads_first=True,
+            win[i] = write_blocks(
+                specs[kind], win[i], tables["window"],
+                k[:, T - stored:], v[:, T - stored:],
             )
         else:
-            full[i] = scatter_kv_blocks(
-                full[i], k, v, tables["full"], bs, heads_first=True
-            )
+            full[i] = write_blocks(specs[kind], full[i], tables["full"], k, v)
         x, load = _mlp_block(x, lp, cfg)
         if load is not None:
             loads.append(load)
     return _finish(x[:, -1:], params, cfg, full, win, loads)
-
-
-def _gather_prefix(pool, ids, dtype):
-    """The K and V of the slots `ids` [B, n], in order: [B, n*block, ...]."""
-    pre = jnp.take(pool, ids, axis=0)  # [B, n, 2, Hkv, block, Dh]
-    B, n, _, Hkv, bs, Dh = pre.shape
-    pre = pre.transpose(0, 2, 1, 4, 3, 5).reshape(B, 2, n * bs, Hkv, Dh)
-    return pre[:, 0].astype(dtype), pre[:, 1].astype(dtype)
 
 
 def prefill_continue(
@@ -501,19 +408,20 @@ def prefill_continue(
     nwin = tables["window"].shape[1] - nsuf
     positions = jnp.broadcast_to(prefix_len + jnp.arange(S), (B, S))
     x = _embed(params, tokens, cfg)
+    specs = cache_groups(cfg)
     full, win, loads = list(pools["full"]), list(pools["window"]), []
     for l, lp in enumerate(params["layers"]):
         kind, i = cfg.slot_of_layer(l)
         sliding = kind == "window"
-        h = _rms_norm(x, lp["ln_in"], cfg.rms_eps, lp["wq"].dtype)
+        h = rms_norm(x, lp["ln_in"], cfg.rms_eps, lp["wq"].dtype)
         q, k, v, g = _qkvg(h, lp, positions, cfg, sliding)
         if sliding:
-            pre_k, pre_v = _gather_prefix(
-                win[i], tables["window"][:, :nwin], k.dtype
+            pre_k, pre_v = gather_prefix(
+                specs[kind], win[i], tables["window"][:, :nwin], k.dtype
             )
         else:
-            pre_k, pre_v = _gather_prefix(
-                full[i], tables["full"][:, :npre], k.dtype
+            pre_k, pre_v = gather_prefix(
+                specs[kind], full[i], tables["full"][:, :npre], k.dtype
             )
         attn = prefill_attention(
             q,
@@ -526,14 +434,13 @@ def prefill_continue(
         )
         x = _attn_block(x, attn, g, lp, cfg)
         if sliding:
-            win[i] = scatter_kv_blocks(
-                win[i], k, v, tables["window"][:, nwin:], bs,
-                heads_first=True,
+            win[i] = write_blocks(
+                specs[kind], win[i], tables["window"][:, nwin:], k, v
             )
         else:
-            full[i] = scatter_kv_blocks(
-                full[i], k, v, tables["full"][:, npre:npre + nsuf], bs,
-                heads_first=True,
+            full[i] = write_blocks(
+                specs[kind], full[i], tables["full"][:, npre:npre + nsuf],
+                k, v,
             )
         x, load = _mlp_block(x, lp, cfg)
         if load is not None:
@@ -541,39 +448,19 @@ def prefill_continue(
     return _finish(x[:, -1:], params, cfg, full, win, loads)
 
 
-def _write_token(pool, ids, at, new):
-    """pool[ids[b], :, :, at[b]] = new[b] for each sequence, as whole slots:
-    each sequence's current slot is read, patched at its position and put
-    back by one slice update along the pool's first axis.  (A scatter or a
-    slice update that addresses the position axis makes the compiler
-    re-lay-out the whole pool around it, twice a layer.)  Idle rows share one
-    scratch slot; what they leave there is read by nobody."""
-    slots = jnp.take(pool, ids, axis=0)  # [B, 2, Hkv, block, Dh]
-    here = jnp.arange(pool.shape[3])[None, :] == at[:, None]  # [B, block]
-    slots = jnp.where(here[:, None, None, :, None],
-                      new[:, :, :, None, :].astype(pool.dtype), slots)
-
-    def one(b, pool):
-        return lax.dynamic_update_slice(
-            pool, lax.dynamic_slice_in_dim(slots, b, 1, axis=0),
-            (ids[b], 0, 0, 0, 0))
-
-    return lax.fori_loop(0, ids.shape[0], one, pool)
-
-
-def _decode_attention(q, pool, table, context_len, start, interpret, plan):
+def _decode_attention(spec, q, pool, table, context_len, start, interpret,
+                      plan):
     """The paged kernel's walk, which copies each table block once, a run of
     them by one copy, where it serves (compiled for the TPU, or interpreted;
     `plan`: the runs of this table, `shared_prefix_plan`'s with nobody
     sharing); elsewhere the XLA gather."""
+    pool, layout = decode_view(spec, pool, kernel=plan is not None)
     if plan is not None:
         return paged_decode_attention_pallas(
-            q, pool, table, context_len, start=start, heads_first=True,
-            mxu_native=False, plan=plan, interpret=interpret,
+            q, pool, table, context_len, start=start, mxu_native=False,
+            plan=plan, interpret=interpret, **layout,
         )
-    return paged_attention(
-        q, pool, table, context_len, start=start, heads_first=True
-    )
+    return paged_attention(q, pool, table, context_len, start=start, **layout)
 
 
 def decode_step(
@@ -607,6 +494,7 @@ def decode_step(
         tables["window"], ((pos - first) // bs)[:, None], axis=1)[:, 0]
     win_ctx = context_len - first
     win_start = jnp.maximum(context_len - cfg.window, 0) - first
+    specs = cache_groups(cfg)
     full, win, loads = list(pools["full"]), list(pools["window"]), []
     # Which waves of a table's walk are runs in the pool, once a group: its
     # layers all see the one table.
@@ -621,19 +509,20 @@ def decode_step(
     for l, lp in enumerate(params["layers"]):
         kind, i = cfg.slot_of_layer(l)
         sliding = kind == "window"
-        h = _rms_norm(x, lp["ln_in"], cfg.rms_eps, lp["wq"].dtype)
+        h = rms_norm(x, lp["ln_in"], cfg.rms_eps, lp["wq"].dtype)
         q, k, v, g = _qkvg(h, lp, pos[:, None], cfg, sliding)
-        new = jnp.stack((k[:, 0], v[:, 0]), axis=1)  # [B, 2, Hkv, Dh]
         if sliding:
-            win[i] = _write_token(win[i], win_id, at, new)
-            attn = _decode_attention(q[:, 0], win[i], tables["window"],
-                                     win_ctx, win_start, interpret,
-                                     plans.get(kind))
+            win[i] = write_token(specs[kind], win[i], win_id, at,
+                                 k[:, 0], v[:, 0])
+            attn = _decode_attention(specs[kind], q[:, 0], win[i],
+                                     tables["window"], win_ctx, win_start,
+                                     interpret, plans.get(kind))
         else:
-            full[i] = _write_token(full[i], full_id, at, new)
-            attn = _decode_attention(q[:, 0], full[i], tables["full"],
-                                     context_len, None, interpret,
-                                     plans.get(kind))
+            full[i] = write_token(specs[kind], full[i], full_id, at,
+                                  k[:, 0], v[:, 0])
+            attn = _decode_attention(specs[kind], q[:, 0], full[i],
+                                     tables["full"], context_len, None,
+                                     interpret, plans.get(kind))
         x = _attn_block(x, attn[:, None], g, lp, cfg)
         x, load = _mlp_block(x, lp, cfg)
         if load is not None:

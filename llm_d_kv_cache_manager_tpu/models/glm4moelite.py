@@ -72,10 +72,12 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from llm_d_kv_cache_manager_tpu.models import moe_serve
+from llm_d_kv_cache_manager_tpu.models import layers, moe_serve
 from llm_d_kv_cache_manager_tpu.models.kv_cache_pool import (
-    KVGroupSpec,
-    scatter_latent_blocks,
+    KVGroupSpec, decode_view, write_blocks, write_token,
+)
+from llm_d_kv_cache_manager_tpu.models.layers import (
+    embed, interpreted, logits, rms_norm, swiglu,
 )
 from llm_d_kv_cache_manager_tpu.ops import paged_decode_pallas
 from llm_d_kv_cache_manager_tpu.ops.latent_prefill_pallas import (
@@ -195,10 +197,7 @@ def new_pool(cfg: Glm4MoeLiteConfig, pool_blocks: int) -> dict:
     """The pod's pool as a pytree: one array a layer, each updated in place.
     (A step hands them back with more leaves, `load` and `attention_read`,
     that step's counts; they are not handed in again.)"""
-    spec = cache_groups(cfg)["full"]
-    return {"full": [jnp.zeros(spec.layer_shape(pool_blocks),
-                               jnp.dtype(spec.dtype))
-                     for _ in range(spec.num_layers)]}
+    return layers.new_pool(cache_groups(cfg), {"full": pool_blocks})
 
 
 def from_published(cfg: dict, block_size: int) -> Glm4MoeLiteConfig:
@@ -312,12 +311,6 @@ def init_params(rng: jax.Array, cfg: Glm4MoeLiteConfig) -> Params:
 # ------------------------------------------------------------ the model step
 
 
-def _rms_norm(x, w, eps, dtype=None):
-    xf = x.astype(jnp.float32)
-    norm = xf * lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
-    return (norm * w.astype(jnp.float32)).astype(dtype or x.dtype)
-
-
 def _rope(x, positions, theta):
     """x: [..., T, n, dr] or [..., T, dr] with positions [..., T]: the lanes
     (2i, 2i + 1) turn together by ``pos * theta^(-2i/dr)``."""
@@ -333,29 +326,13 @@ def _rope(x, positions, theta):
                      axis=-1).reshape(x.shape)
 
 
-def _embed(params, tokens):
-    """The residual stream is float32 from here to the head: matrix products
-    take their operands in the serving type, what they add to the stream is
-    not rounded again (models/afmoe.py has the reading that asked for it)."""
-    return jnp.take(params["embed"], tokens, axis=0).astype(jnp.float32)
-
-
-def _logits(x, params, cfg):
-    """Final norm and the untied head; float32 logits."""
-    x = _rms_norm(x, params["ln_f"], cfg.rms_eps, params["head"].dtype)
-    return jnp.einsum(
-        "...d,vd->...v", x, params["head"],
-        preferred_element_type=jnp.float32,
-    )
-
-
 def _latent(h, lp, positions, cfg):
     """h: [B, T, D] in the serving type -> the cache's slots of its
     positions, [B, T, Rkv + dr] in the serving type: ``[RMS_kv(c') |
     rope(kr')]``, rounded once."""
     ckr = jnp.einsum("btd,dr->btr", h, lp["w_kva"],
                      preferred_element_type=jnp.float32)
-    c = _rms_norm(ckr[..., :cfg.kv_rank], lp["kv_norm"], cfg.rms_eps)
+    c = rms_norm(ckr[..., :cfg.kv_rank], lp["kv_norm"], cfg.rms_eps)
     kr = _rope(ckr[..., cfg.kv_rank:], positions, cfg.rope_theta)
     return jnp.concatenate((c, kr), axis=-1).astype(h.dtype)
 
@@ -366,7 +343,7 @@ def _latent_query(h, lp, positions, cfg):
     serving type (the scores' scale is the kernels')."""
     f32, act = jnp.float32, h.dtype
     cq = jnp.einsum("btd,dr->btr", h, lp["w_qa"], preferred_element_type=f32)
-    cq = _rms_norm(cq, lp["q_norm"], cfg.rms_eps, act)
+    cq = rms_norm(cq, lp["q_norm"], cfg.rms_eps, act)
     q = jnp.einsum("btr,rhk->bthk", cq, lp["w_qb"],
                    preferred_element_type=f32)
     qr = _rope(q[..., cfg.nope_dim:], positions, cfg.rope_theta)
@@ -386,17 +363,6 @@ def _attn_out(o_latent, lp, cfg):
                       preferred_element_type=jnp.float32)
 
 
-def _swiglu(x, w):
-    """x in the serving type; what goes into the stream is float32."""
-    f32 = jnp.float32
-    gate = jnp.einsum("...d,df->...f", x, w["w_gate"],
-                      preferred_element_type=f32)
-    up = jnp.einsum("...d,df->...f", x, w["w_up"], preferred_element_type=f32)
-    hidden = (jax.nn.silu(gate) * up).astype(x.dtype)
-    return jnp.einsum("...f,fd->...d", hidden, w["w_down"],
-                      preferred_element_type=f32)
-
-
 def _moe(h, lp, cfg):
     """h: [B, T, D] float32 -> (Shared(h) + routed experts, float32; picks
     per expert [E]).  Batched under the routing's mask for at most as many
@@ -414,28 +380,22 @@ def _moe(h, lp, cfg):
             batched=picked.shape[0] <= cfg.n_experts)
 
     out, sizes = moe_serve.in_chunks(h, chunk, MOE_CHUNK_TOKENS)
-    return _swiglu(h.astype(act), lp["shared"]) + out.reshape(h.shape), sizes
+    return swiglu(h.astype(act), lp["shared"]) + out.reshape(h.shape), sizes
 
 
 def _ff_block(x, lp, cfg):
     """a -> a + FF(RMS_post(a)), and the expert layer's load (None on a
     dense layer)."""
-    h = _rms_norm(x, lp["ln_post"], cfg.rms_eps)
+    h = rms_norm(x, lp["ln_post"], cfg.rms_eps)
     if "mlp" in lp:
-        return x + _swiglu(h.astype(lp["mlp"]["w_up"].dtype), lp["mlp"]), None
+        return x + swiglu(h.astype(lp["mlp"]["w_up"].dtype), lp["mlp"]), None
     y, sizes = _moe(h, lp, cfg)
     return x + y, jnp.stack((jnp.sum(sizes > 0), jnp.max(sizes)))
 
 
 def _finish(x, params, cfg, full, loads):
     pools = {"full": full, "load": jnp.stack(loads).astype(jnp.int32)}
-    return _logits(x, params, cfg), pools
-
-
-def _interpreted(interpret: bool) -> bool:
-    """The kernels are the only attention here: interpreted where the
-    program is not compiled for the TPU."""
-    return interpret or jax.default_backend() != "tpu"
+    return logits(x, params, cfg), pools
 
 
 def _prefill_attention(h, lp, pool, table, first, cfg, interpret):
@@ -451,7 +411,7 @@ def _prefill_attention(h, lp, pool, table, first, cfg, interpret):
             _latent_query(h, lp, positions, cfg), pool, table, q_offset=at,
             value_dim=cfg.kv_rank, scale=cfg.score_scale,
             q_tile=PREFILL_Q_TILE, blocks_per_step=PREFILL_BLOCKS_PER_STEP,
-            interpret=_interpreted(interpret))
+            interpret=interpreted(interpret))
         return _attn_out(o, lp, cfg)
 
     n = -(-T // ATTN_CHUNK_TOKENS)
@@ -474,12 +434,13 @@ def _prefill(params, tokens, pools, table, first, cfg, interpret):
         raise ValueError("a prefill starts and ends on block boundaries")
     positions = jnp.broadcast_to(first + jnp.arange(T), (B, T))
     new = table[:, first // bs:(first + T) // bs]
-    x = _embed(params, tokens)
+    x = embed(params, tokens)
+    spec = cache_groups(cfg)["full"]
     full, loads = list(pools["full"]), []
     for l, lp in enumerate(params["layers"]):
-        h = _rms_norm(x, lp["ln_in"], cfg.rms_eps, lp["w_qa"].dtype)
-        full[l] = scatter_latent_blocks(
-            full[l], _latent(h, lp, positions, cfg), new, bs, cfg.kv_rank)
+        h = rms_norm(x, lp["ln_in"], cfg.rms_eps, lp["w_qa"].dtype)
+        full[l] = write_blocks(spec, full[l], new,
+                               _latent(h, lp, positions, cfg))
         x = x + _prefill_attention(h, lp, full[l], table, first, cfg,
                                    interpret)
         x, load = _ff_block(x, lp, cfg)
@@ -519,34 +480,6 @@ def prefill_continue(
     return _prefill(params, tokens, pools, table, prefix_len, cfg, interpret)
 
 
-def _write_token(pool, ids, at, new, value_dim):
-    """The slot of position ``at[b]`` of block ``ids[b]`` = new[b] ([B,
-    latent]) for each sequence, as whole slots (`afmoe._write_token`, for
-    latent slots: position p of a block is the first half of row p if p is
-    in the block's first half, else the second half, mirrored, of row
-    p - block/2)."""
-    half, width = pool.shape[1], pool.shape[2]
-    slots = jnp.take(pool, ids, axis=0)  # [B, block/2, 2 latent]
-    new = new.astype(pool.dtype)
-    second = (at >= half)[:, None]
-    zeros = jnp.zeros_like(new)
-    row = jnp.where(
-        second,
-        jnp.concatenate((zeros, new[:, value_dim:], new[:, :value_dim]), -1),
-        jnp.concatenate((new, zeros), -1))  # [B, 2 latent]
-    lanes = (jnp.arange(width)[None, :] >= width // 2) == second
-    here = ((jnp.arange(half)[None, :] == (at % half)[:, None])[:, :, None]
-            & lanes[:, None, :])
-    slots = jnp.where(here, row[:, None, :], slots)
-
-    def one(b, pool):
-        return lax.dynamic_update_slice(
-            pool, lax.dynamic_slice_in_dim(slots, b, 1, axis=0),
-            (ids[b], 0, 0))
-
-    return lax.fori_loop(0, ids.shape[0], one, pool)
-
-
 def decode_step(
     params: Params,
     tokens: jnp.ndarray,
@@ -562,9 +495,10 @@ def decode_step(
     returns (logits [B, V], pools)."""
     bs = cfg.block_size
     pos = context_len - 1
-    x = _embed(params, tokens)[:, None]  # [B, 1, D]
+    x = embed(params, tokens)[:, None]  # [B, 1, D]
     at = pos % bs
     ids = jnp.take_along_axis(table, (pos // bs)[:, None], axis=1)[:, 0]
+    spec = cache_groups(cfg)["full"]
     full, loads = list(pools["full"]), []
     # Which sequences' tables begin with the same blocks, once for all
     # layers: every layer sees this table.
@@ -572,16 +506,16 @@ def decode_step(
         table, context_len, block_size=bs, min_sequences=SHARED_MIN_SEQUENCES,
         blocks_per_wave=DECODE_BLOCKS_PER_WAVE)
     for l, lp in enumerate(params["layers"]):
-        h = _rms_norm(x, lp["ln_in"], cfg.rms_eps, lp["w_qa"].dtype)
-        full[l] = _write_token(
-            full[l], ids, at, _latent(h, lp, pos[:, None], cfg)[:, 0],
-            cfg.kv_rank)
+        h = rms_norm(x, lp["ln_in"], cfg.rms_eps, lp["w_qa"].dtype)
+        full[l] = write_token(
+            spec, full[l], ids, at, _latent(h, lp, pos[:, None], cfg)[:, 0])
+        pool, layout = decode_view(spec, full[l], kernel=True)
         o = paged_decode_attention_pallas(
-            _latent_query(h, lp, pos[:, None], cfg)[:, 0], full[l], table,
-            context_len, latent=cfg.kv_rank, scale=cfg.score_scale,
+            _latent_query(h, lp, pos[:, None], cfg)[:, 0], pool, table,
+            context_len, scale=cfg.score_scale,
             plan=plan, walk_blocks_per_wave=DECODE_BLOCKS_PER_WAVE,
             shared_blocks_per_step=DECODE_BLOCKS_PER_WAVE,
-            interpret=_interpreted(interpret))
+            interpret=interpreted(interpret), **layout)
         x = x + _attn_out(o[:, None], lp, cfg)
         x, load = _ff_block(x, lp, cfg)
         if load is not None:

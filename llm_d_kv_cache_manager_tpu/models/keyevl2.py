@@ -77,12 +77,13 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from llm_d_kv_cache_manager_tpu.models import moe_serve
+from llm_d_kv_cache_manager_tpu.models import layers, moe_serve
 from llm_d_kv_cache_manager_tpu.models.kv_cache_pool import (
-    KVGroupSpec,
-    pack_selected_blocks,
-    scatter_selected_blocks,
-    unpack_selector_keys,
+    KVGroupSpec, gather_picked_tiles, gather_selector_keys, write_blocks,
+    write_token,
+)
+from llm_d_kv_cache_manager_tpu.models.layers import (
+    embed, interpreted, logits, rms_norm,
 )
 from llm_d_kv_cache_manager_tpu.ops import sparse_attention_pallas as sparse
 
@@ -164,10 +165,7 @@ def new_pool(cfg: KeyeVl2Config, pool_blocks: int) -> dict:
     """The pod's pool as a pytree: one array a layer, each updated in place.
     (A step hands them back with one more leaf, `load`, that step's expert
     counts; it is not handed in again.)"""
-    spec = cache_groups(cfg)["full"]
-    return {"full": [jnp.zeros(spec.layer_shape(pool_blocks),
-                               jnp.dtype(spec.dtype))
-                     for _ in range(spec.num_layers)]}
+    return layers.new_pool(cache_groups(cfg), {"full": pool_blocks})
 
 
 def from_published(cfg: dict, block_size: int) -> KeyeVl2Config:
@@ -257,12 +255,6 @@ def init_params(rng: jax.Array, cfg: KeyeVl2Config) -> Params:
 # ------------------------------------------------------------ the model step
 
 
-def _rms_norm(x, w, eps, dtype=None):
-    xf = x.astype(jnp.float32)
-    norm = xf * lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
-    return (norm * w.astype(jnp.float32)).astype(dtype or x.dtype)
-
-
 def _layer_norm(x, w, b):
     mean = jnp.mean(x, axis=-1, keepdims=True)
     var = jnp.mean((x - mean) ** 2, axis=-1, keepdims=True)
@@ -283,27 +275,13 @@ def _rope(x, positions, theta):
     return jnp.concatenate((x1 * cos - x2 * sin, x2 * cos + x1 * sin), -1)
 
 
-def _embed(params, tokens):
-    """The residual stream is float32 from here to the head: matrix products
-    take their operands in the serving type, what they add to the stream is
-    not rounded again (models/afmoe.py has the reading that asked for it)."""
-    return jnp.take(params["embed"], tokens, axis=0).astype(jnp.float32)
-
-
-def _logits(x, params, cfg):
-    """Final norm and the untied head; float32 logits."""
-    x = _rms_norm(x, params["ln_f"], cfg.rms_eps, params["head"].dtype)
-    return jnp.einsum("...d,vd->...v", x, params["head"],
-                      preferred_element_type=jnp.float32)
-
-
 def _queries(h, lp, positions, cfg):
     """h: [B, T, D] in the serving type -> q [B, T, H, dh] in the serving
     type.  Norm and rope run on the product's float32 sums, so q is rounded
     once."""
     q = jnp.einsum("btd,dhk->bthk", h, lp["wq"],
                    preferred_element_type=jnp.float32)
-    return _rope(_rms_norm(q, lp["q_norm"], cfg.rms_eps), positions,
+    return _rope(rms_norm(q, lp["q_norm"], cfg.rms_eps), positions,
                  cfg.rope_theta).astype(h.dtype)
 
 
@@ -325,7 +303,7 @@ def _cached(h, lp, positions, cfg):
     k = jnp.einsum("btd,dhk->bthk", h, lp["wk"], preferred_element_type=f32)
     v = jnp.einsum("btd,dhk->bthk", h, lp["wv"], preferred_element_type=f32)
     ki = jnp.einsum("btd,dk->btk", h, lp["w_ki"], preferred_element_type=f32)
-    k = _rope(_rms_norm(k, lp["k_norm"], cfg.rms_eps), positions,
+    k = _rope(rms_norm(k, lp["k_norm"], cfg.rms_eps), positions,
               cfg.rope_theta)
     ki = _rope(_layer_norm(ki, lp["ki_norm"], lp["ki_bias"]), positions,
                cfg.rope_theta)
@@ -358,35 +336,13 @@ def _moe(h, lp, cfg):
 
 def _ff_block(x, lp, cfg):
     """a -> a + MoE(RMS_post(a)), and the layer's load."""
-    y, sizes = _moe(_rms_norm(x, lp["ln_post"], cfg.rms_eps), lp, cfg)
+    y, sizes = _moe(rms_norm(x, lp["ln_post"], cfg.rms_eps), lp, cfg)
     return x + y, jnp.stack((jnp.sum(sizes > 0), jnp.max(sizes)))
 
 
 def _finish(x, params, cfg, full, loads):
     pools = {"full": full, "load": jnp.stack(loads).astype(jnp.int32)}
-    return _logits(x, params, cfg), pools
-
-
-def _interpreted(interpret: bool) -> bool:
-    """The kernels are the only attention here: interpreted where the
-    program is not compiled for the TPU."""
-    return interpret or jax.default_backend() != "tpu"
-
-
-def _tiles(pool):
-    """A layer's pool with a tile a row: [slots * (bs + t), 2 * G, dh]
-    (merging the two leading axes moves nothing)."""
-    return pool.reshape((-1,) + pool.shape[2:])
-
-
-def _selector_keys(pool, table, cfg):
-    """The selector keys of the positions ``table`` ([B, n]) names, in
-    order: [B, n * bs, dI].  Only the key tiles of the table's slots are
-    read."""
-    per, bs = pool.shape[1], cfg.block_size
-    at = table[..., None] * per + bs + jnp.arange(per - bs)
-    return unpack_selector_keys(jnp.take(_tiles(pool), at, axis=0),
-                                cfg.index_dim)
+    return logits(x, params, cfg), pools
 
 
 def _prefill_attention(h, lp, pool, table, first, cfg, interpret, taps):
@@ -401,7 +357,8 @@ def _prefill_attention(h, lp, pool, table, first, cfg, interpret, taps):
     tests' window."""
     B, T, D = h.shape
     bs = cfg.block_size
-    interpret = _interpreted(interpret)
+    spec = cache_groups(cfg)["full"]
+    interpret = interpreted(interpret)
 
     def attend(table, keys, h, at):
         positions = jnp.broadcast_to(at + jnp.arange(h.shape[1]), h.shape[:2])
@@ -429,7 +386,7 @@ def _prefill_attention(h, lp, pool, table, first, cfg, interpret, taps):
         done, *_, last = 2 * list(group)
         count = last + 1 - done
         seen = table[:, :-(-(first + (last + 1) * chunk) // bs)]
-        keys = _selector_keys(pool, seen, cfg)  # [B, L, dI]
+        keys = gather_selector_keys(spec, pool, seen)  # [B, L, dI]
         hs = h[:, done * chunk:(done + count) * chunk]
         starts = first + chunk * (done + jnp.arange(count, dtype=jnp.int32))
         if count == 1:
@@ -459,12 +416,13 @@ def _prefill(params, tokens, pools, table, first, cfg, interpret, taps):
         raise ValueError("a prefill starts and ends on block boundaries")
     positions = jnp.broadcast_to(first + jnp.arange(T), (B, T))
     new = table[:, first // bs:(first + T) // bs]
-    x = _embed(params, tokens)
+    x = embed(params, tokens)
+    spec = cache_groups(cfg)["full"]
     full, loads = list(pools["full"]), []
     for l, lp in enumerate(params["layers"]):
-        h = _rms_norm(x, lp["ln_in"], cfg.rms_eps, lp["wq"].dtype)
+        h = rms_norm(x, lp["ln_in"], cfg.rms_eps, lp["wq"].dtype)
         k, v, ki = _cached(h, lp, positions, cfg)
-        full[l] = scatter_selected_blocks(full[l], k, v, ki, new, bs)
+        full[l] = write_blocks(spec, full[l], new, k, v, ki)
         x = x + _prefill_attention(h, lp, full[l], table, first, cfg,
                                    interpret, taps)
         x, load = _ff_block(x, lp, cfg)
@@ -507,37 +465,6 @@ def prefill_continue(
                     taps)
 
 
-def _write_token(pool, ids, at, k, v, ki, bs):
-    """The tile of position ``at[b]`` of slot ``ids[b]`` = (k[b], v[b])
-    ([B, G, dh] each) and its selector key = ki[b] ([B, dI]) for each
-    sequence, as whole slots (`afmoe._write_token`, for selected slots: the
-    sequence's current slot is read, patched and put back by one slice update
-    along the pool's first axis).  Idle rows share one scratch slot; what
-    they leave there is read by nobody."""
-    slots = jnp.take(pool, ids, axis=0)  # [B, bs + t, 2G, dh]
-    here = jnp.arange(bs)[None, :] == at[:, None]  # [B, bs]
-
-    def slot_of(k, v, ki):
-        """The slot of a block whose position p holds (k, v, ki)[:, p]."""
-        return pack_selected_blocks(k, v, ki, bs)[:, 0]
-
-    # the slot of a block that holds only this position, and where it is
-    one = slot_of(jnp.where(here[:, :, None, None], k[:, None], 0),
-                  jnp.where(here[:, :, None, None], v[:, None], 0),
-                  jnp.where(here[:, :, None], ki[:, None], 0))
-    mask = slot_of(*(jnp.broadcast_to(
-        here.reshape(here.shape + (1,) * (a.ndim - 1)),
-        here.shape + a.shape[1:]) for a in (k, v, ki)))
-    slots = jnp.where(mask, one.astype(pool.dtype), slots)
-
-    def put(b, pool):
-        return lax.dynamic_update_slice(
-            pool, lax.dynamic_slice_in_dim(slots, b, 1, axis=0),
-            (ids[b], 0, 0, 0))
-
-    return lax.fori_loop(0, ids.shape[0], put, pool)
-
-
 def _decode_attention(q, qi, w, pool, table, context_len, cfg, interpret,
                       taps):
     """One query a sequence: its scores over its own table's selector keys
@@ -560,15 +487,16 @@ def _decode_attention(q, qi, w, pool, table, context_len, cfg, interpret,
     0.02017 with `lax.top_k`, 0.02355 all XLA (a seed each)."""
     B, H, dh = q.shape
     G, K = cfg.n_kv_heads, cfg.index_topk
+    spec = cache_groups(cfg)["full"]
     scores = sparse.sparse_decode_scores_pallas(
         qi, w, pool, table, context_len, selector_dim=cfg.index_dim,
-        interpret=_interpreted(interpret))
+        interpret=interpreted(interpret))
     tiles, at, picked = sparse.picked_tiles(
-        sparse.topk_mask(scores, K), table, K, cfg.block_size, pool.shape[1])
+        sparse.topk_mask(scores, K), table, K, cfg.block_size,
+        spec.slot_tiles)
     if taps is not None:
         taps.append((at, picked))
-    rows = jnp.take(_tiles(pool), tiles, axis=0)
-    k, v = rows[:, :, :G], rows[:, :, G:]  # [B, K, G, dh]
+    k, v = gather_picked_tiles(spec, pool, tiles)  # [B, K, G, dh]
     s = jnp.einsum("bghd,bkgd->bghk", q.reshape(B, G, H // G, dh), k,
                    preferred_element_type=jnp.float32) * dh**-0.5
     p = jax.nn.softmax(jnp.where(picked[:, None, None], s, sparse.NEG_INF),
@@ -594,17 +522,18 @@ def decode_step(
     and returns (logits [B, V], pools)."""
     bs = cfg.block_size
     pos = context_len - 1
-    x = _embed(params, tokens)[:, None]  # [B, 1, D]
+    x = embed(params, tokens)[:, None]  # [B, 1, D]
     at = pos % bs
     ids = jnp.take_along_axis(table, (pos // bs)[:, None], axis=1)[:, 0]
+    spec = cache_groups(cfg)["full"]
     full, loads = list(pools["full"]), []
     for l, lp in enumerate(params["layers"]):
-        h = _rms_norm(x, lp["ln_in"], cfg.rms_eps, lp["wq"].dtype)
+        h = rms_norm(x, lp["ln_in"], cfg.rms_eps, lp["wq"].dtype)
         q = _queries(h, lp, pos[:, None], cfg)
         qi, w = _index_queries(h, lp, pos[:, None], cfg)
         k, v, ki = _cached(h, lp, pos[:, None], cfg)
-        full[l] = _write_token(full[l], ids, at, k[:, 0], v[:, 0], ki[:, 0],
-                               bs)
+        full[l] = write_token(spec, full[l], ids, at, k[:, 0], v[:, 0],
+                              ki[:, 0])
         o = _decode_attention(q[:, 0], qi[:, 0], w[:, 0], full[l], table,
                               context_len, cfg, interpret, taps)
         x = x + _attn_out(o[:, None], lp)

@@ -1,26 +1,41 @@
-"""Paged KV-cache pool: the TPU-resident block store the connector pages.
+"""The paged K/V pools on the chip, and the ONE place a slot's layout is
+written and read.  A pool is, a layer, one array ``[slots, *slot]``;
+``KVGroupSpec`` says what a slot holds and ``KVGroupSpec.layout`` names how it
+lies (the reasons stand beside the spec's fields):
 
-One stacked array ``[num_layers, num_blocks, 2(K/V), block_size,
-num_kv_heads, head_dim]`` rather than per-layer tensors: a single jitted
-gather/scatter moves a block batch across *all* layers in one XLA op and
-one DMA, where the reference's CUDA path loops cudaMemcpyAsync per
-block x layer (tensor_copier.cu:50-97).  The layer axis also gives
-pipeline-parallel sharding a natural home (shard axis 0 over the ``pp``
-mesh axis; blocks axis stays replicated within a stage).
+  ``plain``        [2, block, Hkv, Dh]       `llama`, and ``KVCachePool`` below
+                                             (the offload path's files)
+  ``heads_first``  [2, Hkv, block, Dh]       `afmoe`: fewer than 8 KV heads
+  ``packed``       [block, Hkv, 2 * Dh]      `lfm2moe`: heads of 64
+  ``rows``         [2, block * Hkv, Dh]      `phi4flash`: 10 pair-wise heads
+  ``latent``       [block / 2, 2 * W]        `glm4moelite`: a vector a position
+  ``selected``     [block + t, 2 * Hkv, Dh]  `keyevl2`: a tile a position, then
+                                             the block's selector keys
 
-Sharded pools: pass a NamedSharding; gather/scatter then run under the
-same sharding and XLA inserts the collectives.
+(and ``state``: a recurrent layer's state at a block's end, no K/V).  A family
+names its layout once, in its ``cache_groups``, hands the group's spec to the
+operations below (``write_blocks``, ``write_token``, ``gather_prefix``,
+``decode_view``; for the kinds attended over where they lie
+``unpack_latent_blocks``, ``gather_selector_keys``, ``gather_picked_tiles``)
+and never looks into a pool array.  A new layout is a branch in each of these
+and in ``KVGroupSpec.layer_shape``, here and nowhere else (the kernels in ops/
+read a slot by the keywords ``decode_view`` gives).
+
+``KVCachePool`` stacks the layers, ``[num_layers, num_blocks, *slot]``: one
+jitted gather/scatter moves a block batch across all layers in one XLA op and
+one transfer, under a NamedSharding where one is passed.
 """
 
 from __future__ import annotations
 
 import math
 from dataclasses import dataclass
-from typing import Optional, Sequence, Tuple
+from typing import Optional, Sequence
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
 
 
 @dataclass(frozen=True)
@@ -113,14 +128,35 @@ class KVGroupSpec:
                     "a selected slot is K/V tiles and whole tiles of "
                     "selector keys: selector_dim divides head_dim, and a "
                     "block's keys fill tiles of 2 * num_kv_heads * head_dim")
-        if self.latent_dim is None:
-            return
-        if (self.num_kv_heads != 1 or self.head_dim != self.latent_dim
+        if self.latent_dim is not None and (
+                self.num_kv_heads != 1 or self.head_dim != self.latent_dim
                 or self.block_size % 2 or self.state_shape is not None
                 or not 0 < (self.value_dim or 0) <= self.latent_dim):
             raise ValueError(
                 "a latent slot is one vector a position: one KV head of "
                 "latent_dim, value_dim of its lanes the value, an even block")
+        named = [n for n in ("heads_first", "packed", "rows")
+                 if getattr(self, n)]
+        named += [n for n in ("latent_dim", "selector_dim", "state_shape")
+                  if getattr(self, n) is not None]
+        if len(named) > 1:
+            raise ValueError("a slot lies one way: " + ", ".join(named)
+                             + " exclude each other")
+
+    @property
+    def layout(self) -> str:
+        """How a slot lies, from the fields above (the module's head has the
+        six K/V layouts): what the operations below branch on."""
+        if self.state_shape is not None:
+            return "state"
+        if self.latent_dim is not None:
+            return "latent"
+        if self.selector_dim is not None:
+            return "selected"
+        for name in ("heads_first", "packed", "rows"):
+            if getattr(self, name):
+                return name
+        return "plain"
 
     @property
     def num_readers(self) -> int:
@@ -170,6 +206,11 @@ class KVGroupSpec:
         return (self.block_size * self.selector_dim
                 // (2 * self.num_kv_heads * self.head_dim))
 
+    @property
+    def slot_tiles(self) -> int:
+        """Tiles of a selected slot: a position each, then its keys'."""
+        return self.block_size + self.selector_tiles
+
     def layer_shape(self, num_blocks: int) -> tuple:
         """One layer's share of a pool of ``num_blocks`` slots (a state in
         several arrays has a shape each: ``state_parts``)."""
@@ -179,8 +220,8 @@ class KVGroupSpec:
         if self.latent_dim:
             return (num_blocks, self.block_size // 2, 2 * self.latent_dim)
         if self.selector_dim:
-            return (num_blocks, self.block_size + self.selector_tiles,
-                    2 * self.num_kv_heads, self.head_dim)
+            return (num_blocks, self.slot_tiles, 2 * self.num_kv_heads,
+                    self.head_dim)
         if self.packed:
             return (num_blocks, self.block_size, self.num_kv_heads,
                     2 * self.head_dim)
@@ -217,15 +258,25 @@ class KVGroupSpec:
         return -(-(self.window - 1) // self.block_size)
 
 
+def _blocked(k, v, block_size, heads_first=False):
+    """Per-token K/V ([B, T, Hkv, Dh] each) block by block: [B, T/block, 2,
+    block, Hkv, Dh], or ``heads_first`` [B, T/block, 2, Hkv, block, Dh]."""
+    B, T = k.shape[:2]
+    kv = jnp.stack((k, v), axis=2)  # [B, T, 2, Hkv, Dh]
+    return kv.reshape(
+        B, T // block_size, block_size, 2, kv.shape[-2], kv.shape[-1]
+    ).transpose((0, 1, 3, 4, 2, 5) if heads_first else (0, 1, 3, 2, 4, 5))
+
+
 def scatter_kv_blocks(
     kv_layer, k, v, block_ids, block_size, heads_first=False
 ):
     """Write per-token K/V ([B, T, Hkv, Dh] each, T a multiple of
     ``block_size``) into the slots of one layer's pool
     (``KVGroupSpec.layer_shape``) named by ``block_ids``
-    ([B, T/block_size]).  ONE layout for every prefill path of every
-    family: were it duplicated, a pool layout change could silently
-    diverge between them.
+    ([B, T/block_size]): the plain layout and ``heads_first``.  A family's
+    model step reaches it through ``write_blocks``, which knows every layout;
+    the `llama` programs call it on their merged pool.
 
     Only the named slots are written, so a pool that is carried (a
     scan's carry, or one array a layer as ``models/afmoe.py`` keeps
@@ -234,13 +285,7 @@ def scatter_kv_blocks(
     The first axis may as well hold several layers' slots: the `llama`
     programs merge a pool's ``[L, N]`` into ``L * N`` and name layer
     ``l``'s block ``b`` as ``l * N + b`` (``llama._scan_layers``)."""
-    B, T = k.shape[:2]
-    kv = jnp.stack((k, v), axis=2)  # [B, T, 2, Hkv, Dh]
-    kv = kv.reshape(
-        B, T // block_size, block_size, 2, kv.shape[-2], kv.shape[-1]
-    ).transpose(
-        (0, 1, 3, 4, 2, 5) if heads_first else (0, 1, 3, 2, 4, 5)
-    )  # [B, nb, 2, block, Hkv, Dh], or [B, nb, 2, Hkv, block, Dh]
+    kv = _blocked(k, v, block_size, heads_first)
     return kv_layer.at[block_ids.reshape(-1)].set(
         kv.reshape((-1,) + kv.shape[2:]).astype(kv_layer.dtype)
     )
@@ -272,17 +317,6 @@ def unpack_latent_blocks(slots, value_dim: int):
     return jnp.stack((a, b), axis=-3).reshape(*lead, n * 2 * half, width // 2)
 
 
-def scatter_latent_blocks(kv_layer, latent, block_ids, block_size, value_dim):
-    """``scatter_kv_blocks`` for a latent group: per-token latents
-    [B, T, latent_dim] into the slots of one layer's pool named by
-    ``block_ids`` ([B, T/block_size]), in the one layout
-    ``pack_latent_blocks`` states.  Only the named slots are written."""
-    slots = pack_latent_blocks(latent, block_size, value_dim)
-    return kv_layer.at[block_ids.reshape(-1)].set(
-        slots.reshape((-1,) + slots.shape[2:]).astype(kv_layer.dtype)
-    )
-
-
 def pack_selected_blocks(k, v, key, block_size: int):
     """Per-position K and V ([..., T, Hkv, Dh] each) and selector keys
     ([..., T, dI]), T a multiple of ``block_size``, as the slots of a
@@ -311,16 +345,189 @@ def unpack_selector_keys(tiles, selector_dim: int):
     return jnp.moveaxis(keys, -2, -3).reshape(*lead, -1, selector_dim)
 
 
-def scatter_selected_blocks(kv_layer, k, v, key, block_ids, block_size):
-    """``scatter_kv_blocks`` for a selected group: per-token K/V
-    [B, T, Hkv, Dh] and selector keys [B, T, dI] into the slots of one
-    layer's pool named by ``block_ids`` ([B, T/block_size]), in the one
-    layout ``pack_selected_blocks`` states.  Only the named slots are
-    written."""
-    slots = pack_selected_blocks(k, v, key, block_size)
-    return kv_layer.at[block_ids.reshape(-1)].set(
-        slots.reshape((-1,) + slots.shape[2:]).astype(kv_layer.dtype)
-    )
+# Each operation below takes the group's spec and branches on ``spec.layout``
+# at trace time.  ``pool`` is one layer's array, or several layers' slots merged
+# into its first axis (models/phi4flash.py); ids name slots of that axis.
+
+
+def write_blocks(spec: KVGroupSpec, pool, block_ids, *parts):
+    """Write a prefill's whole blocks into the slots ``block_ids``
+    [B, T/block] of ``pool``.  ``parts``: per-token K and V [B, T, Hkv, Dh]
+    each (T a multiple of the block size); for a latent group the latents
+    [B, T, latent_dim]; for a selected group K, V and the selector keys
+    [B, T, dI].  Only the named slots are written (``scatter_kv_blocks``)."""
+    bs, layout = spec.block_size, spec.layout
+    if layout == "latent":
+        slots = pack_latent_blocks(*parts, bs, spec.value_dim)
+    elif layout == "selected":
+        slots = pack_selected_blocks(*parts, bs)
+    elif layout == "packed":  # a reshape, no transpose
+        slots = jnp.concatenate(parts, axis=-1).reshape((-1,) + pool.shape[1:])
+    elif layout == "rows":
+        # the plain slot with a block's positions and heads as rows.  (Through
+        # a view of the pool with its rows apart the compiler re-laid-out the
+        # whole pool around the scatter.)
+        slots = _blocked(*parts, bs).reshape((-1,) + pool.shape[1:])
+    else:
+        return scatter_kv_blocks(pool, *parts, block_ids, bs,
+                                 heads_first=layout == "heads_first")
+    # (packed and rows have shaped their slots above, before the ids are
+    # flattened, as those families' programs had it: for them this reshape
+    # moves nothing)
+    return pool.at[block_ids.reshape(-1)].set(
+        slots.reshape((-1,) + pool.shape[1:]).astype(pool.dtype))
+
+
+def gather_prefix(spec: KVGroupSpec, pool, ids, dtype):
+    """The K and V of the slots ``ids`` [B, n], in order: [B, n * block, Hkv,
+    Dh] each, in ``dtype``.  (The latent and the selected kind are attended
+    over where they lie: their readers are further down.)"""
+    layout = spec.layout
+    pre = jnp.take(pool, ids, axis=0)  # [B, n, *slot]
+    if layout == "packed":
+        B, n, bs, Hkv, two = pre.shape
+        pre = pre.reshape(B, n * bs, Hkv, two)
+        return (pre[..., :two // 2].astype(dtype),
+                pre[..., two // 2:].astype(dtype))
+    if layout == "rows":
+        B, n, _, rows, Dh = pre.shape
+        Hkv = spec.num_kv_heads
+        pre = pre.transpose(0, 2, 1, 3, 4).reshape(
+            B, 2, n * rows // Hkv, Hkv, Dh)
+    elif layout == "heads_first":
+        B, n, _, Hkv, bs, Dh = pre.shape
+        pre = pre.transpose(0, 2, 1, 4, 3, 5).reshape(B, 2, n * bs, Hkv, Dh)
+    elif layout == "plain":
+        B, n, _, bs, Hkv, Dh = pre.shape
+        pre = pre.transpose(0, 2, 1, 3, 4, 5).reshape(B, 2, n * bs, Hkv, Dh)
+    else:
+        raise ValueError(f"a {layout} slot is not gathered as K and V")
+    return pre[:, 0].astype(dtype), pre[:, 1].astype(dtype)
+
+
+def _patched_slots(spec: KVGroupSpec, pool, ids, at, *parts):
+    """The slots ``ids`` [B] of ``pool`` with position ``at[b]`` of slot b
+    replaced by ``parts[b]``: what ``write_token`` puts back."""
+    bs, layout = spec.block_size, spec.layout
+    if layout in ("plain", "heads_first"):
+        k, v = parts
+        new = jnp.stack((k, v), axis=1)  # [B, 2, Hkv, Dh]
+        slots = jnp.take(pool, ids, axis=0)  # [B, 2, Hkv, block, Dh]
+        here = jnp.arange(bs)[None, :] == at[:, None]  # [B, block]
+        if layout == "plain":  # [B, 2, block, Hkv, Dh]
+            return jnp.where(here[:, None, :, None, None],
+                             new[:, :, None].astype(pool.dtype), slots)
+        return jnp.where(here[:, None, None, :, None],
+                         new[:, :, :, None, :].astype(pool.dtype), slots)
+    if layout == "packed":
+        k, v = parts
+        slots = jnp.take(pool, ids, axis=0)  # [B, block, Hkv, 2 Dh]
+        new = jnp.concatenate((k, v), axis=-1).astype(pool.dtype)
+        here = jnp.arange(bs)[None, :] == at[:, None]  # [B, block]
+        return jnp.where(here[:, :, None, None], new[:, None], slots)
+    if layout == "rows":
+        k, v = parts
+        slots = jnp.take(pool, ids, axis=0)  # [B, 2, block * Hkv, Dh]
+        Hkv = spec.num_kv_heads
+        new = jnp.tile(jnp.stack((k, v), axis=1).astype(pool.dtype),
+                       (1, 1, bs, 1))  # row r: head r % Hkv
+        here = jnp.arange(bs * Hkv)[None, :] // Hkv == at[:, None]
+        return jnp.where(here[:, None, :, None], new, slots)
+    if layout == "latent":
+        # position p of a block is the first half of row p if p is in the
+        # block's first half, else the second half, mirrored, of row
+        # p - block/2 (`pack_latent_blocks`)
+        (new,), value_dim = parts, spec.value_dim
+        half, width = bs // 2, 2 * spec.latent_dim
+        slots = jnp.take(pool, ids, axis=0)  # [B, block/2, 2 latent]
+        new = new.astype(pool.dtype)
+        second = (at >= half)[:, None]
+        zeros = jnp.zeros_like(new)
+        row = jnp.where(
+            second,
+            jnp.concatenate((zeros, new[:, value_dim:], new[:, :value_dim]), -1),
+            jnp.concatenate((new, zeros), -1))  # [B, 2 latent]
+        lanes = (jnp.arange(width)[None, :] >= width // 2) == second
+        here = ((jnp.arange(half)[None, :] == (at % half)[:, None])[:, :, None]
+                & lanes[:, None, :])
+        return jnp.where(here, row[:, None, :], slots)
+    if layout == "selected":
+        k, v, ki = parts  # [B, Hkv, Dh] each and [B, dI]
+        slots = jnp.take(pool, ids, axis=0)  # [B, block + t, 2 Hkv, Dh]
+        here = jnp.arange(bs)[None, :] == at[:, None]  # [B, block]
+
+        def slot_of(k, v, ki):
+            """The slot of a block whose position p holds (k, v, ki)[:, p]."""
+            return pack_selected_blocks(k, v, ki, bs)[:, 0]
+
+        # the slot of a block that holds only this position, and where it is
+        one = slot_of(jnp.where(here[:, :, None, None], k[:, None], 0),
+                      jnp.where(here[:, :, None, None], v[:, None], 0),
+                      jnp.where(here[:, :, None], ki[:, None], 0))
+        mask = slot_of(*(jnp.broadcast_to(
+            here.reshape(here.shape + (1,) * (a.ndim - 1)),
+            here.shape + a.shape[1:]) for a in (k, v, ki)))
+        return jnp.where(mask, one.astype(pool.dtype), slots)
+    raise ValueError(f"a {layout} slot holds no position's K/V")
+
+
+def write_token(spec: KVGroupSpec, pool, ids, at, *parts):
+    """Position ``at[b]`` of slot ``ids[b]`` = ``parts[b]`` for each sequence
+    of a decode step (``parts``: K and V [B, Hkv, Dh] each; the latent
+    [B, latent_dim]; K, V and the selector key [B, dI]), as whole slots: each
+    sequence's current slot is read, patched at its position and put back by
+    one slice update along the pool's first axis.  (A scatter or a slice
+    update that addresses the position axis makes the compiler re-lay-out the
+    whole pool around it, twice a layer.)  Idle rows share one scratch slot;
+    what they leave there is read by nobody."""
+    slots = _patched_slots(spec, pool, ids, at, *parts)
+
+    def one(b, pool):
+        return lax.dynamic_update_slice(
+            pool, lax.dynamic_slice_in_dim(slots, b, 1, axis=0),
+            (ids[b],) + (0,) * (pool.ndim - 1))
+
+    return lax.fori_loop(0, ids.shape[0], one, pool)
+
+
+def decode_view(spec: KVGroupSpec, pool, kernel: bool):
+    """What a decode step's attention is told of the layout: (the pool as the
+    reader takes it, the keywords that name the layout to it), the reader
+    being ``paged_decode_attention_pallas`` (``kernel``) or the XLA gather
+    ``paged_attention``.  Which a step takes is the family's to say."""
+    layout = spec.layout
+    if layout == "rows":
+        # rows apart, [.., block, Hkv, Dh]: the kernel merges them again, and
+        # the two reshapes together move nothing
+        return pool.reshape(pool.shape[:2] + (
+            spec.block_size, spec.num_kv_heads, spec.head_dim)), {}
+    if layout == "packed" and not kernel:  # the gather reads K and V apart
+        Dh = spec.head_dim
+        return jnp.stack((pool[..., :Dh], pool[..., Dh:]), axis=1), {}
+    if layout == "latent" and kernel:
+        return pool, {"latent": spec.value_dim}
+    if layout in ("plain", "heads_first", "packed"):
+        return pool, {} if layout == "plain" else {layout: True}
+    raise ValueError(f"{layout} slots have no such reader")
+
+
+def gather_selector_keys(spec: KVGroupSpec, pool, table):
+    """The selector keys of the positions ``table`` ([B, n]) names, in order:
+    [B, n * block, dI].  Only the key tiles of the table's slots are read, of
+    the pool with a tile a row (merging two leading axes moves nothing)."""
+    per, bs = spec.slot_tiles, spec.block_size
+    at = table[..., None] * per + bs + jnp.arange(per - bs)
+    tiles = pool.reshape((-1,) + pool.shape[2:])
+    return unpack_selector_keys(jnp.take(tiles, at, axis=0),
+                                spec.selector_dim)
+
+
+def gather_picked_tiles(spec: KVGroupSpec, pool, tiles):
+    """The K and V ([B, K, Hkv, Dh] each) of the positions whose tiles
+    ``tiles`` [B, K] names, counted a tile a row (slot * ``slot_tiles`` +
+    position)."""
+    rows = jnp.take(pool.reshape((-1,) + pool.shape[2:]), tiles, axis=0)
+    return rows[:, :, :spec.num_kv_heads], rows[:, :, spec.num_kv_heads:]
 
 
 @dataclass

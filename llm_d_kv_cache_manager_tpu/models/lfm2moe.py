@@ -64,14 +64,13 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from llm_d_kv_cache_manager_tpu.models import moe_serve
-from llm_d_kv_cache_manager_tpu.models.afmoe import (
-    _rms_norm,
-    _rope,
-    _swiglu,
-    prefill_attention,
+from llm_d_kv_cache_manager_tpu.models import layers, moe_serve
+from llm_d_kv_cache_manager_tpu.models.kv_cache_pool import (
+    KVGroupSpec, decode_view, gather_prefix, write_blocks, write_token,
 )
-from llm_d_kv_cache_manager_tpu.models.kv_cache_pool import KVGroupSpec
+from llm_d_kv_cache_manager_tpu.models.layers import (
+    embed, prefill_attention, rms_norm, rope, swiglu,
+)
 from llm_d_kv_cache_manager_tpu.ops import paged_decode_pallas
 from llm_d_kv_cache_manager_tpu.ops.paged_attention import paged_attention
 from llm_d_kv_cache_manager_tpu.ops.paged_decode_pallas import (
@@ -156,14 +155,8 @@ def new_pool(cfg: Lfm2MoeConfig, pool_blocks: int) -> dict:
     """The pod's pools as a pytree: one array a layer, each updated in
     place.  (A step hands them back with one more leaf, `load`, the expert
     layers' counts of that step, as `models/afmoe.py` does.)"""
-    sizes = {"full": pool_blocks, "state": cfg.state_slots}
-    return {
-        kind: [
-            jnp.zeros(spec.layer_shape(sizes[kind]), jnp.dtype(spec.dtype))
-            for _ in range(spec.num_layers)
-        ]
-        for kind, spec in cache_groups(cfg).items()
-    }
+    return layers.new_pool(
+        cache_groups(cfg), {"full": pool_blocks, "state": cfg.state_slots})
 
 
 def from_published(cfg: dict, block_size: int) -> Lfm2MoeConfig:
@@ -265,17 +258,9 @@ def init_params(rng: jax.Array, cfg: Lfm2MoeConfig) -> Params:
 # ------------------------------------------------------------ the model step
 
 
-def _embed(params, tokens):
-    """The residual stream is float32 from here to the head: matrix products
-    take their operands in the serving type, what they add to the stream is
-    not rounded again (as in models/afmoe.py, and for its reason: the router
-    reads the stream's own float32 norm)."""
-    return jnp.take(params["embed"], tokens, axis=0).astype(jnp.float32)
-
-
 def _logits(x, params, cfg):
     """Final norm and the head, which is the embedding; float32 logits."""
-    x = _rms_norm(x, params["ln_f"], cfg.rms_eps, params["embed"].dtype)
+    x = rms_norm(x, params["ln_f"], cfg.rms_eps, params["embed"].dtype)
     return jnp.einsum(
         "...d,vd->...v", x, params["embed"],
         preferred_element_type=jnp.float32,
@@ -289,9 +274,9 @@ def _qkv(h, lp, positions, cfg):
     q = jnp.einsum("btd,dhk->bthk", h, lp["wq"], preferred_element_type=f32)
     k = jnp.einsum("btd,dhk->bthk", h, lp["wk"], preferred_element_type=f32)
     v = jnp.einsum("btd,dhk->bthk", h, lp["wv"], preferred_element_type=f32)
-    q = _rope(_rms_norm(q, lp["q_norm"], cfg.rms_eps), positions,
+    q = rope(rms_norm(q, lp["q_norm"], cfg.rms_eps), positions,
               cfg.rope_theta)
-    k = _rope(_rms_norm(k, lp["k_norm"], cfg.rms_eps), positions,
+    k = rope(rms_norm(k, lp["k_norm"], cfg.rms_eps), positions,
               cfg.rope_theta)
     return q, k.astype(h.dtype), v.astype(h.dtype)
 
@@ -301,53 +286,17 @@ def _attn_out(attn, lp):
                       preferred_element_type=jnp.float32)
 
 
-def _scatter_blocks(pool, k, v, block_ids, bs):
-    """k, v: [B, T, Hkv, Dh], T whole blocks, into the packed slots
-    [block, Hkv, 2 Dh] that ``block_ids`` [B, T/block] names: a reshape, no
-    transpose."""
-    Hkv, two = pool.shape[-2:]
-    kv = jnp.concatenate((k, v), axis=-1).reshape(-1, bs, Hkv, two)
-    return pool.at[block_ids.reshape(-1)].set(kv.astype(pool.dtype))
-
-
-def _gather_prefix(pool, ids, dtype):
-    """The K and V of the slots `ids` [B, n], in order: [B, n*block, ...]."""
-    pre = jnp.take(pool, ids, axis=0)  # [B, n, block, Hkv, 2 Dh]
-    B, n, bs, Hkv, two = pre.shape
-    pre = pre.reshape(B, n * bs, Hkv, two)
-    return (pre[..., :two // 2].astype(dtype),
-            pre[..., two // 2:].astype(dtype))
-
-
-def _write_token(pool, ids, at, k, v):
-    """pool[ids[b], at[b]] = (k[b], v[b]) for each sequence, as whole slots
-    put back by one slice update along the pool's first axis
-    (`afmoe._write_token`, for packed slots)."""
-    slots = jnp.take(pool, ids, axis=0)  # [B, block, Hkv, 2 Dh]
-    new = jnp.concatenate((k, v), axis=-1).astype(pool.dtype)  # [B, Hkv, 2 Dh]
-    here = jnp.arange(pool.shape[1])[None, :] == at[:, None]  # [B, block]
-    slots = jnp.where(here[:, :, None, None], new[:, None], slots)
-
-    def one(b, pool):
-        return lax.dynamic_update_slice(
-            pool, lax.dynamic_slice_in_dim(slots, b, 1, axis=0),
-            (ids[b], 0, 0, 0))
-
-    return lax.fori_loop(0, ids.shape[0], one, pool)
-
-
-def _decode_attention(q, pool, table, context_len, interpret, plan):
+def _decode_attention(spec, q, pool, table, context_len, interpret, plan):
     """The paged kernel where it serves (compiled for the TPU, or
     interpreted; `plan`: its `shared_prefix_plan` of this table); elsewhere
-    the XLA gather over the slots unpacked."""
+    the XLA gather."""
+    pool, layout = decode_view(spec, pool, kernel=plan is not None)
     if plan is not None:
         return paged_decode_attention_pallas(
-            q, pool, table, context_len, packed=True, interpret=interpret,
-            plan=plan,
+            q, pool, table, context_len, interpret=interpret, plan=plan,
+            **layout,
         )
-    Dh = q.shape[-1]
-    unpacked = jnp.stack((pool[..., :Dh], pool[..., Dh:]), axis=1)
-    return paged_attention(q, unpacked, table, context_len)
+    return paged_attention(q, pool, table, context_len, **layout)
 
 
 def _conv_in(h, lp):
@@ -404,9 +353,9 @@ def _moe(h, lp, cfg):
 def _ff_block(x, lp, cfg):
     """a -> a + FF(RMSNorm_ff(a)), and the expert layer's load (None on a
     dense layer)."""
-    h = _rms_norm(x, lp["ln_ff"], cfg.rms_eps)
+    h = rms_norm(x, lp["ln_ff"], cfg.rms_eps)
     if "mlp" in lp:
-        return x + _swiglu(h.astype(lp["mlp"]["w_up"].dtype), lp["mlp"]), None
+        return x + swiglu(h.astype(lp["mlp"]["w_up"].dtype), lp["mlp"]), None
     y, sizes = _moe(h, lp, cfg)
     return x + y, jnp.stack((jnp.sum(sizes > 0), jnp.max(sizes)))
 
@@ -424,33 +373,36 @@ def _prefill(params, tokens, pools, tables, prefix_len, cfg, interpret):
     if prefix_len % bs or S % bs:
         raise ValueError("a prefill's prefix and tokens must be whole blocks")
     npre, nsuf = prefix_len // bs, S // bs
-    kept = cache_groups(cfg)["state"].snapshot_blocks(npre, nsuf)
+    specs = cache_groups(cfg)
+    kept = specs["state"].snapshot_blocks(npre, nsuf)
     ends = [(i - npre + 1) * bs - 1 for i in kept]
     positions = jnp.broadcast_to(prefix_len + jnp.arange(S), (B, S))
-    x = _embed(params, tokens)
+    x = embed(params, tokens)
     full, state, loads = list(pools["full"]), list(pools["state"]), []
     for l, lp in enumerate(params["layers"]):
         kind, i = cfg.slot_of_layer(l)
-        h = _rms_norm(x, lp["ln_op"], cfg.rms_eps, lp["ln_op"].dtype)
+        h = rms_norm(x, lp["ln_op"], cfg.rms_eps, lp["ln_op"].dtype)
         if kind == "state":
             before = (jnp.take(state[i], tables["state_read"], axis=0)
                       if npre else
-                      jnp.zeros((B,) + state[i].shape[1:], state[i].dtype))
+                      jnp.zeros((B, cfg.conv_taps - 1, cfg.d_model),
+                                state[i].dtype))
             y, state[i] = _conv_prefill(h, lp, before, ends, state[i],
                                         tables["state_write"])
         else:
             q, k, v = _qkv(h, lp, positions, cfg)
             keys, values = k, v
             if npre:
-                pre_k, pre_v = _gather_prefix(
-                    full[i], tables["full"][:, :npre], k.dtype)
+                pre_k, pre_v = gather_prefix(
+                    specs["full"], full[i], tables["full"][:, :npre], k.dtype)
                 keys = jnp.concatenate((pre_k, k), axis=1)
                 values = jnp.concatenate((pre_v, v), axis=1)
             attn = prefill_attention(q, keys, values, cfg, prefix_len, None,
                                       interpret)
             y = _attn_out(attn, lp)
-            full[i] = _scatter_blocks(
-                full[i], k, v, tables["full"][:, npre:npre + nsuf], bs)
+            full[i] = write_blocks(
+                specs["full"], full[i], tables["full"][:, npre:npre + nsuf],
+                k, v)
         x, load = _ff_block(x + y, lp, cfg)
         if load is not None:
             loads.append(load)
@@ -522,10 +474,11 @@ def decode_step(
     bs = cfg.block_size
     pos = context_len - 1
     read, write = tables["state"][:, 0], tables["state"][:, 1]
-    x = _embed(params, tokens)[:, None]  # [B, 1, D]
+    x = embed(params, tokens)[:, None]  # [B, 1, D]
     at = pos % bs
     full_id = jnp.take_along_axis(
         tables["full"], (pos // bs)[:, None], axis=1)[:, 0]
+    spec = cache_groups(cfg)["full"]
     full, state, loads = list(pools["full"]), list(pools["state"]), []
     # Which sequences' tables begin with the same blocks, once for the
     # attention layers: all see this table.
@@ -536,7 +489,7 @@ def decode_step(
             blocks_per_wave=paged_decode_pallas.walk_wave(full[0]))
     for l, lp in enumerate(params["layers"]):
         kind, i = cfg.slot_of_layer(l)
-        h = _rms_norm(x, lp["ln_op"], cfg.rms_eps, lp["ln_op"].dtype)
+        h = rms_norm(x, lp["ln_op"], cfg.rms_eps, lp["ln_op"].dtype)
         if kind == "state":
             z, gate = _conv_in(h, lp)
             old = jnp.take(state[i], read, axis=0)  # [B, taps - 1, D]
@@ -547,8 +500,9 @@ def decode_step(
             state[i] = state[i].at[write].set(new)
         else:
             q, k, v = _qkv(h, lp, pos[:, None], cfg)
-            full[i] = _write_token(full[i], full_id, at, k[:, 0], v[:, 0])
-            attn = _decode_attention(q[:, 0], full[i], tables["full"],
+            full[i] = write_token(spec, full[i], full_id, at, k[:, 0],
+                                  v[:, 0])
+            attn = _decode_attention(spec, q[:, 0], full[i], tables["full"],
                                      context_len, interpret, plan)
             y = _attn_out(attn[:, None], lp)
         x, load = _ff_block(x + y, lp, cfg)

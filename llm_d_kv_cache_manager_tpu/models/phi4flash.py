@@ -72,7 +72,7 @@ below and the benchmark's do none of this):
   the convolution of a prefill and the conv state a decode step reads hold the
   same values.  ``s`` is float32 everywhere and never rounded; ``c``, ``D_t``,
   ``m`` are float32 and rounded only as operands of the next matrix product.
-  The residual stream is float32 (as in models/afmoe.py).
+  The residual stream is float32 (models/layers.py's ``embed``).
 - *Stacked layers.*  The (Mamba, window) pairs and the (memory unit, cross)
   pairs are each one ``lax.scan`` over stacked weights (compile time), the
   pools their carry; a group's pool is one array over all its layers, layer i's
@@ -104,11 +104,12 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from llm_d_kv_cache_manager_tpu.models.afmoe import (
-    dense_attention,
-    prefill_attention,
+from llm_d_kv_cache_manager_tpu.models.kv_cache_pool import (
+    KVGroupSpec, decode_view, gather_prefix, write_blocks, write_token,
 )
-from llm_d_kv_cache_manager_tpu.models.kv_cache_pool import KVGroupSpec
+from llm_d_kv_cache_manager_tpu.models.layers import (
+    dense_attention, embed, prefill_attention,
+)
 from llm_d_kv_cache_manager_tpu.ops import paged_decode_pallas
 from llm_d_kv_cache_manager_tpu.ops.paged_attention import paged_attention
 from llm_d_kv_cache_manager_tpu.ops.paged_decode_pallas import (
@@ -367,11 +368,6 @@ def _layer_norm(x, p, eps, dtype=None):
             ).astype(dtype or x.dtype)
 
 
-def _embed(params, tokens):
-    """The residual stream is float32 from here to the head."""
-    return jnp.take(params["embed"], tokens, axis=0).astype(jnp.float32)
-
-
 def _logits(x, params, cfg):
     """Final LayerNorm and the head, which is the embedding; float32 logits."""
     x = _layer_norm(x, params["ln_f"], cfg.ln_eps, params["embed"].dtype)
@@ -479,7 +475,7 @@ def _keep_state(conv_pool, ssm_pool, up, ends, kept, write, cfg):
     at = (np.asarray(kept)[:, None] + 1) * bs + np.arange(taps - 1)[None, :]
     snap = up[:, at]  # [B, n, taps - 1, Di]: the inputs up to each block's end
     conv_pool = conv_pool.at[write.reshape(-1)].set(
-        snap.reshape(-1, conv_pool.shape[1]).astype(conv_pool.dtype))
+        snap.reshape(-1, (taps - 1) * cfg.d_inner).astype(conv_pool.dtype))
     s = ends[:, np.asarray(kept)]
     ssm_pool = ssm_pool.at[write.reshape(-1)].set(
         s.reshape((-1,) + s.shape[2:]).astype(ssm_pool.dtype))
@@ -559,62 +555,20 @@ def _attn_out(o, lp, lam0):
                       preferred_element_type=f32) + lp["bo"].astype(f32)
 
 
-def _scatter_blocks(pool, k, v, block_ids, bs):
-    """k, v: [B, T, Hkv, Dh], T whole blocks, into the slots [2, block * Hkv,
-    Dh] (``KVGroupSpec.rows``) that ``block_ids`` [B, T/block] names:
-    `scatter_kv_blocks`' write with a block's positions and heads as rows.
-    (Through a view of the pool with its rows apart the compiler re-laid-out
-    the whole pool around the scatter.)"""
-    B, T, Hkv, Dh = k.shape
-    kv = jnp.stack((k, v), axis=2).reshape(B, T // bs, bs, 2, Hkv, Dh)
-    kv = kv.transpose(0, 1, 3, 2, 4, 5).reshape(-1, 2, bs * Hkv, Dh)
-    return pool.at[block_ids.reshape(-1)].set(kv.astype(pool.dtype))
-
-
-def _gather_prefix(pool, ids, Hkv, dtype):
-    """The K and V of the slots `ids` [B, n], in order: [B, n * block, ...]."""
-    pre = jnp.take(pool, ids, axis=0)  # [B, n, 2, block * Hkv, Dh]
-    B, n, _, rows, Dh = pre.shape
-    pre = pre.transpose(0, 2, 1, 3, 4).reshape(B, 2, n * rows // Hkv, Hkv, Dh)
-    return pre[:, 0].astype(dtype), pre[:, 1].astype(dtype)
-
-
-def _write_token(pool, ids, at, k, v):
-    """pool[ids[b], :, rows of position at[b]] = (k[b], v[b]) for each
-    sequence, as whole slots put back by one slice update along the pool's
-    first axis (`afmoe._write_token`, for slots [2, block * Hkv, Dh])."""
-    slots = jnp.take(pool, ids, axis=0)  # [B, 2, block * Hkv, Dh]
-    Hkv = k.shape[1]
-    bs = pool.shape[2] // Hkv
-    new = jnp.tile(jnp.stack((k, v), axis=1).astype(pool.dtype),
-                   (1, 1, bs, 1))  # row r: head r % Hkv
-    here = jnp.arange(pool.shape[2])[None, :] // Hkv == at[:, None]
-    slots = jnp.where(here[:, None, :, None], new, slots)
-
-    def one(b, pool):
-        return lax.dynamic_update_slice(
-            pool, lax.dynamic_slice_in_dim(slots, b, 1, axis=0),
-            (ids[b], 0, 0, 0))
-
-    return lax.fori_loop(0, ids.shape[0], one, pool)
-
-
-def _decode_attention(q, pool, table, context_len, start, interpret, plan):
+def _decode_attention(spec, q, pool, table, context_len, start, interpret,
+                      plan):
     """The paged kernel where it serves (compiled for the TPU, or
     interpreted; `plan`: its `shared_prefix_plan` of the full group's table,
     None on a window layer, whose `start` hides what lies before the
-    window); elsewhere the XLA gather.  Both are handed the pool with its
-    rows apart, [.., block, Hkv, Dh]: the kernel merges them again, and the
-    two reshapes together move nothing."""
-    Hkv = q.shape[1] // 4  # four query heads a pair-wise KV head
-    pool = pool.reshape(pool.shape[:2] + (pool.shape[2] // Hkv, Hkv)
-                        + pool.shape[3:])
-    if paged_decode_pallas.serves(interpret):
+    window); elsewhere the XLA gather."""
+    kernel = paged_decode_pallas.serves(interpret)
+    pool, layout = decode_view(spec, pool, kernel)
+    if kernel:
         return paged_decode_attention_pallas(
             q, pool, table, context_len, start=start,
             blocks_per_step=DECODE_BLOCKS_PER_STEP, interpret=interpret,
-            plan=plan)
-    return paged_attention(q, pool, table, context_len, start=start)
+            plan=plan, **layout)
+    return paged_attention(q, pool, table, context_len, start=start, **layout)
 
 
 def _stacked(tree, i):
@@ -627,7 +581,8 @@ def _prefill(params, tokens, pools, tables, prefix_len, cfg, interpret):
     if prefix_len % bs or S % bs:
         raise ValueError("a prefill's prefix and tokens must be whole blocks")
     npre, nsuf = prefix_len // bs, S // bs
-    kept = cache_groups(cfg)["state"].snapshot_blocks(npre, nsuf)
+    specs = cache_groups(cfg)
+    kept = specs["state"].snapshot_blocks(npre, nsuf)
     kept = [i - npre for i in kept]
     W, Sl = cfg.window_slots, cfg.state_slots
     # window slots: of the prefix's last blocks a window layer still sees,
@@ -643,25 +598,25 @@ def _prefill(params, tokens, pools, tables, prefix_len, cfg, interpret):
             s0 = jnp.take(ssm, base + tables["state_read"], axis=0)
         else:
             conv0 = jnp.zeros((B, cfg.d_conv - 1, cfg.d_inner), conv.dtype)
-            s0 = jnp.zeros((B,) + ssm.shape[1:], ssm.dtype)
+            s0 = jnp.zeros((B, cfg.d_state, cfg.d_inner), ssm.dtype)
         y, m, up, ends = _mamba_prefill(h, lp, conv0, s0, cfg)
         conv, ssm = _keep_state(conv, ssm, up, ends, kept,
                                 base + tables["state_write"], cfg)
         return _ff(x + y, lp, cfg), m, conv, ssm
 
-    def attention(x, lp, lam0, pool, pre_ids, write_ids, window):
+    def attention(x, lp, lam0, spec, pool, pre_ids, write_ids, window):
         h = _mix_in(x, lp, cfg)
         q = _queries(h, lp, cfg)
         k, v = _keys_values(h, lp, cfg)
         keys, values = k, v
         if pre_ids.shape[1]:
-            pre_k, pre_v = _gather_prefix(pool, pre_ids, k.shape[2], k.dtype)
+            pre_k, pre_v = gather_prefix(spec, pool, pre_ids, k.dtype)
             keys = jnp.concatenate((pre_k, k), axis=1)
             values = jnp.concatenate((pre_v, v), axis=1)
         attn = prefill_attention(q, keys, values, cfg, keys.shape[1] - S,
                                   window, interpret)
         n = write_ids.shape[1] * bs
-        pool = _scatter_blocks(pool, k[:, S - n:], v[:, S - n:], write_ids, bs)
+        pool = write_blocks(spec, pool, write_ids, k[:, S - n:], v[:, S - n:])
         return _ff(x + _attn_out(attn, lp, lam0), lp, cfg), pool, keys, values
 
     def front(carry, xs):
@@ -669,11 +624,12 @@ def _prefill(params, tokens, pools, tables, prefix_len, cfg, interpret):
         lp, i, lam0 = xs
         x, _, conv, ssm = mamba(x, lp["a"], conv, ssm, i * Sl)
         x, win, _, _ = attention(
-            x, lp["b"], lam0, win, i * W + tables["window"][:, :nwin],
+            x, lp["b"], lam0, specs["window"], win,
+            i * W + tables["window"][:, :nwin],
             i * W + tables["window"][:, nwin:], cfg.window)
         return (x, win, conv, ssm), None
 
-    x = _embed(params, tokens)
+    x = embed(params, tokens)
     n = cfg.n_front
     (x, win, conv, ssm), _ = lax.scan(
         front, (x, win, conv, ssm),
@@ -681,7 +637,7 @@ def _prefill(params, tokens, pools, tables, prefix_len, cfg, interpret):
          jnp.asarray(cfg.lam0(2 * np.arange(n) + 1))))
     x, m, conv, ssm = mamba(x, params["mid"]["a"], conv, ssm, n * Sl)
     x, full, keys, values = attention(
-        x, params["mid"]["b"], cfg.lam0(2 * n + 1), full,
+        x, params["mid"]["b"], cfg.lam0(2 * n + 1), specs["full"], full,
         tables["full"][:, :npre], tables["full"][:, npre:npre + nsuf], None)
     # the lower decoder: the last position alone (the module's head)
     x, m = x[:, -1:], m[:, -1:]
@@ -784,6 +740,7 @@ def decode_step(
     win_start = jnp.maximum(context_len - cfg.window, 0) - first
     read, write = tables["state"][:, 0], tables["state"][:, 1]
     (full,), (win,), (conv, ssm) = pools["full"], pools["window"], pools["state"]
+    specs = cache_groups(cfg)
     plan = None
     if paged_decode_pallas.serves(interpret):
         plan = paged_decode_pallas.shared_prefix_plan(
@@ -801,14 +758,16 @@ def decode_step(
         x, _, conv, ssm = mamba(x, lp["a"], conv, ssm, i * Sl)
         h = _mix_in(x, lp["b"], cfg)
         k, v = _keys_values(h, lp["b"], cfg)
-        win = _write_token(win, i * W + win_id, at, k[:, 0], v[:, 0])
-        attn = _decode_attention(_queries(h, lp["b"], cfg)[:, 0], win,
+        win = write_token(specs["window"], win, i * W + win_id, at, k[:, 0],
+                          v[:, 0])
+        attn = _decode_attention(specs["window"],
+                                 _queries(h, lp["b"], cfg)[:, 0], win,
                                  i * W + tables["window"], win_ctx, win_start,
                                  interpret, None)
         x = _ff(x + _attn_out(attn[:, None], lp["b"], lam0), lp["b"], cfg)
         return (x, win, conv, ssm), None
 
-    x = _embed(params, tokens)[:, None]  # [B, 1, D]
+    x = embed(params, tokens)[:, None]  # [B, 1, D]
     n = cfg.n_front
     (x, win, conv, ssm), _ = lax.scan(
         front, (x, win, conv, ssm),
@@ -818,11 +777,12 @@ def decode_step(
     lp = params["mid"]["b"]
     h = _mix_in(x, lp, cfg)
     k, v = _keys_values(h, lp, cfg)
-    full = _write_token(full, full_id, at, k[:, 0], v[:, 0])
+    full = write_token(specs["full"], full, full_id, at, k[:, 0], v[:, 0])
 
     def over_full(x, h, lp, lam0):
-        attn = _decode_attention(_queries(h, lp, cfg)[:, 0], full, tables["full"],
-                                 context_len, None, interpret, plan)
+        attn = _decode_attention(specs["full"], _queries(h, lp, cfg)[:, 0],
+                                 full, tables["full"], context_len, None,
+                                 interpret, plan)
         return _ff(x + _attn_out(attn[:, None], lp, lam0), lp, cfg)
 
     x = over_full(x, h, lp, cfg.lam0(2 * n + 1))

@@ -29,7 +29,9 @@ from llm_d_kv_cache_manager_tpu.kvevents.events import (
     BlockRemoved, BlockStored, EventBatch,
 )
 from llm_d_kv_cache_manager_tpu.kvevents.pool import Message, Pool, PoolConfig
-from llm_d_kv_cache_manager_tpu.models import afmoe, kv_cache_pool, llama
+from llm_d_kv_cache_manager_tpu.models import (
+    afmoe, kv_cache_pool, layers, llama,
+)
 from llm_d_kv_cache_manager_tpu.models.pod import Pod, jit_programs
 from llm_d_kv_cache_manager_tpu.obs.trace import TRACER
 from llm_d_kv_cache_manager_tpu.ops import flash_pallas
@@ -382,7 +384,7 @@ def test_flash_kernel_with_a_window_is_the_masked_product(window, q_offset):
         q, k, v, q_offset=q_offset, q_block=16, kv_chunk=16, window=window,
         interpret=True)
     close(np.asarray(got),
-          np.asarray(afmoe.dense_attention(q, k, v, q_offset, window)), 1e-5)
+          np.asarray(layers.dense_attention(q, k, v, q_offset, window)), 1e-5)
 
 
 def test_flash_kernel_without_a_window_lowers_as_it_did():

@@ -380,19 +380,20 @@ def test_block_bytes_and_pool_shapes_come_from_the_latent_spec():
 
 
 def test_a_decode_write_lands_where_the_scatter_would_put_it():
-    """`_write_token` into a row's first or second half, mirrored, is what
-    `scatter_latent_blocks` writes for that position; nothing else moves."""
-    value, width = CFG.kv_rank, CFG.latent_dim
+    """`write_token` into a row's first or second half, mirrored, is what
+    `write_blocks` writes for that position; nothing else moves."""
+    width = CFG.latent_dim
     rng = np.random.default_rng(2)
     latents = jnp.asarray(rng.normal(size=(1, 2 * BLOCK, width)), jnp.float32)
     pool = jnp.zeros((4, BLOCK // 2, 2 * width), jnp.float32)
-    want = kv_cache_pool.scatter_latent_blocks(
-        pool, latents, jnp.asarray([[3, 1]]), BLOCK, value)
+    spec = glm4moelite.cache_groups(CFG)["full"]
+    want = kv_cache_pool.write_blocks(spec, pool, jnp.asarray([[3, 1]]),
+                                      latents)
     got = pool
     for pos in range(2 * BLOCK):
-        got = glm4moelite._write_token(
-            got, jnp.asarray([[3, 1][pos // BLOCK]]),
-            jnp.asarray([pos % BLOCK]), latents[:, pos], value)
+        got = kv_cache_pool.write_token(
+            spec, got, jnp.asarray([[3, 1][pos // BLOCK]]),
+            jnp.asarray([pos % BLOCK]), latents[:, pos])
     np.testing.assert_array_equal(got, want)
 
 

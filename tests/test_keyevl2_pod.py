@@ -558,21 +558,21 @@ def test_a_uniform_pool_and_the_offload_spec_price_both_parts(tmp_path):
 
 
 def test_a_decode_write_lands_where_the_scatter_would_put_it():
-    """`_write_token` patches one position's tile and its selector key's
-    lanes; over a whole block it is what `scatter_selected_blocks` writes."""
+    """`write_token` patches one position's tile and its selector key's
+    lanes; over a whole block it is what `write_blocks` writes."""
     rng = np.random.default_rng(2)
     k, v = (jnp.asarray(rng.normal(size=(1, 2 * BLOCK, 2, 16)), jnp.float32)
             for _ in range(2))
     ki = jnp.asarray(rng.normal(size=(1, 2 * BLOCK, 8)), jnp.float32)
     pool = jnp.zeros((4, BLOCK + 2, 4, 16), jnp.float32)
-    want = kv_cache_pool.scatter_selected_blocks(
-        pool, k, v, ki, jnp.asarray([[3, 1]]), BLOCK)
+    spec = keyevl2.cache_groups(CFG)["full"]
+    want = kv_cache_pool.write_blocks(spec, pool, jnp.asarray([[3, 1]]),
+                                      k, v, ki)
     got = pool
     for pos in range(2 * BLOCK):
-        got = keyevl2._write_token(
-            got, jnp.asarray([[3, 1][pos // BLOCK]]),
-            jnp.asarray([pos % BLOCK]), k[:, pos], v[:, pos], ki[:, pos],
-            BLOCK)
+        got = kv_cache_pool.write_token(
+            spec, got, jnp.asarray([[3, 1][pos // BLOCK]]),
+            jnp.asarray([pos % BLOCK]), k[:, pos], v[:, pos], ki[:, pos])
     np.testing.assert_array_equal(got, want)
     np.testing.assert_array_equal(
         kv_cache_pool.unpack_selector_keys(want[jnp.asarray([3, 1]), BLOCK:],

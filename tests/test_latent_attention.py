@@ -14,8 +14,8 @@ from llm_d_kv_cache_manager_tpu.models.kv_cache_pool import (
     KVCachePoolConfig,
     KVGroupSpec,
     pack_latent_blocks,
-    scatter_latent_blocks,
     unpack_latent_blocks,
+    write_blocks,
 )
 from llm_d_kv_cache_manager_tpu.offload.spec import TPUOffloadSpec
 from llm_d_kv_cache_manager_tpu.ops.latent_prefill_pallas import (
@@ -82,7 +82,7 @@ def test_scatter_writes_only_the_named_slots():
     pool = jnp.full(s.layer_shape(6), 7, jnp.bfloat16)
     x = jax.random.normal(jax.random.PRNGKey(0), (1, 2 * BS, W)
                           ).astype(jnp.bfloat16)
-    out = scatter_latent_blocks(pool, x, jnp.asarray([[4, 1]]), BS, VALUE)
+    out = write_blocks(s, pool, jnp.asarray([[4, 1]]), x)
     got = unpack_latent_blocks(out, VALUE)
     np.testing.assert_array_equal(got[4 * BS:5 * BS], x[0, :BS])
     np.testing.assert_array_equal(got[BS:2 * BS], x[0, BS:])

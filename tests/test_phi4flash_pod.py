@@ -21,7 +21,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from llm_d_kv_cache_manager_tpu.models import afmoe, phi4flash
+from llm_d_kv_cache_manager_tpu.models import afmoe, layers, phi4flash
 from llm_d_kv_cache_manager_tpu.models.pod import Pod, jit_programs
 from llm_d_kv_cache_manager_tpu.obs.trace import TRACER
 
@@ -276,7 +276,7 @@ def test_pairwise_heads_are_the_four_products_as_written():
     h = jax.random.normal(jax.random.key(4), (1, T, cfg.d_model), jnp.float32)
     lam0 = float(cfg.lam0(5))
     k, v = phi4flash._keys_values(h, lp, cfg)
-    attn = afmoe.dense_attention(phi4flash._queries(h, lp, cfg), k, v, 0, None)
+    attn = layers.dense_attention(phi4flash._queries(h, lp, cfg), k, v, 0, None)
     got = np.asarray(phi4flash._attn_out(attn, lp, lam0))[0]
 
     hp = jax.lax.Precision.HIGHEST

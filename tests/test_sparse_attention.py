@@ -15,6 +15,7 @@ from llm_d_kv_cache_manager_tpu.models import kv_cache_pool as kp
 from llm_d_kv_cache_manager_tpu.ops import sparse_attention_pallas as sparse
 
 H, HKV, DH, BS, DI, N = 4, 2, 16, 16, 8, 12
+SPEC = kp.KVGroupSpec(1, BS, HKV, DH, "float32", selector_dim=DI)
 
 
 def normal(rng, *shape):
@@ -30,7 +31,7 @@ def pool_of(rng, batch, blocks):
     table = jnp.asarray(np.stack([
         b * N + rng.permutation(N)[:blocks] for b in range(batch)]), jnp.int32)
     pool = jnp.zeros((batch * N, BS + 2, 2 * HKV, DH), jnp.float32)
-    return kp.scatter_selected_blocks(pool, k, v, ki, table, BS), table, k, v, ki
+    return kp.write_blocks(SPEC, pool, table, k, v, ki), table, k, v, ki
 
 
 def dense(q, k, v, picked):
@@ -97,8 +98,8 @@ def test_decode_scores_walk_the_tables_selector_keys(wave):
                             rng.permutation(np.arange(34, 40))[:5]]])
     ki = normal(rng, 3, blocks * BS, DI)
     zeros = jnp.zeros((3, blocks * BS, HKV, DH))
-    pool = kp.scatter_selected_blocks(pool, zeros, zeros, ki,
-                                      jnp.asarray(table, jnp.int32), BS)
+    pool = kp.write_blocks(SPEC, pool, jnp.asarray(table, jnp.int32), zeros,
+                           zeros, ki)
     q, w = normal(rng, 3, HI, DI), normal(rng, 3, HI)
     ctx = jnp.asarray([blocks * BS, 70, 5])
     got = sparse.sparse_decode_scores_pallas(

@@ -21,7 +21,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from llm_d_kv_cache_manager_tpu.models import (
-    afmoe, glm4moelite, keyevl2, lfm2moe, llama, phi4flash,
+    afmoe, glm4moelite, keyevl2, kv_cache_pool, lfm2moe, llama, phi4flash,
 )
 from llm_d_kv_cache_manager_tpu.models import pod as pod_programs
 from llm_d_kv_cache_manager_tpu.ops import flash_pallas
@@ -577,7 +577,7 @@ def test_a_selected_slot_lies_in_the_pool_as_it_is_written(one_chip):
 
     def step(pool, ids, new, tiles):
         pool = pool.at[ids].set(new)
-        return pool, jnp.take(keyevl2._tiles(pool), tiles, axis=0)
+        return pool, kv_cache_pool.gather_picked_tiles(spec, pool, tiles)
 
     compiled = jax.jit(step, donate_argnums=(0,)).trace(*args).lower(
         lowering_platforms=("tpu",)).compile()
