@@ -46,7 +46,20 @@ def route(h, router, bias, top_k: int, norm: bool, scale: float,
     return picked, w * scale
 
 
-def routed_experts(h, picked, w, experts, n_experts: int, batched: bool):
+def _hidden(rows, experts, product):
+    """An expert's hidden values, float32: ``silu(gate) * up`` where the
+    family's experts have a gate matrix, ``relu(up)^2`` where they have none
+    (two matrices an expert: models/nemotronh.py).  ``product(rows, w)`` is
+    the form's product with a stack of expert matrices."""
+    if "w_gate" not in experts:
+        return jnp.square(jax.nn.relu(product(rows, experts["w_up"])))
+    gate = product(rows, experts["w_gate"])
+    up = product(rows, experts["w_up"])
+    return jax.nn.silu(gate) * up
+
+
+def routed_experts(h, picked, w, experts, n_experts: int, batched: bool,
+                   held: tuple | None = None):
     """Sum over each token's picked experts of w_e Expert_e(h), without a
     capacity; h [N, D] in the serving type; returns ([N, D] float32, picks
     per expert).  Two ways, the caller's choice (each family's rule is read
@@ -58,31 +71,51 @@ def routed_experts(h, picked, w, experts, n_experts: int, batched: bool):
       experts a seed's router favours; it reads every expert's weights;
     - else (a prefill): the N*k picks are sorted by expert and each expert
       multiplies its own rows (`lax.ragged_dot`).
-    """
+
+    ``held=(first, count)``: this chip holds the experts ``first .. first +
+    count - 1`` of the ``n_experts`` the router scored (``experts`` stacks
+    those ``count``; the others lie on the chips that share the layer).  The
+    sum is over the picks that fall in the range, the part of the layer's
+    result that the held experts give: batched, the weight matrix has the
+    held columns only; sorted, the picks outside sort behind the held
+    experts' and are not multiplied.  The counts are then ``count + 1``: the
+    picks of each held expert, and last the picks that fell outside.  No
+    exchange and nothing that stands in for the other chips.  With ``held``
+    None every expert is here and the operations are what they were."""
     N, k = picked.shape
     f32 = jnp.float32
+    n_held = n_experts
+    if held is not None:
+        first, n_held = held
+        inside = (picked >= first) & (picked < first + n_held)
+        # an outside pick takes the id behind the last held expert
+        picked = jnp.where(inside, picked - first, n_held)
+        w = jnp.where(inside, w, 0.0)
     flat = picked.reshape(-1)
-    sizes = jnp.bincount(flat, length=n_experts).astype(jnp.int32)
+    sizes = jnp.bincount(
+        flat, length=n_held + (held is not None)).astype(jnp.int32)
     if batched:
-        weight = jnp.zeros((N, n_experts), f32).at[
-            jnp.arange(N)[:, None], picked].add(w)
-        gate = jnp.einsum("nd,edf->enf", h, experts["w_gate"],
-                          preferred_element_type=f32)
-        up = jnp.einsum("nd,edf->enf", h, experts["w_up"],
-                        preferred_element_type=f32)
-        hidden = jax.nn.silu(gate) * up * weight.T[:, :, None]
+        weight = jnp.zeros((N, n_held), f32).at[
+            jnp.arange(N)[:, None], picked].add(
+                w, **({} if held is None else {"mode": "drop"}))
+        hidden = _hidden(h, experts, lambda x, m: jnp.einsum(
+            "nd,edf->enf", x, m, preferred_element_type=f32)
+        ) * weight.T[:, :, None]
         out = jnp.einsum("enf,efd->nd", hidden.astype(h.dtype),
                          experts["w_down"], preferred_element_type=f32)
         return out, sizes
     order = jnp.argsort(flat)
     rows = jnp.take(h, order // k, axis=0)  # [N*k, D], grouped by expert
-    gate = lax.ragged_dot(rows, experts["w_gate"], sizes,
-                          preferred_element_type=f32)
-    up = lax.ragged_dot(rows, experts["w_up"], sizes,
-                        preferred_element_type=f32)
-    hidden = (jax.nn.silu(gate) * up).astype(h.dtype)
-    out = lax.ragged_dot(hidden, experts["w_down"], sizes,
+    # with a share, the rows behind the held experts' belong to no group of
+    # the products, and their weight is zero
+    groups = sizes if held is None else sizes[:n_held]
+    hidden = _hidden(rows, experts, lambda x, m: lax.ragged_dot(
+        x, m, groups, preferred_element_type=f32)).astype(h.dtype)
+    out = lax.ragged_dot(hidden, experts["w_down"], groups,
                          preferred_element_type=f32)
+    if held is not None:
+        out = jnp.where((jnp.arange(N * k) < N * k - sizes[n_held])[:, None],
+                        out, 0.0)
     out = out * jnp.take(w.reshape(-1), order)[:, None]
     back = jnp.zeros_like(order).at[order].set(jnp.arange(N * k))
     return jnp.take(out, back, axis=0).reshape(N, k, -1).sum(axis=1), sizes

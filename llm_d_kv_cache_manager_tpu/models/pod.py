@@ -182,8 +182,10 @@ from llm_d_kv_cache_manager_tpu.obs.trace import root_trace, span
 
 RESERVED = np.iinfo(np.int64).max  # a slot taken and not yet in any window
 # What a decode step may hand back beside its pools: counts made on the device
-# ("load": a row an expert layer; "attention_read": blocks read, blocks walked,
-# blocks the walk brought by runs).
+# ("load": a row an expert layer, the held experts with a pick and the most
+# picks of one, then, where a chip holds a share of the layer's experts, all
+# picks and those that fell on a held one; "attention_read": blocks read,
+# blocks walked, blocks the walk brought by runs).
 COUNTED = ("load", "attention_read")
 
 
@@ -920,14 +922,19 @@ class Pod:
                 s.set_attr("read_blocks", int(read))
                 s.set_attr("walked_blocks", int(walked))
                 s.set_attr("run_blocks", int(by_runs))
-        for layer, (touched, most) in enumerate(counted.get("load", ())):
+        held = getattr(model, "experts_held", None)  # a chip's share of them
+        for layer, (touched, most, *picks) in enumerate(
+                counted.get("load", ())):
             with span("moe.expert_load") as s:
                 s.set_attr("layer", layer)
-                s.set_attr("experts_held", model.n_experts)
+                s.set_attr("experts_held", held or model.n_experts)
                 s.set_attr("experts_touched", int(touched))
                 s.set_attr("max_tokens", int(most))
                 s.set_attr("mean_tokens",
                            tokens * model.top_k / model.n_experts)
+                if picks:  # the router's picks, and those that fell here
+                    s.set_attr("picks", int(picks[0]))
+                    s.set_attr("picks_held", int(picks[1]))
 
     def keep_load(self, counted: dict, tokens: int) -> None:
         """A traced decode step's device counts, kept for the next call's

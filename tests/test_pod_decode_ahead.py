@@ -5,7 +5,9 @@ through one schedule of decode calls, finishes and admissions, as the
 benchmark's `run_chat` makes them, and have to serve the same.
 
 The families at the small sizes of their own tests: `afmoe` (a window group),
-`lfm2moe` (a state group), `phi4flash` (both), float32, kernels interpreted.
+`lfm2moe` (a state group), `phi4flash` (both), `nemotronh` (a state group
+whose slot is a matrix a head, advanced a sequence at a time where it lies in
+the pool), float32, kernels interpreted.
 """
 
 from __future__ import annotations
@@ -19,7 +21,9 @@ import pytest
 
 from benchmarks import run
 from benchmarks.harness import program_spans
-from llm_d_kv_cache_manager_tpu.models import afmoe, lfm2moe, phi4flash
+from llm_d_kv_cache_manager_tpu.models import (
+    afmoe, lfm2moe, nemotronh, phi4flash,
+)
 from llm_d_kv_cache_manager_tpu.models.pod import Pod, jit_programs
 from llm_d_kv_cache_manager_tpu.obs.trace import TRACER
 
@@ -37,6 +41,10 @@ def family(name: str, slots: int):
     if name == "state":
         return lfm2moe, lfm2moe.Lfm2MoeConfig(
             dtype="float32", vocab_size=VOCAB, layer_types=(C, A, C, C),
+            state_slots=slots, state_stride_blocks=2)
+    if name == "matrix":  # a state group whose slot is a matrix a head
+        return nemotronh, nemotronh.NemotronHConfig(
+            dtype="float32", vocab_size=VOCAB, pattern="MEM*E", held=(0, 4),
             state_slots=slots, state_stride_blocks=2)
     return phi4flash, phi4flash.Phi4FlashConfig(
         dtype="float32", vocab_size=VOCAB, window=32, window_slots=slots,
@@ -243,7 +251,7 @@ def launches(seen: dict) -> tuple[list, list]:
                     if n == "pod.launch.decode"] for c in seen["spans"]]
 
 
-@pytest.mark.parametrize("name", ("window", "state", "both"))
+@pytest.mark.parametrize("name", ("window", "state", "both", "matrix"))
 def test_a_pod_that_launches_ahead_serves_what_one_that_does_not(
         name, monkeypatch):
     """Slots to spare in the groups, a full group that evicts: tokens,
@@ -370,18 +378,21 @@ def test_decode_ahead_stands_beside_a_window_or_a_state_group(
 
 
 def test_the_families_that_say_the_key():
-    """`phi4flash` and `keyevl2` (long generations, long contexts: few
-    events a step); the 64-slot chat families do not (models/pod.py)."""
+    """`phi4flash`, `nemotronh` and `keyevl2` (long generations, long
+    contexts: few events a step); the 64-slot chat families do not
+    (models/pod.py)."""
     from llm_d_kv_cache_manager_tpu.models import glm4moelite, keyevl2
 
     says = {m.__name__.rsplit(".", 1)[1]: bool(
         m.cache_policy(c).get("decode_ahead")) for m, c in (
         (phi4flash, phi4flash.Phi4FlashConfig()),
+        (nemotronh, nemotronh.NemotronHConfig()),
         (keyevl2, keyevl2.KeyeVl2Config()),
         (afmoe, afmoe.AfmoeConfig()),
         (lfm2moe, lfm2moe.Lfm2MoeConfig()),
         (glm4moelite, glm4moelite.Glm4MoeLiteConfig()))}
-    assert says == {"phi4flash": True, "keyevl2": True, "afmoe": False,
+    assert says == {"phi4flash": True, "nemotronh": True, "keyevl2": True,
+                    "afmoe": False,
                     "lfm2moe": False, "glm4moelite": False}
 
 

@@ -21,10 +21,11 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from llm_d_kv_cache_manager_tpu.models import (
-    afmoe, glm4moelite, keyevl2, kv_cache_pool, lfm2moe, llama, phi4flash,
+    afmoe, glm4moelite, keyevl2, kv_cache_pool, lfm2moe, llama, nemotronh,
+    phi4flash,
 )
 from llm_d_kv_cache_manager_tpu.models import pod as pod_programs
-from llm_d_kv_cache_manager_tpu.ops import flash_pallas
+from llm_d_kv_cache_manager_tpu.ops import flash_pallas, ssd_pallas
 from llm_d_kv_cache_manager_tpu.ops import sparse_attention_pallas as sparse
 from llm_d_kv_cache_manager_tpu.ops.latent_prefill_pallas import (
     latent_prefill_attention_pallas,
@@ -113,6 +114,10 @@ WALKED = {  # name: sequences, table columns, query heads, the pool, packed
     # tile) and handed over with them apart, as `phi4flash._decode_attention`
     "phi-4-mini-flash-reasoning": (64, 416, (40, DH),
                                    (24576, 2, BLOCK * 10, DH), False),
+    # the full group of `nemotron3nano-agents-reasoning`, two KV heads of 16
+    # query heads each, a block's rows merged as `phi4flash`'s
+    "nemotron-3-nano-30b-a3b-l9": (128, 736, (32, DH),
+                                   (32768, 2, BLOCK * 2, DH), False),
 }
 
 
@@ -664,6 +669,127 @@ def test_keyevl2_programs_compile_at_the_cells_shapes(one_chip, monkeypatch,
                      for a in jax.tree.leaves(pools))
     assert pool_bytes == KEYE_POOL_BLOCKS * 139264
     assert memory.alias_size_in_bytes >= pool_bytes  # the pool handed back
+    assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes
+            + memory.output_size_in_bytes - memory.alias_size_in_bytes
+            ) < HBM_BYTES
+
+
+# -------------- the nemotronh programs: a state group heavier than the K/V pool
+
+# benchmarks/configs/nemotron-3-nano-30b-a3b-l9.json and
+# benchmarks/traffic/agents-reasoning.json
+NEMO = nemotronh.NemotronHConfig(
+    vocab_size=65536, d_model=2688, pattern="MEMEM*EME", n_heads=32,
+    n_kv_heads=2, head_dim=128, mamba_heads=64, mamba_head_dim=64, n_groups=8,
+    d_state=128, d_conv=4, chunk=128, d_expert=1856, d_shared=3712,
+    n_experts=128, held=(0, 64), top_k=6, state_slots=452,
+    state_stride_blocks=64)
+NEMO_SHAPES = {"miss": (8704,), "hit": (8192, 512), "decode": (128,),
+               "max_blocks": 736}
+NEMO_POOL_BLOCKS = 32768
+# Temporaries beside 6.33 GB of weights and 4.63 GB of pools (4.10 of them
+# the state group's): compiled here they read 2.06 / 0.18 / 0.04 GB (a miss
+# holds 8704 positions' projections of 10 304 lanes; a decode step that
+# gathered its 128 sequences' states, 268 MB a Mamba-2 layer, read 1.65 GB:
+# it advances them where they lie, a sequence at a time).
+NEMO_TEMP_LIMIT = {"miss": 2.4e9, "hit": 0.3e9, "decode": 0.2e9}
+# kernels by the names the trace reduction finds them under: a call of the
+# chunk scan a kept boundary a Mamba-2 layer (a miss keeps nine, a hit one)
+NEMO_KERNELS = {
+    "miss": {"flash_gqa_attention_pallas": 1, "ssd_chunk_scan_pallas": 36},
+    "hit": {"flash_gqa_attention_pallas": 1, "ssd_chunk_scan_pallas": 4},
+    "decode": {"paged_decode_attention_pallas": 2},
+}
+
+
+@pytest.mark.parametrize("tokens", (512, 1024))
+def test_ssd_kernel_compiles_at_the_served_shapes(one_chip, tokens):
+    """The chunk scan of a hit's suffix and of a miss's stretch between two
+    kept boundaries: a group's eight heads a grid step, x in bfloat16 with
+    time in the lanes, the state float32 and resident over the chunks."""
+    bf16, f32 = jnp.bfloat16, jnp.float32
+    compiled = compile_for(
+        one_chip, functools.partial(ssd_pallas.ssd_chunk_scan_pallas,
+                                    chunk=128),
+        ((1, tokens, 64, 64), bf16), ((1, tokens, 64), f32), ((64,), f32),
+        ((1, tokens, 8, 128), bf16), ((1, tokens, 8, 128), bf16),
+        ((1, 64, 64, 128), f32))
+    assert re.findall(r"%(ssd_chunk_scan_pallas)\S* = .*tpu_custom_call",
+                      compiled.as_text())
+
+
+def test_flash_kernel_compiles_at_two_kv_heads(one_chip):
+    """A hit prefill's 512 queries over 8704 keys, 16 query heads a KV head."""
+    bf16 = jnp.bfloat16
+    compile_for(one_chip, functools.partial(
+        flash_pallas.flash_gqa_attention_pallas, q_offset=8192, window=None),
+        ((1, 512, 32, DH), bf16), ((1, 8704, 2, DH), bf16),
+        ((1, 8704, 2, DH), bf16))
+
+
+@pytest.mark.parametrize("key", ("miss", "hit", "decode"))
+def test_nemotronh_programs_compile_at_the_cells_shapes(one_chip, monkeypatch,
+                                                        key):
+    """The cell `nemotron3nano-agents-reasoning`'s three programs
+    (`miss_prefill_T8704`, `hit_prefill_P8192_S512`, `decode_B128`) as
+    `models/pod.py` jits them, both groups' pools donated: they compile for
+    the v5e (the chunk scan, the flash kernel and the paged kernel with its
+    shared pass at two KV heads), fit the chip beside the weights, hand the
+    pools back where they lie, and the state group's arrays lie as they are
+    written: the arguments weigh what the arrays weigh (a state [64, 64,
+    128] float32 is whole tiles, a row of 3 x 6144 conv inputs whole lanes)
+    and no instruction copies or re-lays-out one around a write."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    def spec(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+
+    params = jax.tree.map(spec, jax.eval_shape(
+        lambda: nemotronh.init_params(jax.random.key(0), NEMO)))
+    pools = jax.tree.map(spec, jax.eval_shape(
+        lambda: nemotronh.new_pool(NEMO, NEMO_POOL_BLOCKS)))
+    policy = nemotronh.cache_policy(NEMO)
+
+    class Shapes:  # what `example_args` reads of a pod
+        window, decode_ahead = None, policy["decode_ahead"]
+
+        class state:
+            spec = policy["specs"]["state"]
+
+    first, second = jax.tree.map(
+        spec, pod_programs.example_args(key, NEMO_SHAPES, Shapes, BLOCK))
+    if key == "decode":
+        served, ints = first
+        assert Shapes.decode_ahead and served.shape == (2, 128)
+        assert ints.shape == (128, 2 + 2) and second.shape == (128, 736)
+    else:
+        assert first.shape == (1, NEMO_SHAPES[key][-1])
+        assert second["state_write"].shape == (1, 9 if key == "miss" else 1)
+    program = pod_programs.inner_programs(nemotronh, NEMO, NEMO_SHAPES,
+                                          False)[key]
+    assert program.__name__ == {
+        "miss": "miss_prefill_T8704", "hit": "hit_prefill_P8192_S512",
+        "decode": "decode_B128"}[key]
+    compiled = program.trace(params, first, pools, second).lower(
+        lowering_platforms=("tpu",)).compile()
+    hlo = compiled.as_text()
+    kernels = re.findall(r"%(\w+_pallas)\S* = .*tpu_custom_call", hlo)
+    assert {k: kernels.count(k) for k in set(kernels)} == NEMO_KERNELS[key]
+    sizes = {a.shape[0] for a in jax.tree.leaves(pools)}
+    assert sizes == {NEMO_POOL_BLOCKS, 452}
+    for n in sizes:
+        assert not re.search(rf"= \w+\[{n},[\d,]*\]\S* copy\(", hlo), n
+    memory = compiled.memory_analysis()
+    held = sum(a.dtype.itemsize * math.prod(a.shape)
+               for a in jax.tree.leaves((params, pools)))
+    pool_bytes = sum(a.dtype.itemsize * math.prod(a.shape)
+                     for a in jax.tree.leaves(pools))
+    assert pool_bytes == 452 * 8536064 + NEMO_POOL_BLOCKS * 16384
+    # the arguments as they lie on the chip: the arrays' own bytes and the
+    # call's integers, nothing padded to a tile
+    assert held <= memory.argument_size_in_bytes < held + (4 << 20)
+    assert memory.alias_size_in_bytes >= pool_bytes  # the pools handed back
+    assert memory.temp_size_in_bytes < NEMO_TEMP_LIMIT[key]
     assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes
             + memory.output_size_in_bytes - memory.alias_size_in_bytes
             ) < HBM_BYTES
