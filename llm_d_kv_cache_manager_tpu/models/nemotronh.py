@@ -60,8 +60,10 @@ below and the benchmark's do none of this):
   position whose state the pod keeps (``KVGroupSpec.snapshot_blocks``), so
   the state there is a call's result and nothing of size T x H x P x N is
   made; what of a call is no whole chunk (a suffix that ends inside one) runs
-  through the one-position recurrence.  A decode step is that recurrence, in
-  XLA.
+  through the one-position recurrence.  A decode step is that recurrence
+  on the slots its tables name, where they lie in the pool
+  (``ssd_decode_step_pallas``; in XLA, on the gathered slots, where no TPU
+  compiles the kernel).
 - *Rounding.*  ``u`` is rounded once, to the serving type, where it is made:
   the convolution of a prefill and the conv state a decode step reads hold
   the same values.  ``S`` is float32 everywhere and never rounded; ``c``,
@@ -445,39 +447,29 @@ def _mamba_prefill(h, lp, conv0, s0, ends, cfg, interpret):
             snap.reshape(snap.shape[:2] + (-1,)), states)
 
 
-def _mamba_decode(h, lp, conv_pool, ssm_pool, read, write, cfg):
+def _mamba_decode(h, lp, conv_pool, ssm_pool, read, write, cfg, interpret):
     """One position of a Mamba-2 layer for each sequence: the state of slot
     `read` advanced by one input into slot `write`.  h: [B, 1, D].  Returns
     (the mixer's output [B, 1, D], the pools).
 
-    The matrices go a sequence at a time, each read where it lies in the
-    pool, advanced and put back by one slice update, as `write_token` puts a
-    K/V slot back: a slot is 2 MB here, and gathered for all sequences at
-    once, advanced and scattered back they crossed memory five times (the
-    gather's result zeroed, written, read; the new states written, read and
-    scattered: 3.4 ms a layer of a 25-ms step at 128 sequences, the loop 1.1
-    of a 17-ms one; my chip runs, PR 49)."""
+    The matrices are advanced where they lie in the pool
+    (`ssd_pallas.ssd_decode_step_pallas`: a slot is 2 MB here and crosses
+    memory once in and once out, the next sequence's on its way while one is
+    advanced) where the paged kernel serves too; elsewhere the plain form,
+    the step on the gathered slots and a scatter back."""
     z, u, dt = _mamba_in(h, lp, cfg)
     B, taps = u.shape[0], cfg.d_conv
     old = jnp.take(conv_pool, read, axis=0).reshape(B, taps - 1, -1)
     x, bm, cm, d = _mamba_ssm_in(
         [old[:, j:j + 1] for j in range(taps - 1)] + [u], dt, lp, cfg)
     a = -jnp.exp(lp["a_log"].astype(jnp.float32))
-
-    def one(b, carry):
-        pool, ys = carry
-        x_b, d_b, b_b, c_b = (  # this sequence's x, d, B, C
-            lax.dynamic_slice_in_dim(v, b, 1, axis=0)[:, 0]
-            for v in (x, d, bm, cm))
-        s, y = ssd_pallas.ssd_step(
-            lax.dynamic_slice_in_dim(pool, read[b], 1, axis=0), x_b, d_b, a,
-            b_b, c_b)
-        return (lax.dynamic_update_slice_in_dim(pool, s, write[b], axis=0),
-                lax.dynamic_update_slice_in_dim(ys, y, b, axis=0))
-
-    ssm_pool, y = lax.fori_loop(
-        0, B, one, (ssm_pool, jnp.zeros(x.shape[:1] + x.shape[2:],
-                                        jnp.float32)))
+    step = (x[:, 0], d[:, 0], a, bm[:, 0], cm[:, 0])
+    if paged_decode_pallas.serves(interpret):
+        ssm_pool, y = ssd_pallas.ssd_decode_step_pallas(
+            ssm_pool, read, write, *step, interpret=interpret)
+    else:
+        s, y = ssd_pallas.ssd_step(jnp.take(ssm_pool, read, axis=0), *step)
+        ssm_pool = ssm_pool.at[write].set(s)
     conv_pool = conv_pool.at[write].set(jnp.concatenate(
         (old[:, 1:], u.astype(old.dtype)), axis=1).reshape(B, -1))
     return _mamba_out(y[:, None], x, z, lp, cfg), conv_pool, ssm_pool
@@ -690,7 +682,8 @@ def decode_step(
         h = h32.astype(lp["ln"].dtype)
         if kind == MAMBA:
             y, state[2 * i], state[2 * i + 1] = _mamba_decode(
-                h, lp, state[2 * i], state[2 * i + 1], read, write, cfg)
+                h, lp, state[2 * i], state[2 * i + 1], read, write, cfg,
+                interpret)
         elif kind == EXPERTS:
             y, load = _moe(h32, lp, cfg)
             loads.append(load)

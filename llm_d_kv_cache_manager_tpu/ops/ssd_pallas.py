@@ -1,6 +1,7 @@
 """Mamba-2's selective scan, chunk-wise (the state-space dual form of
 arXiv:2405.21060), as a Pallas TPU kernel, with the same products as
-``jax.numpy`` einsums and the recurrence they equal beside it.
+``jax.numpy`` einsums and the recurrence they equal beside it; and one
+position of that recurrence over the slots of a pool, as a second kernel.
 
 The recurrence, a head h of P lanes that reads group ``g = h // (H / G)`` of
 the input's G groups, its state ``S[h]`` a matrix [P, N] in float32:
@@ -36,6 +37,28 @@ operands in x's type with float32 sums, as the flash kernel's do; the state's
 two products (``S . C^T``, ``x^T . B``) are float32 whatever x is, so that a
 state carried over thousands of positions is rounded nowhere.
 
+``ssd_decode_step_pallas`` is a decode step: ``ssd_step`` for every sequence
+on the slot of a pool that a table names, written to the slot another table
+names, the pool aliased to its result.  A grid step is a sequence's whole
+slot, [H P, N] with N in the lanes as it lies (2 MB at the model's sizes),
+brought and written back by the pipeline through index maps that read the
+tables, so the state crosses memory once in and once out and nothing else a
+step reads is a fiftieth of it: ``d x`` and y are rows [.., H P / 128, 128], a
+head's decay is handed over along the 128 lanes, B and C are rows, and no
+operand has a minor dimension of 1 (padded 128-fold in memory, it held a
+first form of this kernel at 2.44 ms a layer; PERF.md, PR 49).  Inside, a
+block of 128 rows at a time, float32 on the VPU in ``ssd_step``'s order (on
+the chip the pool and y came out bit for bit what the loop over sequences it
+replaced gave): ``d x`` comes down the sublanes by a transpose of its row
+broadcast, and ``S . C`` is the product with C's row summed along the lanes.
+On the chip at the model's sizes (128 sequences of a pool of 452 slots, one
+layer; PERF.md, PR 51) it reads 0.856 ms alone and 0.823 in the cell's step,
+beside 0.843 for a copy through the same blocks (537 MB at 637 GB/s), where
+the loop read 1.56-1.74 alone and 1.07 + 0.32 of gaps in the step.  The same
+with y as a product on the MXU (``C . S^T``, ``HIGHEST``), the outer product
+as one (``x^T . B``) or y by a transpose and a sum down the sublanes read the
+same 0.86, both as products 1.52, and a group's 256 KB a grid step 1.03-1.14.
+
 ``ssd_chunk_scan`` is the same, chunk by chunk under a ``lax.scan``, for where
 no TPU compiles the kernel (the pairing ``paged_decode_pallas`` /
 ``paged_attention`` has); tests/test_ssd_scan.py holds the three to each
@@ -50,6 +73,7 @@ positions in nine calls 1.59 against 9.51.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -240,3 +264,112 @@ def ssd_chunk_scan_pallas(x, dt, a, b, c, s0, chunk: int,
     )
     return (jnp.transpose(y.reshape(B, H, P, T), (0, 3, 1, 2)),
             s.reshape(B, H, P, N))
+
+
+# ------------------------------------------------------------ a decode step
+
+LANES = 128  # a row block of the decode kernel: a transpose's square side
+BLOCKS_AN_ITERATION = 4
+# a 2-MB slot in and out, each twice for the pipeline, and a block's values
+SLOT_VMEM_BYTES = 48 * 1024 * 1024
+
+
+def _ssd_decode_kernel(read_ref, write_ref, dx_ref, decay_ref, b_ref, c_ref,
+                       s_ref, o_ref, y_ref, *, head_rows: int,
+                       group_rows: int):
+    """One sequence's slot, a row block at a time.  read_ref, write_ref: SMEM
+    [B] (scalar prefetch; the index maps read them); s_ref, o_ref: [1, H P,
+    N], a head's P rows after the last's; dx_ref, y_ref: [1, blocks, rows], a
+    block's ``d x`` and y along the lanes; decay_ref: [1, H, N], a head's
+    decay along its row; b_ref, c_ref: [1, G, N]."""
+    N = s_ref.shape[-1]
+    blocks, rows = dx_ref.shape[1:]
+    heads = rows // head_rows
+
+    def block(i):
+        at = pl.ds(pl.multiple_of(i * rows, rows), rows)
+        g = i * rows // group_rows
+        decay = jnp.concatenate(
+            [jnp.broadcast_to(decay_ref[0, pl.ds(i * heads + h, 1), :],
+                              (head_rows, N)) for h in range(heads)], axis=0)
+        # d x down the sublanes: its row, broadcast, turned
+        dx = jnp.broadcast_to(dx_ref[0, pl.ds(i, 1), :], (N, rows)).T
+        s = decay * s_ref[0, at, :] + dx * b_ref[0, pl.ds(g, 1), :]
+        o_ref[0, at, :] = s
+        y_ref[0, pl.ds(i, 1), :] = jnp.sum(
+            s * c_ref[0, pl.ds(g, 1), :], axis=1).reshape(1, rows)
+
+    # Some blocks an iteration, written out: the scheduler lays a block's
+    # loads and stores beside its neighbours' arithmetic.  A layer at the
+    # model's sizes, ms by blocks an iteration (PERF.md, PR 51): 1 1.166, 2
+    # 1.081, 4 0.856, 8 0.854, all 32 0.853, beside a copy's 0.843 through
+    # the same blocks; the kernel alone then takes 0.03 / 0.04 / 0.11 / 0.21 /
+    # 0.67 s to trace and lower, four is within half a per cent of the best,
+    # and set-up pays for every equation (tests/test_tpu_compile.py).
+    several = math.gcd(blocks, BLOCKS_AN_ITERATION)
+
+    def some(k, carry):
+        for j in range(several):
+            block(k * several + j)
+        return carry
+
+    lax.fori_loop(0, blocks // several, some, 0)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def ssd_decode_step_pallas(pool, read, write, x, dt, a, b, c,
+                           interpret: bool = False):
+    """`ssd_step` on the slots of a pool, where they lie: row i's state is
+    slot ``read[i]`` and goes, advanced, into slot ``write[i]``.  pool:
+    [slots, H, P, N] float32, aliased to the pool returned (a slot no row
+    writes is not touched); read, write: [B] int32; x: [B, H, P]; dt: [B, H]
+    float32; a: [H]; b, c: [B, G, N].  Returns (pool, y [B, H, P] float32).
+
+    The grid walks the sequences, and the state's index maps read the tables:
+    the pipeline brings row i + 1's slot while row i's is advanced and row i
+    - 1's written back.  So no row may write a slot that a row whose result
+    is used reads; a row may write the slot it reads itself.  models/pod.py
+    keeps that: under `decode_ahead` a sequence alternates between two slots
+    that are its own (`StateGroup._alternate`), without it a sequence inside
+    a block reads and writes its block's own.  The engine's idle rows share
+    one block, hence one pair of slots, whose content nobody reads."""
+    f32 = jnp.float32
+    slots, H, P, N = pool.shape
+    B, G = b.shape[:2]
+    group_rows = H // G * P
+    rows = math.gcd(group_rows, LANES)
+    if rows % P:
+        raise ValueError(f"heads of {P} rows fill no row block of {rows}")
+    blocks = H * P // rows
+    dx = (dt.astype(f32)[..., None] * x.astype(f32)).reshape(B, blocks, rows)
+    decay = jnp.broadcast_to(jnp.exp(dt * a)[..., None], (B, H, N))
+
+    def tile(block):
+        return pl.BlockSpec(block, lambda i, *_: (i, 0, 0),
+                            memory_space=pltpu.VMEM)
+
+    def slot(table):
+        return pl.BlockSpec(
+            (1, H * P, N), lambda i, *tables: (tables[table][i], 0, 0),
+            memory_space=pltpu.VMEM)
+
+    out, y = pl.pallas_call(
+        functools.partial(_ssd_decode_kernel, head_rows=P,
+                          group_rows=group_rows),
+        out_shape=(jax.ShapeDtypeStruct((slots, H * P, N), f32),
+                   jax.ShapeDtypeStruct((B, blocks, rows), f32)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(B,),
+            in_specs=[tile((1, blocks, rows)), tile((1, H, N)),
+                      tile((1, G, N)), tile((1, G, N)), slot(0)],
+            out_specs=(slot(1), tile((1, blocks, rows))),
+        ),
+        input_output_aliases={6: 0},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=SLOT_VMEM_BYTES),
+        interpret=interpret,
+    )(read, write, dx, decay, b.astype(f32), c.astype(f32),
+      pool.reshape(slots, H * P, N))
+    return out.reshape(pool.shape), y.reshape(B, H, P)

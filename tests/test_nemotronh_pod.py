@@ -440,6 +440,96 @@ def test_a_step_launched_ahead_and_not_taken_leaves_the_state_it_read():
     close(np.asarray(out)[1, 0], want.max())
 
 
+def test_no_decode_step_writes_a_slot_that_a_live_row_of_it_reads():
+    """What `ssd_decode_step_pallas` stands on: it brings row b + 1's slot
+    while row b's is advanced and row b - 1's written back, so no row of a
+    step may write what a live row of the same step reads.  Six decode slots
+    over shared prompts, requests ending and admitted for 300 steps, the
+    idle rows on the engine's scratch block, a call that goes on handing the
+    next its tables as `jit_programs.run_ahead` does: in every table a `Pod`
+    makes under `decode_ahead`, the step's own and the one launched ahead,
+    the live rows' read slots and all rows' written slots are disjoint, the
+    live rows write a slot each, and the idle rows share one pair."""
+
+    class Program:  # the cache's rules alone: no model, no pools
+        cache_policy = staticmethod(nemotronh.cache_policy)
+        new_pool = staticmethod(lambda cfg, blocks: {})
+
+    slots, columns = 6, 12
+    pod = Pod("pod-0", Program, dataclasses.replace(CFG, state_slots=34), 96)
+    assert pod.decode_ahead
+    rng = np.random.default_rng(50)
+    prompts = [tokens_of(64, 40 + i) for i in range(2)]
+    scratch = pod.alloc(1)[0][0]
+    pod.hold([scratch], +1)
+    table = np.full((slots, columns), scratch, np.int32)
+    ctx, live = np.ones(slots, np.int32), [None] * slots
+    seen = {"steps": 0, "idle": 0, "ahead": 0}
+
+    def admit(slot):
+        tokens = np.concatenate((prompts[rng.integers(2)],
+                                 tokens_of(16, 60 + int(rng.integers(1000)))))
+        hashes, n_out = hashes_of(tokens), int(rng.integers(3, 70))
+        cached = pod.cached_prefix(hashes[:4])
+        first = 4 if len(cached) == 4 else 0
+        pod.touch(hashes[:first])
+        pod.hold(cached[:first], +1)
+        new, _ = pod.alloc(len(hashes) - first)
+        pod.hold(cached[:first], -1)
+        blocks = cached[:first] + new
+        pod.hold(blocks, +1)
+        own, _ = pod.alloc(-(-n_out // BLOCK))
+        pod.hold(own, +1)
+        pod.tables("hit" if first else "miss",
+                   np.asarray(blocks, np.int32)[None], prefix_blocks=first)
+        for h, bid in zip(hashes[first:], blocks[first:]):
+            pod.cached[h] = bid
+        live[slot] = dict(blocks=blocks + own, own=own, left=n_out)
+        table[slot] = scratch
+        table[slot, :len(blocks + own)] = blocks + own
+        ctx[slot] = len(tokens) + 1
+
+    def check(pairs):
+        rows = np.asarray([r is not None for r in live])
+        reads, writes = pairs[rows, 0], pairs[:, 1]
+        assert not set(reads) & set(writes)
+        assert len(set(pairs[rows, 1])) == rows.sum()
+        assert len({tuple(p) for p in pairs[~rows]}) <= 1
+        seen["idle"] += int((~rows).sum())
+
+    went_on, handed = False, None
+    for step in range(300):
+        for slot in range(slots):  # an admission a step, some slots idle
+            if live[slot] is None and rng.random() < 0.3:
+                admit(slot)
+                went_on = False
+                break
+        if went_on:
+            own, handed = pod.tables(
+                "decode", table.copy(), context_len=ctx.copy(), made=handed,
+                ahead=np.minimum(ctx + 1, columns * BLOCK))
+            check(handed["state"])
+            seen["ahead"] += 1
+        else:
+            own, handed = pod.tables("decode", table.copy(),
+                                     context_len=ctx.copy()), None
+        check(own["state"])
+        seen["steps"] += 1
+        went_on = True
+        for slot, req in enumerate(live):
+            if req is None:
+                continue
+            ctx[slot] += 1
+            req["left"] -= 1
+            if req["left"] <= 0:
+                pod.hold(req["blocks"], -1)
+                pod.free.extend(req["own"])
+                table[slot], ctx[slot], live[slot] = scratch, 1, None
+                went_on = False
+    assert seen["steps"] == 300 and seen["idle"] > 100 and seen["ahead"] > 100
+    assert pod.state.counts["released"] > 0
+
+
 # ------------------------------------------------- the chip's share of experts
 
 

@@ -20,6 +20,7 @@ from llm_d_kv_cache_manager_tpu.ops.paged_decode_pallas import (
     paged_decode_attention_pallas,
     shared_prefix_plan,
 )
+from tests.helpers.jaxprs import equations
 from tests.test_latent_attention import SCALE, VALUE, W
 from tests.test_paged_decode_pallas import BS, LAYOUTS, close, shared_case
 
@@ -277,22 +278,6 @@ SERVED_HEADS = {"llama": (16, 8, 128), "packed": (32, 8, 64),
 # walk for them on purpose reads these anew, and measures their cells).
 TEXT_AT_PR_44 = {"llama": "696644170fc7344a", "packed": "708fa3ec9b129802",
                  "pairwise": "9616ef93b6d5ab9a", "latent": "b896062ff3f1ec4c"}
-
-
-def equations(jaxpr) -> int:
-    """The equations of a jaxpr, those of every jaxpr among their parameters
-    (a jit's, a kernel's body, a loop's, a `pl.when`'s branches) counted in."""
-    def inner(value):
-        if hasattr(value, "eqns"):
-            yield value
-        elif hasattr(value, "jaxpr"):
-            yield from inner(value.jaxpr)
-        elif isinstance(value, (tuple, list)):
-            for v in value:
-                yield from inner(v)
-
-    return sum(1 + sum(equations(j) for v in eqn.params.values()
-                       for j in inner(v)) for eqn in jaxpr.eqns)
 
 
 @pytest.mark.parametrize("slots", SERVED_HEADS)

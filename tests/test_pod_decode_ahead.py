@@ -6,8 +6,8 @@ benchmark's `run_chat` makes them, and have to serve the same.
 
 The families at the small sizes of their own tests: `afmoe` (a window group),
 `lfm2moe` (a state group), `phi4flash` (both), `nemotronh` (a state group
-whose slot is a matrix a head, advanced a sequence at a time where it lies in
-the pool), float32, kernels interpreted.
+whose slot is a matrix a head, advanced where it lies in the pool by
+`ssd_decode_step_pallas`), float32, kernels interpreted.
 """
 
 from __future__ import annotations

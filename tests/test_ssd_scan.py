@@ -1,6 +1,8 @@
 """Mamba-2's scan three ways (ops/ssd_pallas.py), at a small size on the CPU:
 the Pallas kernel (interpret mode) against the same products as einsums under
-a `lax.scan` over chunks, against the recurrence a position at a time.
+a `lax.scan` over chunks, against the recurrence a position at a time; and a
+decode step's kernel, which advances the slots of a pool where they lie,
+against the recurrence's one position on the gathered slots.
 
 In float32 the three are one set of equations in three orders of summation:
 they agree to 2e-5 of the largest value (the chunk form multiplies decays that
@@ -96,6 +98,53 @@ def test_a_step_is_the_recurrences_position():
     s1 = (np.exp(float(d[0, 0, h] * a[h])) * np.asarray(s0[0, h])
           + float(d[0, 0, h]) * np.outer(x[0, 0, h], b[0, 0, g]))
     close(s1 @ np.asarray(c[0, 0, g]), want_y[0, 0, h], 1e-5)
+
+
+TABLES = {  # a step's (read, written) slots of a pool of 12, a row each
+    # every row its own pair
+    "own pairs": ((7, 2), (0, 9), (5, 4), (3, 11), (10, 1)),
+    # the engine's idle rows share one block, hence one pair, whose content
+    # nobody reads; a live row between them
+    "idle rows on one pair": ((6, 8), (7, 2), (6, 8), (6, 8), (0, 9)),
+}
+
+
+@pytest.mark.parametrize("table", TABLES)
+@pytest.mark.parametrize("dtype", (jnp.float32, jnp.bfloat16),
+                         ids=("float32", "bfloat16"))
+def test_the_decode_kernel_is_a_step_on_the_slots_its_tables_name(table,
+                                                                  dtype):
+    """`ssd_decode_step_pallas` (interpreted) against `ssd_step` on the
+    gathered slots: the written slots and y to the step's own tolerance, the
+    slots no row writes (the read ones among them) bit for bit.  The state is
+    float32 whatever x, B and C are."""
+    pairs = np.asarray(TABLES[table], np.int32)
+    live = np.flatnonzero([(pairs == p).all(1).sum() == 1 for p in pairs])
+    B, H, P, G, N, slots = len(pairs), 4, 8, 2, 16, 12
+    x, d, a, b, c, _ = inputs(B, 1, H, P, G, N, seed=7, dtype=dtype)
+    step = (x[:, 0], d[:, 0], a, b[:, 0], c[:, 0])
+    pool = jax.random.normal(jax.random.key(8), (slots, H, P, N))
+    before = np.asarray(pool)
+    read, write = jnp.asarray(pairs[:, 0]), jnp.asarray(pairs[:, 1])
+    want_s, want_y = ssd_pallas.ssd_step(pool[read], *step)
+    got, y = ssd_pallas.ssd_decode_step_pallas(pool, read, write, *step,
+                                               interpret=True)
+    assert got.dtype == jnp.float32 and y.dtype == jnp.float32
+    assert got.shape == pool.shape and y.shape == (B, H, P)
+    close(y[live], want_y[live], 1e-6)
+    close(got[write[live]], want_s[live], 1e-6)
+    kept = np.setdiff1d(np.arange(slots), pairs[:, 1])
+    assert set(pairs[:, 0]) <= set(kept)
+    np.testing.assert_array_equal(np.asarray(got)[kept], before[kept])
+
+
+def test_the_decode_kernel_refuses_heads_that_fill_no_row_block():
+    x, d, a, b, c, _ = inputs(1, 1, 2, 24, 2, 16)
+    with pytest.raises(ValueError, match="row block"):
+        ssd_pallas.ssd_decode_step_pallas(
+            jnp.zeros((2, 2, 24, 16)), jnp.zeros(1, jnp.int32),
+            jnp.ones(1, jnp.int32), x[:, 0], d[:, 0], a, b[:, 0], c[:, 0],
+            interpret=True)
 
 
 @pytest.mark.parametrize("form", ("einsums", "kernel"))

@@ -33,6 +33,7 @@ from llm_d_kv_cache_manager_tpu.ops.latent_prefill_pallas import (
 from llm_d_kv_cache_manager_tpu.ops.paged_decode_pallas import (
     paged_decode_attention_pallas,
 )
+from tests.helpers.jaxprs import equations
 
 H, HKV, DH, BLOCK = 32, 4, 128, 16  # the afmoe cell's attention widths
 
@@ -691,14 +692,16 @@ NEMO_POOL_BLOCKS = 32768
 # the state group's): compiled here they read 2.06 / 0.18 / 0.04 GB (a miss
 # holds 8704 positions' projections of 10 304 lanes; a decode step that
 # gathered its 128 sequences' states, 268 MB a Mamba-2 layer, read 1.65 GB:
-# it advances them where they lie, a sequence at a time).
+# it advances them where they lie, `ssd_decode_step_pallas` on the pool
+# aliased to its result).
 NEMO_TEMP_LIMIT = {"miss": 2.4e9, "hit": 0.3e9, "decode": 0.2e9}
 # kernels by the names the trace reduction finds them under: a call of the
 # chunk scan a kept boundary a Mamba-2 layer (a miss keeps nine, a hit one)
 NEMO_KERNELS = {
     "miss": {"flash_gqa_attention_pallas": 1, "ssd_chunk_scan_pallas": 36},
     "hit": {"flash_gqa_attention_pallas": 1, "ssd_chunk_scan_pallas": 4},
-    "decode": {"paged_decode_attention_pallas": 2},
+    "decode": {"paged_decode_attention_pallas": 2,
+               "ssd_decode_step_pallas": 4},
 }
 
 
@@ -716,6 +719,43 @@ def test_ssd_kernel_compiles_at_the_served_shapes(one_chip, tokens):
         ((1, 64, 64, 128), f32))
     assert re.findall(r"%(ssd_chunk_scan_pallas)\S* = .*tpu_custom_call",
                       compiled.as_text())
+
+
+def test_ssd_decode_kernel_compiles_and_advances_the_pool_in_place(one_chip):
+    """A decode step's state update of one Mamba-2 layer at the cell's
+    sizes: 128 rows over a pool of 452 slots [64, 64, 128] float32 (0.95
+    GB), 8 groups.  One kernel under its own name, the pool donated and
+    handed back where it lies: no instruction copies it and nothing of its
+    size is a temporary.  And set-up pays for every equation of the traced
+    call: the grid walks the rows, so 8 rows trace to what 128 do, and a
+    slot's row blocks go a few an iteration of a rolled loop (179 equations
+    at four; all 32 written out would be 1327)."""
+    f32, i32 = jnp.float32, jnp.int32
+
+    def shapes(rows, slots=452):
+        return (((slots, 64, 64, 128), f32), ((rows,), i32), ((rows,), i32),
+                ((rows, 64, 64), f32), ((rows, 64), f32), ((64,), f32),
+                ((rows, 8, 128), f32), ((rows, 8, 128), f32))
+
+    args = [jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+            for shape, dtype in shapes(128)]
+    compiled = jax.jit(
+        ssd_pallas.ssd_decode_step_pallas, donate_argnums=(0,)).trace(
+            *args).lower(lowering_platforms=("tpu",)).compile()
+    hlo = compiled.as_text()
+    assert len(re.findall(
+        r"%(ssd_decode_step_pallas)\S* = .*tpu_custom_call", hlo)) == 1
+    assert not re.search(r"= \w+\[452,[\d,]*\]\S* copy\(", hlo)
+    memory = compiled.memory_analysis()
+    pool_bytes = 452 * 64 * 64 * 128 * 4
+    assert memory.alias_size_in_bytes >= pool_bytes
+    assert memory.temp_size_in_bytes < pool_bytes // 64
+    counts = {rows: equations(jax.make_jaxpr(
+        ssd_pallas.ssd_decode_step_pallas)(
+            *(jax.ShapeDtypeStruct(*s) for s in shapes(rows, 16))).jaxpr)
+        for rows in (8, 32, 128)}
+    assert len(set(counts.values())) == 1, counts
+    assert counts[128] <= 256, counts
 
 
 def test_flash_kernel_compiles_at_two_kv_heads(one_chip):
