@@ -530,9 +530,7 @@ def decode_step(
     logits, pools = _finish(x[:, 0], params, cfg, full, win, loads)
     if plans:  # what the walks read: a group's plan by the layers that read it
         pools["attention_read"] = sum(
-            len(pools[kind]) * jnp.stack(
-                (plan["read_blocks"], plan["walked_blocks"],
-                 plan["run_blocks"]))
+            len(pools[kind]) * paged_decode_pallas.attention_read_counts(plan)
             for kind, plan in plans.items())
     return logits, pools
 

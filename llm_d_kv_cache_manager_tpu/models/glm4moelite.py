@@ -504,7 +504,8 @@ def decode_step(
     # layers: every layer sees this table.
     plan = paged_decode_pallas.shared_prefix_plan(
         table, context_len, block_size=bs, min_sequences=SHARED_MIN_SEQUENCES,
-        blocks_per_wave=DECODE_BLOCKS_PER_WAVE)
+        blocks_per_wave=DECODE_BLOCKS_PER_WAVE,
+        shared_blocks_per_step=DECODE_BLOCKS_PER_WAVE)
     for l, lp in enumerate(params["layers"]):
         h = rms_norm(x, lp["ln_in"], cfg.rms_eps, lp["w_qa"].dtype)
         full[l] = write_token(
@@ -521,8 +522,7 @@ def decode_step(
         if load is not None:
             loads.append(load)
     logits, pools = _finish(x[:, 0], params, cfg, full, loads)
-    pools["attention_read"] = jnp.stack(
-        (plan["read_blocks"], plan["walked_blocks"], plan["run_blocks"]))
+    pools["attention_read"] = paged_decode_pallas.attention_read_counts(plan)
     return logits, pools
 
 

@@ -697,8 +697,8 @@ def decode_step(
         x = x + y
     logits, pools = _finish(x[:, 0], params, cfg, full, state, loads)
     if plan is not None:
-        pools["attention_read"] = jnp.stack(
-            (plan["read_blocks"], plan["walked_blocks"], plan["run_blocks"]))
+        pools["attention_read"] = (
+            paged_decode_pallas.attention_read_counts(plan))
     return logits, pools
 
 

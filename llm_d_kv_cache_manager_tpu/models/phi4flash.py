@@ -798,8 +798,8 @@ def decode_step(
          jnp.asarray(cfg.lam0(2 * n + 3 + 2 * np.arange(cfg.n_back)))))
     pools = {"full": [full], "window": [win], "state": [conv, ssm]}
     if plan is not None:
-        pools["attention_read"] = jnp.stack(
-            (plan["read_blocks"], plan["walked_blocks"], plan["run_blocks"]))
+        pools["attention_read"] = (
+            paged_decode_pallas.attention_read_counts(plan))
     return _logits(x[:, 0], params, cfg), pools
 
 

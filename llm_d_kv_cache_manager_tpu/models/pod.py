@@ -179,13 +179,15 @@ import jax.numpy as jnp
 import numpy as np
 
 from llm_d_kv_cache_manager_tpu.obs.trace import root_trace, span
+from llm_d_kv_cache_manager_tpu.ops.paged_decode_pallas import READ_COUNTS
 
 RESERVED = np.iinfo(np.int64).max  # a slot taken and not yet in any window
 # What a decode step may hand back beside its pools: counts made on the device
 # ("load": a row an expert layer, the held experts with a pick and the most
 # picks of one, then, where a chip holds a share of the layer's experts, all
-# picks and those that fell on a held one; "attention_read": blocks read,
-# blocks walked, blocks the walk brought by runs).
+# picks and those that fell on a held one; "attention_read": the plan's
+# `READ_COUNTS`, blocks read, blocks walked, blocks the walk and the shared
+# pass brought by runs).
 COUNTED = ("load", "attention_read")
 
 
@@ -917,11 +919,10 @@ class Pod:
             s.set_attr("arrays", len(counted))
             s.set_attr("bytes", sum(a.nbytes for a in counted.values()))
         if "attention_read" in counted:
-            read, walked, by_runs = counted["attention_read"]
             with span("attention.read") as s:
-                s.set_attr("read_blocks", int(read))
-                s.set_attr("walked_blocks", int(walked))
-                s.set_attr("run_blocks", int(by_runs))
+                for name, blocks in zip(READ_COUNTS,
+                                        counted["attention_read"]):
+                    s.set_attr(name, int(blocks))
         held = getattr(model, "experts_held", None)  # a chip's share of them
         for layer, (touched, most, *picks) in enumerate(
                 counted.get("load", ())):

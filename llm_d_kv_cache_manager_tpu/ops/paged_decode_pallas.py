@@ -11,11 +11,18 @@ online softmax.  It has two forms, read from the slot layout
 (``heads_first``, ``packed``: as the pool's ``KVGroupSpec`` states it) and
 from ``start``.
 
-**The shared pass and the walk** (slots [2, bs, Hkv, D], models/llama.py and
-models/phi4flash.py's full group; ``packed`` slots, models/lfm2moe.py).  A
-block is taken as it lies, [bs*Hkv, D] rows against every query head with the
-other KV heads' columns masked (K and V pass the MXU once either way, and
-nothing is re-laid-out in VMEM).  ``packed`` slots [bs*Hkv, 2*D] (head size
+**The shared pass and the walk** (slots [2, bs, Hkv, D], models/llama.py,
+models/phi4flash.py's full group and models/nemotronh.py; ``packed`` slots,
+models/lfm2moe.py).  A step's blocks are taken as they lie in the buffer they
+were copied into, ONE operand: [P, bs*Hkv, D] = [P*bs*Hkv, D] rows (a block's
+rows are whole tiles: nothing is re-laid-out in VMEM) against every query
+head in one product, the other KV heads' columns masked, one online-softmax
+update over the step's width and one product for the weighted values
+(``_attend_rows``; K and V pass the MXU once either way).  Until PR 52 a step
+was a list of blocks, a product and a piece of the scores each, the pieces
+concatenated along the lanes: at 2 KV heads a block is 32 rows, sixteen
+32-lane pieces at offsets that are no tile's (PERF.md section 6, PR 52).
+``packed`` slots [bs*Hkv, 2*D] (head size
 64) are that form with a position's K in the lower half of a row's lanes and
 its V in the upper: the pool's minor axis is then 128 wide, as the chip lays
 arrays out (with 64 the compiler makes the slot axis the minor one and
@@ -52,7 +59,13 @@ wait; any other wave, a sequence's last partial one among them, by a copy a
 block.  Starting a copy and waiting for it are trips of the same scalar core
 that issues the products, ≈50 cycles each whatever the copy carries, so a
 slot of 18 KB held the walk by the number of its copies (PERF.md section 6,
-PR 43): the same bytes into the same places by fewer descriptors.
+PR 43): the same bytes into the same places by fewer descriptors.  The
+shared pass brings its steps the same way (``_bring`` is both kernels'): a
+step of ``SHARED_BLOCKS_PER_STEP`` columns that lies wholly inside its
+group's run and whose blocks ascend is a run too (the plan's ``shared`` says
+which, and counts them: ``shared_run_blocks``), as a prompt that one miss
+prefilled out of a fresh pool lies; its last partial step, and a step with a
+break, come by a copy a block, in the same rolled loop (PR 52).
 A sequence that shares nothing walks its whole table, and a table where
 nobody shares costs the set-finding and the shared pass's one empty step.
 
@@ -103,7 +116,8 @@ walk's copies and what it traces to by tests/test_paged_decode_walk.py;
 tests/test_tpu_compile.py compiles every form for the v5e at the served
 shapes, and the decode steps of models/llama.py, afmoe.py (one plan a group
 of slots: the full layer's and the four window layers'), lfm2moe.py,
-glm4moelite.py and phi4flash.py serve through it (the last with four query
+glm4moelite.py, nemotronh.py (two KV heads of sixteen query heads each) and
+phi4flash.py serve through it (the last with four query
 heads a pair-wise KV head of twice the model's head size, the window layers
 by ``start`` and the full group by one plan for the eight layers that read
 it).
@@ -134,6 +148,24 @@ MXU_NATIVE = True
 # sets are of about eight) and blocks a step (the scores of a step are
 # [sequences * H, blocks * bs * Hkv] float32: 1 and 2 MB; 8 / 16 / 32 blocks
 # read 6.96 / 6.55 / 6.53 ms and 3.73 / 3.51 / 3.57 ms).
+# A step is ONE operand since PR 52, its blocks as they lie in the buffer, and
+# a step that is a run in the pool comes by one copy (PERF.md section 6,
+# PR 52; hack/paged_decode_alone.py, kernel alone, one layer, ms the shared
+# pass + the walk).  At `nemotron3nano-agents-reasoning`'s shapes (128
+# sequences of 32 heads over 2 KV heads, 32-row blocks; 8 prompts of 512
+# blocks, 16 sequences on each: 16 groups x 32 steps), a step a list of
+# blocks before: 1.538 + 0.919; with copies and no products 0.240 + 0.368,
+# products and no copies 1.371 + 0.805: the sixteen products a step and their
+# 32-lane pieces held it, not the 8192 descriptors.  One operand: 0.755 +
+# 0.627 with a copy a block, **0.486 + 0.625** with runs (copies alone
+# 0.244 + 0.363, products alone 0.444 + 0.524); runs under the list of
+# blocks 1.475 + 0.896.  Where a block's rows were whole 128-lane tiles
+# already the two forms read the same: `lfm2moe-chat-agents` (128-row blocks,
+# `packed`) 0.610 + 0.198 -> 0.553 + 0.198 (0.687 with a copy a block: the
+# runs carry it), `internlm2-chat-sysprompt` 0.075 + 0.138 -> 0.074 + 0.139
+# (0.1367 -> 0.1373 once a wait's descriptor no longer reads the table a
+# block: 0.1382 -> 0.1408 before), `phi4flash-reasoning-longgen` (160-row
+# blocks) whole 4.648 -> 4.570.
 SHARED_SEQUENCES = 8
 SHARED_BLOCKS_PER_STEP = 16
 # The walk's own (PERF.md section 6, PR 41): blocks a wave, as many as make
@@ -194,9 +226,16 @@ def _softmax_update(s, seen, m_ref, l_ref):
     return p, correction
 
 
+def _other_heads(shape, heads: int, groups: int):
+    """NEG_INF where scores [R, n*Hkv] are a query row's against a row of
+    another KV head than its own, 0 where of its own (`_own_head`): added to
+    a step's scores."""
+    return jnp.where(_own_head(shape, heads, groups), 0.0, NEG_INF)
+
+
 def _own_head(shape, heads: int, groups: int):
-    """Where a block's scores [R, bs*Hkv] are a query row's (row r: query
-    head r % heads) against a row of its own KV head (column c: KV head
+    """Where scores [R, n*Hkv] are a query row's (row r: query head
+    r % heads) against a row of its own KV head (column c: KV head
     c % Hkv)."""
     col = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
     row = jax.lax.broadcasted_iota(jnp.int32, shape, 0)
@@ -205,56 +244,45 @@ def _own_head(shape, heads: int, groups: int):
     return jax.lax.rem(col, heads // groups) == jax.lax.div(row, groups)
 
 
-def _attend_rows(q, kv_refs, hide, m_ref, l_ref, acc_ref, *, packed: bool):
-    """One online-softmax update over the step's blocks for slots
-    [2, bs, Hkv, D], handed in as [2, bs*Hkv, D] (merging the two moves
-    nothing): a block's K is one [bs*Hkv, D] operand whose row r is position
-    r // Hkv of KV head r % Hkv.  Every query row (q: [R, D] in the products'
-    type) is multiplied against every row of K, and ``hide(i, scores)`` puts
-    block i's scores against other KV heads' rows, and against positions the
-    row does not see, at NEG_INF before the softmax: the step is one update
-    over P*bs*Hkv columns, with no re-layout of K or V in VMEM.  (Hkv times
-    the products' FLOPs on an MXU that few query rows leave idle; each K and
-    V row passes it once, as in any other form.  Measured against a transpose
-    a step and a transpose a block: PERF.md section 6, PR 32.)  The walk
-    hands in one sequence's heads, the shared pass those of a whole group."""
+def _attend_rows(q, wave, other, seen, m_ref, l_ref, acc_ref, *, packed: bool):
+    """One online-softmax update over a step's blocks of slots [2, bs, Hkv, D]
+    as they lie in the buffer, ``wave``: [P, 2, bs*Hkv, D] (merging a slot's
+    two axes moves nothing).  The step's keys ``wave[:, 0]`` are
+    [P, bs*Hkv, D], whose leading axes collapse to [P*bs*Hkv, D] with no
+    re-layout (a block's rows are whole tiles at every served shape: 32, 128,
+    160 rows), row r of them position r // Hkv of the step, of KV head
+    r % Hkv; ``packed``: ``wave`` is [P, bs*Hkv, 2*D], one operand both K and
+    V.  Every query row (q: [R, D] in the products' type) is multiplied
+    against every row of the keys in ONE product; the scores [R, P*bs*Hkv]
+    against other KV heads' rows (``other``: NEG_INF under them, 0 under a
+    row's own, `_other_heads`) and against the rows from ``seen`` on, which
+    the query rows do not see (the step's positions past the context, or past
+    a shared run's end), are at NEG_INF before the softmax; ONE product
+    weighs the values.  Nothing is concatenated along the keys and no weight
+    is sliced back a block at a time (as `_attend_heads` has it for its
+    slots; the list of blocks this took before is the grid form's alone,
+    `_decode_kernel`).  (Hkv times the products' FLOPs on an MXU that few
+    query rows leave idle; each K and V row passes it once, as in any other
+    form.  Measured against a transpose a step and a transpose a block:
+    PERF.md section 6, PR 32; one product against a product a block: PR 52.)
+    The walk hands in one sequence's heads, the shared pass those of a whole
+    group."""
+    width = wave.shape[-1]
     if packed:  # K and V side by side in the lanes: one operand is both
-
-        def key(r):
-            return r[0]
-
-        value = key
+        keys = values = wave[...].reshape(-1, width).astype(q.dtype)
     else:
-
-        def key(r):
-            return r[0, 0]
-
-        def value(r):
-            return r[0, 1]
-
-    rows = key(kv_refs[0]).shape[0]
-    s = jnp.concatenate(
-        [
-            hide(i, jax.lax.dot_general(
-                q,
-                key(r).astype(q.dtype),
-                (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            ))
-            for i, r in enumerate(kv_refs)
-        ],
-        axis=1,
-    )  # [R, width]
-    p, correction = _softmax_update(s, None, m_ref, l_ref)
-    p = p.astype(q.dtype)
-    o = sum(
-        jax.lax.dot_general(
-            p[:, i * rows : (i + 1) * rows],
-            value(r).astype(q.dtype),
-            (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        for i, r in enumerate(kv_refs)
+        keys = wave[:, 0].reshape(-1, width).astype(q.dtype)
+        values = wave[:, 1].reshape(-1, width).astype(q.dtype)
+    s = jax.lax.dot_general(
+        q, keys, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    )  # [R, P*bs*Hkv]
+    row = jax.lax.broadcasted_iota(jnp.int32, (1, s.shape[1]), 1)
+    p, correction = _softmax_update(
+        s + other + jnp.where(row < seen, 0.0, NEG_INF), None, m_ref, l_ref)
+    o = jax.lax.dot_general(
+        p.astype(q.dtype), values, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32,
     )  # [R, D]
     acc_ref[...] = acc_ref[...] * correction + o
 
@@ -367,6 +395,44 @@ def _latent_queries(q_refs, scale: float, compute_dtype):
     )
 
 
+def _bring(act: str, table_ref, row, column, count, is_run, kv_hbm, target,
+           sem):
+    """Start, or wait for (``act``), the copies that bring ``count`` blocks,
+    those row ``row`` of the table names from ``column`` on, out of the pool
+    into the first places of ``target`` ([P, a slot]: a wave of the walk, a
+    step of the shared pass): where the plan found them to be a run
+    (``is_run``: all P, one after another in the pool) by ONE copy of P slots,
+    else by a copy a block, in a rolled loop: one body to trace, lower and
+    compile whatever P is, and as many trips of the scalar core as there are
+    descriptors.  The flag the start read decides the wait, and a wait's
+    descriptor stands for its size alone: each names the first block, so that
+    waiting reads the table once and not once a block (the walk of tables
+    with few runs is held by these trips: `internlm2-chat-sysprompt`, PERF.md
+    section 6, PR 52).  A pool of fewer blocks than P holds no run, and no
+    slice of P blocks could be taken of it."""
+    P = target.shape[0]
+    starts = act == "start"
+    act = operator.methodcaller(act)
+    if kv_hbm.shape[0] >= P:
+
+        @pl.when(is_run & (count > 0))
+        def _run():
+            act(pltpu.make_async_copy(
+                kv_hbm.at[pl.ds(table_ref[row, column], P)], target, sem))
+
+        count = jnp.where(is_run, 0, count)
+
+    def one(i, _):
+        act(pltpu.make_async_copy(
+            kv_hbm.at[pl.ds(
+                table_ref[row, column + i if starts else column], 1)],
+            target.at[pl.ds(i, 1)],
+            sem,
+        ))
+
+    jax.lax.fori_loop(0, count, one, None)
+
+
 def _decode_kernel(
     table_ref,  # SMEM [B, max_blocks] int32 (scalar prefetch)
     ctx_ref,  # SMEM [B] int32 (scalar prefetch)
@@ -382,7 +448,9 @@ def _decode_kernel(
 ):
     """The grid of tables by steps (window layers of slots [2, bs, Hkv, D]):
     a grid step takes ``blocks_per_step`` pool blocks of one sequence, each
-    an operand with its own pipelined DMA."""
+    an operand with its own pipelined DMA, a block's K one [bs*Hkv, D]
+    operand against every query head, the other KV heads' columns and the
+    positions the sequence does not see at NEG_INF."""
     del last_ref
     kv_refs = rest[:blocks_per_step]
     out_ref, m_ref, l_ref, acc_ref = rest[blocks_per_step:]
@@ -423,14 +491,32 @@ def _decode_kernel(
             jax.lax.broadcasted_iota(jnp.int32, shape, 1), Hkv
         )
 
-        def hide(i, s):
+        def scores(i, r):
+            s = jax.lax.dot_general(
+                x, r[0, 0].astype(x.dtype), (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)
             seen = in_context(position, first + i * block_size)
             return jnp.where(own & seen, s, NEG_INF)
 
-        _attend_rows(
-            q.astype(compute_dtype), kv_refs, hide, m_ref, l_ref,
-            acc_ref, packed=False,
-        )
+        # The step's blocks are separate operands here (each its own
+        # pipelined DMA), so this form alone multiplies a block at a time:
+        # the pieces of the scores side by side for one update, and the
+        # weights sliced back a block at a time.
+        x = q.astype(compute_dtype)
+        s = jnp.concatenate(
+            [scores(i, r) for i, r in enumerate(kv_refs)], axis=1)
+        p, correction = _softmax_update(s, None, m_ref, l_ref)
+        p = p.astype(x.dtype)
+        o = sum(
+            jax.lax.dot_general(
+                p[:, i * shape[1] : (i + 1) * shape[1]],
+                r[0, 1].astype(x.dtype),
+                (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
+            for i, r in enumerate(kv_refs)
+        )  # [H, D]
+        acc_ref[...] = acc_ref[...] * correction + o
 
     @pl.when(j == n_steps - 1)
     def _finalize():
@@ -500,49 +586,19 @@ def _walk_kernel(
         a run ends before the block of the write position)."""
         return skip_ref[seq], last_ref[seq] + 1 - skip_ref[seq]
 
-    def copy(seq, block, slot, i):
-        return pltpu.make_async_copy(
-            kv_hbm.at[pl.ds(table_ref[seq, block], 1)],
-            buf.at[slot, pl.ds(i, 1)],
-            sem.at[slot],
-        )
-
-    def loose(seq, j, slot, count, act):
-        """How many of the ``count`` blocks of wave j of a sequence come by a
-        copy each: all of them, or none where the plan found the wave to be a
-        run, whose one copy ``act`` starts or waits for here (the flag the
-        start read decides the wait).  A pool of fewer blocks than a wave
-        holds no run, and no slice of a wave's blocks could be taken of it."""
-        if kv_hbm.shape[0] < P:
-            return count
-        is_run = runs_ref[seq, j] == 1
-
-        @pl.when(is_run & (count > 0))
-        def _run():
-            act(pltpu.make_async_copy(
-                kv_hbm.at[pl.ds(table_ref[seq, skip_ref[seq] + j * P], P)],
-                buf.at[slot],
-                sem.at[slot],
-            ))
-
-        return jnp.where(is_run, 0, count)
+    def bring(act, seq, j, slot, count):
+        """Wave j of a sequence, its first ``count`` blocks, into a buffer."""
+        _bring(act, table_ref, seq, skip_ref[seq] + j * P, count,
+               runs_ref[seq, j] == 1, kv_hbm, buf.at[slot], sem.at[slot])
 
     def start(seq, j, number):
         """Ask for wave j of a sequence, the call's wave ``number``: a run by
         one copy, any other wave by a copy a block, none past the sequence's
-        last and none behind the last sequence.  Rolled, as the waves are:
-        one body to trace, lower and compile whatever the wave's size."""
+        last and none behind the last sequence."""
         at = jnp.minimum(seq, B - 1)
-        first, n = own(at)
+        _, n = own(at)
         count = jnp.where(seq < B, jnp.minimum(n - j * P, P), 0)
-        slot = jax.lax.rem(number, N)
-
-        def one(i, _):
-            copy(at, first + j * P + i, slot, i).start()
-
-        jax.lax.fori_loop(
-            0, loose(at, j, slot, count, operator.methodcaller("start")),
-            one, None)
+        bring("start", at, j, jax.lax.rem(number, N), count)
 
     def behind(seq, j):
         """The wave behind wave j of a sequence in the call's stream."""
@@ -596,10 +652,9 @@ def _walk_kernel(
             position = jax.lax.broadcasted_iota(
                 jnp.int32, (H, P * block_size), 1)
         else:
-            shape = (H, block_size * Hkv)
-            own_head = _own_head(shape, H, groups)
-            position = jax.lax.div(
-                jax.lax.broadcasted_iota(jnp.int32, shape, 1), Hkv)
+            # column c of a wave's scores is the wave's position c // Hkv
+            # under KV head c % Hkv (a block is bs * Hkv rows)
+            other = _other_heads((H, P * block_size * Hkv), H, groups)
     else:
         q = _latent_queries([q_ref], scale, compute_dtype)
         half = block_size // 2  # rows a block: column c is row c % half of
@@ -614,20 +669,8 @@ def _walk_kernel(
         number = before + j
         slot = jax.lax.rem(number, N)
         start(*stream(b, j)[-1], number + N - 1)
-        count = jnp.minimum(n - j * P, P)
-
-        def arrived(i, _):
-            copy(b, first, slot, i).wait()
-
-        jax.lax.fori_loop(
-            0, loose(b, j, slot, count, operator.methodcaller("wait")),
-            arrived, None)
+        bring("wait", b, j, slot, jnp.minimum(n - j * P, P))
         at = (first + j * P) * block_size
-
-        def hide(i, s):
-            # blocks past the sequence's last lie past its context too
-            seen = position < ctx - (at + i * block_size)
-            return jnp.where(own_head & seen, s, NEG_INF)
 
         if heads_first:
             seen = position < ctx - at
@@ -635,10 +678,9 @@ def _walk_kernel(
                 seen &= position >= start_ref[b] - at
             _attend_heads(q, buf.at[slot], seen, m_ref, l_ref, acc_ref)
         elif not isinstance(latent, int):
-            _attend_rows(
-                q, [buf.at[slot, pl.ds(i, 1)] for i in range(P)], hide,
-                m_ref, l_ref, acc_ref, packed=packed,
-            )
+            # blocks past the sequence's last lie past its context too
+            _attend_rows(q, buf.at[slot], other, (ctx - at) * Hkv,
+                         m_ref, l_ref, acc_ref, packed=packed)
         else:
             _attend_latent(
                 q, buf[slot].reshape(P * half, -1),
@@ -655,8 +697,9 @@ def _walk_kernel(
 def _shared_kernel(
     table_ref,  # SMEM [B, max_blocks] int32 (scalar prefetch)
     row_ref,  # SMEM [C]: each group's table row (its first member's),
-    run_ref,  # SMEM [C]: and its run, in blocks
+    run_ref,  # SMEM [C]: its run, in blocks,
     members_ref,  # SMEM [C*G] (the index maps' own)
+    step_runs_ref,  # SMEM [C, steps]: and which steps of its run are runs
     *rest,  # a q ref a member, the pool (HBM); out: m, l, acc; scratch
     groups: int,
     scale: float,
@@ -668,16 +711,19 @@ def _shared_kernel(
 ):
     """The shared-prefix pass: a grid step is a group, whose sequences all
     have the same run of blocks at the head of their tables.  It brings the
-    run in ``blocks_per_step`` blocks at a time, each block by a copy of its
-    own into one of two buffers (the next blocks arrive while these are
-    multiplied), and makes one online-softmax update of all the group's query
-    rows over them.  The statistics and the weighted values are left
-    unnormalised in the output blocks: the walk resumes each sequence's
-    softmax from them."""
+    run in ``blocks_per_step`` blocks at a time into one of two buffers (the
+    next blocks arrive while these are multiplied), a step that the plan
+    found to be a run in the pool (``step_runs_ref``) by one copy of the
+    step's slots and one wait, any other, the last partial one among them, by
+    a copy a block, none past the run's end: every block of the run is
+    brought once either way.  It makes one online-softmax update of all the
+    group's query rows over a step.  The statistics and the weighted values
+    are left unnormalised in the output blocks: the walk resumes each
+    sequence's softmax from them."""
     del members_ref
     q_refs = rest[:sequences]
     kv_hbm = rest[sequences]
-    m_ref, l_ref, acc_ref, buf, sem, other_ref = rest[sequences + 1 :]
+    m_ref, l_ref, acc_ref, buf, sem, *other = rest[sequences + 1 :]
     P = blocks_per_step
     g = pl.program_id(0)
     row, run = row_ref[g], run_ref[g]
@@ -685,42 +731,34 @@ def _shared_kernel(
 
     @pl.when(g == 0)
     def _once():
-        # NEG_INF under the other KV heads' rows, added to every block's
-        # scores: made once a call, where the walk's few rows make theirs
-        # from iotas a step.
-        other_ref[...] = jnp.where(
-            _own_head(other_ref.shape, H, groups), 0.0, NEG_INF
-        )
+        # Places of a buffer that no copy has written yet are multiplied
+        # under weights of zero: they must hold numbers.
+        buf[...] = jnp.zeros_like(buf)
+        # NEG_INF under the other KV heads' rows, added to a step's scores:
+        # made once a call, where the walk's few rows make theirs from iotas.
+        if not isinstance(latent, int):
+            other[0][...] = _other_heads(other[0].shape, H, groups)
 
     m_ref[...] = jnp.full_like(m_ref, NEG_INF)
     l_ref[...] = jnp.zeros_like(l_ref)
     acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    def copies(step, half):
-        # past the run's end its last block again: hidden below
-        return [
-            pltpu.make_async_copy(
-                kv_hbm.at[
-                    pl.ds(table_ref[row, jnp.minimum(step * P + i, run - 1)], 1)
-                ],
-                buf.at[half, i],
-                sem.at[half],
-            )
-            for i in range(P)
-        ]
+    def bring(act, step, half):
+        _bring(act, table_ref, row, step * P, jnp.minimum(run - step * P, P),
+               step_runs_ref[g, step] == 1, kv_hbm, buf.at[half],
+               sem.at[half])
 
     @pl.when(run > 0)
     def _first():
-        for copy in copies(0, 0):
-            copy.start()
+        bring("start", 0, 0)
 
     compute_dtype = q_refs[0].dtype if mxu_native else jnp.float32
+    rows = buf.shape[-2]  # a block's: column c of a step is of block c // rows
     if not isinstance(latent, int):
         q = jnp.concatenate([r[0] for r in q_refs], axis=0)  # [G*H, D]
         q = (q.astype(jnp.float32) * scale).astype(compute_dtype)
     else:
         q = _latent_queries(q_refs, scale, compute_dtype)
-        rows = buf.shape[-2]  # a block's: column c is of block c // rows
         block = jax.lax.div(
             jax.lax.broadcasted_iota(
                 jnp.int32, (sequences * H, P * rows), 1), rows)
@@ -731,21 +769,15 @@ def _shared_kernel(
 
         @pl.when(j + 1 < n_steps)
         def _next():
-            for copy in copies(j + 1, 1 - half):
-                copy.start()
+            bring("start", j + 1, 1 - half)
 
-        for copy in copies(j, half):
-            copy.wait()
+        bring("wait", j, half)
         # Every position of the run lies before every member's own: only
         # the blocks past the run's end, in its last step, are hidden whole.
         count = run - j * P
         if not isinstance(latent, int):
-            _attend_rows(
-                q,
-                [buf.at[half, i] for i in range(P)],
-                lambda i, s: jnp.where(i < count, s + other_ref[...], NEG_INF),
-                m_ref, l_ref, acc_ref, packed=packed,
-            )
+            _attend_rows(q, buf.at[half], other[0][...], count * rows,
+                         m_ref, l_ref, acc_ref, packed=packed)
         else:  # one KV "head": only the blocks past the run's end hide
             _attend_latent(
                 q, buf[half].reshape(P * rows, -1),
@@ -763,6 +795,7 @@ def shared_prefix_plan(
     block_size: int,
     blocks_per_wave: int,
     min_sequences: int | None = 2,
+    shared_blocks_per_step: int = SHARED_BLOCKS_PER_STEP,
 ) -> dict:
     """Which sequences' tables begin with the same run of full blocks, the
     groups the shared pass takes them in, and where each sequence's walk
@@ -784,20 +817,25 @@ def shared_prefix_plan(
     ``blocks_per_wave`` at a time (`walk_wave` of the pool, where the caller
     names no other), and a wave all in context whose blocks lie one after
     another in the pool (each column's id the one before's + 1, as an
-    allocator deals a fresh pool out) is a run, which one copy brings.
-    Keys: ``shared`` (each group's table row, run and members) and ``walk``
-    (each sequence's place in the shared pass's results, the first block of
+    allocator deals a fresh pool out) is a run, which one copy brings; the
+    shared pass takes a group's run ``shared_blocks_per_step`` at a time
+    (the call's, where it names another than the module's), and a step all
+    inside the run whose blocks lie so is a run too.
+    Keys: ``shared`` (each group's table row, run and members, and which
+    steps of its run are runs, [C, steps a table]) and ``walk`` (each
+    sequence's place in the shared pass's results, the first block of
     its own: its run, 0 where it shares nothing, and which of its waves are
     runs, [B, waves a table]): the two kernels' scalar prefetch after the
     table; ``shared_steps``: the shared pass's grid; ``read_blocks`` (what
     the two read: each group's run once and every sequence's rest, which is
-    what the walk copies), ``run_blocks`` (what of it the walk brings by runs)
-    and ``walked_blocks`` (what a walk of every table reads)."""
+    what the walk copies), ``run_blocks`` (what of it the walk brings by
+    runs), ``shared_run_blocks`` (what of it the shared pass brings by runs)
+    and ``walked_blocks`` (what a walk of every table reads):
+    `attention_read_counts` hands the four to a model's decode step."""
     i32 = jnp.int32
-    B, M = block_table.shape
     ctx = context_len.astype(i32)
     if min_sequences is None:
-        slot = skip = jnp.zeros((B,), i32)
+        slot = skip = jnp.zeros(ctx.shape, i32)
     else:
         slot, skip, heads, groups = _shared_sets(
             block_table, ctx, block_size, min_sequences)
@@ -805,30 +843,54 @@ def shared_prefix_plan(
     # The walk: every sequence's rest, its write position's block at least.
     blocks = jnp.maximum(ctx - 1, 0) // block_size + 1
     skip = skip.astype(i32)
-    # Wave w of a walk is columns skip + w*P .. + P - 1: a run where the last
-    # of them is in context and no step from one of them to the next breaks
-    # the ascent.
-    P = blocks_per_wave
-    start = skip[:, None] + jnp.arange(-(-M // P), dtype=i32)[None] * P
-    column = jnp.arange(M - 1, dtype=i32)  # the step from it to the next
-    steps = (column >= start[..., None]) & (column < start[..., None] + P - 1)
-    breaks = block_table[:, 1:] != block_table[:, :-1] + 1
-    runs = (start + P <= blocks[:, None]) & ~jnp.any(
-        breaks[:, None] & steps, axis=2)
-    plan = {"walk": (slot, skip, runs.astype(i32))}
-    shared_blocks = 0
+    plan = {"walk": (slot, skip, _whole_runs(
+        block_table, skip, blocks, blocks_per_wave))}
+    shared_blocks = by_shared_runs = jnp.zeros((), i32)
     if min_sequences is not None:
-        plan["shared"] = tuple(a.astype(i32) for a in groups)
+        row, run, members = (a.astype(i32) for a in groups)
+        step_runs = _whole_runs(
+            block_table[row], jnp.zeros_like(run), run, shared_blocks_per_step)
+        plan["shared"] = (row, run, members, step_runs)
         # a grid of no step at all is not asked of the compiler: one step
         # that reads nothing where nobody shares
         plan["shared_steps"] = jnp.maximum(jnp.sum(heads), 1).astype(i32)
-        shared_blocks = jnp.sum(groups[1])
+        shared_blocks = jnp.sum(run)
+        by_shared_runs = jnp.sum(step_runs) * shared_blocks_per_step
     return {
         **plan,
         "read_blocks": (shared_blocks + jnp.sum(blocks - skip)).astype(i32),
-        "run_blocks": (jnp.sum(runs) * P).astype(i32),
+        "run_blocks": (
+            jnp.sum(plan["walk"][2]) * blocks_per_wave).astype(i32),
+        "shared_run_blocks": by_shared_runs.astype(i32),
         "walked_blocks": jnp.sum(blocks).astype(i32),
     }
+
+
+# The plan's counts as a decode step hands them back (`pools["attention_read"]`)
+# and as the span `attention.read` names them (models/pod.py).
+READ_COUNTS = ("read_blocks", "walked_blocks", "run_blocks",
+               "shared_run_blocks")
+
+
+def attention_read_counts(plan: dict) -> jnp.ndarray:
+    """`READ_COUNTS` of a plan, [4] int32."""
+    return jnp.stack([plan[name] for name in READ_COUNTS])
+
+
+def _whole_runs(table, first, blocks, P: int):
+    """Which steps of P columns of each row of ``table``, counted from the
+    row's column ``first``, are runs ([rows, steps a table] int32): step w is
+    columns first + w*P .. + P - 1, a run where the last of them is before
+    the row's column ``blocks`` and no step from one of them to the next
+    breaks the ascent."""
+    i32 = jnp.int32
+    M = table.shape[1]
+    start = first[:, None] + jnp.arange(-(-M // P), dtype=i32)[None] * P
+    column = jnp.arange(M - 1, dtype=i32)  # the step from it to the next
+    steps = (column >= start[..., None]) & (column < start[..., None] + P - 1)
+    breaks = table[:, 1:] != table[:, :-1] + 1
+    return ((start + P <= blocks[:, None]) & ~jnp.any(
+        breaks[:, None] & steps, axis=2)).astype(i32)
 
 
 def _shared_sets(block_table, ctx, block_size: int, min_sequences: int):
@@ -1083,7 +1145,6 @@ def _shared_pass_and_walk(q, kv_layer, block_table, context_len, plan, window,
     # what a head keeps of a block: the query's own lanes, or a latent's value
     Dq = statics.get("latent") or q.shape[-1]
     q_block, q_zeros = (1,) + q.shape[1:], (0,) * (q.ndim - 1)
-    kv_block = (1,) + kv_layer.shape[1:]
     if blocks_per_wave is None:
         blocks_per_wave = walk_wave(kv_layer)
     windowed = len(window) == 1
@@ -1092,11 +1153,12 @@ def _shared_pass_and_walk(q, kv_layer, block_table, context_len, plan, window,
         plan = shared_prefix_plan(
             block_table, context_len, block_size=block_size,
             blocks_per_wave=blocks_per_wave,
+            shared_blocks_per_step=shared_blocks_per_step,
             **({"min_sequences": None} if walk_only else {}),
         )
     place, skip, runs = plan["walk"]
-    waves = -(-block_table.shape[1] // blocks_per_wave)
-    if place.shape != (B,) or runs.shape != (B, waves):
+    columns = block_table.shape[1]
+    if place.shape != (B,) or runs.shape != (B, -(-columns // blocks_per_wave)):
         raise ValueError("the plan was made for another table or wave")
     resumed = []
     if "shared" in plan:
@@ -1104,7 +1166,7 @@ def _shared_pass_and_walk(q, kv_layer, block_table, context_len, plan, window,
             raise ValueError("heads-first slots and window starts share "
                              "nothing: a plan of min_sequences None")
         resumed = _shared_pass(
-            q, kv_layer, block_table, plan, kv_block=kv_block,
+            q, kv_layer, block_table, plan,
             blocks_per_step=shared_blocks_per_step, interpret=interpret,
             **statics,
         )
@@ -1155,7 +1217,7 @@ def _shared_pass_and_walk(q, kv_layer, block_table, context_len, plan, window,
     )(*(a.astype(jnp.int32) for a in scalars), q, kv_layer, *resumed)
 
 
-def _shared_pass(q, kv_layer, block_table, plan, *, kv_block, blocks_per_step,
+def _shared_pass(q, kv_layer, block_table, plan, *, blocks_per_step,
                  interpret, **statics):
     """The shared pass over the plan's groups: the running maximum, the sum
     and the weighted values (float32, unnormalised) of each group member's
@@ -1165,10 +1227,13 @@ def _shared_pass(q, kv_layer, block_table, plan, *, kv_block, blocks_per_step,
     H = q.shape[-2]
     Dq = statics.get("latent") or q.shape[-1]
     G = SHARED_SEQUENCES
-    C = plan["shared"][-1].shape[0] // G
+    members, step_runs = plan["shared"][2:]
+    C = members.shape[0] // G
+    if step_runs.shape != (C, -(-block_table.shape[1] // blocks_per_step)):
+        raise ValueError("the plan was made for another table or shared step")
 
     def q_index(i):
-        def index(g, table_ref, row_ref, run_ref, members_ref):
+        def index(g, table_ref, row_ref, run_ref, members_ref, *_):
             return (members_ref[g * G + i],) + (0,) * (q.ndim - 1)
 
         return index
@@ -1191,10 +1256,14 @@ def _shared_pass(q, kv_layer, block_table, plan, *, kv_block, blocks_per_step,
             for width in widths
         ],
         scratch_shapes=[
-            pltpu.VMEM((2, blocks_per_step) + kv_block, kv_layer.dtype),
+            # a step's blocks as they lie in the pool: a run's one target
+            pltpu.VMEM((2, blocks_per_step) + kv_layer.shape[1:],
+                       kv_layer.dtype),
             pltpu.SemaphoreType.DMA((2,)),
-            pltpu.VMEM((G * H, kv_block[-2]), jnp.float32),
-        ],
+        ]
+        # the other KV heads' columns of a step's scores (a latent has none)
+        + ([] if isinstance(statics.get("latent"), int) else [pltpu.VMEM(
+            (G * H, blocks_per_step * kv_layer.shape[-2]), jnp.float32)]),
     )
     return pl.pallas_call(
         functools.partial(

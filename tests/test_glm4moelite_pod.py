@@ -172,8 +172,9 @@ def test_prefill_continue_and_decode_repeat_the_reference(prefix_blocks):
         close(np.stack(s["rows"]), reference(tuple(s["tokens"]))[-25:-1])
     # the two tables begin with the same blocks, and two are too few for the
     # shared pass (`SHARED_MIN_SEQUENCES`): each walks its whole table
-    read, walked, by_runs = eng.read
-    assert read == walked and by_runs == 0  # no table holds a wave of 64
+    read, walked, by_runs, by_shared_runs = eng.read
+    # no table holds a wave of 64
+    assert read == walked and by_runs == by_shared_runs == 0
     assert eng.load.shape == (2, 2) and (eng.load[:, 0] <= 4).all()
 
 
@@ -196,8 +197,8 @@ def test_a_prefix_that_enough_sequences_share_is_read_once():
         for s, row in zip(seqs, eng.decode(seqs)):
             s["rows"].append(row)
             s["tokens"].append(int(np.argmax(row)))
-        read, walked, by_runs = eng.read
-        assert read == walked - 3 * 5 and by_runs == 0
+        read, walked, by_runs, by_shared_runs = eng.read
+        assert read == walked - 3 * 5 and by_runs == by_shared_runs == 0
     for s in seqs:
         close(np.stack(s["rows"]), reference(tuple(s["tokens"]))[-7:-1])
 
@@ -220,7 +221,7 @@ def test_a_decode_step_counts_the_blocks_its_walk_brings_by_runs(monkeypatch):
     for s, row in zip(seqs, eng.decode(seqs)):
         close(row, reference(tuple(s["tokens"]))[-1])
     # (0, 1), (2, 3), (4, 5); (6, 7), (8, 9) and block 10 alone
-    assert list(eng.read) == [11, 11, 10]
+    assert list(eng.read) == [11, 11, 10, 0]  # and nobody shares
 
 
 def test_a_long_prefill_attends_a_chunk_of_queries_at_a_time(monkeypatch):
@@ -332,7 +333,7 @@ def test_the_three_programs_serve_the_reference_tokens_and_record_the_read():
     walked = [r["attrs"] for r in spans if r["span"] == "attention.read"]
     # a pair over one run is walked whole (`SHARED_MIN_SEQUENCES`)
     assert walked == [{"read_blocks": 7 + 8, "walked_blocks": 7 + 8,
-                       "run_blocks": 0}]
+                       "run_blocks": 0, "shared_run_blocks": 0}]
     load = [r["attrs"] for r in spans if r["span"] == "moe.expert_load"]
     assert len(load) == 2 and all(
         a["experts_held"] == 8 and 1 <= a["experts_touched"] <= 4

@@ -170,7 +170,7 @@ def test_latent_decode_with_a_shared_prefix(ctx, shared):
     once for the sequences of the set, and the walk resumes from it."""
     q, pool, table, ctx_arr, latents = decode_case(ctx, shared_blocks=shared)
     plan = shared_prefix_plan(table, ctx_arr, block_size=BS,
-                              blocks_per_wave=2)
+                              blocks_per_wave=2, shared_blocks_per_step=2)
     assert int(plan["read_blocks"]) < int(plan["walked_blocks"])
     got = paged_decode_attention_pallas(
         q, pool, table, ctx_arr, latent=VALUE, scale=SCALE, plan=plan,
@@ -186,7 +186,8 @@ def test_latent_sets_under_the_least_size_are_walked(least, shares):
     ctx = [70, 90, 50]
     q, pool, table, ctx_arr, latents = decode_case(ctx, shared_blocks=3)
     plan = shared_prefix_plan(table, ctx_arr, block_size=BS,
-                              blocks_per_wave=2, min_sequences=least)
+                              blocks_per_wave=2, shared_blocks_per_step=2,
+                              min_sequences=least)
     walked = int(plan["walked_blocks"])
     assert int(plan["read_blocks"]) == (walked - 2 * 3 if shares else walked)
     got = paged_decode_attention_pallas(

@@ -348,7 +348,8 @@ def test_a_decode_step_counts_the_blocks_its_attention_reads():
     assert shared == 4 and dropped == 0
     # a tiny pool's waves of 16 hold no table of nine columns: no run
     assert read == [{"read_blocks": shared + 2 * (7 - shared),
-                     "walked_blocks": 2 * 7, "run_blocks": 0}]
+                     "walked_blocks": 2 * 7, "run_blocks": 0,
+                     "shared_run_blocks": 0}]
 
 
 def test_pallas_decode_in_the_step_agrees_with_the_gather():
@@ -630,10 +631,13 @@ def test_a_float8_pass_fails_the_tolerance_the_decode_comparison_holds():
 # in a shared pass and walks the rest) and in PR 41 (the walk is a sequence a
 # grid step and copies its own blocks; the plan lists sequences, not steps)
 # and in PR 43 (the plan flags the waves that are runs in the pool and the
-# walk brings such a wave by one copy);
+# walk brings such a wave by one copy) and in PR 52 (a step of `rows` slots
+# is one operand, and the shared pass brings a step that is a run by one copy:
+# the plan flags those too and counts `shared_run_blocks`);
 # `afmoe.decode.True` was read anew in PR 45 (heads-first slots and their
 # window layers' tables are walked: a plan of the runs a group of slots, the
-# step counts `attention_read`);
+# step counts `attention_read`) and in PR 52 (the walk's copies are
+# `_bring`'s, the shared pass's too, and `attention_read` has a fourth count);
 # `llama.decode.False` stands, because off the TPU and uninterpreted the step
 # keeps the XLA gather and makes no plan, and so do the other five `afmoe.*`
 # and the four `llama.miss/hit.*`.
@@ -641,11 +645,11 @@ TEXT_AT_PR_32 = {
     "afmoe.miss.False": "e6f04f9f13e0aa70", "afmoe.hit.False": "83fd523e8393f5b3",
     "afmoe.decode.False": "50748779141b4cb4",
     "afmoe.miss.True": "e6f04f9f13e0aa70", "afmoe.hit.True": "83fd523e8393f5b3",
-    "afmoe.decode.True": "25e78136eac99456",
+    "afmoe.decode.True": "19c2b8838b605cef",
     "llama.miss.False": "90c7da6d6fb5ae9d", "llama.hit.False": "364331e0e1475faa",
     "llama.decode.False": "53c6a3b76efc093d",
     "llama.miss.True": "90c7da6d6fb5ae9d", "llama.hit.True": "364331e0e1475faa",
-    "llama.decode.True": "e2042b2557c47a3f",
+    "llama.decode.True": "a9d7f3002d789864",
 }
 
 

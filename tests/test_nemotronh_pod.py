@@ -406,7 +406,8 @@ def test_the_three_programs_serve_the_reference_tokens_and_report_spans():
         assert a["mean_tokens"] == 2 * CFG.top_k / CFG.n_experts
     assert spans["attention.read"][-1] == {"read_blocks": 4 + 2 * 3,
                                            "walked_blocks": 14,
-                                           "run_blocks": 0}
+                                           "run_blocks": 0,
+                                           "shared_run_blocks": 0}
 
 
 def test_a_step_launched_ahead_and_not_taken_leaves_the_state_it_read():

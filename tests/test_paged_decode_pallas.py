@@ -277,11 +277,16 @@ SHARED_CASES = {
 # sequences of a prompt each walk its blocks.
 LAYOUTS = {"llama": (16, 8, 128), "packed": (8, 4, 64), "pairwise": (8, 2, 128),
            "heads_first": (8, 2, 128)}
+# Rows a block as two more cells serve them, a step of which is one operand
+# (PR 52): `nemotron-3-nano-30b-a3b`'s 2 KV heads of 32 (32-row blocks) and
+# `phi-4-mini-flash-reasoning`'s pair-wise 10 of 40 (160-row).
+SERVED_ROWS = {"two_kv_heads_of_32": (32, 2, 128),
+               "pairwise_10_of_40": (40, 10, 128)}
 
 
 def shared_case(name, slots, key=7):
     prompts, sequences, wave, shared_step, layers, layer = SHARED_CASES[name]
-    H, Hkv, D = LAYOUTS[slots]
+    H, Hkv, D = {**LAYOUTS, **SERVED_ROWS}[slots]
     q, kv, table, ctx, ref = make_shared_case(
         jax.random.PRNGKey(key), H, Hkv, D, 12, prompts, sequences,
         packed=slots == "packed", layers=layers, layer=layer,
@@ -292,8 +297,14 @@ def shared_case(name, slots, key=7):
     return (q, kv, table, ctx), statics, ref
 
 
-@pytest.mark.parametrize("slots", LAYOUTS)
-@pytest.mark.parametrize("name", SHARED_CASES)
+@pytest.mark.parametrize("name, slots", [
+    (name, slots) for slots in LAYOUTS for name in SHARED_CASES] + [
+    # the shared pass and the walk at the served rows: a run's last partial
+    # step hidden, groups of three and two beside loners, waves past the end
+    (name, slots) for slots in SERVED_ROWS for name in (
+        "run_not_a_multiple_of_the_shared_step",
+        "two_uneven_sets_a_loner_and_an_idle_slot",
+        "more_own_blocks_than_two_waves")])
 def test_shared_prefix_pass_matches_xla_gather(name, slots):
     args, statics, ref = shared_case(name, slots)
     close(paged_decode_attention_pallas(*args, **statics), ref)
@@ -307,10 +318,11 @@ def test_groups_under_the_least_size_are_walked(name, least):
     Its sequences walk their whole tables, the groups left are numbered from
     0, the counts say what is read, and the kernels give what they gave."""
     (q, kv, table, ctx), statics, ref = shared_case(name, "llama")
-    wave = statics["walk_blocks_per_wave"]
-    every = shared_prefix_plan(table, ctx, block_size=BS, blocks_per_wave=wave)
-    plan = shared_prefix_plan(table, ctx, block_size=BS, blocks_per_wave=wave,
-                              min_sequences=least)
+    steps = dict(blocks_per_wave=statics["walk_blocks_per_wave"],
+                 shared_blocks_per_step=statics["shared_blocks_per_step"])
+    every = shared_prefix_plan(table, ctx, block_size=BS, **steps)
+    plan = shared_prefix_plan(table, ctx, block_size=BS, min_sequences=least,
+                              **steps)
     place, skip = (np.asarray(a) for a in every["walk"][:2])
     group = np.where(skip > 0, place // 8, -1)
     size = np.asarray([np.sum(group == g) if g >= 0 else 0 for g in group])
@@ -346,7 +358,9 @@ def test_the_plan_finds_the_sets_and_counts_what_is_read():
     assert list(place) == [0, 8, 0, 1, 0, 9, 0, 2]
     assert list(skip) == [6, 4, 0, 6, 0, 4, 0, 6]
     blocks = [-(-c // BS) for c in np.asarray(ctx)]
-    row, run, members = (np.asarray(a) for a in plan["shared"])
+    row, run, members, step_runs = (np.asarray(a) for a in plan["shared"])
+    assert step_runs.shape == (4, 1) and not step_runs.any()
+    assert int(plan["shared_run_blocks"]) == 0
     assert int(plan["shared_steps"]) == 2
     assert list(row[:2]) == [0, 1] and list(run[:2]) == [6, 4]
     assert list(members[:16]) == [0, 3, 7, 0, 0, 0, 0, 0,

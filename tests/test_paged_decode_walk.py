@@ -1,8 +1,9 @@
 """The paged kernel's walk of each sequence's own blocks (PR 41): what it
 copies, counted as the interpreted kernel runs (a wave whose blocks lie one
 after another in the pool by one copy, PR 43; heads-first slots and a window
-layer's `start`, which share nothing, PR 45), and what it traces to, which is
-set-up's time.  (Against the XLA gather: tests/test_paged_decode_pallas.py.)
+layer's `start`, which share nothing, PR 45; a step of the shared pass by one
+copy too, PR 52), and what it traces to, which is set-up's time.  (Against
+the XLA gather: tests/test_paged_decode_pallas.py.)
 """
 
 import hashlib
@@ -22,7 +23,13 @@ from llm_d_kv_cache_manager_tpu.ops.paged_decode_pallas import (
 )
 from tests.helpers.jaxprs import equations
 from tests.test_latent_attention import SCALE, VALUE, W
-from tests.test_paged_decode_pallas import BS, LAYOUTS, close, shared_case
+from tests.test_paged_decode_pallas import (
+    BS,
+    LAYOUTS,
+    SERVED_ROWS,
+    close,
+    shared_case,
+)
 
 
 class CountedCopy:
@@ -32,6 +39,7 @@ class CountedCopy:
     loop the kernel put it in."""
 
     started: list = []
+    waited: list = []  # how many blocks each wait was for
 
     def __init__(self, copy, block, blocks):
         self.copy, self.block, self.blocks = copy, block, blocks
@@ -43,35 +51,46 @@ class CountedCopy:
         self.copy.start()
 
     def wait(self):
+        jax.debug.callback(lambda: CountedCopy.waited.append(self.blocks))
         self.copy.wait()
 
 
-@pytest.fixture
-def counted_walk(monkeypatch):
-    """The walk's kernel with its copies counted (the shared pass's are not)."""
-    make, walk = pltpu.make_async_copy, paged_decode_pallas._walk_kernel
+def counted_kernel(monkeypatch, name: str) -> list:
+    """One of the module's two kernels with its copies counted (the other's
+    are not)."""
+    make, kernel = pltpu.make_async_copy, getattr(paged_decode_pallas, name)
 
     def counted(src, dst, sem):
         # the pool blocks a descriptor names: its source's slice of the pool
         blocks = src.transforms[0].indices[0]
         return CountedCopy(make(src, dst, sem), blocks.start, blocks.size)
 
-    def walk_counted(*refs, **statics):
+    def kernel_counted(*refs, **statics):
         with monkeypatch.context() as m:
             m.setattr(pltpu, "make_async_copy", counted)
-            return walk(*refs, **statics)
+            return kernel(*refs, **statics)
 
-    monkeypatch.setattr(paged_decode_pallas, "_walk_kernel", walk_counted)
-    CountedCopy.started = []
+    monkeypatch.setattr(paged_decode_pallas, name, kernel_counted)
+    CountedCopy.started, CountedCopy.waited = [], []
     return CountedCopy.started
 
 
-def plan_of(slots, table, ctx, wave) -> dict:
+@pytest.fixture
+def counted_walk(monkeypatch):
+    return counted_kernel(monkeypatch, "_walk_kernel")
+
+
+@pytest.fixture
+def counted_shared(monkeypatch):
+    return counted_kernel(monkeypatch, "_shared_kernel")
+
+
+def plan_of(slots, table, ctx, wave, shared_step=2) -> dict:
     """The plan a call over such slots makes: heads-first ones share
     nothing."""
     least = {"min_sequences": None} if slots == "heads_first" else {}
     return shared_prefix_plan(table, ctx, block_size=BS, blocks_per_wave=wave,
-                              **least)
+                              shared_blocks_per_step=shared_step, **least)
 
 
 @pytest.mark.parametrize("slots", LAYOUTS)
@@ -90,7 +109,8 @@ def test_the_walk_copies_each_block_in_context_past_the_runs_once(
     the context (the walk before it multiplied whole steps of 32)."""
     args, statics, ref = shared_case(name, slots)
     _, _, table, ctx = args
-    plan = plan_of(slots, table, ctx, statics["walk_blocks_per_wave"])
+    plan = plan_of(slots, table, ctx, statics["walk_blocks_per_wave"],
+                   statics["shared_blocks_per_step"])
     own = [block for copy in expected_copies(
         table, ctx, plan, statics["walk_blocks_per_wave"]) for block in copy]
     # not through the jit's cache: the counted kernel must be traced
@@ -116,13 +136,21 @@ def expected_copies(table, ctx, plan, wave) -> list:
     table, skip = np.asarray(table), np.asarray(plan["walk"][1])
     copies = []
     for b, c in enumerate(np.asarray(ctx)):
-        own = table[b, skip[b]:-(-int(c) // BS)]
-        for at in range(0, len(own), wave):
-            ids = [int(i) for i in own[at:at + wave]]
-            if len(ids) == wave and ids == list(range(ids[0], ids[0] + wave)):
-                copies.append(ids)
-            else:
-                copies.extend([i] for i in ids)
+        copies += copies_of(table[b, skip[b]:-(-int(c) // BS)], wave)
+    return copies
+
+
+def copies_of(blocks, wave) -> list:
+    """The copies that bring ``blocks`` (ids in a table row's order) a wave
+    at a time: a whole wave whose ids ascend by one as one copy, any other a
+    copy a block."""
+    copies = []
+    for at in range(0, len(blocks), wave):
+        ids = [int(i) for i in blocks[at:at + wave]]
+        if len(ids) == wave and ids == list(range(ids[0], ids[0] + wave)):
+            copies.append(ids)
+        else:
+            copies.extend([i] for i in ids)
     return copies
 
 
@@ -146,17 +174,37 @@ RUN_CASES = {
     "a_run_starts_at_a_shared_runs_end": ([(3, 8, 0), (3, 5, 1)], "ascending"),
 }
 RUN_LAYOUTS = {**LAYOUTS, "latent": (5, 1, W)}
+# The same, and the ids of the prompt the sequences share: the shared pass
+# takes it 2 blocks a step, the last step of 7 a single block.
+SHARED_RUN_CASES = {
+    "a_prompt_dealt_of_a_fresh_pool": (
+        [(7, 3, 0), (7, 5, 0), (0, 4, 0), (7, 1, 2)], "ascending",
+        [1, 2, 3, 4, 5, 6, 7]),
+    "one_break_inside_a_step": (  # 4, 3: the second step of four
+        [(7, 3, 0), (7, 5, 0), (7, 1, 2)], "ascending", [1, 2, 4, 3, 5, 6, 7]),
+    "a_prompt_in_no_order": (
+        [(7, 3, 0), (7, 5, 0), (7, 1, 2)], "no_order", [6, 2, 7, 4, 1, 3, 5]),
+    # two prompts, the second's run of 4 two whole steps
+    "two_prompts": (
+        [(7, 2, 0), (4, 3, 0), (7, 4, 0), (4, 5, 1)], "ascending",
+        [1, 2, 3, 4, 5, 6, 7]),
+}
+SHARED_RUN_LAYOUTS = {
+    **{k: v for k, v in RUN_LAYOUTS.items() if k != "heads_first"},
+    "two_kv_heads_of_32": SERVED_ROWS["two_kv_heads_of_32"]}
 
 
 def run_case(name, slots):
     """(q, pool, table, ctx), the call's static arguments, and the same
     content with the pool's blocks permuted into no order and the table
     naming them there."""
-    sequences, order = RUN_CASES[name]
-    H, Hkv, D = RUN_LAYOUTS[slots]
+    sequences, order, *prompt = {**RUN_CASES, **SHARED_RUN_CASES}[name]
+    prompt = prompt[0] if prompt else [1, 2, 3]
+    H, Hkv, D = {**RUN_LAYOUTS, **SHARED_RUN_LAYOUTS}[slots]
     rng = np.random.default_rng(43)
-    columns, N = 12, 1 + 3 + sum(own + more for _, own, more in sequences)
-    free = iter(range(4, N))
+    columns = 12
+    N = 1 + len(prompt) + sum(own + more for _, own, more in sequences)
+    free = iter(range(1 + len(prompt), N))
     table, ctx = [], []
     for shared, own, more in sequences:
         ids = [next(free) for _ in range(own + more)]
@@ -167,7 +215,8 @@ def run_case(name, slots):
         elif order == "broken":  # a swap inside each wave
             for at in range(1, len(ids) - 1, WAVE):
                 ids[at], ids[at + 1] = ids[at + 1], ids[at]
-        row = [1, 2, 3][:shared] + ids
+        # the second of two prompts is the first's last blocks
+        row = prompt[len(prompt) - shared:] + ids
         table.append(row + [0] * (columns - len(row)))
         ctx.append((shared + own) * BS - int(rng.integers(0, BS)))
     table, ctx = np.asarray(table, np.int32), jnp.asarray(ctx, jnp.int32)
@@ -214,6 +263,58 @@ def test_a_wave_that_is_a_run_in_the_pool_comes_by_one_copy(
         *in_no_order, **statics)
     jax.effects_barrier()
     assert len(counted_walk) == len(brought(copies))
+    np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                  np.asarray(scattered, np.float32))
+
+
+# ------------------------------------------------ a shared step that is a run
+
+@pytest.mark.parametrize("name, slots", [
+    # every layout over a prompt that lies in runs; the other tables at two
+    # (a file is one worker's in tier-1: seconds here are the run's)
+    (name, slots) for name in SHARED_RUN_CASES for slots in (
+        SHARED_RUN_LAYOUTS if name == "a_prompt_dealt_of_a_fresh_pool"
+        else ("packed", "two_kv_heads_of_32"))])
+def test_a_shared_step_that_is_a_run_comes_by_one_copy(
+        counted_shared, name, slots):
+    """Copies the shared pass issues = whole ascending steps + loose blocks: a
+    step of a group's run that lies wholly inside it, its blocks one after
+    another in the pool in the table's order, is one descriptor of a step's
+    blocks and one wait, any other step (one with a break, the run's last
+    partial one) a descriptor and a wait a block; every block of every
+    group's run is brought once either way, none past the run's end; the
+    plan's `shared_run_blocks` is what came by runs; and the output is that
+    of the XLA gather and, bit for bit, that of the same content lying in no
+    order."""
+    args, statics, in_no_order = run_case(name, slots)
+    q, pool, table, ctx = args
+    step = statics["shared_blocks_per_step"]
+    plan = plan_of(slots, table, ctx, WAVE, step)
+    row, run, _, step_runs = (np.asarray(a) for a in plan["shared"])
+    prompts = [np.asarray(table)[row[g], :run[g]]
+               for g in range(int(plan["shared_steps"]))]
+    want = [ids for prompt in prompts for ids in copies_of(prompt, step)]
+    got = paged_decode_attention_pallas.__wrapped__(*args, plan=plan, **statics)
+    jax.effects_barrier()
+    copies = list(counted_shared)
+    assert copies == [(ids[0], len(ids)) for ids in want]
+    assert CountedCopy.waited == [blocks for _, blocks in copies]
+    assert brought(copies) == [int(i) for prompt in prompts for i in prompt]
+    by_runs = sum(blocks for _, blocks in copies if blocks > 1)
+    assert int(plan["shared_run_blocks"]) == by_runs == step * step_runs.sum()
+    assert by_runs == {"a_prompt_dealt_of_a_fresh_pool": 6, "two_prompts": 10,
+                       "one_break_inside_a_step": 4}.get(name, 0)
+    assert int(plan["read_blocks"]) >= sum(len(p) for p in prompts) >= by_runs
+    if slots != "latent":
+        kv = pool if slots != "packed" else jnp.stack(
+            jnp.split(pool, 2, axis=-1), axis=1)
+        close(got, paged_attention(q, kv, table, ctx))
+    counted_shared.clear()
+    scattered = paged_decode_attention_pallas.__wrapped__(
+        *in_no_order, **statics)
+    jax.effects_barrier()
+    # (two blocks of a prompt may come to lie side by side by chance)
+    assert len(brought(counted_shared)) == len(brought(copies))
     np.testing.assert_array_equal(np.asarray(got, np.float32),
                                   np.asarray(scattered, np.float32))
 
@@ -271,13 +372,18 @@ def test_a_window_tables_walk_hides_what_lies_before_its_start(
 # family's waves of 64.
 SERVED_HEADS = {"llama": (16, 8, 128), "packed": (32, 8, 64),
                 "pairwise": (40, 10, 128), "latent": (20, 1, 576),
+                "two_kv_heads": (32, 2, 128),  # `nemotron-3-nano-30b-a3b`
                 "heads_first": (32, 4, 128), "heads_first_window": (32, 4, 128)}
-# sha256 of the traced call's text at 8 sequences of 48 columns, read on commit
-# 81cfceb (PR 44), before the walk knew heads-first slots or a `start`: the
-# four layouts it served trace to what they traced to (a PR that changes the
-# walk for them on purpose reads these anew, and measures their cells).
-TEXT_AT_PR_44 = {"llama": "696644170fc7344a", "packed": "708fa3ec9b129802",
-                 "pairwise": "9616ef93b6d5ab9a", "latent": "b896062ff3f1ec4c"}
+# sha256 of the traced call's text at 8 sequences of 48 columns.  They stood
+# from commit 81cfceb (PR 44), through the walk's learning heads-first slots
+# and a `start` (PR 45), until PR 52 changed the shared pass and the walk for
+# these layouts on purpose (a step is one operand; a shared step that is a run
+# comes by one copy; both kernels' copies are `_bring`'s) and read them anew,
+# with the fifth beside them, and measured their cells (PERF.md section 6).
+# A PR that changes the kernels for them on purpose does the same.
+TEXT_AT_PR_52 = {"llama": "ab27662ee73a62aa", "packed": "5a261fe9528c6da1",
+                 "pairwise": "3a65b6367bff6a5d", "latent": "767839128531f238",
+                 "two_kv_heads": "55d4d6de63cffd28"}
 
 
 @pytest.mark.parametrize("slots", SERVED_HEADS)
@@ -317,9 +423,9 @@ def test_the_walk_traces_to_the_same_kernel_whatever_the_table(slots):
     counts = {shape: traced(*shape)
               for shape in ((8, 48), (8, 192), (32, 48), (32, 192))}
     assert len(set(counts.values())) == 1, counts
-    if slots in TEXT_AT_PR_44:
+    if slots in TEXT_AT_PR_52:
         assert hashlib.sha256(texts[8, 48].encode()).hexdigest()[:16] == (
-            TEXT_AT_PR_44[slots])
+            TEXT_AT_PR_52[slots])
     wave = paged_decode_pallas.walk_wave(
         jax.ShapeDtypeStruct(pool, jnp.bfloat16))
     assert wave <= paged_decode_pallas.WALK_WAVE_BLOCKS

@@ -368,7 +368,8 @@ def test_the_three_programs_serve_the_reference_tokens_and_report_spans():
     # shared prompt's four blocks once, each sequence's other three
     assert spans["attention.read"][-1] == {"read_blocks": 4 + 2 * 3,
                                            "walked_blocks": 14,
-                                           "run_blocks": 0}
+                                           "run_blocks": 0,
+                                           "shared_run_blocks": 0}
 
 
 # ---------------------------------------------------- the cache's three groups

@@ -237,11 +237,12 @@ def test_a_steps_counts_are_read_at_the_next_call_with_their_values(family):
     assert not named(decodes[0], "attention.read")
     for kept, spans in zip(run.kept, decodes[1:]):
         if "attention_read" in counts:
-            read, walked, by_runs = kept["attention_read"]
+            read, walked, by_runs, by_shared_runs = kept["attention_read"]
             assert [s["attrs"] for s in named(spans, "attention.read")] == [
                 {"read_blocks": int(read), "walked_blocks": int(walked),
-                 "run_blocks": int(by_runs)}]
-            assert 0 <= by_runs <= read
+                 "run_blocks": int(by_runs),
+                 "shared_run_blocks": int(by_shared_runs)}]
+            assert 0 <= by_runs + by_shared_runs <= read
         if "load" in counts:
             assert [s["attrs"] for s in named(spans, "moe.expert_load")] == [
                 {"layer": layer, "experts_held": cfg.n_experts,

@@ -845,7 +845,9 @@ def test_nemotronh_programs_compile_at_the_cells_shapes(one_chip, monkeypatch,
 # `lfm2moe.decode.True` in PR 41 (the interpreted step holds the paged
 # kernel, whose walk became a sequence a grid step with its own copies) and
 # in PR 43 (a wave of the walk that is a run in the pool comes by one copy,
-# and the step counts `run_blocks`).
+# and the step counts `run_blocks`) and in PR 52 (a step of `packed` slots is
+# one operand, the shared pass brings a step that is a run by one copy, and
+# the step counts `shared_run_blocks`).
 AT_PR_34 = {
     "afmoe.tables": "66348e6e9d5f9ed8", "lfm2moe.tables": "a43cc9a2ce3fa258",
     "lfm2moe.miss.False": "6b66837c74b57be8",
@@ -853,7 +855,7 @@ AT_PR_34 = {
     "lfm2moe.decode.False": "4855b16f775d4a8e",
     "lfm2moe.miss.True": "6b66837c74b57be8",
     "lfm2moe.hit.True": "a0f712e977db715f",
-    "lfm2moe.decode.True": "a1a34d0c45c97e86",
+    "lfm2moe.decode.True": "d4b822376274fd6c",
 }
 
 
