@@ -11,15 +11,18 @@ lies (the reasons stand beside the spec's fields):
   ``latent``       [block / 2, 2 * W]        `glm4moelite`: a vector a position
   ``selected``     [block + t, 2 * Hkv, Dh]  `keyevl2`: a tile a position, then
                                              the block's selector keys
+  ``latent_selected``  [block / 2, 2 * W + 2 * dI]  `deepseekv32`: the latent
+                                             row, then its two selector keys
 
 (and ``state``: a recurrent layer's state at a block's end, no K/V).  A family
 names its layout once, in its ``cache_groups``, hands the group's spec to the
 operations below (``write_blocks``, ``write_token``, ``gather_prefix``,
 ``decode_view``; for the kinds attended over where they lie
-``unpack_latent_blocks``, ``gather_selector_keys``, ``gather_picked_tiles``)
-and never looks into a pool array.  A new layout is a branch in each of these
-and in ``KVGroupSpec.layer_shape``, here and nowhere else (the kernels in ops/
-read a slot by the keywords ``decode_view`` gives).
+``unpack_latent_blocks``, ``gather_selector_keys``, ``gather_picked_tiles``,
+``gather_picked_latents``) and never looks into a pool array.  A new layout
+is a branch in each of these and in ``KVGroupSpec.layer_shape``, here and
+nowhere else (the kernels in ops/ read a slot by the keywords ``decode_view``
+gives, or by the lanes a family's spec names).
 
 ``KVCachePool`` stacks the layers, ``[num_layers, num_blocks, *slot]``: one
 jitted gather/scatter moves a block batch across all layers in one XLA op and
@@ -62,6 +65,8 @@ class KVGroupSpec:
     position a layer, K and V per head AND one selector key of that many
     lanes (learned sparse attention: an indexer scores every cached position
     by its key and attention reads the best only), kept and evicted together.
+    With both, a slot holds the latent vector and the selector key of each
+    position (learned sparse attention over a latent cache).
     Block bytes, pool shapes and the scatter's geometry are read from here by
     the pool below, by the pod's cache (models/pod.py) and by each family's
     model step."""
@@ -116,11 +121,29 @@ class KVGroupSpec:
     # how many positions a query reads of the group, the best by the
     # selector's score (None: every position the window or context admits)
     selected: Optional[int] = None
+    # The latent-selected kind (``latent_dim`` AND ``selector_dim``): a slot
+    # as [block / 2, 2 * latent_dim + 2 * selector_dim], row r the latent
+    # kind's row of the positions r and r + block / 2 (mirrored, as above),
+    # then their two selector keys, [key_r | key_r+].  A position's 704 lanes
+    # (576 + 128 in models/deepseekv32.py) are 5.5 of the chip's 128-lane
+    # tiles; two positions a row are 11: the latent part is the latent kind's
+    # to the lane (its kernels read a row's first 9 tiles as they read a
+    # latent slot: both values start on a tile), the keys are a row's last 2
+    # tiles, which a copy that names lanes brings without the latents (a
+    # decode step's scores read 256 B of a position's 1408), and a query's
+    # picked positions come as rows of the pool seen a row a line
+    # (``gather_picked_latents``: a row is the least piece of whole tiles
+    # that holds a position's latent).  Keys in rows of their own, as the
+    # selected kind has them, would make a slot 8 rows of 1152 lanes and 1 of
+    # 2048: no one array.
 
     def __post_init__(self) -> None:
-        if self.selector_dim is not None:
+        if self.selector_dim is not None and self.latent_dim is not None:
+            if self.selector_dim <= 0:
+                raise ValueError("a selector key has lanes")
+        elif self.selector_dim is not None:
             tile = 2 * self.num_kv_heads * self.head_dim
-            if (self.latent_dim is not None or self.state_shape is not None
+            if (self.state_shape is not None
                     or self.selector_dim <= 0
                     or self.head_dim % self.selector_dim
                     or (self.block_size * self.selector_dim) % tile):
@@ -139,6 +162,8 @@ class KVGroupSpec:
                  if getattr(self, n)]
         named += [n for n in ("latent_dim", "selector_dim", "state_shape")
                   if getattr(self, n) is not None]
+        if named == ["latent_dim", "selector_dim"]:
+            named = ["latent_selected"]  # one kind: both in a row
         if len(named) > 1:
             raise ValueError("a slot lies one way: " + ", ".join(named)
                              + " exclude each other")
@@ -146,11 +171,11 @@ class KVGroupSpec:
     @property
     def layout(self) -> str:
         """How a slot lies, from the fields above (the module's head has the
-        six K/V layouts): what the operations below branch on."""
+        seven K/V layouts): what the operations below branch on."""
         if self.state_shape is not None:
             return "state"
         if self.latent_dim is not None:
-            return "latent"
+            return "latent" if self.selector_dim is None else "latent_selected"
         if self.selector_dim is not None:
             return "selected"
         for name in ("heads_first", "packed", "rows"):
@@ -202,7 +227,8 @@ class KVGroupSpec:
 
     @property
     def selector_tiles(self) -> int:
-        """Tiles of [2 * Hkv, Dh] that hold a block's selector keys."""
+        """Tiles of [2 * Hkv, Dh] that hold a block's selector keys (the
+        selected kind's)."""
         return (self.block_size * self.selector_dim
                 // (2 * self.num_kv_heads * self.head_dim))
 
@@ -218,7 +244,8 @@ class KVGroupSpec:
             ((shape, _),) = self.state_parts
             return (num_blocks,) + shape
         if self.latent_dim:
-            return (num_blocks, self.block_size // 2, 2 * self.latent_dim)
+            return (num_blocks, self.block_size // 2,
+                    2 * (self.latent_dim + (self.selector_dim or 0)))
         if self.selector_dim:
             return (num_blocks, self.slot_tiles, 2 * self.num_kv_heads,
                     self.head_dim)
@@ -317,6 +344,19 @@ def unpack_latent_blocks(slots, value_dim: int):
     return jnp.stack((a, b), axis=-3).reshape(*lead, n * 2 * half, width // 2)
 
 
+def pack_latent_selected_blocks(latent, key, block_size: int, value_dim: int):
+    """Per-position latents [..., T, latent_dim] and selector keys
+    [..., T, dI] as the slots of a latent-selected group: the latent kind's
+    rows (``pack_latent_blocks``), each followed by its two positions' keys,
+    [..., T/block_size, block_size/2, 2*latent_dim + 2*dI]."""
+    *lead, T, dI = key.shape
+    keys = key.reshape(*lead, T // block_size, 2, block_size // 2, dI)
+    return jnp.concatenate(
+        (pack_latent_blocks(latent, block_size, value_dim),
+         keys[..., 0, :, :].astype(latent.dtype),
+         keys[..., 1, :, :].astype(latent.dtype)), axis=-1)
+
+
 def pack_selected_blocks(k, v, key, block_size: int):
     """Per-position K and V ([..., T, Hkv, Dh] each) and selector keys
     ([..., T, dI]), T a multiple of ``block_size``, as the slots of a
@@ -355,10 +395,13 @@ def write_blocks(spec: KVGroupSpec, pool, block_ids, *parts):
     [B, T/block] of ``pool``.  ``parts``: per-token K and V [B, T, Hkv, Dh]
     each (T a multiple of the block size); for a latent group the latents
     [B, T, latent_dim]; for a selected group K, V and the selector keys
-    [B, T, dI].  Only the named slots are written (``scatter_kv_blocks``)."""
+    [B, T, dI]; for a latent-selected group the latents and the keys.  Only
+    the named slots are written (``scatter_kv_blocks``)."""
     bs, layout = spec.block_size, spec.layout
     if layout == "latent":
         slots = pack_latent_blocks(*parts, bs, spec.value_dim)
+    elif layout == "latent_selected":
+        slots = pack_latent_selected_blocks(*parts, bs, spec.value_dim)
     elif layout == "selected":
         slots = pack_selected_blocks(*parts, bs)
     elif layout == "packed":  # a reshape, no transpose
@@ -433,14 +476,14 @@ def _patched_slots(spec: KVGroupSpec, pool, ids, at, *parts):
                        (1, 1, bs, 1))  # row r: head r % Hkv
         here = jnp.arange(bs * Hkv)[None, :] // Hkv == at[:, None]
         return jnp.where(here[:, None, :, None], new, slots)
-    if layout == "latent":
+    if layout in ("latent", "latent_selected"):
         # position p of a block is the first half of row p if p is in the
         # block's first half, else the second half, mirrored, of row
-        # p - block/2 (`pack_latent_blocks`)
-        (new,), value_dim = parts, spec.value_dim
+        # p - block/2 (`pack_latent_blocks`); its selector key, where the
+        # kind has one, the first or the second of the row's two
+        new, value_dim = parts[0].astype(pool.dtype), spec.value_dim
         half, width = bs // 2, 2 * spec.latent_dim
-        slots = jnp.take(pool, ids, axis=0)  # [B, block/2, 2 latent]
-        new = new.astype(pool.dtype)
+        slots = jnp.take(pool, ids, axis=0)  # [B, block/2, 2 latent (+ 2 dI)]
         second = (at >= half)[:, None]
         zeros = jnp.zeros_like(new)
         row = jnp.where(
@@ -448,6 +491,12 @@ def _patched_slots(spec: KVGroupSpec, pool, ids, at, *parts):
             jnp.concatenate((zeros, new[:, value_dim:], new[:, :value_dim]), -1),
             jnp.concatenate((new, zeros), -1))  # [B, 2 latent]
         lanes = (jnp.arange(width)[None, :] >= width // 2) == second
+        if layout == "latent_selected":
+            key = parts[1].astype(pool.dtype)
+            dI = spec.selector_dim
+            row = jnp.concatenate((row, key, key), -1)
+            lanes = jnp.concatenate(
+                (lanes, (jnp.arange(2 * dI)[None, :] >= dI) == second), -1)
         here = ((jnp.arange(half)[None, :] == (at % half)[:, None])[:, :, None]
                 & lanes[:, None, :])
         return jnp.where(here, row[:, None, :], slots)
@@ -474,12 +523,13 @@ def _patched_slots(spec: KVGroupSpec, pool, ids, at, *parts):
 def write_token(spec: KVGroupSpec, pool, ids, at, *parts):
     """Position ``at[b]`` of slot ``ids[b]`` = ``parts[b]`` for each sequence
     of a decode step (``parts``: K and V [B, Hkv, Dh] each; the latent
-    [B, latent_dim]; K, V and the selector key [B, dI]), as whole slots: each
-    sequence's current slot is read, patched at its position and put back by
-    one slice update along the pool's first axis.  (A scatter or a slice
-    update that addresses the position axis makes the compiler re-lay-out the
-    whole pool around it, twice a layer.)  Idle rows share one scratch slot;
-    what they leave there is read by nobody."""
+    [B, latent_dim]; K, V and the selector key [B, dI]; the latent and the
+    selector key), as whole slots: each sequence's current slot is read,
+    patched at its position and put back by one slice update along the
+    pool's first axis.  (A scatter or a slice update that addresses the
+    position axis makes the compiler re-lay-out the whole pool around it,
+    twice a layer.)  Idle rows share one scratch slot; what they leave there
+    is read by nobody."""
     slots = _patched_slots(spec, pool, ids, at, *parts)
 
     def one(b, pool):
@@ -514,7 +564,20 @@ def decode_view(spec: KVGroupSpec, pool, kernel: bool):
 def gather_selector_keys(spec: KVGroupSpec, pool, table):
     """The selector keys of the positions ``table`` ([B, n]) names, in order:
     [B, n * block, dI].  Only the key tiles of the table's slots are read, of
-    the pool with a tile a row (merging two leading axes moves nothing)."""
+    the pool with a tile a row (merging two leading axes moves nothing); of a
+    latent-selected pool the table's slots whole, their rows' last lanes kept
+    (a gather whose slices start at those lanes made the compiler re-lay-out
+    the whole pool, slot axis minor, 6.9 GB for a 0.44-GB layer; compiled
+    for the v5e, PR 53)."""
+    if spec.layout == "latent_selected":
+        B, n = table.shape
+        half, dI = spec.block_size // 2, spec.selector_dim
+        keys = pool.at[table.reshape(-1)].get(mode="promise_in_bounds")[
+            ..., 2 * spec.latent_dim:]
+        # [B * n, half, (first | second)] -> a block's first half, then its
+        # second
+        return keys.reshape(B, n, half, 2, dI).swapaxes(2, 3).reshape(
+            B, n * 2 * half, dI)
     per, bs = spec.slot_tiles, spec.block_size
     at = table[..., None] * per + bs + jnp.arange(per - bs)
     tiles = pool.reshape((-1,) + pool.shape[2:])
@@ -528,6 +591,25 @@ def gather_picked_tiles(spec: KVGroupSpec, pool, tiles):
     position)."""
     rows = jnp.take(pool.reshape((-1,) + pool.shape[2:]), tiles, axis=0)
     return rows[:, :, :spec.num_kv_heads], rows[:, :, spec.num_kv_heads:]
+
+
+def gather_picked_latents(spec: KVGroupSpec, pool, rows, second):
+    """The latents [B, K, latent_dim] of the positions that ``rows`` [B, K]
+    (rows of a latent-selected pool seen a row a line: slot * block / 2 +
+    position % (block / 2)) and ``second`` [B, K] (the position lies in its
+    block's second half) name: a row is gathered whole and the mirrored half
+    turned back.  The rows are the caller's promise (they come from a table's
+    slots): a gather that has to answer for a row outside the pool fills it
+    in a pass of its own, 3.79 ms against 2.29 for 32 x 2048 rows of 2816 B
+    (my chip run, PR 53)."""
+    W, value = spec.latent_dim, spec.value_dim
+    lines = pool.reshape((-1, pool.shape[-1])).at[rows].get(
+        mode="promise_in_bounds")
+    return jnp.where(
+        second[..., None],
+        jnp.concatenate((lines[..., 2 * W - value:2 * W],
+                         lines[..., W:2 * W - value]), axis=-1),
+        lines[..., :W])
 
 
 @dataclass

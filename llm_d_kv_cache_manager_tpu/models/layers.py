@@ -10,6 +10,8 @@ models/llama.py keeps its own norm (a constant epsilon, ROADMAP D12).
 
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
 from jax import lax
@@ -27,6 +29,26 @@ def rms_norm(x, w, eps, dtype=None):
     xf = x.astype(jnp.float32)
     norm = xf * lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
     return (norm * w.astype(jnp.float32)).astype(dtype or x.dtype)
+
+
+def yarn_inv_freq(dim: int, theta: float, factor: float, original: int,
+                  beta_fast: float, beta_slow: float):
+    """The ``dim / 2`` inverse frequencies of a rotation over ``dim`` lanes,
+    rescaled as YaRN does: pair i turns by ``pos * f'_i``, ``f_i =
+    theta^(-2i/dim)``; pairs that turn more than ``beta_fast`` times over the
+    ``original`` context keep ``f_i``, those that turn fewer than
+    ``beta_slow`` times take ``f_i / factor``, and between the two (pairs
+    ``low`` to ``high``) a ramp mixes them.  float32 [dim / 2]."""
+    def pair_of(turns):  # the pair that turns this often over `original`
+        return dim * math.log(original / (turns * 2 * math.pi)) / (
+            2 * math.log(theta))
+
+    low = max(math.floor(pair_of(beta_fast)), 0)
+    high = min(math.ceil(pair_of(beta_slow)), dim // 2 - 1)
+    i = jnp.arange(dim // 2, dtype=jnp.float32)
+    freqs = theta ** (-i / (dim // 2))
+    ramp = jnp.clip((i - low) / max(high - low, 1e-3), 0.0, 1.0)
+    return freqs * (1.0 - ramp) + freqs / factor * ramp
 
 
 def rope(x, positions, theta):

@@ -1,8 +1,9 @@
 """The serve path's expert layer: the router, and the sum over each token's
 picked experts without a capacity.  Shared by the families of two score
 kinds: sigmoid scores an expert, with a selection bias (`models/afmoe.py`,
-`models/lfm2moe.py`, `models/glm4moelite.py`), and softmax scores over all
-experts, with none (`models/keyevl2.py`).  (`models/moe.py` is the training
+`models/lfm2moe.py`, `models/glm4moelite.py`, `models/nemotronh.py`; with a
+limit to the best groups of experts, `models/deepseekv32.py`), and softmax
+scores over all experts, with none (`models/keyevl2.py`).  (`models/moe.py` is the training
 path's: softmax scores, a static capacity, tokens over it dropped.)
 
 What differs between the families is an argument, and where an argument would
@@ -22,23 +23,41 @@ MOE_CHUNK_TOKENS = 4096
 
 
 def route(h, router, bias, top_k: int, norm: bool, scale: float,
-          norm_eps: float | None = None, scores: str = "sigmoid"):
+          norm_eps: float | None = None, scores: str = "sigmoid",
+          n_group: int = 1, topk_group: int = 1):
     """h: [N, D] float32 -> (experts picked [N, k], their weights [N, k]
     float32).  Scores in float32 at precision highest, ``sigmoid(h .
     router)`` an expert or, with ``scores="softmax"``, the softmax of
     ``h . router`` over all experts; the bias (None: the family has none)
     enters the selection only; with ``norm`` the picked scores are divided by
     their sum (plus ``norm_eps`` where a family's published code adds one),
-    then multiplied by ``scale``."""
+    then multiplied by ``scale``.  With ``n_group`` > 1 the experts are that
+    many groups of neighbouring ids, a group's score is the sum of its two
+    largest ``s + bias``, and a token picks within its ``topk_group`` best
+    groups only (ties to the lower group, as to the lower expert); with 1
+    (every other family) the operations are what they were."""
     if scores not in ("sigmoid", "softmax"):
         raise ValueError(f"route: scores={scores!r} is not implemented")
+    if n_group < 1 or router.shape[-1] % n_group or not (
+            1 <= topk_group <= n_group):
+        raise ValueError("route: groups divide the experts evenly, and a "
+                         "token picks within 1 to n_group of them")
     s = jnp.dot(
         h.astype(jnp.float32),
         router.astype(jnp.float32),
         precision=HI,
     )
     s = jax.nn.sigmoid(s) if scores == "sigmoid" else jax.nn.softmax(s, -1)
-    _, picked = lax.top_k(s if bias is None else s + bias, top_k)
+    choose = s if bias is None else s + bias
+    if n_group > 1:
+        grouped = choose.reshape(choose.shape[0], n_group, -1)
+        best, _ = lax.top_k(grouped, 2)
+        _, groups = lax.top_k(best.sum(-1), topk_group)
+        kept = jnp.zeros((choose.shape[0], n_group), bool).at[
+            jnp.arange(choose.shape[0])[:, None], groups].set(True)
+        choose = jnp.where(kept[:, :, None], grouped, -jnp.inf).reshape(
+            choose.shape)
+    _, picked = lax.top_k(choose, top_k)
     w = jnp.take_along_axis(s, picked, axis=1)
     if norm:
         total = jnp.sum(w, axis=1, keepdims=True)

@@ -849,13 +849,14 @@ class Pod:
                 blocks = int(((live - 1) // full.block_size + 1).sum())
                 with span("kv.read") as s:
                     s.set_attr("full_blocks", blocks)
-                    if full.latent_dim:
+                    if full.selector_dim is None:
                         read = blocks * full.read_nbytes
                         s.set_attr("latent_bytes", read)
                     else:
-                        # every live position's selector key, and K and V of
-                        # the positions a query picks; beside them what
-                        # reading every live position's K and V would be
+                        # every live position's selector key, and K and V
+                        # (or the latent vector) of the positions a query
+                        # picks; beside them what reading every live
+                        # position's would be
                         position = full.read_nbytes // full.block_size
                         key = (full.num_readers * full.selector_dim
                                * jnp.dtype(full.dtype).itemsize)

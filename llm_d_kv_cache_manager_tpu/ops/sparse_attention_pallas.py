@@ -32,6 +32,11 @@ heads'), then the block's selector keys.
 - ``picked_tiles``: a row's picks as rows of the pool seen a tile a row, in
   position order, by counting and small products (XLA): what a decode step
   hands a gather.
+- ``latent_index_scores_pallas`` / ``picked_latent_rows``: the same two over
+  a pool whose slot is a latent vector and a selector key a position
+  (``KVGroupSpec``'s latent-selected kind, models/deepseekv32.py): the walk
+  copies a row's last lanes only, its two positions' keys, and a pick is a
+  row of the pool and which half of it.
 
 Products in the serving type with float32 sums, as everywhere on this path.
 """
@@ -39,6 +44,7 @@ Products in the serving type with float32 sums, as everywhere on this path.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -167,6 +173,21 @@ def picked_tiles(picked, block_table, k: int, block_size: int,
         (lane // block_size)[:, :, None] == jnp.arange(per), block, 0), axis=-1)
     block = jnp.where(valid, block, block_table[:, :1])
     return (block * slot_tiles + lane % block_size, row * 128 + lane, valid)
+
+
+def picked_latent_rows(picked, block_table, k: int, block_size: int):
+    """``picked_tiles`` for a pool of latent-selected slots ([slots,
+    block_size / 2, ..], a row two positions): -> (rows [B, k] int32: the
+    picked positions, in order, as rows of the pool seen a row a line, ``slot
+    * block_size / 2 + position % (block_size / 2)``; second [B, k] bool: the
+    position is its row's second; positions [B, k] int32; which of the k are
+    picks [B, k] bool)."""
+    half = block_size // 2
+    at, positions, valid = picked_tiles(picked, block_table, k, block_size,
+                                        block_size)
+    inside = at % block_size
+    return (at // block_size * half + inside % half, inside >= half,
+            positions, valid)
 
 
 # ---------------------------------------------------------- the index scores
@@ -412,30 +433,33 @@ def _walk_scores_kernel(
     table_ref,  # SMEM [B, n] int32 (scalar prefetch)
     runs_ref,  # SMEM [B, n / W] int32: a wave's blocks lie one after another
     count_ref,  # SMEM [B] int32: waves that hold a position of the context
-    q_ref,  # VMEM [1, per*HI, Dh]: the heads' queries, a lane group a copy
+    q_ref,  # VMEM [1, per*HI, lanes]: the heads' queries, a lane group a copy
     w_ref,  # VMEM [1, per*HI, 1] float32
-    pool_ref,  # HBM [slots, bs + t, rows, Dh]
+    pool_ref,  # HBM [slots, *slot]
     out_ref,  # VMEM [1, per, n * R]
-    buf,  # VMEM [2, W, t, rows, Dh]
+    buf,  # VMEM [2, W, *a slot's keys]: R rows of `lanes` a block in all
     sem,  # DMA [2]
     *,
-    block_size: int,
+    keys_at: tuple,
     heads: int,
 ):
+    """``keys_at``: where a slot's selector keys lie, the index of a slot's
+    piece after its number: the tiles behind the positions' of a selected
+    slot, the last lanes of every row of a latent-selected one."""
     b = pl.program_id(0)
-    W, t, rows, Dh = buf.shape[1:]
-    R = t * rows
+    W, lanes = buf.shape[1], buf.shape[-1]
+    R = math.prod(buf.shape[2:-1])
     per = q_ref.shape[1] // heads
     n_waves = count_ref[b]
 
     def whole(j, slot):
         return pltpu.make_async_copy(
-            pool_ref.at[pl.ds(table_ref[b, j * W], W), pl.ds(block_size, t)],
+            pool_ref.at[(pl.ds(table_ref[b, j * W], W),) + keys_at],
             buf.at[slot], sem.at[slot])
 
     def single(j, i, slot):
         return pltpu.make_async_copy(
-            pool_ref.at[table_ref[b, j * W + i], pl.ds(block_size, t)],
+            pool_ref.at[(table_ref[b, j * W + i],) + keys_at],
             buf.at[slot, i], sem.at[slot])
 
     def each(j, slot, what):
@@ -465,7 +489,7 @@ def _walk_scores_kernel(
             each(j + 1, 1 - slot, lambda copy: copy.start())
 
         each(j, slot, lambda copy: copy.wait())
-        keys = buf[slot].reshape(W * R, Dh)
+        keys = buf[slot].reshape(W * R, lanes)
         s = lax.dot_general(q, keys, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32)
         s = (jnp.maximum(s, 0.0) * w).reshape(per, heads, W * R).sum(axis=1)
@@ -473,6 +497,55 @@ def _walk_scores_kernel(
         return 0
 
     lax.fori_loop(0, n_waves, wave, 0)
+
+
+def _walked_scores(q, w, kv_pool, block_table, context_len, *, keys_at,
+                   keys_shape, block_size, wave_blocks, interpret):
+    """The walk both pools' decode scores share: ``keys_shape`` is a slot's
+    keys as ``keys_at`` cuts them out, R rows of ``per`` positions side by
+    side; row r of lane group g holds position ``g * R + r`` of its block."""
+    B, HI, dI = q.shape
+    N, bs = kv_pool.shape[0], block_size
+    lanes = keys_shape[-1]
+    per, R = lanes // dI, math.prod(keys_shape[:-1])
+    n = block_table.shape[1]
+    W = min(wave_blocks, n)
+    pad = (-n) % W
+    table = jnp.pad(block_table.astype(jnp.int32), ((0, 0), (0, pad)),
+                    mode="edge")
+    waves = table.reshape(B, -1, W)
+    runs = (jnp.all(waves == waves[:, :, :1] + jnp.arange(W), axis=-1)
+            & (waves[:, :, 0] + W <= N)).astype(jnp.int32)
+    count = -(-context_len.astype(jnp.int32) // (W * bs))
+    # the heads' queries once a lane group: row g * HI + j holds q_j in the
+    # lanes of a row's g-th position, so one product scores a row's positions
+    eye = jnp.eye(per, dtype=q.dtype)
+    q2 = jnp.einsum("gh,bjd->bgjhd", eye, q).reshape(B, per * HI, lanes)
+    w2 = jnp.tile(w.astype(jnp.float32), (1, per))[..., None]
+    out = pl.pallas_call(
+        functools.partial(_walk_scores_kernel, keys_at=keys_at, heads=HI),
+        out_shape=jax.ShapeDtypeStruct((B, per, (n + pad) * R), jnp.float32),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(B,),
+            in_specs=[
+                pl.BlockSpec((1, per * HI, lanes), lambda b, *_: (b, 0, 0)),
+                pl.BlockSpec((1, per * HI, 1), lambda b, *_: (b, 0, 0)),
+                pl.BlockSpec(memory_space=pl.ANY),
+            ],
+            out_specs=pl.BlockSpec((1, per, (n + pad) * R),
+                                   lambda b, *_: (b, 0, 0)),
+            scratch_shapes=[pltpu.VMEM((2, W) + tuple(keys_shape),
+                                       kv_pool.dtype),
+                            pltpu.SemaphoreType.DMA((2,))],
+        ),
+        interpret=interpret,
+    )(table, runs, count, q2, w2, kv_pool)
+    # [B, per, blocks, R] -> positions in order: block, lane group, row
+    scores = out.reshape(B, per, n + pad, R).transpose(0, 2, 1, 3).reshape(
+        B, -1)[:, :n * bs]
+    seen = jnp.arange(n * bs)[None, :] < context_len[:, None]
+    return jnp.where(seen, scores, -jnp.inf)
 
 
 @functools.partial(jax.jit, static_argnames=("selector_dim", "wave_blocks",
@@ -489,46 +562,35 @@ def sparse_decode_scores_pallas(q, w, kv_pool, block_table, context_len, *,
     float32; kv_pool: [slots, bs + t, rows, Dh]; block_table: [B, n] int32;
     context_len: [B].  Returns [B, n * bs] float32, ``-inf`` past the
     context."""
-    B, HI, dI = q.shape
+    dI = q.shape[-1]
     N, slot_tiles, rows, Dh = kv_pool.shape
-    per = Dh // dI
     t = slot_tiles * dI // (rows * Dh + dI)  # bs + t tiles, bs * dI = t * tile
     bs = slot_tiles - t
-    R = t * rows
-    n = block_table.shape[1]
-    W = min(wave_blocks, n)
-    pad = (-n) % W
-    table = jnp.pad(block_table.astype(jnp.int32), ((0, 0), (0, pad)),
-                    mode="edge")
-    waves = table.reshape(B, -1, W)
-    runs = (jnp.all(waves == waves[:, :, :1] + jnp.arange(W), axis=-1)
-            & (waves[:, :, 0] + W <= N)).astype(jnp.int32)
-    count = -(-context_len.astype(jnp.int32) // (W * bs))
-    # the heads' queries once a lane group: row g * HI + j holds q_j in the
-    # lanes of a row's g-th position, so one product scores a row's positions
-    eye = jnp.eye(per, dtype=q.dtype)
-    q2 = jnp.einsum("gh,bjd->bgjhd", eye, q).reshape(B, per * HI, Dh)
-    w2 = jnp.tile(w.astype(jnp.float32), (1, per))[..., None]
-    out = pl.pallas_call(
-        functools.partial(_walk_scores_kernel, block_size=bs, heads=HI),
-        out_shape=jax.ShapeDtypeStruct((B, per, (n + pad) * R), jnp.float32),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
-            grid=(B,),
-            in_specs=[
-                pl.BlockSpec((1, per * HI, Dh), lambda b, *_: (b, 0, 0)),
-                pl.BlockSpec((1, per * HI, 1), lambda b, *_: (b, 0, 0)),
-                pl.BlockSpec(memory_space=pl.ANY),
-            ],
-            out_specs=pl.BlockSpec((1, per, (n + pad) * R),
-                                   lambda b, *_: (b, 0, 0)),
-            scratch_shapes=[pltpu.VMEM((2, W, t, rows, Dh), kv_pool.dtype),
-                            pltpu.SemaphoreType.DMA((2,))],
-        ),
-        interpret=interpret,
-    )(table, runs, count, q2, w2, kv_pool)
-    # [B, per, blocks, R] -> positions in order: block, lane group, row
-    scores = out.reshape(B, per, n + pad, R).transpose(0, 2, 1, 3).reshape(
-        B, -1)[:, :n * bs]
-    seen = jnp.arange(n * bs)[None, :] < context_len[:, None]
-    return jnp.where(seen, scores, -jnp.inf)
+    return _walked_scores(
+        q, w, kv_pool, block_table, context_len, keys_at=(pl.ds(bs, t),),
+        keys_shape=(t, rows, Dh), block_size=bs, wave_blocks=wave_blocks,
+        interpret=interpret)
+
+
+@functools.partial(jax.jit, static_argnames=("latent_dim", "wave_blocks",
+                                             "interpret"))
+def latent_index_scores_pallas(q, w, kv_pool, block_table, context_len, *,
+                               latent_dim: int,
+                               wave_blocks: int = SCORE_WAVE_BLOCKS,
+                               interpret: bool = False):
+    """``sparse_decode_scores_pallas`` over a pool of latent-selected slots:
+    kv_pool [slots, bs / 2, 2 * latent_dim + 2 * dI], a row its two
+    positions' latents and then their two selector keys, of which the walk
+    copies the keys alone (a wave that is a run in the pool by one copy of
+    the rows' last lanes).  q: [B, HI, dI] in the serving type; w: [B, HI]
+    float32; block_table: [B, n] int32; context_len: [B].  Returns
+    [B, n * bs] float32, ``-inf`` past the context."""
+    dI = q.shape[-1]
+    _, half, width = kv_pool.shape
+    if width != 2 * latent_dim + 2 * dI:
+        raise ValueError("a latent-selected row is two latents and two keys")
+    return _walked_scores(
+        q, w, kv_pool, block_table, context_len,
+        keys_at=(slice(None), pl.ds(2 * latent_dim, 2 * dI)),
+        keys_shape=(half, 2 * dI), block_size=2 * half,
+        wave_blocks=wave_blocks, interpret=interpret)

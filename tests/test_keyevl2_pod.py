@@ -528,9 +528,11 @@ def test_block_bytes_and_pool_shapes_come_from_the_selected_spec():
     assert "window" not in policy and "state" not in policy
     with pytest.raises(ValueError, match="selected slot"):
         KVGroupSpec(4, 16, 4, 128, selector_dim=48)  # no whole tiles
-    with pytest.raises(ValueError, match="selected slot"):
-        KVGroupSpec(4, 16, 1, 128, selector_dim=64, latent_dim=128,
-                    value_dim=64)
+    # a latent vector AND a selector key is a kind of its own (PR 53)
+    assert KVGroupSpec(4, 16, 1, 128, selector_dim=64, latent_dim=128,
+                       value_dim=64).layout == "latent_selected"
+    with pytest.raises(ValueError, match="exclude each other"):
+        KVGroupSpec(4, 16, 4, 128, selector_dim=64, packed=True)
 
 
 def test_a_uniform_pool_and_the_offload_spec_price_both_parts(tmp_path):

@@ -1,4 +1,4 @@
-"""The six K/V slot layouts behind `KVGroupSpec`: whatever a family's model
+"""The seven K/V slot layouts behind `KVGroupSpec`: whatever a family's model
 step writes through `kv_cache_pool`'s operations it reads back through them,
 layout by layout, and a decode step's one-position write gives the slot a
 prefill's scatter of the same block would."""
@@ -26,6 +26,8 @@ SPECS = {
     "latent": KVGroupSpec(1, BLOCK, 1, 24, "float32", latent_dim=24,
                           value_dim=16),
     "selected": KVGroupSpec(1, BLOCK, HKV, DH, "float32", selector_dim=8),
+    "latent_selected": KVGroupSpec(1, BLOCK, 1, 24, "float32", latent_dim=24,
+                                   value_dim=16, selector_dim=8, selected=4),
 }
 IDS = jnp.asarray([[3, 1]])  # two blocks of one sequence, out of order
 
@@ -40,6 +42,8 @@ def parts_of(spec: KVGroupSpec, seed: int = 0) -> tuple:
 
     if spec.layout == "latent":
         return (normal(spec.latent_dim),)
+    if spec.layout == "latent_selected":
+        return (normal(spec.latent_dim), normal(spec.selector_dim))
     kv = (normal(spec.num_kv_heads, spec.head_dim),
           normal(spec.num_kv_heads, spec.head_dim))
     if spec.layout == "selected":
@@ -57,6 +61,16 @@ def read_back(spec: KVGroupSpec, pool) -> tuple:
     if spec.layout == "latent":
         return (kv_cache_pool.unpack_latent_blocks(pool[IDS],
                                                    spec.value_dim),)
+    if spec.layout == "latent_selected":
+        # every position's row and half, in table order
+        at = jnp.arange(BLOCK)
+        rows = (IDS[..., None] * (BLOCK // 2) + at % (BLOCK // 2)).reshape(1, -1)
+        second = jnp.tile(at >= BLOCK // 2, 2)[None]
+        whole = kv_cache_pool.unpack_latent_blocks(  # the keys left aside
+            pool[IDS][..., :2 * spec.latent_dim], spec.value_dim)
+        picked = kv_cache_pool.gather_picked_latents(spec, pool, rows, second)
+        np.testing.assert_array_equal(whole, picked)
+        return (whole, kv_cache_pool.gather_selector_keys(spec, pool, IDS))
     if spec.layout == "selected":
         # every position's tile, in table order
         tiles = (IDS[..., None] * spec.slot_tiles

@@ -21,14 +21,14 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from llm_d_kv_cache_manager_tpu.models import (
-    afmoe, glm4moelite, keyevl2, kv_cache_pool, lfm2moe, llama, nemotronh,
-    phi4flash,
+    afmoe, deepseekv32, glm4moelite, keyevl2, kv_cache_pool, lfm2moe, llama,
+    nemotronh, phi4flash,
 )
 from llm_d_kv_cache_manager_tpu.models import pod as pod_programs
 from llm_d_kv_cache_manager_tpu.ops import flash_pallas, ssd_pallas
 from llm_d_kv_cache_manager_tpu.ops import sparse_attention_pallas as sparse
 from llm_d_kv_cache_manager_tpu.ops.latent_prefill_pallas import (
-    latent_prefill_attention_pallas,
+    latent_picked_prefill_pallas, latent_prefill_attention_pallas,
 )
 from llm_d_kv_cache_manager_tpu.ops.paged_decode_pallas import (
     paged_decode_attention_pallas,
@@ -670,6 +670,138 @@ def test_keyevl2_programs_compile_at_the_cells_shapes(one_chip, monkeypatch,
                      for a in jax.tree.leaves(pools))
     assert pool_bytes == KEYE_POOL_BLOCKS * 139264
     assert memory.alias_size_in_bytes >= pool_bytes  # the pool handed back
+    assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes
+            + memory.output_size_in_bytes - memory.alias_size_in_bytes
+            ) < HBM_BYTES
+
+
+# ------------- the latent-selected cache's kernels and programs (PR 53)
+
+# benchmarks/configs/deepseek-v3.2-exp-l5.json; the pool and the shapes of
+# benchmarks/traffic/chat-longctx-shared.json
+DSV32 = deepseekv32.DeepseekV32Config(
+    vocab_size=16160, d_model=7168, n_layers=5, n_heads=128, q_rank=1536,
+    kv_rank=512, nope_dim=128, rope_dim=64, v_dim=128, index_heads=64,
+    index_dim=128, index_topk=2048, d_ff=18432, d_expert=2048, n_experts=256,
+    held=(0, 16), top_k=8, n_group=8, topk_group=4, rope_original=4096)
+DSV32_SHAPES = {"miss": (32768,), "hit": (32256, 512), "decode": (32,),
+                "max_blocks": 2080}
+DSV32_POOL_BLOCKS = 20480
+DSV32_SLOT = (8, 1408)
+# Temporaries beside 9.27 GB of weights and a 2.31-GB pool: compiled here
+# they read 4.00 / 0.50 / 0.41 GB (a 32 768-token miss holds its stream
+# twice and the stream's norm; its chunks make their own queries and add to
+# their own piece of the stream).
+DSV32_TEMP_LIMIT = {"miss": 4.3e9, "hit": 0.7e9, "decode": 0.6e9}
+
+
+def test_a_latent_selected_slot_lies_in_the_pool_as_it_is_written(one_chip):
+    """Slots [8, 1408] (a row two positions' latents, mirrored, 9 tiles, then
+    their two selector keys, 2 tiles): the pool takes its own bytes on the
+    chip, a prefill's scatter and a decode step's slice update write it where
+    it lies, and the gathers of a table's keys and of picked rows read it as
+    it lies (a gather whose slices START at the key lanes did not: it had the
+    whole pool re-laid-out, slot axis minor)."""
+    spec = deepseekv32.cache_groups(DSV32)["full"]
+    shape = spec.layer_shape(DSV32_POOL_BLOCKS)
+    assert shape == (DSV32_POOL_BLOCKS,) + DSV32_SLOT
+    bf16, i32 = jnp.bfloat16, jnp.int32
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in (
+        (shape, bf16), ((1, 32), i32), ((1, 512, 576), bf16),
+        ((1, 512, 128), bf16), ((32,), i32), ((32,), i32), ((32, 576), bf16),
+        ((32, 128), bf16), ((1, 2048), i32), ((32, 2048), i32),
+        ((32, 2048), jnp.bool_))]
+
+    def step(pool, new, latent, key, ids, at, one, one_key, table, rows,
+             second):
+        pool = kv_cache_pool.write_blocks(spec, pool, new, latent, key)
+        pool = kv_cache_pool.write_token(spec, pool, ids, at, one, one_key)
+        return (pool, kv_cache_pool.gather_selector_keys(spec, pool, table),
+                kv_cache_pool.gather_picked_latents(spec, pool, rows, second))
+
+    compiled = jax.jit(step, donate_argnums=(0,)).trace(*args).lower(
+        lowering_platforms=("tpu",)).compile()
+    nbytes = 2 * math.prod(shape)
+    assert nbytes * spec.num_layers // DSV32_POOL_BLOCKS \
+        == spec.block_nbytes == 112640
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= nbytes
+    assert memory.temp_size_in_bytes < 0.6e9  # the picked rows: 184 MB twice
+    hlo = compiled.as_text()
+    assert f"bf16[{DSV32_POOL_BLOCKS},8,1408]{{2,1,0:" in hlo
+    assert not re.search(rf"= bf16\[{DSV32_POOL_BLOCKS},\S* copy\(", hlo)
+
+
+def test_latent_selected_kernels_compile_at_the_served_shapes(one_chip):
+    """A chunk's index scores at 64 heads of 128 over a whole table, the
+    latent kernel under the picks over the pool where it lies at the tile of
+    32 positions x 128 heads (a hit's suffix and a chunk of a miss's queries
+    are one kernel: the offset is data), and a decode step's scores walked
+    over each sequence's own table, the rows' key lanes copied alone."""
+    bf16, f32, i32 = jnp.bfloat16, jnp.float32, jnp.int32
+    pool = ((DSV32_POOL_BLOCKS,) + DSV32_SLOT, bf16)
+    compile_for(
+        one_chip, lambda q, w, k, at: sparse.sparse_index_scores_pallas(
+            q, w, k, q_offset=at),
+        ((512, 64, 128), bf16), ((512, 64), f32), ((32768, 128), bf16),
+        ((), i32))
+    hlo = compile_for(
+        one_chip, lambda q, p, t, m, at: latent_picked_prefill_pallas(
+            q, p, t, m, q_offset=at, value_dim=512, scale=0.135),
+        ((1, 512, 128, 576), bf16), pool, ((1, 2048), i32),
+        ((1, 512, 32768), jnp.bool_), ((), i32)).as_text()
+    assert not re.search(rf"= bf16\[{DSV32_POOL_BLOCKS},\S* copy\(", hlo)
+    hlo = compile_for(
+        one_chip, functools.partial(sparse.latent_index_scores_pallas,
+                                    latent_dim=576),
+        ((32, 64, 128), bf16), ((32, 64), f32), pool, ((32, 2080), i32),
+        ((32,), i32)).as_text()
+    assert not re.search(rf"= bf16\[{DSV32_POOL_BLOCKS},\S* copy\(", hlo)
+
+
+@pytest.mark.parametrize("key", ("miss", "hit", "decode"))
+def test_deepseekv32_programs_compile_at_the_cells_shapes(one_chip,
+                                                          monkeypatch, key):
+    """The cell `deepseekv32-chat-longctx-shared`'s three programs as
+    `models/pod.py` jits them, the pool of latent-selected slots donated:
+    they compile for the v5e, fit the chip beside 9.27 GB of weights and a
+    2.31-GB pool (the 32 768-token miss is the risk), hand the pool back
+    where it lies, and no instruction copies or re-lays-out a layer of it."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    def spec(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+
+    params = jax.tree.map(spec, jax.eval_shape(
+        lambda: deepseekv32.init_params(jax.random.key(0), DSV32)))
+    pools = jax.tree.map(spec, jax.eval_shape(
+        lambda: deepseekv32.new_pool(DSV32, DSV32_POOL_BLOCKS)))
+
+    class Shapes:  # what `example_args` reads of a pod: one group, and a
+        # decode call that launches the step after its own (`decode_ahead`)
+        window = state = None
+        decode_ahead = True
+
+    first, second = jax.tree.map(
+        spec, pod_programs.example_args(key, DSV32_SHAPES, Shapes, BLOCK))
+    program = pod_programs.inner_programs(deepseekv32, DSV32, DSV32_SHAPES,
+                                          False)[key]
+    compiled = program.trace(params, first, pools, second).lower(
+        lowering_platforms=("tpu",)).compile()
+    hlo = compiled.as_text()
+    kernels = set(re.findall(r"%(\w+_pallas)\S* = .*tpu_custom_call", hlo))
+    assert kernels == ({"latent_index_scores_pallas"} if key == "decode" else {
+        "sparse_index_scores_pallas", "latent_picked_prefill_pallas"})
+    assert not re.search(
+        rf"= \w+\[{DSV32_POOL_BLOCKS},[\d,]*\]\S* copy\(", hlo)
+    assert not pool_sized_moves(
+        hlo, (DSV32.n_layers, DSV32_POOL_BLOCKS) + DSV32_SLOT)
+    memory = compiled.memory_analysis()
+    pool_bytes = sum(a.dtype.itemsize * math.prod(a.shape)
+                     for a in jax.tree.leaves(pools))
+    assert pool_bytes == DSV32_POOL_BLOCKS * 112640
+    assert memory.alias_size_in_bytes >= pool_bytes  # the pool handed back
+    assert memory.temp_size_in_bytes < DSV32_TEMP_LIMIT[key]
     assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes
             + memory.output_size_in_bytes - memory.alias_size_in_bytes
             ) < HBM_BYTES
