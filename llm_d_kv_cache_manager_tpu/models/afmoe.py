@@ -290,39 +290,44 @@ def route(h, lp, cfg):
                            cfg.route_norm, cfg.route_scale)
 
 
-def routed_experts(h, picked, w, experts, cfg):
+def routed_experts(h, picked, w, experts, cfg, interpret: bool = False):
     """`moe_serve.routed_experts`, batched under the routing's mask for at
     most as many tokens as experts (a decode step), sorted by expert above (a
-    prefill).  A step of 64 sequences picks 512 times among 128 experts, so
-    nearly every expert's weights are read either way, and the grouped
-    product then took 0.04 ms an expert touched, 5.1 ms for 127, against
-    2.3 ms for all 128 in one batched product, 84 % of the chip's bandwidth
-    (my chip run, PR 29)."""
+    prefill).  A step of 64 sequences picks 512 times among 128 experts:
+    the grouped product (`lax.ragged_dot`) took 0.04 ms an expert touched,
+    5.1 ms for 127, against 2.3 ms for all 128 in one batched product, 84 %
+    of the chip's bandwidth (my chip run, PR 29).  Since PR 54 the batched
+    form of a decode step is `ops/moe_decode_pallas.py`'s kernel, which
+    copies the touched experts alone: 2.26 ms at all 128 and 2.04 at the
+    114 a step of this cell touches, the einsum 2.44 (my chip run, PR 54;
+    `moe_serve.decode_kernel_serves`)."""
     return moe_serve.routed_experts(h, picked, w, experts, cfg.n_experts,
-                                    batched=picked.shape[0] <= cfg.n_experts)
+                                    batched=picked.shape[0] <= cfg.n_experts,
+                                    interpret=interpret)
 
 
-def _moe(h, lp, cfg):
+def _moe(h, lp, cfg, interpret):
     """h: [B, T, D] float32 -> (Shared(h) + routed experts, float32; picks
     per expert [E])."""
     act = lp["router"].dtype  # the serving type
 
     def chunk(rows):
         picked, w = route(rows, lp, cfg)
-        return routed_experts(rows.astype(act), picked, w, lp["experts"], cfg)
+        return routed_experts(rows.astype(act), picked, w, lp["experts"], cfg,
+                              interpret)
 
     out, sizes = moe_serve.in_chunks(h, chunk, MOE_CHUNK_TOKENS)
     return swiglu(h.astype(act), lp["shared"]) + out.reshape(h.shape), sizes
 
 
-def _mlp_block(x, lp, cfg):
+def _mlp_block(x, lp, cfg, interpret):
     """a -> a + RMSNorm_post_mlp(MLP(RMSNorm_pre_mlp(a))), and the expert
     layer's load (None on a dense layer)."""
     h = rms_norm(x, lp["ln_pre_mlp"], cfg.rms_eps)
     if "mlp" in lp:
         y, load = swiglu(h.astype(lp["mlp"]["w_up"].dtype), lp["mlp"]), None
     else:
-        y, sizes = _moe(h, lp, cfg)
+        y, sizes = _moe(h, lp, cfg, interpret)
         load = jnp.stack((jnp.sum(sizes > 0), jnp.max(sizes)))
     return x + rms_norm(y, lp["ln_post_mlp"], cfg.rms_eps), load
 
@@ -376,7 +381,7 @@ def prefill_paged(
             )
         else:
             full[i] = write_blocks(specs[kind], full[i], tables["full"], k, v)
-        x, load = _mlp_block(x, lp, cfg)
+        x, load = _mlp_block(x, lp, cfg, interpret)
         if load is not None:
             loads.append(load)
     return _finish(x[:, -1:], params, cfg, full, win, loads)
@@ -442,7 +447,7 @@ def prefill_continue(
                 specs[kind], full[i], tables["full"][:, npre:npre + nsuf],
                 k, v,
             )
-        x, load = _mlp_block(x, lp, cfg)
+        x, load = _mlp_block(x, lp, cfg, interpret)
         if load is not None:
             loads.append(load)
     return _finish(x[:, -1:], params, cfg, full, win, loads)
@@ -524,7 +529,7 @@ def decode_step(
                                      tables["full"], context_len, None,
                                      interpret, plans.get(kind))
         x = _attn_block(x, attn[:, None], g, lp, cfg)
-        x, load = _mlp_block(x, lp, cfg)
+        x, load = _mlp_block(x, lp, cfg, interpret)
         if load is not None:
             loads.append(load)
     logits, pools = _finish(x[:, 0], params, cfg, full, win, loads)

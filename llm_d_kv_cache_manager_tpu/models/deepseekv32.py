@@ -114,12 +114,16 @@ LN_EPS = 1e-6  # the selector key's LayerNorm
 ATTN_CHUNK_TOKENS = 512
 # A feed-forward runs over at most this many tokens at a time: a 32 768-token
 # miss through the dense layer's 18 432 lanes would be 2.4 GB of float32 a
-# product.  Every chunk goes through every held expert in one batched product
+# product.  Every chunk goes through the held experts in one batched product
 # under the routing's mask (`moe_serve.routed_experts`): the form
 # models/nemotronh.py reads faster up to 1024 tokens at a held share of its
 # own (PERF.md section 6, PR 49), and the sorted form's rows are 8 picks a
 # token of which 15 in 16 fall on other chips (0.94 GB of float32 for 4096
-# tokens, twice: compiled for the v5e, PR 53).
+# tokens, twice: compiled for the v5e, PR 53).  A prefill's chunk touches
+# every held expert and is the einsum; a decode step's 32 rows touch half of
+# the 16, and its batched product is the kernel that copies those alone
+# (1.04 ms a layer at 8 touched against 1.98; my chip run, PR 54;
+# `moe_serve.decode_kernel_serves`).
 FF_CHUNK_TOKENS = 1024
 
 
@@ -480,7 +484,7 @@ def _in_chunks(x, chunk, limit: int):
     return out.reshape(x.shape), counts
 
 
-def _ff_block(x, lp, cfg):
+def _ff_block(x, lp, cfg, interpret):
     """a -> a + FF(RMS_post(a)), a chunk of tokens at a time (the norm too:
     a long miss holds no second stream), and the expert layer's counts: held
     experts with a pick, the most picks of one, all picks, the picks that
@@ -499,7 +503,7 @@ def _ff_block(x, lp, cfg):
             topk_group=cfg.topk_group)
         out, sizes = moe_serve.routed_experts(
             h.astype(act), picked, w, lp["experts"], cfg.n_experts,
-            batched=True, held=cfg.held)
+            batched=True, held=cfg.held, interpret=interpret)
         return swiglu(h.astype(act), lp["shared"]) + out, sizes
 
     if "mlp" in lp:
@@ -583,7 +587,7 @@ def _prefill(params, tokens, pools, table, first, cfg, interpret, taps):
                                *_cached(h, lp, positions, cfg))
         x = _prefill_attention(x, h, _bottleneck(h, lp, cfg), lp, full[l],
                                table, first, cfg, interpret, taps)
-        x, load = _ff_block(x, lp, cfg)
+        x, load = _ff_block(x, lp, cfg, interpret)
         if load is not None:
             loads.append(load)
     return _finish(x[:, -1:], params, cfg, full, loads)
@@ -699,7 +703,7 @@ def decode_step(
         o = _decode_attention(q[:, 0], qi[:, 0], w[:, 0], full[l], table,
                               context_len, cfg, interpret, taps)
         x = x + _attn_out(o[:, None], lp, cfg)
-        x, load = _ff_block(x, lp, cfg)
+        x, load = _ff_block(x, lp, cfg, interpret)
         if load is not None:
             loads.append(load)
     return _finish(x[:, 0], params, cfg, full, loads)

@@ -363,12 +363,13 @@ def _attn_out(o_latent, lp, cfg):
                       preferred_element_type=jnp.float32)
 
 
-def _moe(h, lp, cfg):
+def _moe(h, lp, cfg, interpret):
     """h: [B, T, D] float32 -> (Shared(h) + routed experts, float32; picks
     per expert [E]).  Batched under the routing's mask for at most as many
     tokens as experts (a decode step: 64 sequences pick 256 times among 64
-    experts, so nearly every expert's weights are read either way), sorted by
-    expert above (a prefill)."""
+    experts and touch 57 of them in the cell; the batched form there is the
+    kernel that copies the touched experts alone,
+    `moe_serve.decode_kernel_serves`), sorted by expert above (a prefill)."""
     act = lp["router"].dtype  # the serving type
 
     def chunk(rows):
@@ -377,19 +378,19 @@ def _moe(h, lp, cfg):
             cfg.route_scale, ROUTE_NORM_EPS)
         return moe_serve.routed_experts(
             rows.astype(act), picked, w, lp["experts"], cfg.n_experts,
-            batched=picked.shape[0] <= cfg.n_experts)
+            batched=picked.shape[0] <= cfg.n_experts, interpret=interpret)
 
     out, sizes = moe_serve.in_chunks(h, chunk, MOE_CHUNK_TOKENS)
     return swiglu(h.astype(act), lp["shared"]) + out.reshape(h.shape), sizes
 
 
-def _ff_block(x, lp, cfg):
+def _ff_block(x, lp, cfg, interpret):
     """a -> a + FF(RMS_post(a)), and the expert layer's load (None on a
     dense layer)."""
     h = rms_norm(x, lp["ln_post"], cfg.rms_eps)
     if "mlp" in lp:
         return x + swiglu(h.astype(lp["mlp"]["w_up"].dtype), lp["mlp"]), None
-    y, sizes = _moe(h, lp, cfg)
+    y, sizes = _moe(h, lp, cfg, interpret)
     return x + y, jnp.stack((jnp.sum(sizes > 0), jnp.max(sizes)))
 
 
@@ -443,7 +444,7 @@ def _prefill(params, tokens, pools, table, first, cfg, interpret):
                                _latent(h, lp, positions, cfg))
         x = x + _prefill_attention(h, lp, full[l], table, first, cfg,
                                    interpret)
-        x, load = _ff_block(x, lp, cfg)
+        x, load = _ff_block(x, lp, cfg, interpret)
         if load is not None:
             loads.append(load)
     return _finish(x[:, -1:], params, cfg, full, loads)
@@ -518,7 +519,7 @@ def decode_step(
             shared_blocks_per_step=DECODE_BLOCKS_PER_WAVE,
             interpret=interpreted(interpret), **layout)
         x = x + _attn_out(o[:, None], lp, cfg)
-        x, load = _ff_block(x, lp, cfg)
+        x, load = _ff_block(x, lp, cfg, interpret)
         if load is not None:
             loads.append(load)
     logits, pools = _finish(x[:, 0], params, cfg, full, loads)

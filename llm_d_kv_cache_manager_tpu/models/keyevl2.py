@@ -315,12 +315,13 @@ def _attn_out(o, lp):
                       preferred_element_type=jnp.float32)
 
 
-def _moe(h, lp, cfg):
+def _moe(h, lp, cfg, interpret):
     """h: [B, T, D] float32 -> (the routed experts' sum, float32; picks per
     expert [E]).  Batched under the routing's mask for at most as many
     tokens as experts (a decode step: 24 sequences pick 192 times among 128
-    experts, so most experts' weights are read either way), sorted by expert
-    above (a prefill)."""
+    experts and touch 99 of them in the cell; the batched form there is the
+    kernel that copies the touched experts alone,
+    `moe_serve.decode_kernel_serves`), sorted by expert above (a prefill)."""
     act = lp["router"].dtype  # the serving type
 
     def chunk(rows):
@@ -328,15 +329,16 @@ def _moe(h, lp, cfg):
                                     True, 1.0, scores="softmax")
         return moe_serve.routed_experts(
             rows.astype(act), picked, w, lp["experts"], cfg.n_experts,
-            batched=picked.shape[0] <= cfg.n_experts)
+            batched=picked.shape[0] <= cfg.n_experts, interpret=interpret)
 
     out, sizes = moe_serve.in_chunks(h, chunk, MOE_CHUNK_TOKENS)
     return out.reshape(h.shape), sizes
 
 
-def _ff_block(x, lp, cfg):
+def _ff_block(x, lp, cfg, interpret):
     """a -> a + MoE(RMS_post(a)), and the layer's load."""
-    y, sizes = _moe(rms_norm(x, lp["ln_post"], cfg.rms_eps), lp, cfg)
+    y, sizes = _moe(rms_norm(x, lp["ln_post"], cfg.rms_eps), lp, cfg,
+                    interpret)
     return x + y, jnp.stack((jnp.sum(sizes > 0), jnp.max(sizes)))
 
 
@@ -425,7 +427,7 @@ def _prefill(params, tokens, pools, table, first, cfg, interpret, taps):
         full[l] = write_blocks(spec, full[l], new, k, v, ki)
         x = x + _prefill_attention(h, lp, full[l], table, first, cfg,
                                    interpret, taps)
-        x, load = _ff_block(x, lp, cfg)
+        x, load = _ff_block(x, lp, cfg, interpret)
         loads.append(load)
     return _finish(x[:, -1:], params, cfg, full, loads)
 
@@ -537,6 +539,6 @@ def decode_step(
         o = _decode_attention(q[:, 0], qi[:, 0], w[:, 0], full[l], table,
                               context_len, cfg, interpret, taps)
         x = x + _attn_out(o[:, None], lp)
-        x, load = _ff_block(x, lp, cfg)
+        x, load = _ff_block(x, lp, cfg, interpret)
         loads.append(load)
     return _finish(x[:, 0], params, cfg, full, loads)

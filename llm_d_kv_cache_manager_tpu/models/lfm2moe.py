@@ -85,7 +85,10 @@ ROUTE_NORM_EPS = 1e-6  # + the published code's, under the picked scores' sum
 # every expert in one batched product under the routing's mask, more through
 # `lax.ragged_dot`.  Read on the chip at the cell's sizes, 32 experts of width
 # 1792, a layer: 64 tokens 1.03 ms batched / 1.90 ms grouped, 512 tokens
-# 2.03 / 3.04 (my chip run, PR 33; PERF.md section 6).
+# 2.03 / 3.04 (my chip run, PR 33; PERF.md section 6).  The batched form
+# stays the einsum here: 64 rows of 4 picks touch all 32 experts, and the
+# kernel that skips an untouched expert's copies then read 1.060 ms against
+# 1.042 (my chip run, PR 54; `moe_serve.decode_kernel_serves`).
 BATCHED_EXPERTS_MAX_TOKENS = 512
 
 
@@ -333,7 +336,7 @@ def _conv_prefill(h, lp, before, ends, pool, write):
     return y, pool
 
 
-def _moe(h, lp, cfg):
+def _moe(h, lp, cfg, interpret):
     """h: [B, T, D] float32 -> (sum of each token's picked experts, float32;
     picks per expert [E])."""
     act = lp["router"].dtype  # the serving type
@@ -344,19 +347,20 @@ def _moe(h, lp, cfg):
             cfg.route_scale, ROUTE_NORM_EPS)
         return moe_serve.routed_experts(
             rows.astype(act), picked, w, lp["experts"], cfg.n_experts,
-            batched=rows.shape[0] <= BATCHED_EXPERTS_MAX_TOKENS)
+            batched=rows.shape[0] <= BATCHED_EXPERTS_MAX_TOKENS,
+            interpret=interpret)
 
     out, sizes = moe_serve.in_chunks(h, chunk)
     return out.reshape(h.shape), sizes
 
 
-def _ff_block(x, lp, cfg):
+def _ff_block(x, lp, cfg, interpret):
     """a -> a + FF(RMSNorm_ff(a)), and the expert layer's load (None on a
     dense layer)."""
     h = rms_norm(x, lp["ln_ff"], cfg.rms_eps)
     if "mlp" in lp:
         return x + swiglu(h.astype(lp["mlp"]["w_up"].dtype), lp["mlp"]), None
-    y, sizes = _moe(h, lp, cfg)
+    y, sizes = _moe(h, lp, cfg, interpret)
     return x + y, jnp.stack((jnp.sum(sizes > 0), jnp.max(sizes)))
 
 
@@ -403,7 +407,7 @@ def _prefill(params, tokens, pools, tables, prefix_len, cfg, interpret):
             full[i] = write_blocks(
                 specs["full"], full[i], tables["full"][:, npre:npre + nsuf],
                 k, v)
-        x, load = _ff_block(x + y, lp, cfg)
+        x, load = _ff_block(x + y, lp, cfg, interpret)
         if load is not None:
             loads.append(load)
     return _finish(x[:, -1:], params, cfg, full, state, loads)
@@ -505,7 +509,7 @@ def decode_step(
             attn = _decode_attention(spec, q[:, 0], full[i], tables["full"],
                                      context_len, interpret, plan)
             y = _attn_out(attn[:, None], lp)
-        x, load = _ff_block(x + y, lp, cfg)
+        x, load = _ff_block(x + y, lp, cfg, interpret)
         if load is not None:
             loads.append(load)
     logits, pools = _finish(x[:, 0], params, cfg, full, state, loads)

@@ -8,7 +8,10 @@ path's: softmax scores, a static capacity, tokens over it dropped.)
 
 What differs between the families is an argument, and where an argument would
 add an operation to a family's trace the branch is taken in Python, so that
-each family's programs trace to what they were.
+each family's programs trace to what they were.  What does not differ is
+decided here from a trace's shapes alone: whether a batched expert layer is
+the einsum or the kernel that copies only the touched experts
+(`decode_kernel_serves`, `ops/moe_decode_pallas.py`).
 """
 
 from __future__ import annotations
@@ -17,9 +20,37 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from llm_d_kv_cache_manager_tpu.ops import moe_decode_pallas, paged_decode_pallas
+
 HI = lax.Precision.HIGHEST
 # An expert layer's prefill runs over at most this many tokens at a time.
 MOE_CHUNK_TOKENS = 4096
+# Where a batched expert layer is the kernel that copies the touched experts
+# alone (ops/moe_decode_pallas.py): `decode_kernel_serves`.  Read on the chip,
+# the kernel and the einsum alone, a layer at each expert cell's shapes, ms a
+# layer where eight follow one another (hack/moe_decode_alone.py; my chip
+# run, PR 54; PERF.md section 6):
+#
+#   rows held  D     F    einsum  kernel, every expert touched / some touched
+#    32   16  7168  2048  1.975   1.983 / 1.041 at  8  (deepseekv32)
+#    24  128  2048   768  1.719   1.711 / 1.339 at 99  (keyevl2)
+#    64  128  2048  1024  2.436   2.264 / 2.036 at 114 (afmoe)
+#    64   64  2048  1536  1.726   1.713 / 1.538 at 57  (glm4moelite)
+#    64   32  2048  1792  1.042   1.060 / 0.590 at 16  (lfm2moe)
+#   128   64  2688  1856  1.801   2.094 / 1.851 at 55  (nemotronh), and 2.4 ms
+#         a call more: 1856 lanes are 14.5 tiles, and `w_up` is re-laid-out
+#   256   16  7168  2048  2.473   2.125;   512 rows: 4.339 / 4.502
+#
+# So the kernel's time follows the experts touched (677-715 GB/s of them) and
+# at every expert touched it is the einsum's within 2 % either way where the
+# hidden width is whole lane tiles: it serves up to DECODE_KERNEL_MAX_TOKENS
+# rows (read as far; at 512 the products hold it and nothing is skipped),
+# whole lane tiles, and shapes that leave experts untouched: under even
+# routing N rows touch 1 - (1 - k/E)^N of the experts (the share the
+# benchmark prices a step by), 0.638 / 0.788 / 0.984 / 0.984 for the first
+# four rows of the table, 0.9998 and 0.998 for the two where the kernel loses.
+DECODE_KERNEL_MAX_TOKENS = 256
+DECODE_KERNEL_MAX_SHARE = 0.99
 
 
 def route(h, router, bias, top_k: int, norm: bool, scale: float,
@@ -77,8 +108,29 @@ def _hidden(rows, experts, product):
     return jax.nn.silu(gate) * up
 
 
+def touched_share(rows: int, top_k: int, n_experts: int) -> float:
+    """The share of the experts that ``rows`` rows of ``top_k`` picks among
+    ``n_experts`` touch under even routing: what the benchmark prices a
+    step's expert weights by."""
+    return 1 - (1 - top_k / n_experts) ** rows
+
+
+def decode_kernel_serves(rows: int, top_k: int, n_experts: int, width: int,
+                         interpret: bool) -> bool:
+    """The one rule by which a batched expert layer is the kernel, from what
+    a trace sees and nothing else: few rows, an expert's hidden ``width`` in
+    whole lane tiles, picks that leave held experts untouched (the constants'
+    comment has the readings), and a program compiled for the TPU or
+    interpreted."""
+    return (rows <= DECODE_KERNEL_MAX_TOKENS
+            and width % moe_decode_pallas.LANES == 0
+            and touched_share(rows, top_k, n_experts) <= (
+                DECODE_KERNEL_MAX_SHARE)
+            and paged_decode_pallas.serves(interpret))
+
+
 def routed_experts(h, picked, w, experts, n_experts: int, batched: bool,
-                   held: tuple | None = None):
+                   held: tuple | None = None, interpret: bool = False):
     """Sum over each token's picked experts of w_e Expert_e(h), without a
     capacity; h [N, D] in the serving type; returns ([N, D] float32, picks
     per expert).  Two ways, the caller's choice (each family's rule is read
@@ -86,8 +138,14 @@ def routed_experts(h, picked, w, experts, n_experts: int, batched: bool,
 
     - ``batched`` (a decode step's few tokens): every expert multiplies
       every token and the routing weights, zero for an expert not picked,
-      mask the sum.  One batched product whose time does not depend on which
-      experts a seed's router favours; it reads every expert's weights;
+      mask the sum.  As one batched einsum its time does not depend on which
+      experts a seed's router favours, and it reads every expert's weights:
+      the plain form, and the one a hit's suffix keeps.  Where
+      `decode_kernel_serves` says so (a decode step's rows at shapes that
+      leave experts untouched, compiled for the TPU or ``interpret``-ed) the
+      same sum is `moe_decode_pallas`: an expert a grid step, the touched
+      ones first, so the weights of an expert no token picked are not
+      copied and a step's time follows the experts its tokens touched;
     - else (a prefill): the N*k picks are sorted by expert and each expert
       multiplies its own rows (`lax.ragged_dot`).
 
@@ -117,6 +175,12 @@ def routed_experts(h, picked, w, experts, n_experts: int, batched: bool,
         weight = jnp.zeros((N, n_held), f32).at[
             jnp.arange(N)[:, None], picked].add(
                 w, **({} if held is None else {"mode": "drop"}))
+        if decode_kernel_serves(N, k, n_experts, experts["w_up"].shape[-1],
+                                interpret):
+            out = moe_decode_pallas.moe_decode_pallas(
+                h, weight, experts,
+                *moe_decode_pallas.touched_order(sizes[:n_held]), interpret)
+            return out, sizes
         hidden = _hidden(h, experts, lambda x, m: jnp.einsum(
             "nd,edf->enf", x, m, preferred_element_type=f32)
         ) * weight.T[:, :, None]

@@ -118,7 +118,11 @@ ROUTE_NORM_EPS = 1e-20  # + the published code's, under the picked scores' sum
 # the chip at this layer's sizes, ms batched / sorted by rows: 128 1.75 /
 # 16.1, 512 3.49 / 18.1, 1024 7.08 / 19.4 (PERF.md, PR 49): the sorted form's
 # two products cost 16 ms before their first row, so the batched form holds
-# as far as it was read.
+# as far as it was read.  It stays the einsum in a decode step too: the kernel
+# that skips an untouched expert's copies read 2.09 ms at all 64 held and
+# 1.85 at the 55 a step of the cell touches against the einsum's 1.80, and
+# 2.4 ms a call more, because 1856 lanes are 14.5 tiles and `w_up` is
+# re-laid-out for it (my chip run, PR 54; `moe_serve.decode_kernel_serves`).
 BATCHED_EXPERTS_MAX_TOKENS = 1024
 
 
@@ -487,7 +491,7 @@ def _relu2(x, w):
                       preferred_element_type=f32)
 
 
-def _moe(h, lp, cfg):
+def _moe(h, lp, cfg, interpret):
     """h: [B, T, D] float32 -> (the shared expert plus the held experts' part
     of each token's picked sum, float32; the layer's counts: held experts
     with a pick, the most picks of one, all picks, the picks that fell on a
@@ -501,7 +505,7 @@ def _moe(h, lp, cfg):
         return moe_serve.routed_experts(
             rows.astype(act), picked, w, lp["experts"], cfg.n_experts,
             batched=rows.shape[0] <= BATCHED_EXPERTS_MAX_TOKENS,
-            held=cfg.held)
+            held=cfg.held, interpret=interpret)
 
     out, sizes = moe_serve.in_chunks(h, chunk)
     here = sizes[:-1]  # the last count: the picks that fell outside
@@ -579,7 +583,7 @@ def _prefill(params, tokens, pools, tables, prefix_len, cfg, interpret):
             state[2 * i + 1] = ssm.at[write.reshape(-1)].set(
                 states.reshape((-1,) + ssm.shape[1:]))
         elif kind == EXPERTS:
-            y, load = _moe(h32, lp, cfg)
+            y, load = _moe(h32, lp, cfg, interpret)
             loads.append(load)
         else:
             q, k, v = _qkv(h, lp)
@@ -685,7 +689,7 @@ def decode_step(
                 h, lp, state[2 * i], state[2 * i + 1], read, write, cfg,
                 interpret)
         elif kind == EXPERTS:
-            y, load = _moe(h32, lp, cfg)
+            y, load = _moe(h32, lp, cfg, interpret)
             loads.append(load)
         else:
             q, k, v = _qkv(h, lp)

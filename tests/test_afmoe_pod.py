@@ -363,9 +363,9 @@ def test_expert_layer_in_chunks_is_the_layer_whole(monkeypatch):
     lp = PARAMS["layers"][1]
     h = jnp.asarray(np.random.default_rng(5).normal(size=(1, 96, CFG.d_model)),
                     jnp.float32)
-    whole, sizes = afmoe._moe(h, lp, CFG)
+    whole, sizes = afmoe._moe(h, lp, CFG, False)
     monkeypatch.setattr(afmoe, "MOE_CHUNK_TOKENS", 32)
-    parts, sizes3 = afmoe._moe(h, lp, CFG)
+    parts, sizes3 = afmoe._moe(h, lp, CFG, False)
     close(np.asarray(parts), np.asarray(whole), 1e-6)
     assert np.array_equal(np.asarray(sizes), np.asarray(sizes3))
 

@@ -327,14 +327,14 @@ def test_the_shares_add_up_to_the_uncut_layer():
     cfg = deepseekv32.from_published(whole, BLOCK)
     x = jnp.asarray(np.random.default_rng(2).normal(size=(1, 24, 64)),
                     jnp.float32)
-    uncut, load = deepseekv32._ff_block(x, lp, cfg)
+    uncut, load = deepseekv32._ff_block(x, lp, cfg, False)
     assert int(load[2]) == int(load[3]) == 48  # every pick falls here
     parts, held_picks = [], 0
     for first in range(0, 8, 2):
         share = {**lp, "experts": jax.tree.map(lambda a: a[first:first + 2],
                                                lp["experts"])}
         out, load = deepseekv32._ff_block(
-            x, share, dataclasses.replace(cfg, held=(first, 2)))
+            x, share, dataclasses.replace(cfg, held=(first, 2)), False)
         parts.append(out - x)
         held_picks += int(load[3])
         assert int(load[2]) == 48

@@ -555,10 +555,10 @@ def test_the_two_halves_and_the_shared_expert_once_are_the_uncut_layer():
         cfg = dataclasses.replace(CFG, held=(first, 4))
         half = {**lp, "experts": jax.tree.map(
             lambda a: a[first:first + 4], lp["experts"])}
-        out, load = nemotronh._moe(h, half, cfg)
+        out, load = nemotronh._moe(h, half, cfg, False)
         parts.append(out - shared)
         counts.append(np.asarray(load))
-    uncut, load = nemotronh._moe(h, lp, whole)
+    uncut, load = nemotronh._moe(h, lp, whole, False)
     close(np.asarray(parts[0] + parts[1] + shared), np.asarray(uncut), 1e-5)
     assert counts[0][2] == counts[1][2] == 24 * CFG.top_k == load[2] == load[3]
     assert counts[0][3] + counts[1][3] == 24 * CFG.top_k

@@ -25,7 +25,9 @@ from llm_d_kv_cache_manager_tpu.models import (
     nemotronh, phi4flash,
 )
 from llm_d_kv_cache_manager_tpu.models import pod as pod_programs
-from llm_d_kv_cache_manager_tpu.ops import flash_pallas, ssd_pallas
+from llm_d_kv_cache_manager_tpu.ops import (
+    flash_pallas, moe_decode_pallas, ssd_pallas,
+)
 from llm_d_kv_cache_manager_tpu.ops import sparse_attention_pallas as sparse
 from llm_d_kv_cache_manager_tpu.ops.latent_prefill_pallas import (
     latent_picked_prefill_pallas, latent_prefill_attention_pallas,
@@ -790,7 +792,11 @@ def test_deepseekv32_programs_compile_at_the_cells_shapes(one_chip,
         lowering_platforms=("tpu",)).compile()
     hlo = compiled.as_text()
     kernels = set(re.findall(r"%(\w+_pallas)\S* = .*tpu_custom_call", hlo))
-    assert kernels == ({"latent_index_scores_pallas"} if key == "decode" else {
+    # a decode step's 32 rows leave held experts untouched: its expert layers
+    # are the kernel that copies the touched ones alone; a hit's 512 rows and
+    # a miss's chunks of 1024 keep the einsum (`moe_serve.decode_kernel_serves`)
+    assert kernels == ({"latent_index_scores_pallas", "moe_decode_pallas"}
+                       if key == "decode" else {
         "sparse_index_scores_pallas", "latent_picked_prefill_pallas"})
     assert not re.search(
         rf"= \w+\[{DSV32_POOL_BLOCKS},[\d,]*\]\S* copy\(", hlo)
@@ -888,6 +894,42 @@ def test_ssd_decode_kernel_compiles_and_advances_the_pool_in_place(one_chip):
         for rows in (8, 32, 128)}
     assert len(set(counts.values())) == 1, counts
     assert counts[128] <= 256, counts
+
+
+# a decode step's rows, the experts held, D, F, a gate matrix or none: the six
+# expert configurations under benchmarks/configs/
+MOE_DECODE = {
+    "trinity-mini": (64, 128, 2048, 1024, True),
+    "lfm2-8b-a1b": (64, 32, 2048, 1792, True),
+    "glm-4.7-flash": (64, 64, 2048, 1536, True),
+    "keye-vl-2.0": (24, 128, 2048, 768, True),
+    "nemotron-3-nano": (128, 64, 2688, 1856, False),  # 14.5 lane tiles wide
+    "deepseek-v3.2-exp": (32, 16, 7168, 2048, True),  # four tiles an expert
+}
+
+
+@pytest.mark.parametrize("name", MOE_DECODE)
+def test_moe_decode_kernel_compiles_at_the_served_shapes(one_chip, name):
+    """A decode step's expert layer as the kernel that copies the touched
+    experts alone, at each expert configuration's widths: an expert's
+    matrices whole where two buffers of them fit its VMEM share, tiles of
+    the hidden width where they do not."""
+    rows, held, D, F, gated = MOE_DECODE[name]
+    bf16, f32, i32 = jnp.bfloat16, jnp.float32, jnp.int32
+    tile = moe_decode_pallas.hidden_tile(D, F, 2 + gated, 2)
+    assert tile == (512 if name == "deepseek-v3.2-exp" else F)
+    names = ("w_up", "w_down") + ("w_gate",) * gated
+
+    def layer(x, weight, order, n, *matrices):
+        return moe_decode_pallas.moe_decode_pallas(
+            x, weight, dict(zip(names, matrices)), order, n)
+
+    compiled = compile_for(
+        one_chip, layer, ((rows, D), bf16), ((rows, held), f32),
+        ((held,), i32), ((1,), i32), ((held, D, F), bf16),
+        ((held, F, D), bf16), *((((held, D, F), bf16),) * gated))
+    assert len(re.findall(r"%(moe_decode_pallas)\S* = .*tpu_custom_call",
+                          compiled.as_text())) == 1
 
 
 def test_flash_kernel_compiles_at_two_kv_heads(one_chip):
